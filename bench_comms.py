@@ -1,7 +1,7 @@
 """Gradient-communication bench: the RL update's allreduce ladder.
 
-Round-5 put the RL update at bw_util 0.451 / MFU 0.199 (BENCH_r05.json) —
-bandwidth-bound, and its allreduce was spelled one psum per parameter
+Round-5 put the RL update at bw_util 0.451 / MFU 0.199 (round-5 record,
+removed in PR 21; code older than PRs 1–20) — bandwidth-bound, and its allreduce was spelled one psum per parameter
 leaf. This bench isolates that update program and measures the
 parallel/comms.py ladder against it on a data mesh over every visible
 device:
@@ -67,7 +67,8 @@ MAX_LEN = 30
 K_ROLLOUTS = 4  # divisible by the overlapped rung's 2 chunks
 VOCAB = 9000
 
-# round-5 update baseline on TPU v5 lite (BENCH_r05.json programs.update)
+# round-5 update baseline on TPU v5 lite (round-5 record, removed in PR 21:
+# programs.update)
 R05_UPDATE = {"seconds_per_step": 0.7, "mfu": 0.199, "bw_util": 0.451,
               "device_kind": "TPU v5 lite", "batch": 1792}
 
@@ -169,7 +170,10 @@ def main() -> None:
         ("overlapped_eager_ref", CommConfig(overlap="eager"), chunks),
     )
 
-    peak = peak_flops(kind)
+    try:
+        peak = peak_flops(kind)
+    except KeyError:
+        peak = None  # no published peak (the CPU smoke): MFU not measured
     results: dict[str, dict] = {}
     updated: dict[str, object] = {}
     for name, comm, n_chunks in rungs:
@@ -218,7 +222,7 @@ def main() -> None:
             "compiled_flops": cost["flops"] if cost else None,
             "mfu": (
                 round(cost["flops"] / sec / peak / max(n_chips, 1), 4)
-                if cost else None
+                if cost and peak else None
             ),
         }
         print(f"bench_comms: {name} {sec * 1e3:.1f}ms/step "

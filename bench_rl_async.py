@@ -1,7 +1,8 @@
 """Decoupled actor/learner SCST bench: the async rollout ladder.
 
 Round-5 ledgered the synchronous SCST loop at 3629 clips/s/chip
-(BENCH_r05.json, TPU v5 lite) with the decode claiming 0.851 of the
+(round-5 record, removed in PR 21; TPU v5 lite, code older than PRs 1–20)
+with the decode claiming 0.851 of the
 sequential time — the learner chips idle behind the rollout. The
 decoupled topology (rl/async_scst.py, ``train.rl_topology="decoupled"``)
 splits the data mesh into actor and learner submeshes so decode and
@@ -62,7 +63,7 @@ MAX_LEN = 30
 K_ROLLOUTS = 5
 VOCAB = 9000
 
-# round-5 synchronous loop on TPU v5 lite (BENCH_r05.json)
+# round-5 synchronous loop on TPU v5 lite (round-5 record, removed in PR 21)
 R05_RL = {"clips_per_s_per_chip": 3629.42, "device_kind": "TPU v5 lite",
           "batch": 1792, "rollouts": 5}
 

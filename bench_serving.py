@@ -488,12 +488,15 @@ def main() -> None:
         frames, d_embed, d_hidden, d_att, vocab_n, feat_dims, 1
     )
     analytic_stride = capacity * (1 + K) * stride * per_tok
-    peak = peak_flops(kind)
+    try:
+        peak = peak_flops(kind)
+    except KeyError:
+        peak = None  # no published peak (the CPU smoke): MFU not measured
     cont_p = traces_out["poisson"]["continuous"]
     mfu_flops = (stride_cost or {}).get("flops", analytic_stride)
     serving_mfu = (
         cont_p["strides"] * mfu_flops / cont_p["makespan_s"] / peak
-        if cont_p["makespan_s"] else 0.0
+        if cont_p["makespan_s"] and peak else None
     )
 
     beats = {
@@ -576,7 +579,9 @@ def main() -> None:
             "per_stride_hlo": (stride_cost or {}).get("flops"),
             "per_stride_analytic": round(analytic_stride),
             "backend": "xla_hlo" if stride_cost else "analytic",
-            "serving_decode_mfu_poisson": round(serving_mfu, 8),
+            "serving_decode_mfu_poisson": (
+                None if serving_mfu is None else round(serving_mfu, 8)
+            ),
             "assumed_peak_bf16_flops": peak,
         },
         "paged": {

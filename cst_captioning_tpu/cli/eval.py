@@ -18,6 +18,7 @@ from cst_captioning_tpu.models import CaptionModel
 from cst_captioning_tpu.train.mesh import make_mesh, replicate
 from cst_captioning_tpu.train.steps import batch_arrays
 from cst_captioning_tpu.data.batcher import Batcher
+from cst_captioning_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv: list[str] | None = None) -> None:
@@ -28,6 +29,7 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument("--split", default="")
     p.add_argument("--results-json", default="results.json")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     from cst_captioning_tpu import obs
     from cst_captioning_tpu.train import multihost

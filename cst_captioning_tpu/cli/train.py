@@ -17,6 +17,7 @@ import argparse
 from cst_captioning_tpu.cli.common import add_common_args, load_config, open_dataset
 from cst_captioning_tpu.train import multihost
 from cst_captioning_tpu.train.trainer import Trainer
+from cst_captioning_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv: list[str] | None = None) -> None:
@@ -24,6 +25,7 @@ def main(argv: list[str] | None = None) -> None:
     add_common_args(p)
     p.add_argument("--skip-xe", action="store_true", help="run only the RL phase")
     args = p.parse_args(argv)
+    enable_compile_cache()
     # multi-host: no-op unless JAX_COORDINATOR_ADDRESS etc. are set
     multihost.initialize()
     if args.log_jsonl and multihost.is_multiprocess():

@@ -5,7 +5,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from cst_captioning_tpu.compat import pcast, vma_of
 from cst_captioning_tpu.config.config import BOS_ID, EOS_ID, PAD_ID
 
 
@@ -151,10 +150,10 @@ def pcast_varying(tree, axes: tuple[str, ...]):
         return tree
 
     def cast(x):
-        vma = vma_of(x)
+        vma = jax.typeof(x).vma
         for a in axes:
             if a not in vma:
-                x = pcast(x, a, to="varying")
+                x = jax.lax.pcast(x, a, to="varying")
         return x
 
     return jax.tree.map(cast, tree)

@@ -6,6 +6,6 @@ the RL reward host path, per the SURVEY's design note: "implement a small C++
 extension … with a pure-numpy fallback".
 """
 
-from cst_captioning_tpu.native.build import load_creward
+from cst_captioning_tpu.native.build import load_creward, load_error
 
-__all__ = ["load_creward"]
+__all__ = ["load_creward", "load_error"]

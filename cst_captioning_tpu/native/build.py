@@ -2,7 +2,9 @@
 
 Compiles ``creward.cpp`` with g++ on first use into the package directory and
 memoizes the handle. Every failure path (no compiler, compile error, load
-error) returns None so callers fall back to the pure-Python scorer.
+error) returns None so callers fall back to the pure-Python scorer, and
+leaves the reason in :func:`load_error` so the fallback can be named rather
+than taken silently (the trainer logs it; ``chip_smoke.py`` fails on it).
 
 The binary name embeds a hash of the source (``libcreward-<sha>.so``), so a
 stale prebuilt library can never shadow newer source — git clones don't
@@ -24,6 +26,7 @@ _SRC = os.path.join(_DIR, "creward.cpp")
 
 _lock = threading.Lock()
 _cached: "ctypes.CDLL | None | bool" = False  # False = not attempted yet
+_error = ""  # why the last load_creward() returned None
 
 
 def _lib_path() -> str:
@@ -32,7 +35,8 @@ def _lib_path() -> str:
     return os.path.join(_DIR, f"libcreward-{digest}.so")
 
 
-def _compile(lib_path: str) -> bool:
+def _compile(lib_path: str) -> str:
+    """Build the library; "" on success, else the reason it failed."""
     tmp = f"{lib_path}.{os.getpid()}.tmp"  # per-process: builders can't collide
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
@@ -41,7 +45,8 @@ def _compile(lib_path: str) -> bool:
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
         if proc.returncode != 0:
-            return False
+            tail = proc.stderr.decode(errors="replace").strip()[-400:]
+            return f"g++ exited {proc.returncode}: {tail}"
         # sweep dead binaries from previous source revisions
         for old in os.listdir(_DIR):
             if old.startswith("libcreward-") and old.endswith(".so"):
@@ -51,9 +56,9 @@ def _compile(lib_path: str) -> bool:
                     except OSError:
                         pass
         os.replace(tmp, lib_path)  # atomic publish
-        return True
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        return ""
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"g++ build failed: {e!r}"
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -76,7 +81,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def load_creward() -> "ctypes.CDLL | None":
     """Load (building if needed) the reward kernel; None -> use Python path."""
-    global _cached
+    global _cached, _error
     with _lock:
         if _cached is not False:
             return _cached
@@ -84,11 +89,16 @@ def load_creward() -> "ctypes.CDLL | None":
         try:
             path = _lib_path()
             if not os.path.exists(path):
-                if not _compile(path):
-                    _cached = None
-                    return None
-            lib = _bind(ctypes.CDLL(path))
-        except OSError:
-            lib = None
+                _error = _compile(path)
+            if not _error:
+                lib = _bind(ctypes.CDLL(path))
+        except OSError as e:
+            _error = f"loading the built library failed: {e!r}"
         _cached = lib
         return lib
+
+
+def load_error() -> str:
+    """Why :func:`load_creward` returned None ("" when it loaded, or was
+    never asked)."""
+    return _error

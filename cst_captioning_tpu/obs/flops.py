@@ -21,39 +21,45 @@ from __future__ import annotations
 
 # peak dense bf16 FLOP/s and HBM bandwidth per chip by device kind (public
 # TPU specs); the match is substring-based and callers carry the assumed
-# values in their JSON so they cannot be misread as measured
+# values in their JSON so they cannot be misread as measured. A kind that is
+# not in the table has no peak: a utilization against a guessed peak is a
+# number about nothing, so the lookups raise instead of defaulting
 PEAK_BF16_FLOPS = (
     ("v6e", 918e12), ("v6 lite", 918e12),
     ("v5p", 459e12),
     ("v5e", 197e12), ("v5 lite", 197e12), ("v5litepod", 197e12),
     ("v4", 275e12),
 )
-DEFAULT_PEAK = 197e12
 PEAK_HBM_BYTES = (
     ("v6e", 1640e9), ("v6 lite", 1640e9),
     ("v5p", 2765e9),
     ("v5e", 819e9), ("v5 lite", 819e9), ("v5litepod", 819e9),
     ("v4", 1228e9),
 )
-DEFAULT_PEAK_HBM = 819e9
+
+
+def _peak(table, device_kind: str) -> float:
+    kind = device_kind.lower()
+    for frag, peak in table:
+        if frag in kind:
+            return peak
+    raise KeyError(
+        f"no published peak for device kind {device_kind!r} — utilization "
+        "against it is not measured (add the chip to obs/flops.py with its "
+        "source to measure it)"
+    )
 
 
 def peak_flops(device_kind: str) -> float:
-    """Assumed peak dense bf16 FLOP/s for a ``device_kind`` string."""
-    kind = device_kind.lower()
-    for frag, peak in PEAK_BF16_FLOPS:
-        if frag in kind:
-            return peak
-    return DEFAULT_PEAK
+    """Published peak dense bf16 FLOP/s for a ``device_kind`` string;
+    raises ``KeyError`` for a kind the table does not hold (e.g. "cpu")."""
+    return _peak(PEAK_BF16_FLOPS, device_kind)
 
 
 def peak_hbm(device_kind: str) -> float:
-    """Assumed peak HBM bytes/s for a ``device_kind`` string."""
-    kind = device_kind.lower()
-    for frag, peak in PEAK_HBM_BYTES:
-        if frag in kind:
-            return peak
-    return DEFAULT_PEAK_HBM
+    """Published peak HBM bytes/s for a ``device_kind`` string; raises
+    ``KeyError`` for a kind the table does not hold."""
+    return _peak(PEAK_HBM_BYTES, device_kind)
 
 
 def enc_and_per_tok_flops(

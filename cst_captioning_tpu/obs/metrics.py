@@ -292,20 +292,15 @@ def install_compile_listener(registry: Registry | None = None) -> bool:
     """Feed ``jit.compiles`` / ``jit.compile_seconds`` from jax.monitoring.
 
     Registers a duration listener for the ``/jax/core/compile/*`` events jax
-    records around tracing/lowering/backend-compile. Idempotent; returns
-    False when the monitoring API is missing (older/stripped jax) — the
-    metrics then simply stay absent, nothing breaks.
+    records around tracing/lowering/backend-compile. Idempotent (returns
+    True). jax is imported here, not at module top: ``cli.obs_report`` reads
+    this module on boxes without jax.
     """
     global _COMPILE_LISTENER_INSTALLED
     if _COMPILE_LISTENER_INSTALLED:
         return True
     reg = registry or REGISTRY
-    try:
-        from jax import monitoring
-    except Exception:
-        return False
-    if not hasattr(monitoring, "register_event_duration_secs_listener"):
-        return False
+    from jax import monitoring
 
     def _on_duration(event: str, duration: float, **_kw) -> None:
         if "/compile/" not in event and not event.endswith("compile_time_sec"):

@@ -42,9 +42,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from cst_captioning_tpu.compat import vma_of
-
 NEG = -1.0e9
+
+
+def _vma(*xs) -> frozenset:
+    """Mesh axes any input is typed as varying over (shard_map's checker)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _reference(q, v, memory, memory_proj, mask):
@@ -125,14 +128,9 @@ def _fused_forward(q, v, memory, memory_proj, mask,
     # inside a shard_map with the varying-axis check on (the DP train step),
     # the output's vma must be declared: it varies over every axis any
     # input varies over
-    vma = frozenset()
-    for x in (q, memory, memory_proj, mask):
-        vma = vma | vma_of(x)
-    if vma:
-        out_shape = jax.ShapeDtypeStruct((Bp, E), memory.dtype, vma=vma)
-    else:
-        # also the 0.4.x path, whose ShapeDtypeStruct has no vma parameter
-        out_shape = jax.ShapeDtypeStruct((Bp, E), memory.dtype)
+    out_shape = jax.ShapeDtypeStruct(
+        (Bp, E), memory.dtype, vma=_vma(q, memory, memory_proj, mask)
+    )
 
     grid = (Bp // block_b, Mp // block_m)
     out = pl.pallas_call(
@@ -176,9 +174,7 @@ def fused_additive_attention(q, v, memory, memory_proj, mask,
     structure (see module docstring); gradients recompute via the composite.
     """
     interpret = jax.default_backend() != "tpu"
-    if interpret and any(
-        vma_of(x) for x in (q, memory, memory_proj, mask)
-    ):
+    if interpret and _vma(q, memory, memory_proj, mask):
         # Pallas INTERPRET mode can't execute under a varying-axis-checked
         # shard_map (the interpreter's loop constants are axis-invariant and
         # trip the vma check) — fall back to the composite there. Only the

@@ -64,11 +64,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from cst_captioning_tpu.compat import vma_of
 from cst_captioning_tpu.config.config import BOS_ID, EOS_ID, PAD_ID
 from cst_captioning_tpu.models.decoder import LSTM_GATE_ORDER
 
 NEG = -1.0e9
+
+
+def _vma(*trees) -> frozenset:
+    """Mesh axes any leaf is typed as varying over (the shard_map checker's
+    view) — a kernel's outputs vary over every axis any input does."""
+    out = frozenset()
+    for x in jax.tree.leaves(trees):
+        out |= jax.typeof(x).vma
+    return out
 
 
 def _num_layers(cell_params) -> int:
@@ -238,6 +246,33 @@ def _pad_to(x, axis, mult, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+# rows per (8, 128) f32 vreg tile: Mosaic takes a block's second-to-last
+# dim only in whole tiles (or the whole array)
+_SUBLANES = 8
+_LANES = 128
+
+# Mosaic's default scoped-VMEM budget (16 MiB on a v5e) is below what the
+# weight-resident kernels hold at the paper's widths: the f32 LSTM gate
+# matrices alone are 12 MiB at d=512, and the compiler asks ~30 MiB for the
+# stride kernel at block_b=32, block_v=1024, V=9000. Half of the core's
+# 128 MiB leaves room for the wider-than-paper models too; a kernel that
+# outgrows it is refused at compile time, never silently spilled.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _batch_block(block_b: int, B: int, interpret: bool) -> int:
+    """The batch block the grid runs: the requested one clamped to ``B``
+    and, when Mosaic compiles it, rounded up to whole sublane tiles — the
+    smallest block >= the request the chip accepts (batch rows pad up to
+    it; padded rows are born finished). Interpret mode has no tiling and
+    keeps the request exactly, so the CPU parity tests still pin the
+    per-row ``block_b=1`` layout bit for bit."""
+    block_b = max(1, min(block_b, B))
+    if interpret:
+        return block_b
+    return -(-block_b // _SUBLANES) * _SUBLANES
+
+
 def _fused_call(cell_params, carry, emb, memory, memory_proj, memory_mask,
                 block_b: int, block_v: int, interpret: bool):
     L = _num_layers(cell_params)
@@ -250,7 +285,7 @@ def _fused_call(cell_params, carry, emb, memory, memory_proj, memory_mask,
     bo = cell_params["out_proj"]["bias"][None, :]
     V = wo.shape[-1]
 
-    block_b = min(block_b, B) if B else block_b
+    block_b = _batch_block(block_b, B, interpret)
     Bp = -(-B // block_b) * block_b
     block_v = min(block_v, -(-V // 128) * 128 if V > 128 else V)
     Vp = -(-V // block_v) * block_v
@@ -314,14 +349,10 @@ def _fused_call(cell_params, carry, emb, memory, memory_proj, memory_mask,
     args += [wop, bop]
 
     # inside a varying-axis-checked shard_map the outputs' vma must be
-    # declared (same recipe as ops/attention_pallas.py); 0.4.x has no vma
-    # parameter on ShapeDtypeStruct
-    vma = frozenset()
-    for x in (emb, memory, memory_proj, memory_mask, *jax.tree.leaves(carry)):
-        vma = vma | vma_of(x)
-    sds = (
-        (lambda s, d: jax.ShapeDtypeStruct(s, d, vma=vma)) if vma
-        else jax.ShapeDtypeStruct
+    # declared (same recipe as ops/attention_pallas.py)
+    sds = functools.partial(
+        jax.ShapeDtypeStruct,
+        vma=_vma(emb, memory, memory_proj, memory_mask, carry),
     )
     out_shape = [sds((G, Bp, Vp), jnp.float32)]
     out_specs = [
@@ -344,6 +375,7 @@ def _fused_call(cell_params, carry, emb, memory, memory_proj, memory_mask,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_b, H), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     logits = outs[0][:, :B, :V]
@@ -384,11 +416,8 @@ def fused_decode_step(cell_params, carry, token, memory, memory_proj,
     # cell_params join the check: under the vocab-sharded shard_map
     # (ops/decode_mp.py) the activations are all invariant (emb arrives
     # psum-merged) but out_proj/word_embed vary over 'mp'
-    if interpret and any(
-        vma_of(x)
-        for x in (emb, memory, memory_proj, memory_mask,
-                  *jax.tree.leaves(carry),
-                  *jax.tree.leaves(cell_params))
+    if interpret and _vma(
+        emb, memory, memory_proj, memory_mask, carry, cell_params
     ):
         # Pallas interpret mode can't run under a varying-axis-checked
         # shard_map — fall back to the composite (CPU tests only; compiled
@@ -476,7 +505,7 @@ def _stride_kernel(*refs, num_layers: int, m_true: int, V: int, S: int,
     def _():
         # per-(i, g) stride state lives in scratch; (re)seed it here
         embc_scr[:] = emb0_ref[0].astype(jnp.float32)
-        fin_scr[:] = fin0_ref[0][:, None]
+        fin_scr[:] = fin0_ref[0]
         for layer in range(L):
             cs[layer][0][:] = carry_refs[layer][0][0].astype(jnp.float32)
             cs[layer][1][:] = carry_refs[layer][1][0].astype(jnp.float32)
@@ -502,7 +531,13 @@ def _stride_kernel(*refs, num_layers: int, m_true: int, V: int, S: int,
         )
         t = jnp.tanh(proj_ref[:].astype(jnp.float32) + q[:, None, :])
         sc = jnp.sum(t * v_ref[0].astype(jnp.float32)[None, None, :], axis=-1)
-        sc = jnp.where(mask_ref[:] > 0, sc, NEG)
+        mask = mask_ref[:]
+        if mask.ndim == 3:
+            # the paged slab keeps slots on sublanes with the value
+            # repeated across a lane tile (see _paged_stride_call); the
+            # lane reduce moves them to lanes like the score reduce above
+            mask = jnp.max(mask, axis=-1)
+        sc = jnp.where(mask > 0, sc, NEG)
         # per-ROW raggedness: each row's memory columns past ITS length
         # leave the softmax entirely (serving's paged gathers are ragged
         # per request; exp underflow makes the exclusion bit-exact vs the
@@ -598,8 +633,8 @@ def _stride_kernel(*refs, num_layers: int, m_true: int, V: int, S: int,
         tok = jnp.where(fin, jnp.int32(PAD_ID), bi_scr[:])
         lse = lm_scr[:] + jnp.log(ls_scr[:])
         lp = jnp.where(fin, 0.0, sl_scr[:] - lse)
-        tok_ref[0, 0] = tok[:, 0]
-        lp_ref[0, 0] = lp[:, 0]
+        tok_ref[0, 0] = tok
+        lp_ref[0, 0] = lp
         fin_scr[:] = jnp.logical_or(fin, tok == EOS_ID).astype(jnp.int32)
         embc_scr[:] = jnp.where(fin, pade_scr[:], embn_scr[:])
 
@@ -614,9 +649,9 @@ def _stride_kernel(*refs, num_layers: int, m_true: int, V: int, S: int,
     # carry passthrough — no attention/LSTM/projection/selection work
     @pl.when(jnp.logical_not(active) & last_vb)
     def _():
-        tok_ref[0, 0] = jnp.full((bb,), PAD_ID, jnp.int32)
+        tok_ref[0, 0] = jnp.full((bb, 1), PAD_ID, jnp.int32)
         # frozen-row logprobs are f32 by the output contract
-        lp_ref[0, 0] = jnp.zeros((bb,), jnp.float32)  # graftlint: disable=GL005
+        lp_ref[0, 0] = jnp.zeros((bb, 1), jnp.float32)  # graftlint: disable=GL005
 
     @pl.when(jnp.logical_not(active) & (s == S - 1) & last_vb)
     def _():
@@ -677,7 +712,7 @@ def _stride_call(cell_params, carry, emb0, finished, memory, memory_proj,
     embt = jnp.asarray(cell_params["word_embed"]["embedding"])
     V = wo.shape[-1]
 
-    block_b = min(block_b, B) if B else block_b
+    block_b = _batch_block(block_b, B, interpret)
     Bp = -(-B // block_b) * block_b
     block_v = min(block_v, -(-V // 128) * 128 if V > 128 else V)
     Vp = -(-V // block_v) * block_v
@@ -685,7 +720,7 @@ def _stride_call(cell_params, carry, emb0, finished, memory, memory_proj,
 
     emb0p = _pad_to(emb0, 1, block_b)
     # padded rows are born finished: their outputs freeze to PAD/0
-    fin0p = _pad_to(finished.astype(jnp.int32), 1, block_b, value=1)
+    fin0p = _pad_to(finished.astype(jnp.int32), 1, block_b, value=1)[..., None]
     # per-row memory lengths (serving's ragged paged gathers); uniform M
     # when the caller passes none. Clamped to >= 1 so a zero-length row
     # (unoccupied serving lane, padding) keeps a finite softmax — its
@@ -724,7 +759,7 @@ def _stride_call(cell_params, carry, emb0, finished, memory, memory_proj,
     in_specs += [
         pl.BlockSpec((1, block_b, E), lambda i, g, s, vb: (g, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_b), lambda i, g, s, vb: (g, i),
+        pl.BlockSpec((1, block_b, 1), lambda i, g, s, vb: (g, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((block_b, 1), lambda i, g, s, vb: (i, 0),
                      memory_space=pltpu.VMEM),
@@ -772,19 +807,18 @@ def _stride_call(cell_params, carry, emb0, finished, memory, memory_proj,
     ]
     args += [wop, bop, embtp, noisep]
 
-    vma = frozenset()
-    for x in (emb0, memory, memory_proj, memory_mask, finished, noise,
-              *jax.tree.leaves(carry)):
-        vma = vma | vma_of(x)
-    sds = (
-        (lambda sh, d: jax.ShapeDtypeStruct(sh, d, vma=vma)) if vma
-        else jax.ShapeDtypeStruct
+    sds = functools.partial(
+        jax.ShapeDtypeStruct,
+        vma=_vma(
+            emb0, memory, memory_proj, memory_mask, finished, noise, carry
+        ),
     )
-    out_shape = [sds((S, G, Bp), jnp.int32), sds((S, G, Bp), jnp.float32)]
+    out_shape = [sds((S, G, Bp, 1), jnp.int32),
+                 sds((S, G, Bp, 1), jnp.float32)]
     out_specs = [
-        pl.BlockSpec((1, 1, block_b), lambda i, g, s, vb: (s, g, i),
+        pl.BlockSpec((1, 1, block_b, 1), lambda i, g, s, vb: (s, g, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_b), lambda i, g, s, vb: (s, g, i),
+        pl.BlockSpec((1, 1, block_b, 1), lambda i, g, s, vb: (s, g, i, 0),
                      memory_space=pltpu.VMEM),
     ]
     for c, h in carry:
@@ -824,10 +858,11 @@ def _stride_call(cell_params, carry, emb0, finished, memory, memory_proj,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
-    tokens = outs[0][:, :, :B]
-    lps = outs[1][:, :, :B]
+    tokens = outs[0][:, :, :B, 0]
+    lps = outs[1][:, :, :B, 0]
     flat = outs[2:]
     new_carry = tuple(
         (flat[2 * layer][:, :B], flat[2 * layer + 1][:, :B])
@@ -880,10 +915,8 @@ def fused_decode_stride(cell_params, carry, token, finished, memory,
     if n_active is None:
         n_active = B
     interpret = jax.default_backend() != "tpu"
-    if interpret and any(
-        vma_of(x)
-        for x in (memory, memory_proj, memory_mask, finished, noise,
-                  *jax.tree.leaves(carry))
+    if interpret and _vma(
+        memory, memory_proj, memory_mask, finished, noise, carry
     ):
         # Pallas interpret mode can't run under a varying-axis-checked
         # shard_map — the composite carries it (CPU tests only)
@@ -977,7 +1010,9 @@ def _paged_stride_kernel(*refs, num_layers: int, page_size: int,
             slab_proj[:, tail, :] = jnp.zeros(
                 (bb, pad_m, slab_proj.shape[2]), slab_proj.dtype
             )
-            slab_mask[:, tail] = jnp.zeros((bb, pad_m), slab_mask.dtype)
+            slab_mask[:, tail, :] = jnp.zeros(
+                (bb, pad_m, slab_mask.shape[2]), slab_mask.dtype
+            )
         copies = []
         for r in range(bb):
             for p in range(table_width):
@@ -990,7 +1025,7 @@ def _paged_stride_kernel(*refs, num_layers: int, page_size: int,
                     proj_hbm.at[pg], slab_proj.at[r, dst, :], dma_sem
                 ))
                 copies.append(pltpu.make_async_copy(
-                    mask_hbm.at[pg], slab_mask.at[r, dst], dma_sem
+                    mask_hbm.at[pg], slab_mask.at[r, dst, :], dma_sem
                 ))
         # start ALL page fetches before waiting on any: the DMA engine
         # overlaps them; program order only pins issue order
@@ -1043,14 +1078,14 @@ def _paged_stride_call(cell_params, carry, emb0, finished, mem_pool,
     embt = jnp.asarray(cell_params["word_embed"]["embedding"])
     V = wo.shape[-1]
 
-    block_b = min(block_b, B) if B else block_b
+    block_b = _batch_block(block_b, B, interpret)
     Bp = -(-B // block_b) * block_b
     block_v = min(block_v, -(-V // 128) * 128 if V > 128 else V)
     Vp = -(-V // block_v) * block_v
     Wp = -(-W // 128) * 128 if not interpret else W
 
     emb0p = _pad_to(emb0, 1, block_b)
-    fin0p = _pad_to(finished.astype(jnp.int32), 1, block_b, value=1)
+    fin0p = _pad_to(finished.astype(jnp.int32), 1, block_b, value=1)[..., None]
     if mem_lens is None:
         mem_lens = jnp.full((B,), W, jnp.int32)
     lensp = _pad_to(
@@ -1086,7 +1121,7 @@ def _paged_stride_call(cell_params, carry, emb0, finished, mem_pool,
     in_specs += [
         pl.BlockSpec((1, block_b, E), lambda i, g, s, vb, tbl: (g, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_b), lambda i, g, s, vb, tbl: (g, i),
+        pl.BlockSpec((1, block_b, 1), lambda i, g, s, vb, tbl: (g, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((block_b, 1), lambda i, g, s, vb, tbl: (i, 0),
                      memory_space=pltpu.VMEM),
@@ -1102,13 +1137,20 @@ def _paged_stride_call(cell_params, carry, emb0, finished, mem_pool,
             args.append(arr)
     in_specs += [
         # the pools stay whole in HBM; the kernel DMAs pages out by table id
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec((H, A), const, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, A), const, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, A), const, memory_space=pltpu.VMEM),
     ]
+    # the mask pool rides lane-broadcast as [N+1, P, 128]: a page is then
+    # P whole sublane rows of whole lane tiles in all three pools, the only
+    # granule Mosaic's HBM->VMEM page DMA slices (a [1, P] lane slice of
+    # the 2-D pool, or a 1-lane column, is refused off 128-alignment)
+    mask_pool = jnp.broadcast_to(
+        mask_pool[..., None], mask_pool.shape + (_LANES,)
+    )
     args += [mem_pool, proj_pool, mask_pool, wq, bq, vs]
     for layer in range(L):
         wi, wh, b = _gate_weights(cell_params[f"lstm{layer}"])
@@ -1134,19 +1176,21 @@ def _paged_stride_call(cell_params, carry, emb0, finished, mem_pool,
     ]
     args += [wop, bop, embtp, noisep]
 
-    vma = frozenset()
-    for x in (emb0, mem_pool, proj_pool, mask_pool, table, finished, noise,
-              *jax.tree.leaves(carry)):
-        vma = vma | vma_of(x)
-    sds = (
-        (lambda sh, d: jax.ShapeDtypeStruct(sh, d, vma=vma)) if vma
-        else jax.ShapeDtypeStruct
+    sds = functools.partial(
+        jax.ShapeDtypeStruct,
+        vma=_vma(
+            emb0, mem_pool, proj_pool, mask_pool, table, finished, noise,
+            carry,
+        ),
     )
-    out_shape = [sds((S, G, Bp), jnp.int32), sds((S, G, Bp), jnp.float32)]
+    out_shape = [sds((S, G, Bp, 1), jnp.int32),
+                 sds((S, G, Bp, 1), jnp.float32)]
     out_specs = [
-        pl.BlockSpec((1, 1, block_b), lambda i, g, s, vb, tbl: (s, g, i),
+        pl.BlockSpec((1, 1, block_b, 1),
+                     lambda i, g, s, vb, tbl: (s, g, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_b), lambda i, g, s, vb, tbl: (s, g, i),
+        pl.BlockSpec((1, 1, block_b, 1),
+                     lambda i, g, s, vb, tbl: (s, g, i, 0),
                      memory_space=pltpu.VMEM),
     ]
     for c, h in carry:
@@ -1163,7 +1207,7 @@ def _paged_stride_call(cell_params, carry, emb0, finished, mem_pool,
         # gathers without a cast, so the slab must hold the same bytes)
         pltpu.VMEM((block_b, Wp, Em), mem_pool.dtype),
         pltpu.VMEM((block_b, Wp, A), proj_pool.dtype),
-        pltpu.VMEM((block_b, Wp), mask_pool.dtype),
+        pltpu.VMEM((block_b, Wp, _LANES), mask_pool.dtype),
         pltpu.SemaphoreType.DMA,
         # the dense kernel's own scratch, unchanged
         pltpu.VMEM((block_b, H), jnp.float32),    # x_stash
@@ -1199,10 +1243,11 @@ def _paged_stride_call(cell_params, carry, emb0, finished, mem_pool,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(tablep, *args)
-    tokens = outs[0][:, :, :B]
-    lps = outs[1][:, :, :B]
+    tokens = outs[0][:, :, :B, 0]
+    lps = outs[1][:, :, :B, 0]
     flat = outs[2:]
     new_carry = tuple(
         (flat[2 * layer][:, :B], flat[2 * layer + 1][:, :B])
@@ -1264,10 +1309,16 @@ def fused_decode_stride_paged(cell_params, carry, token, finished,
     if n_active is None:
         n_active = B
     interpret = jax.default_backend() != "tpu"
-    if interpret and any(
-        vma_of(x)
-        for x in (mem_pool, proj_pool, mask_pool, page_table, finished,
-                  noise, *jax.tree.leaves(carry))
+    if not interpret and mem_pool.shape[1] % _SUBLANES:
+        # Mosaic: "Slice shape along dimension 1 must be aligned to tiling
+        # (8)" — a page is DMA'd as whole sublane rows of its pool
+        raise ValueError(
+            f"fused_decode_stride_paged on TPU needs page_size % "
+            f"{_SUBLANES} == 0 (pages are DMA'd as whole sublane tiles); "
+            f"got page_size={mem_pool.shape[1]}"
+        )
+    if interpret and _vma(
+        mem_pool, proj_pool, mask_pool, page_table, finished, noise, carry
     ):
         # Pallas interpret mode can't run under a varying-axis-checked
         # shard_map — gather the dense bank and run the composite (CPU
@@ -1429,8 +1480,8 @@ def _beam_kernel(*refs, num_layers: int, m_true: int, V: int, W: int,
         # score their survivors in the row_logprobs association, finished
         # lanes emit the closed-form PAD continuation row's top-W
         lse = lm_scr[:] + jnp.log(ls_scr[:])            # [bb, 1]
-        fin = fin_ref[0][:, None] > 0                   # [bb, 1]
-        sc = sc_ref[0][:, None]                         # [bb, 1]
+        fin = fin_ref[0] > 0                            # [bb, 1]
+        sc = sc_ref[0]                                  # [bb, 1]
         wio = jax.lax.broadcasted_iota(jnp.int32, (bb, W), 1)
         live_tot = sc + (val_scr[:] - lse)
         live_flat = g * V + idx_scr[:]
@@ -1510,7 +1561,7 @@ def _beam_call(cell_params, carry, emb, finished, scores, memory,
     bo = cell_params["out_proj"]["bias"][None, :]
     V = wo.shape[-1]
 
-    block_b = min(block_b, B) if B else block_b
+    block_b = _batch_block(block_b, B, interpret)
     Bp = -(-B // block_b) * block_b
     block_v = min(block_v, -(-V // 128) * 128 if V > 128 else V)
     Vp = -(-V // block_v) * block_v
@@ -1520,8 +1571,8 @@ def _beam_call(cell_params, carry, emb, finished, scores, memory,
     # padded rows are born finished with score 0 — their candidate rows are
     # sliced off below, never merged into a real row's top-W (the merge is
     # per batch row)
-    finp = _pad_to(finished.astype(jnp.int32), 1, block_b, value=1)
-    scp = _pad_to(scores.astype(jnp.float32), 1, block_b)
+    finp = _pad_to(finished.astype(jnp.int32), 1, block_b, value=1)[..., None]
+    scp = _pad_to(scores.astype(jnp.float32), 1, block_b)[..., None]
     carryp = [
         (_pad_to(c, 1, block_b), _pad_to(h, 1, block_b)) for c, h in carry
     ]
@@ -1544,9 +1595,9 @@ def _beam_call(cell_params, carry, emb, finished, scores, memory,
     in_specs += [
         pl.BlockSpec((1, block_b, E), lambda i, g, vb: (g, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_b), lambda i, g, vb: (g, i),
+        pl.BlockSpec((1, block_b, 1), lambda i, g, vb: (g, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_b), lambda i, g, vb: (g, i),
+        pl.BlockSpec((1, block_b, 1), lambda i, g, vb: (g, i, 0),
                      memory_space=pltpu.VMEM),
     ]
     args += [embp, finp, scp]
@@ -1585,13 +1636,11 @@ def _beam_call(cell_params, carry, emb, finished, scores, memory,
     ]
     args += [wop, bop]
 
-    vma = frozenset()
-    for x in (emb, memory, memory_proj, memory_mask, finished, scores,
-              *jax.tree.leaves(carry)):
-        vma = vma | vma_of(x)
-    sds = (
-        (lambda sh, d: jax.ShapeDtypeStruct(sh, d, vma=vma)) if vma
-        else jax.ShapeDtypeStruct
+    sds = functools.partial(
+        jax.ShapeDtypeStruct,
+        vma=_vma(
+            emb, memory, memory_proj, memory_mask, finished, scores, carry
+        ),
     )
     W = G
     out_shape = [sds((Bp, W), jnp.float32), sds((Bp, W), jnp.int32)]
@@ -1628,6 +1677,7 @@ def _beam_call(cell_params, carry, emb, finished, scores, memory,
             pltpu.VMEM((block_b, W), jnp.float32),    # cross-lane totals
             pltpu.VMEM((block_b, W), jnp.int32),      # cross-lane flat ids
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     top_scores = outs[0][:B]
@@ -1671,10 +1721,8 @@ def fused_beam_step(cell_params, carry, token, finished, scores, memory,
             f"lane's candidate list; got W={W} > V={V}"
         )
     interpret = jax.default_backend() != "tpu"
-    if interpret and any(
-        vma_of(x)
-        for x in (memory, memory_proj, memory_mask, finished, scores,
-                  *jax.tree.leaves(carry))
+    if interpret and _vma(
+        memory, memory_proj, memory_mask, finished, scores, carry
     ):
         # Pallas interpret mode can't run under a varying-axis-checked
         # shard_map — the composite carries it (CPU tests only)

@@ -1,7 +1,8 @@
 """Gradient communication: bucketed / compressed / overlapped allreduce.
 
-The RL update is the bandwidth-bound program of the step (BENCH_r05:
-bw_util 0.45 at MFU 0.20) and its allreduce is spelled per-leaf — one
+The RL update is the bandwidth-bound program of the step (round-5 record,
+removed in PR 21: bw_util 0.45 at MFU 0.20, on code older than PRs 1–20)
+and its allreduce is spelled per-leaf — one
 ``psum`` per parameter array, dozens of small messages per update. This
 module centralizes the cross-device gradient reduction behind one knob
 surface (``train.comm_*``), applying the *Densifying Assumed-sparse
@@ -233,6 +234,27 @@ def _observe_plan(plan: BucketPlan) -> None:
     hist = obs.histogram("comm.bucket_bytes", _BUCKET_BYTES_BUCKETS)
     for b in plan.buckets:
         hist.observe(float(b.bytes_on_wire))
+
+
+def local_params(params, axis: str):
+    """``params`` typed as VARYING over mesh axis ``axis`` (call INSIDE a
+    shard_map body): differentiating w.r.t. the result yields this shard's
+    LOCAL gradient — :func:`reduce_tree`'s input.
+
+    Replicated params are typed invariant, and jax then sums their
+    cotangent over the axis on its own (the transpose of the implicit
+    invariant->varying cast is a psum). The data-parallel factories own that
+    reduction explicitly — bucketed, optionally bf16 on the wire, optionally
+    overlapped with the next chunk's backward — so they must differentiate
+    w.r.t. the varying copy; reducing the already-summed gradient again
+    makes it ``axis_size`` x too large, which Adam's scale invariance hides
+    and the global-norm clip, the ``grad_norm`` metric and the divergence
+    guard do not (found on four chips in PR 21)."""
+    import jax
+
+    return jax.tree.map(
+        lambda x: jax.lax.pcast(x, axis, to="varying"), params
+    )
 
 
 def reduce_tree(grads, axis: str, comm: CommConfig | None):

@@ -32,8 +32,6 @@ from typing import Any, Callable
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from cst_captioning_tpu.compat import shard_map
-
 _MODES = ("auto", "jit", "shard_map", "pjit")
 
 
@@ -106,7 +104,7 @@ def partition(fn: Callable, plan: CompilePlan) -> Callable:
             f"partition() only builds shard_map programs, plan resolved to "
             f"{how!r}"
         )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=plan.mesh, in_specs=plan.in_specs, out_specs=plan.out_specs
     )
 

@@ -61,7 +61,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cst_captioning_tpu import obs
-from cst_captioning_tpu.compat import shard_map
 from cst_captioning_tpu.config.config import RLConfig
 from cst_captioning_tpu.decoding import fused_decode, sample_decode
 from cst_captioning_tpu.parallel.submesh import (
@@ -142,7 +141,7 @@ def make_actor_decode(model, mesh: Mesh | None, num_rollouts: int,
             (P(axis), P(None, axis), P(None, axis)) if with_greedy
             else (P(None, axis), P(None, axis))
         )
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             sharded, mesh=mesh,
             in_specs=(P(), P(axis), P(axis), P()),
             out_specs=out_specs,

@@ -174,6 +174,8 @@ class RewardComputer:
 
         self.num_threads = num_threads if num_threads > 0 else (os.cpu_count() or 1)
         self._native = None
+        # why a requested native scorer is not the one running ("" otherwise)
+        self.native_error = ""
         if use_native:
             self._init_native(refs)
         if self._native is None:
@@ -197,10 +199,11 @@ class RewardComputer:
             NUM_SPECIAL_TOKENS,
             PAD_ID,
         )
-        from cst_captioning_tpu.native import load_creward
+        from cst_captioning_tpu.native import load_creward, load_error
 
         lib = load_creward()
         if lib is None:
+            self.native_error = load_error() or "native library unavailable"
             return
         import ctypes
 
@@ -265,6 +268,11 @@ class RewardComputer:
         self._lib = lib
         self._handle = handle
         self._native = True
+
+    @property
+    def scorer(self) -> str:
+        """Which implementation scores: "native" (creward.cpp) or "python"."""
+        return "native" if self._native else "python"
 
     def __del__(self):
         if getattr(self, "_native", None) and getattr(self, "_handle", None):

@@ -32,14 +32,13 @@ import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from cst_captioning_tpu import obs
-from cst_captioning_tpu.compat import pcast
 from cst_captioning_tpu.config.config import PAD_ID, RLConfig
 from cst_captioning_tpu.decoding import fused_decode, greedy_decode, sample_decode
 from cst_captioning_tpu.decoding.common import _exit_stride, mask_from_tokens
 from cst_captioning_tpu.obs import flops as _flops
 from cst_captioning_tpu.losses import reinforce_loss, sequence_log_probs
 from cst_captioning_tpu.models.captioner import CaptionModel
-from cst_captioning_tpu.parallel.comms import reduce_tree
+from cst_captioning_tpu.parallel.comms import local_params, reduce_tree
 from cst_captioning_tpu.parallel.compile import CompilePlan, compile_fn
 from cst_captioning_tpu.resilience import chaos
 from cst_captioning_tpu.resilience.health import collective_span
@@ -276,6 +275,10 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
     if K % chunks:
         raise ValueError(f"update_chunks {chunks} must divide K={K} rollouts")
     kc = K // chunks
+    if vary_axis is not None:
+        # per-shard LOCAL grads below; the caller (or the overlap path
+        # here) owns the one explicit reduction over the axis
+        params = local_params(params, vary_axis)
 
     def enc_fn(p):
         e = model.apply(p, feats, masks, method=CaptionModel.encode)
@@ -320,7 +323,9 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
         # `chunks` additions is avoidable error
         return jax.tree.map(lambda a_, g: a_ + g.astype(a_.dtype), ge_acc, ge)
 
-    zeros_p = jax.tree.map(jnp.zeros_like, params)
+    # from shapes, not zeros_like: the (varying) params would hand their
+    # type to the zeros, and the reduced-grad accumulator must be invariant
+    zeros_p = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
     zeros_e = jax.tree.map(
         lambda x: jnp.zeros(x.shape, jnp.promote_types(x.dtype, jnp.float32)),
         enc,
@@ -329,7 +334,7 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
         # inside shard_map the per-chunk grads/sums vary over the batch
         # axis; the scan carry init must carry the same varying-axis type
         vary = lambda t: jax.tree.map(
-            lambda x: pcast(x, vary_axis, to="varying"), t
+            lambda x: jax.lax.pcast(x, vary_axis, to="varying"), t
         )
     else:
         vary = lambda t: t
@@ -489,7 +494,7 @@ def make_parallel_rl_update(model, mesh: Mesh, axis: str = "data",
 
             (num, den), grads_num = jax.value_and_grad(
                 local_num, has_aux=True
-            )(state.params)
+            )(local_params(state.params, axis))
         den_total = jax.lax.psum(den, axis)
         loss = jax.lax.psum(num, axis) / jnp.maximum(den_total, 1.0)
         if not overlap:

@@ -389,9 +389,11 @@ class CaptionService:
         # (finished rows and the compaction prefix die row by row), and each
         # row computes in exactly the [1, ..] block shape an offline B=1
         # decode uses — which is what makes serving-pallas bit-identical to
-        # offline-pallas per request (wider blocks change the matmul
-        # accumulation shape; on TPU raise this toward the sublane width
-        # and accept fraction-grade parity, like the offline kernel)
+        # offline-pallas per request in interpret mode (pinned by test). On
+        # the chip the kernel runs the smallest block >= this one that
+        # Mosaic's tiling accepts (ops/decode_pallas._batch_block: whole
+        # 8-row sublane tiles), so skips are per 8 lanes there and the
+        # served-equals-offline contract is what chip_smoke.py reports
         self.kernel_block_b = int(kernel_block_b)
         self._queue: deque[ClipRequest] = deque()
         self._tickets: dict[str, _Ticket] = {}
@@ -805,12 +807,28 @@ class CaptionService:
         pools and lane state exist then)."""
         from cst_captioning_tpu.obs.flops import compiled_cost
 
+        args = self._stride_args()
+        return None if args is None else compiled_cost(self._stride_fn, *args)
+
+    def stride_program_text(self) -> str | None:
+        """Compiled text of the stride program as the backend built it —
+        what a caller reads to verify which kernels the program REALLY
+        holds (a ``tpu_custom_call`` is a Mosaic kernel; a flag is a wish).
+        Available under the same condition as :meth:`stride_cost`."""
+        args = self._stride_args()
+        if args is None:
+            return None
+        return self._stride_fn.lower(*args).compile().as_text()
+
+    def _stride_args(self):
+        """Example arguments of one stride dispatch (identity permutation,
+        every lane stepping), or None before the first admission."""
         if self._state is None or self.bank.mem is None:
             return None
         B = self.B
         perm = np.arange(B, dtype=np.int32)
-        return compiled_cost(
-            self._stride_fn, self.params,
+        return (
+            self.params,
             (self.bank.mem, self.bank.proj, self.bank.mask),
             self.bank.row_table, self.bank.row_lens,
             perm, perm, np.int32(B), self._state,

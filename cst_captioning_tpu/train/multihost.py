@@ -36,7 +36,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from cst_captioning_tpu.compat import distributed_is_initialized
 # DCN-stall probe (resilience/health.py): every cross-host barrier/broadcast
 # below runs inside collective_span — a dcn.collective span + histogram, a
 # structured dcn_stall event past the threshold, and a piggybacked liveness
@@ -80,15 +79,8 @@ def initialize(coordinator_address: str | None = None,
     """
     # NOTE: must not touch jax.process_count()/jax.devices() here — any
     # backend-initializing call before jax.distributed.initialize is an error
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return
-    if os.environ.get("JAX_PLATFORMS"):
-        # pin the platform list via config BEFORE distributed init: with a
-        # registered out-of-tree PJRT plugin, the env var alone is not
-        # honored by the distributed handshake and init silently degrades to
-        # a single-process cluster (observed: procs=1 and XLA_FLAGS ignored
-        # unless this config is set first)
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     coordinator_address = coordinator_address or os.environ.get(
         "JAX_COORDINATOR_ADDRESS"
     )

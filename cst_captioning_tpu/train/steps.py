@@ -152,7 +152,7 @@ def make_parallel_xe_step(model, mesh: Mesh, label_smoothing: float = 0.0,
     """
     # imported lazily: parallel/__init__ -> seq_parallel imports this module,
     # so a module-level import here would close the cycle mid-initialization
-    from cst_captioning_tpu.parallel.comms import reduce_tree
+    from cst_captioning_tpu.parallel.comms import local_params, reduce_tree
     from cst_captioning_tpu.parallel.compile import CompilePlan, compile_fn
 
     def device_step(state: TrainState, feats, masks, labels, mask, weights):
@@ -167,7 +167,7 @@ def make_parallel_xe_step(model, mesh: Mesh, label_smoothing: float = 0.0,
             return num, den
 
         (num, den), grads_num = jax.value_and_grad(local_num, has_aux=True)(
-            state.params
+            local_params(state.params, axis)
         )
         den_total = jax.lax.psum(den, axis)
         num_total = jax.lax.psum(num, axis)

@@ -158,11 +158,15 @@ class Trainer:
                 snapshot_every=cfg.train.log_every_steps,
             )
             # the run report's MFU column divides the flops.<phase> counters
-            # by this assumed chip peak (obs/flops.py table, keyed on the
-            # device kind — same table bench.py carries in its JSON)
-            obs.gauge("device.peak_flops").set(
-                _flops.peak_flops(jax.devices()[0].device_kind)
-            )
+            # by the chip's published peak (obs/flops.py table, keyed on the
+            # device kind). A kind without one (the CPU tests) sets no gauge
+            # and the report's MFU cells read "not measured" (None)
+            try:
+                obs.gauge("device.peak_flops").set(
+                    _flops.peak_flops(jax.devices()[0].device_kind)
+                )
+            except KeyError:
+                pass
         # flight recorder (obs/recorder.py): per-step training-dynamics ring
         # + postmortem bundles. stats=True threads the extra on-device
         # update-ratio outputs through every step factory; the params math is
@@ -1348,6 +1352,12 @@ class Trainer:
             bleu_scale=cfg.rl.reward_bleu4_scale,
             num_threads=cfg.rl.reward_threads,
         )
+        # name the scorer: a failed g++ build degrades to the much slower
+        # Python scorer, which must be visible in the log, not inferred
+        self.log.log(
+            "reward_scorer", scorer=reward.scorer, error=reward.native_error,
+        )
+
         def build_scst():
             """SCST step closures + batcher for the CURRENT mesh — rebuilt
             after a degraded-mesh continuation shrinks it."""

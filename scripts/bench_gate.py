@@ -17,8 +17,6 @@ enforces the invariants the benches themselves promise:
   measurements (numbers / dicts of true booleans) or a machine-checkable
   skip reason (a string starting with ``"skipped"``) — never false, never
   an unexplained null;
-- the round ledgers (``BENCH_r0*.json``) carry the driver schema
-  (n / cmd / rc / parsed) with rc == 0;
 - the flagship summaries carry a ``metric`` name, and any non-TPU rerun
   carries the standard TPU-rerun ``note`` so a CPU number can never be
   mistaken for the committed TPU operating point.
@@ -33,9 +31,6 @@ import json
 import numbers
 import os
 import sys
-
-# round ledgers written by the growth driver: a fixed schema, rc must be 0
-ROUND_KEYS = {"n", "cmd", "rc", "parsed"}
 
 
 def _check_parity(path: str, key: str, block, errors: list[str]) -> None:
@@ -102,15 +97,6 @@ def check_file(path: str) -> list[str]:
         return [f"{path}: does not parse as JSON ({e})"]
 
     name = os.path.basename(path)
-    if name.startswith("BENCH_r"):
-        missing = ROUND_KEYS - set(data)
-        if missing:
-            errors.append(
-                f"{path}: round ledger missing {sorted(missing)}"
-            )
-        if data.get("rc") != 0:
-            errors.append(f"{path}: round ledger rc = {data.get('rc')!r}")
-        return errors
 
     # flagship summaries: every bench names what it measured — a headline
     # "metric" field, or (the recipe ledger) nested *metrics* tables

@@ -35,7 +35,7 @@ python -m cst_captioning_tpu.tools.graftlint \
     cst_captioning_tpu tests scripts \
     bench.py bench_attention.py bench_comms.py bench_decode.py \
     bench_eval.py bench_recipe.py bench_rl_async.py bench_rl_online.py \
-    bench_scaling.py bench_serving.py \
+    bench_scaling.py bench_serving.py chip_smoke.py \
     --fix-check --check-stale --timings --budget 3
 
 # catches syntax errors in files graftlint may not reach (non-.py-suffixed
@@ -43,7 +43,7 @@ python -m cst_captioning_tpu.tools.graftlint \
 python -m compileall -q cst_captioning_tpu tests scripts \
     bench.py bench_attention.py bench_comms.py bench_decode.py \
     bench_eval.py bench_recipe.py bench_rl_async.py bench_rl_online.py \
-    bench_scaling.py bench_serving.py
+    bench_scaling.py bench_serving.py chip_smoke.py
 
 # obs_report smoke check: the report CLI must aggregate a known-good run dir
 # without a jax import or backend init (it is part of the operator loop for

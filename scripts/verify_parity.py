@@ -46,7 +46,6 @@ import argparse
 import json
 import os
 import re
-import subprocess
 import sys
 import tempfile
 import time

@@ -101,11 +101,10 @@ def main() -> None:
     tmp = sys.argv[6]
     mode = sys.argv[7] if len(sys.argv) > 7 else "train"
 
+    # read when jax is imported / the CPU client starts, so set them first
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from cst_captioning_tpu.train import multihost
 

@@ -19,9 +19,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from cst_captioning_tpu.compat import shard_map
 from cst_captioning_tpu.config.config import (
     ExperimentConfig,
     MeshConfig,
@@ -285,13 +285,18 @@ def test_bf16_rl_update_within_tolerance(model_setup):
     np.testing.assert_allclose(
         float(m0["rl_loss"]), float(m1["rl_loss"]), rtol=1e-6
     )  # the loss never rides the wire — only grads are compressed
-    for a, b in zip(jax.tree.leaves(s0.params), jax.tree.leaves(s1.params)):
-        # one Adam step from identical state: bf16 grad noise moves the
-        # update by ~2^-8 of its magnitude (lr 5e-2), nowhere near the
-        # O(lr) displacement a broken accumulation would produce
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=5e-3, rtol=0
-        )
+    # one Adam step from identical, zero-moment state moves every element
+    # by ~lr * sign(g) (lr 5e-2). Each shard's LOCAL grad rides the wire in
+    # bf16 and the shards' terms partly cancel, so an element whose summed
+    # grad is near zero can land anywhere within +-lr of the f32 answer;
+    # everything else moves by ~2^-8 of its update. A broken accumulation
+    # (a dropped bucket, a wrong scale) displaces MOST elements by O(lr).
+    diffs = np.concatenate([
+        np.abs(np.asarray(a) - np.asarray(b)).ravel()
+        for a, b in zip(jax.tree.leaves(s0.params), jax.tree.leaves(s1.params))
+    ])
+    assert diffs.max() <= 2 * 5e-2 + 1e-6
+    assert np.mean(diffs > 5e-3) < 0.01
 
 
 def test_overlap_defer_bitexact_vs_eager(model_setup):

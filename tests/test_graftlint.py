@@ -24,7 +24,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO_LINT_PATHS = [
     os.path.join(REPO, p)
     for p in ("cst_captioning_tpu", "tests", "scripts", "bench.py",
-              "bench_attention.py", "bench_recipe.py")
+              "bench_attention.py", "bench_recipe.py", "chip_smoke.py")
 ]
 
 

@@ -347,7 +347,7 @@ def test_compile_fn_jit_mode_is_bit_identical_to_plain_jit():
 
 
 def test_compile_fn_shard_map_is_bit_identical_to_direct_spelling():
-    from cst_captioning_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh()
 

@@ -150,6 +150,10 @@ def test_parallel_rl_update_matches_single(model_setup):
         shard_batch(mesh, valid),
     )
     np.testing.assert_allclose(float(s_m["rl_loss"]), float(p_m["rl_loss"]), rtol=1e-5)
+    # the gradient's scale too — Adam hides a doubly-reduced (8x) gradient
+    np.testing.assert_allclose(
+        float(s_m["grad_norm"]), float(p_m["grad_norm"]), rtol=1e-4
+    )
     for a, b in zip(
         jax.tree_util.tree_leaves(s_state.params),
         jax.tree_util.tree_leaves(p_state.params),
@@ -199,6 +203,9 @@ def test_chunked_rl_update_matches_fused(model_setup, chunks):
         )
         np.testing.assert_allclose(
             float(f_m["rl_loss"]), float(p_m["rl_loss"]), rtol=1e-5
+        )
+        np.testing.assert_allclose(
+            float(f_m["grad_norm"]), float(p_m["grad_norm"]), rtol=1e-4
         )
         for a, b in zip(
             jax.tree_util.tree_leaves(f_state.params),

@@ -12,8 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
-from cst_captioning_tpu.compat import shard_map
 from cst_captioning_tpu.config.config import ModelConfig, TrainConfig
 from cst_captioning_tpu.models import CaptionModel
 from cst_captioning_tpu.parallel import (

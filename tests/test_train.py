@@ -81,6 +81,13 @@ def test_parallel_step_matches_single_device(setup):
     np.testing.assert_allclose(
         float(s_metrics["loss"]), float(p_metrics["loss"]), rtol=1e-5
     )
+    # the gradient itself, not only the Adam step it produces: Adam is scale
+    # invariant, so a gradient reduced twice (8x too large) moved the params
+    # exactly like the right one and slipped past the check below
+    np.testing.assert_allclose(
+        float(s_metrics["grad_norm"]), float(p_metrics["grad_norm"]),
+        rtol=1e-4,
+    )
     # updated params identical (up to float assoc in psum ordering)
     flat_s = jax.tree_util.tree_leaves(s_state.params)
     flat_p = jax.tree_util.tree_leaves(p_state.params)
