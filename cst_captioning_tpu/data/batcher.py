@@ -27,6 +27,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from cst_captioning_tpu import obs
 from cst_captioning_tpu.config.config import EOS_ID, PAD_ID
 from cst_captioning_tpu.data.dataset import CaptionDataset
 
@@ -128,7 +129,8 @@ class Batcher:
             )
             rng = np.random.default_rng(key)
             self.epoch_index += 1
-        items = self._items(rng)
+        with obs.span("data.epoch_order"):  # the row list and its shuffle
+            items = self._items(rng)
         bs = self.batch_size
         idx, count = self.host_shard
         lb = self.local_batch_size
@@ -150,42 +152,45 @@ class Batcher:
             yield self._collate(chunk, valid)
 
     def _collate(self, items: list[tuple[int, int]], valid: np.ndarray) -> Batch:
-        bs, T = self.local_batch_size, self.max_len
-        names = list(self.ds.stores)
-        feats = {
-            n: np.zeros((bs, self.ds.max_frames, self.ds.stores[n].dim), np.float32)
-            for n in names
-        }
-        fmasks = {n: np.zeros((bs, self.ds.max_frames), np.float32) for n in names}
-        labels = np.full((bs, T), PAD_ID, dtype=np.int32)
-        mask = np.zeros((bs, T), dtype=np.float32)
-        weights = np.ones((bs,), dtype=np.float32)
-        video_ids = []
-        # memoize per-video features within the batch: seq_per_vid>1 and
-        # wrap-padding repeat videos, and h5 reads are the host hot path
-        feat_cache: dict[str, dict] = {}
-        for b, (ri, ci) in enumerate(items):
-            rec = self.ds.records[ri]
-            video_ids.append(rec.video_id)
-            if rec.video_id not in feat_cache:
-                feat_cache[rec.video_id] = self.ds.features_for(rec.video_id)
-            for n, (f, fm) in feat_cache[rec.video_id].items():
-                feats[n][b] = f
-                fmasks[n][b] = fm
-            if rec.caption_ids:
-                ci = min(ci, len(rec.caption_ids) - 1)
-                labels[b], mask[b] = encode_label_row(rec.caption_ids[ci], T)
-                if rec.weights:
-                    weights[b] = rec.weights[ci]
-        return Batch(
-            feats=feats,
-            feat_masks=fmasks,
-            labels=labels,
-            mask=mask,
-            weights=weights,
-            valid=valid,
-            video_ids=video_ids,
-        )
+        # one span a batch (never one a row), on the caller's thread: the
+        # prefetch worker in training
+        with obs.span("data.collate", rows=len(items)):
+            bs, T = self.local_batch_size, self.max_len
+            names = list(self.ds.stores)
+            feats = {
+                n: np.zeros((bs, self.ds.max_frames, self.ds.stores[n].dim), np.float32)
+                for n in names
+            }
+            fmasks = {n: np.zeros((bs, self.ds.max_frames), np.float32) for n in names}
+            labels = np.full((bs, T), PAD_ID, dtype=np.int32)
+            mask = np.zeros((bs, T), dtype=np.float32)
+            weights = np.ones((bs,), dtype=np.float32)
+            video_ids = []
+            # memoize per-video features within the batch: seq_per_vid>1 and
+            # wrap-padding repeat videos, and h5 reads are the host hot path
+            feat_cache: dict[str, dict] = {}
+            for b, (ri, ci) in enumerate(items):
+                rec = self.ds.records[ri]
+                video_ids.append(rec.video_id)
+                if rec.video_id not in feat_cache:
+                    feat_cache[rec.video_id] = self.ds.features_for(rec.video_id)
+                for n, (f, fm) in feat_cache[rec.video_id].items():
+                    feats[n][b] = f
+                    fmasks[n][b] = fm
+                if rec.caption_ids:
+                    ci = min(ci, len(rec.caption_ids) - 1)
+                    labels[b], mask[b] = encode_label_row(rec.caption_ids[ci], T)
+                    if rec.weights:
+                        weights[b] = rec.weights[ci]
+            return Batch(
+                feats=feats,
+                feat_masks=fmasks,
+                labels=labels,
+                mask=mask,
+                weights=weights,
+                valid=valid,
+                video_ids=video_ids,
+            )
 
     def num_batches(self) -> int:
         n = len(self._items(None))

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Callable, Iterable, Iterator
 
 import jax
@@ -69,13 +68,11 @@ def prefetch_to_device(
     else:
         _place = jax.device_put
 
-    # per-batch staging metrics: stage latency (collate+transfer, on the
-    # worker thread's own trace track), batches staged, and the queue depth
-    # the consumer sees — depth pinned at 0 is the "input-bound" smoking gun
-    # next to a fat xe.epoch/rl.epoch self-time in the run report
-    stage_hist = obs.histogram("prefetch.stage_seconds")
-    staged = obs.counter("prefetch.batches")
+    # the queue depth the consumer sees: pinned at 0 it is the "input-bound"
+    # smoking gun next to a fat prefetch.wait in the run report
     depth = obs.gauge("prefetch.queue_depth")
+    it = iter(it)
+    _END = object()
 
     def _h2d(x):
         def put():
@@ -91,23 +88,36 @@ def prefetch_to_device(
             ),
         )
 
-    def _stage(x):
-        t0 = time.perf_counter()
-        with obs.span("prefetch.stage"):
+    def _stage():
+        """Pull the next item from upstream and stage it; ``_END`` when
+        upstream is exhausted. One ``prefetch.stage`` span a batch, on the
+        staging thread's own trace track, from before the pull (upstream's
+        collate runs in it) to after the upload; the pull that finds
+        upstream exhausted records none. ``prefetch.h2d`` inside it covers
+        ``transform`` and the placement: the *enqueue* of ``device_put`` /
+        ``put_global``, not the transfer's completion (no sync is added to
+        learn that)."""
+        stage = obs.span("prefetch.stage").begin()
+        leave = stage.end
+        try:
+            try:
+                x = next(it)
+            except StopIteration:
+                leave = stage.cancel
+                return _END
             x = chaos.visit("prefetch.stage", x)
-            x = transform(x) if transform is not None else x
-            x = _h2d(x)
-        stage_hist.observe(time.perf_counter() - t0)
-        staged.inc()
-        return x
+            with obs.span("prefetch.h2d"):
+                x = transform(x) if transform is not None else x
+                return _h2d(x)
+        finally:
+            leave()
 
     if size < 1:
-        for x in it:
-            yield _stage(x)
+        while (x := _stage()) is not _END:
+            yield x
         return
 
     q: queue.Queue = queue.Queue(maxsize=size)
-    _END = object()
     err: list[BaseException] = []
     stop = threading.Event()
 
@@ -123,10 +133,12 @@ def prefetch_to_device(
 
     def worker():
         try:
-            for x in it:
+            while True:
                 if stop_event is not None and stop_event.is_set():
                     return  # preempting: yield only what's already staged
-                x = _stage(x)
+                x = _stage()
+                if x is _END:
+                    return
                 if not _put(x):
                     return  # consumer gone: drop staged work, free buffers
                 depth.set(q.qsize())
@@ -161,7 +173,10 @@ def prefetch_to_device(
     t.start()
     try:
         while True:
-            x = _get_with_stall_watchdog()
+            # the consumer standing still for the input pipeline: every
+            # get, the one that returns the end marker included
+            with obs.span("prefetch.wait"):
+                x = _get_with_stall_watchdog()
             # depth as the CONSUMER sees it post-get: 0 here while the
             # worker is mid-stage means the step loop is input-bound
             depth.set(q.qsize())

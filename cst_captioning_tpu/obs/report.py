@@ -53,12 +53,18 @@ _PROC_DIR_RE = re.compile(r"^proc(\d+)$")
 
 # canonical phase order for the table; unknown names sort after, by total
 _PHASE_ORDER = (
-    "setup", "xe.epoch", "xe.step", "rl.epoch", "rl.decode", "rl.reward",
-    "rl.update", "rl.actor.decode", "rl.actor.broadcast", "rl.learner.step",
+    "setup", "xe.epoch", "xe.step", "rl.epoch.keys", "rl.epoch",
+    "prefetch.wait", "rl.decode", "rl.reward", "rl.reward.readback",
+    "rl.reward.observe", "rl.reward.score", "rl.update", "rl.epoch.drain",
+    "rl.actor.decode", "rl.actor.broadcast", "rl.learner.step",
     "eval", "eval.pipeline.fill", "eval.pipeline.drain",
     "eval.score", "serving.admit", "serving.encode",
-    "serving.stride", "serving.detok", "ckpt", "ckpt.save", "ckpt.restore",
-    "dcn.collective", "degraded_rendezvous", "prefetch.stage",
+    "serving.stride", "serving.detok", "obs.snapshot", "ckpt",
+    "ckpt.readback", "ckpt.save", "ckpt.restore",
+    "dcn.collective", "degraded_rendezvous",
+    # the prefetch worker's (the overlap section): a stage is the pull
+    # (epoch order once, then the collate) and the upload's enqueue
+    "prefetch.stage", "data.epoch_order", "data.collate", "prefetch.h2d",
     "profile.window",
 )
 

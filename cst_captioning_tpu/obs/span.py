@@ -19,6 +19,13 @@ shared no-op object — one global load and an identity check, so hot paths
 keep their instrumentation unconditionally. Spans never read device values
 (wall clock only): instrumentation adds zero host syncs by construction.
 
+While a recorder is installed every nesting span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so any ``jax.profiler``
+trace taken meanwhile (``train.profile_dir``, a benchmark's traced stretch)
+holds the program's spans on its host threads beside the device operations,
+on the profiler's own clock. Outside a profiler session the annotation is a
+flag check.
+
 A thread-local context carries run-position fields (``phase``/``epoch``/
 ``step`` via :func:`set_context`) onto every event emitted by that thread;
 a thread-local span stack provides nesting depth, parent names, and exact
@@ -92,6 +99,8 @@ class _NoopSpan:
     def end(self) -> None:
         pass
 
+    cancel = end
+
 
 _NOOP = _NoopSpan()
 
@@ -103,7 +112,7 @@ class Span:
     keeps it out of the thread's nesting stack — for exactly those
     improperly-nested windows."""
 
-    __slots__ = ("rec", "name", "track", "attrs", "_t0", "_child")
+    __slots__ = ("rec", "name", "track", "attrs", "_t0", "_child", "_ann")
 
     def __init__(self, rec: "ObsRecorder", name: str, track: str | None,
                  attrs: dict):
@@ -113,30 +122,50 @@ class Span:
         self.attrs = attrs
         self._t0 = 0.0
         self._child = 0.0  # seconds spent in child spans
+        self._ann = None   # the profiler's annotation while the span is open
 
     def begin(self) -> "Span":
         if self.track is None:
             _stack().append(self)
+            # a track= window may end on another thread than it began on;
+            # the profiler's annotations, like the stack, are per thread
+            if self.rec.annotation is not None:
+                self._ann = self.rec.annotation(self.name)
+                self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     __enter__ = begin
 
+    def _leave(self) -> "Span | None":
+        """Close the annotation and take the span off this thread's stack;
+        returns the span it was nested in."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self.track is not None:
+            return None
+        stack = _stack()
+        # tolerate a foreign stack state (a begin() without end() above
+        # us): pop down to self so accounting degrades, never corrupts
+        while stack:
+            top = stack.pop()
+            if top is self:
+                break
+        return stack[-1] if stack else None
+
+    def cancel(self) -> None:
+        """Leave the window without recording it: for a span begun around
+        an attempt that found nothing to do (a pull from an exhausted
+        iterator)."""
+        self._leave()
+
     def end(self) -> None:
         t1 = time.perf_counter()
         dur = t1 - self._t0
-        parent = None
-        if self.track is None:
-            stack = _stack()
-            # tolerate a foreign stack state (a begin() without end() above
-            # us): pop down to self so accounting degrades, never corrupts
-            while stack:
-                top = stack.pop()
-                if top is self:
-                    break
-            if stack:
-                parent = stack[-1]
-                parent._child += dur
+        parent = self._leave()
+        if parent is not None:
+            parent._child += dur
         self.rec.record_span(
             name=self.name,
             t0=self._t0,
@@ -150,6 +179,16 @@ class Span:
 
     def __exit__(self, *exc) -> None:
         self.end()
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax or its profiler
+    is missing: the spans then record as before, off the profiler's trace."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except (ImportError, AttributeError):
+        return None
+    return TraceAnnotation
 
 
 class ObsRecorder:
@@ -170,6 +209,7 @@ class ObsRecorder:
         self._atexit = self.close
         atexit.register(self._atexit)
         _metrics.install_compile_listener()
+        self.annotation = _profiler_annotation()
         # the configuring thread is the run's foreground timeline: the
         # report partitions wall clock over ITS spans only (background
         # threads overlap it and are listed separately)
@@ -225,7 +265,12 @@ class ObsRecorder:
 
     def snapshot(self, **fields: Any) -> None:
         """Snapshot the process-wide registry into the event stream (plus the
-        Prometheus textfile), refreshing the device-memory gauges first."""
+        Prometheus textfile), refreshing the device-memory gauges first.
+        Tracing's own cost on the caller's thread, so it has a span."""
+        with Span(self, "obs.snapshot", None, {}):
+            self._snapshot(**fields)
+
+    def _snapshot(self, **fields: Any) -> None:
         _metrics.observe_device_memory()
         snap = _metrics.snapshot()
         self.emit("metrics", **fields, **snap)
@@ -260,7 +305,7 @@ class ObsRecorder:
         if self._atexit is not None:
             atexit.unregister(self._atexit)
             self._atexit = None
-        self.snapshot(final=True)
+        self._snapshot(final=True)  # shutdown's, not the run's: no span
         self.emit("run_end", run=self.run)
         self.write_trace()
         with self._lock:

@@ -727,18 +727,24 @@ class SCSTTrainer:
         # so its p95 against rl.decode/rl.update is THE pipelining health
         # signal in the run report
         with obs.span("rl.reward"):
-            samples_np = multihost.to_host_local(          # [K, B_local, T]
-                samples, self.mesh, P(None, "data")
-            ) if self.mesh is not None else np.asarray(samples)
-            greedy_np = None
-            if greedy is not None:
-                greedy_np = multihost.to_host_local(
-                    greedy, self.mesh, P("data")
-                ) if self.mesh is not None else np.asarray(greedy)
-            entropy = self._observe_decode(greedy_np, samples_np)
-            advantage, host_metrics = self._advantage(
-                greedy_np, samples_np, video_ids, valid_np
-            )
+            # its three parts, one span each a batch: the blocking read-back
+            # (the wait for the decode ends here), tracing's own accounting
+            # (real work only while obs is on), the scoring
+            with obs.span("rl.reward.readback"):
+                samples_np = multihost.to_host_local(      # [K, B_local, T]
+                    samples, self.mesh, P(None, "data")
+                ) if self.mesh is not None else np.asarray(samples)
+                greedy_np = None
+                if greedy is not None:
+                    greedy_np = multihost.to_host_local(
+                        greedy, self.mesh, P("data")
+                    ) if self.mesh is not None else np.asarray(greedy)
+            with obs.span("rl.reward.observe"):
+                entropy = self._observe_decode(greedy_np, samples_np)
+            with obs.span("rl.reward.score"):
+                advantage, host_metrics = self._advantage(
+                    greedy_np, samples_np, video_ids, valid_np
+                )
             if entropy is not None:
                 host_metrics["sample_entropy"] = entropy
         return (advantage, host_metrics, samples, feats, masks, valid_np)

@@ -707,8 +707,10 @@ class Trainer:
                     ),
                 }
             with obs.span("ckpt", kind="step"):
+                with obs.span("ckpt.readback"):
+                    host_state = jax.device_get(self.state)
                 self.ckpt.save_step(
-                    jax.device_get(self.state), step_no,
+                    host_state, step_no,
                     self._ckpt_infos(phase, batch_index, step_no),
                     extra_files=extra,
                 )
@@ -1184,10 +1186,10 @@ class Trainer:
         meter.begin_epoch()
         losses = []
         stop = threading.Event()
-        # xe.step spans cover the loop body (dispatch + bookkeeping); the
-        # xe.epoch span's SELF time is therefore exactly the host's wait on
-        # the input pipeline — the report splits compute-bound from
-        # data-bound epochs without any extra probe
+        # xe.step spans cover the loop body (dispatch + bookkeeping) and
+        # prefetch.wait the host's wait on the input pipeline, so the report
+        # splits compute-bound from data-bound epochs; the xe.epoch span's
+        # SELF time is the unattributed remainder (the epoch-end flushes)
         with obs.span("xe.epoch"):
             try:
                 for arrays in self._device_batches(self.batcher, skip=skip,
@@ -1471,14 +1473,15 @@ class Trainer:
         # batch order
         # device_key: eager jax.random.key would stage the seed through
         # an implicit transfer once per epoch, inside the sanitized loop
-        base_rng = device_key(cfg.train.seed + 1)
-        if self.batcher.salt:
-            base_rng = device_fold_in(base_rng, self.batcher.salt)
-        ep_rng = device_fold_in(base_rng, self.epoch)
-        # mid-epoch resume: advance the per-batch split chain past the
-        # ``skip`` batches the checkpoint already trained on
-        for _ in range(skip):
-            ep_rng = jax.random.split(ep_rng)[0]
+        with obs.span("rl.epoch.keys"):
+            base_rng = device_key(cfg.train.seed + 1)
+            if self.batcher.salt:
+                base_rng = device_fold_in(base_rng, self.batcher.salt)
+            ep_rng = device_fold_in(base_rng, self.epoch)
+            # mid-epoch resume: advance the per-batch split chain past the
+            # ``skip`` batches the checkpoint already trained on
+            for _ in range(skip):
+                ep_rng = jax.random.split(ep_rng)[0]
         step_counter = {"step": int(self.state.step)}
         batch_counter = {"n": skip}
         if obs.enabled():
@@ -1528,9 +1531,9 @@ class Trainer:
         # should_stop: a SIGTERM stops consuming at the next batch boundary
         # and the pipeline drains, so state == batch_counter steps exactly
         stop = threading.Event()
-        # the rl.epoch span's self time is everything the decode/reward/
-        # update spans inside scst.train_epoch don't claim: input-pipeline
-        # waits, rng bookkeeping, drain stalls
+        # the rl.epoch span's self time is what no span inside it claims
+        # (rl.decode/reward/update, prefetch.wait, rl.epoch.drain): rng
+        # splits, step bookkeeping, the epoch's unattributed remainder
         # drain-aware stop: the pipelined loop decodes the seam batch at
         # its exact schedule position and captures the tokens here; the
         # preemption/peer-loss save persists them next to the state
@@ -1568,8 +1571,11 @@ class Trainer:
                     "rl", step_counter["step"], batch_counter["n"], sentinel,
                     seam=seam_sink or None,
                 )
-            flight.flush()
-            sentinel.flush()
+            # the wait for the queued updates: both read device scalars of
+            # the epoch's last steps back (the device is busy meanwhile)
+            with obs.span("rl.epoch.drain"):
+                flight.flush()
+                sentinel.flush()
         self.epoch += 1
         self.rl_epochs += 1
         n_valid = float(np.sum(valid_rows)) if valid_rows else 0.0
@@ -1605,8 +1611,12 @@ class Trainer:
         if jax.process_index() != 0:
             return value
         with obs.span("ckpt", kind="epoch"):
+            # the read-back apart from the write: it waits for whatever the
+            # device still has queued, then the device idles for the copy
+            with obs.span("ckpt.readback"):
+                host_state = jax.device_get(self.state)
             is_best = self.ckpt.save(
-                jax.device_get(self.state),
+                host_state,
                 value,
                 # full config snapshot: the reference's `infos` pickle carried
                 # the whole opt namespace (SURVEY.md §5 checkpoint row);

@@ -10,6 +10,7 @@ injected faults.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import os
@@ -1056,3 +1057,235 @@ def test_report_serving_without_slo_has_no_slo_key(tmp_path):
     rep = report_run(run)
     assert "slo" not in rep["serving"]
     assert "slo" not in render_report(rep)
+
+
+# ---- spans where the host's time goes (prefetch, collate, RL epoch) ----------
+
+_INPUT_SPANS = {"prefetch.stage", "data.epoch_order", "data.collate",
+                "prefetch.h2d", "prefetch.wait"}
+_RL_SPANS = {"rl.epoch.keys", "rl.epoch.drain", "ckpt.readback",
+             "rl.reward.readback", "rl.reward.observe", "rl.reward.score"}
+
+
+def _spy_on_spans(monkeypatch):
+    """Every ``obs.span(...)`` call site reached: name -> the objects it got."""
+    seen: dict[str, list] = {}
+    real = obs.span
+
+    def spy(name, /, **kw):
+        s = real(name, **kw)
+        seen.setdefault(name, []).append(s)
+        return s
+
+    monkeypatch.setattr(obs, "span", spy)
+    return seen
+
+
+def _prefetch_an_epoch(train_ds, size):
+    from cst_captioning_tpu.data.batcher import Batcher
+    from cst_captioning_tpu.data.prefetch import prefetch_to_device
+
+    batcher = Batcher(train_ds, batch_size=4, max_len=8, mode="video")
+    got = list(prefetch_to_device(
+        batcher.epoch(), size=size,
+        transform=lambda b: (b.feats, b.feat_masks),
+    ))
+    assert len(got) == batcher.num_batches() > 1
+    return len(got)
+
+
+def _inside(child, parent, slack_us=1.0):
+    return (child["tid"] == parent["tid"]
+            and child["ts"] >= parent["ts"] - slack_us
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + slack_us)
+
+
+@pytest.mark.parametrize("size", [2, 0], ids=["worker", "inline"])
+def test_prefetch_spans_cover_pull_collate_and_upload(
+    chaos_datasets, tmp_path, size,
+):
+    """One prefetch.stage a batch on the staging thread, from before the
+    pull (the collate runs in it) to after the upload; none for the pull
+    that finds the epoch at its end; one prefetch.wait per get on the
+    consumer's thread, the get of the end marker included."""
+    import threading
+
+    obs.configure(str(tmp_path / "run"), run="t")
+    n = _prefetch_an_epoch(chaos_datasets, size)
+    obs.shutdown()
+    evs = json.load(open(tmp_path / "run" / "trace.json"))["traceEvents"]
+    by = {name: [e for e in evs if e["name"] == name] for name in _INPUT_SPANS}
+    me = threading.current_thread().name
+    stager = "prefetch" if size else me
+    assert len(by["prefetch.stage"]) == n
+    assert {e["tid"] for e in by["prefetch.stage"]} == {stager}
+    assert len(by["data.epoch_order"]) == 1
+    for name in ("data.collate", "prefetch.h2d"):
+        assert len(by[name]) == n
+        for stage in by["prefetch.stage"]:
+            assert sum(_inside(e, stage) for e in by[name]) == 1, name
+    assert _inside(by["data.epoch_order"][0], by["prefetch.stage"][0])
+    assert [e["args"]["rows"] for e in by["data.collate"]] == [4] * n
+    # the inline path has no queue to wait on
+    assert len(by["prefetch.wait"]) == (n + 1 if size else 0)
+    assert {e["tid"] for e in by["prefetch.wait"]} <= {me}
+    parents = {s["name"]: s.get("parent")
+               for s in spans_of(read_events(str(tmp_path / "run")))}
+    assert parents["data.collate"] == parents["prefetch.h2d"] == "prefetch.stage"
+
+
+def test_input_span_call_sites_are_noops_when_disabled(
+    chaos_datasets, tmp_path, monkeypatch,
+):
+    from cst_captioning_tpu.obs.span import _NOOP
+
+    monkeypatch.chdir(tmp_path)
+    seen = _spy_on_spans(monkeypatch)
+    _prefetch_an_epoch(chaos_datasets, 2)
+    assert set(seen) == _INPUT_SPANS
+    assert all(s is _NOOP for got in seen.values() for s in got)
+    assert list(tmp_path.iterdir()) == []
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["obs_on", "obs_off"])
+def test_rl_epoch_spans_name_the_turnover_and_the_reward_parts(
+    chaos_datasets, tmp_path, monkeypatch, enabled,
+):
+    """A tiny pipelined RL run through Trainer.train_rl. Obs on: keys, drain
+    and read-back once an epoch, the reward span's three parts inside every
+    rl.reward and summing to no more than it. Obs off: the same call sites
+    get the shared no-op and nothing is written."""
+    from cst_captioning_tpu.config.config import (
+        DataConfig,
+        EvalConfig,
+        ExperimentConfig,
+        ModelConfig,
+        RLConfig,
+        TrainConfig,
+    )
+    from cst_captioning_tpu.obs.span import _NOOP
+    from cst_captioning_tpu.train.trainer import Trainer
+
+    train_ds = chaos_datasets
+    ckpt, run_dir = str(tmp_path / "ckpt"), str(tmp_path / "obs")
+    epochs = 2
+    cfg = ExperimentConfig(
+        name="rl-spans",
+        model=ModelConfig(
+            vocab_size=len(train_ds.vocab), modalities=(("resnet", 16),),
+            d_embed=16, d_hidden=16, d_att=8, encoder="meanpool",
+            dropout=0.0, max_len=8, max_frames=4, dtype="float32",
+        ),
+        data=DataConfig(batch_size=4, seq_per_vid=2),
+        train=TrainConfig(
+            lr=5e-3, ckpt_dir=ckpt, seed=0, epochs=0, eval_every_epochs=100,
+            obs=enabled, obs_dir=run_dir,
+        ),
+        rl=RLConfig(enabled=True, num_rollouts=2, lr=1e-3, epochs=epochs,
+                    pipelined=True),
+        eval=EvalConfig(beam_size=1, max_len=8),
+    )
+    seen = _spy_on_spans(monkeypatch)
+    tr = Trainer(cfg, train_ds, None, log_path=ckpt + "/ev.jsonl",
+                 use_mesh=False)
+    tr.train_rl()
+    tr.close()
+    obs.shutdown()
+    assert _RL_SPANS | {"prefetch.wait", "rl.reward"} <= set(seen)
+    if not enabled:
+        assert all(s is _NOOP for got in seen.values() for s in got)
+        assert not os.path.exists(run_dir)
+        return
+    sp = spans_of(read_events(run_dir))
+    count = collections.Counter(s["name"] for s in sp)
+    for name in ("rl.epoch.keys", "rl.epoch.drain", "ckpt.readback",
+                 "rl.epoch"):
+        assert count[name] == epochs, name
+    assert {s["parent"] for s in sp if s["name"] == "ckpt.readback"} == {"ckpt"}
+    assert {s["parent"] for s in sp
+            if s["name"] == "rl.epoch.drain"} == {"rl.epoch"}
+    rewards = [s for s in sp if s["name"] == "rl.reward"]
+    steps = epochs * -(-len(train_ds.records) // cfg.data.batch_size)
+    assert len(rewards) == steps
+    parts = ("rl.reward.readback", "rl.reward.observe", "rl.reward.score")
+    for name in parts:
+        assert count[name] == steps
+        assert {s["parent"] for s in sp if s["name"] == name} == {"rl.reward"}
+    # events land in end order: a reward's parts are the three before it
+    for i, s in enumerate(sp):
+        if s["name"] == "rl.reward":
+            mine = sp[i - 3:i]
+            assert tuple(p["name"] for p in mine) == parts
+            assert sum(p["dur"] for p in mine) <= s["dur"] + 3e-6
+            assert s["self_dur"] == pytest.approx(
+                s["dur"] - sum(p["dur"] for p in mine), abs=5e-6)
+    # every get of the epoch's batches, the end marker's included
+    assert count["prefetch.wait"] == steps + epochs
+
+
+def test_spans_land_in_a_profiler_trace(tmp_path):
+    """While a recorder is installed a nesting span is also a profiler
+    annotation: a jax.profiler trace taken meanwhile holds host events of
+    the spans' names, on the threads they ran on; a track= window (it may
+    end on another thread) is not annotated."""
+    import glob
+    import threading
+
+    import jax
+
+    obs.configure(str(tmp_path / "run"), run="t")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        def background():
+            with obs.span("span_on_worker"):
+                time.sleep(0.002)
+
+        t = threading.Thread(target=background)
+        t.start()
+        with obs.span("span_outer"):
+            with obs.span("span_inner", tag=1):
+                time.sleep(0.002)
+        obs.span("span_cancelled").begin().cancel()
+        obs.span("span_window", track="virtual").begin().end()
+        t.join()
+    finally:
+        jax.profiler.stop_trace()
+    obs.shutdown()
+    (path,) = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    lines = [
+        {e.name: (e.start_ns, e.duration_ns) for e in line.events
+         if e.name.startswith("span_")}
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines
+    ]
+    lines = [names for names in lines if names]
+    assert sorted(map(sorted, lines)) == [
+        ["span_cancelled", "span_inner", "span_outer"], ["span_on_worker"]]
+    (main,) = [names for names in lines if "span_outer" in names]
+    (o0, od), (i0, idur) = main["span_outer"], main["span_inner"]
+    assert o0 <= i0 and i0 + idur <= o0 + od and idur >= 2e6
+    # the recorder's own stream is unchanged by the annotations
+    names = [s["name"] for s in spans_of(read_events(str(tmp_path / "run")))]
+    assert sorted(names) == ["span_inner", "span_on_worker", "span_outer",
+                             "span_window"]
+    assert names.index("span_inner") < names.index("span_outer")
+
+
+def test_spans_record_without_the_profiler(tmp_path, monkeypatch):
+    """jax.profiler missing: the recorder installs, spans record as before."""
+    import sys
+
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    rec = obs.configure(str(tmp_path / "run"), run="t")
+    assert rec.annotation is None
+    with obs.span("outer"):
+        with obs.span("inner"):
+            pass
+    obs.shutdown()
+    sp = spans_of(read_events(str(tmp_path / "run")))
+    assert [(s["name"], s.get("parent")) for s in sp] == [
+        ("inner", "outer"), ("outer", None)]
