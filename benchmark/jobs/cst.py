@@ -51,11 +51,12 @@ def run(ctx) -> dict:
 
     seen: list[dict] = []
     timer = training.LoopTimer()
+    period = -(-len(ds) // cfg.data.batch_size)     # steps an epoch
     clock = training.StepClock(traffic["warmup_steps"], ctx.seconds,
                                on_open=ctx.window_opened,
                                on_close=ctx.window_closed,
-                               period=-(-len(ds) // cfg.data.batch_size),
-                               chips=ctx.chips)
+                               on_step=ctx.step_listener(period),
+                               period=period, chips=ctx.chips)
     original = scst_mod.SCSTTrainer.train_epoch
 
     def train_epoch(self, state, batches, rng, on_step=None, **kw):

@@ -68,8 +68,9 @@ def run(ctx) -> dict:
     timer = training.LoopTimer()
     clock = training.StepClock(ctx.workload["params"]["warmup_steps"],
                                ctx.seconds, on_open=ctx.window_opened,
-                               on_close=ctx.window_closed, period=per_epoch,
-                               chips=ctx.chips)
+                               on_close=ctx.window_closed,
+                               on_step=ctx.step_listener(per_epoch),
+                               period=per_epoch, chips=ctx.chips)
     trainer.xe_step = _TimedStep(trainer.xe_step, clock, clips_of_step, losses)
     device_batches = trainer._device_batches
 

@@ -37,9 +37,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 CACHE_DIR = os.path.join(HERE, ".cache")
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
-# the traced stretch of the window: starts this long after the window opens
+# the traced stretch of the window (``Stretch``): the trace starts this long
+# after the window opens, and a stretch that has not seen its period of steps
+# is cut this long after it began
 TRACE_DELAY_S = 1.0
-TRACE_SECONDS = 4.0
+TRACE_SECONDS = 5.0
+# what the profiler is told to collect, as attributes of ``ProfileOptions``
+PROFILE_OPTIONS = {"python_tracer_level": 0}
 
 
 def log(msg: str) -> None:
@@ -73,9 +77,109 @@ def metrics_of(manifest: dict, group: str, cell: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
+class Stretch:
+    """The traced stretch of a ``--trace 1`` run, measured in work: one
+    ``period`` of steps (an epoch), from whatever phase the trace starts in.
+
+    The tracer thread starts the profiler ``delay_s`` after the window opens
+    and takes the ``bench.sync`` mark; ``c0`` is the first step completion
+    after the mark and the stretch is ``(c0, the period-th completion after
+    it)``: exactly one epoch's steps and one turnover, so the device work in
+    the trace does not grow as the host gets out of the device's way (the
+    profiler's stop costs tens of seconds for every second a device was busy
+    in the trace: ``PERF.md`` section 2).
+    ``window`` is that interval, on ``time.perf_counter()``'s clock; the
+    reduction and the per-step readers read over it. The trace stops early,
+    said on stderr, when the window closes or ``cap_s`` after the stretch
+    began (after the mark, while no step has completed); ``window`` is then
+    ``(c0, the last completion seen)``, whole steps still, or from the mark to
+    the stop when fewer than two steps completed."""
+
+    def __init__(self, trace_dir: str, period: int, profiler=None,
+                 delay_s: float = TRACE_DELAY_S, cap_s: float = TRACE_SECONDS):
+        self.trace_dir, self.period = trace_dir, max(int(period), 1)
+        self.delay_s, self.cap_s = delay_s, cap_s
+        self._profiler = profiler
+        self._cv = threading.Condition()
+        self._done: list[float] = []    # step completions inside the window
+        self._closed = False
+        self._thread = threading.Thread(target=self._run, name="bench-tracer",
+                                        daemon=True)
+        self.sync_mark_perf: float | None = None
+        self.window: tuple[float, float] | None = None
+        self.steps = 0      # whole steps read: ``period`` unless cut short
+        self.stop_trace_s: float | None = None
+        self.error: str | None = None
+
+    # -- called by the job's clock thread: record, wake, nothing else -------
+    def step_completed(self, t: float) -> None:
+        with self._cv:
+            self._done.append(t)
+            self._cv.notify()
+
+    def window_closed(self) -> None:
+        with self._cv:
+            self._closed = True
+            self._cv.notify()
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def join(self) -> None:
+        if self._thread.ident is not None:
+            self._thread.join()
+
+    def _run(self) -> None:
+        try:
+            prof = self._profiler
+            if prof is None:
+                import jax
+
+                prof = jax.profiler
+            with self._cv:
+                if self._cv.wait_for(lambda: self._closed, self.delay_s):
+                    raise RuntimeError("the window closed before the stretch "
+                                       "began")
+            opts = prof.ProfileOptions()
+            for key, value in PROFILE_OPTIONS.items():
+                setattr(opts, key, value)
+            prof.start_trace(self.trace_dir, profiler_options=opts)
+            try:
+                self.sync_mark_perf = mark = time.perf_counter()
+                with prof.TraceAnnotation("bench.sync"):
+                    time.sleep(0.001)
+                with self._cv:
+                    while True:
+                        # c0 and up to ``period`` completions after it
+                        read = [t for t in self._done
+                                if t > mark][:self.period + 1]
+                        began = read[0] if read else mark
+                        left = began + self.cap_s - time.perf_counter()
+                        if len(read) > self.period or self._closed or left <= 0:
+                            break
+                        self._cv.wait(left)
+                    closed = self._closed
+                if len(read) > 1:
+                    self.window, self.steps = (read[0], read[-1]), len(read) - 1
+                else:
+                    self.window = (mark, time.perf_counter())
+                if self.steps < self.period:
+                    log(f"THE TRACED STRETCH WAS CUT by "
+                        f"{'the window closing' if closed else f'its cap of {self.cap_s} s'}"
+                        f": {self.steps} of its period of {self.period} steps; "
+                        "the per-layer readings are over what there is")
+            finally:
+                t0 = time.perf_counter()
+                prof.stop_trace()
+                self.stop_trace_s = time.perf_counter() - t0
+        except Exception as e:  # reported; the per-layer trace metrics drop out
+            self.error = f"{type(e).__name__}: {e}"
+
+
 class Run:
     """What a job gets: the cell's data, the run's arguments, the places it
-    may write, and the window's two callbacks."""
+    may write, the window's two callbacks and, for a job that counts steps,
+    the listener for their completions (``step_listener``)."""
 
     def __init__(self, cell, workload, config, seed, seconds, trace):
         self.cell, self.workload, self.config = cell, workload, config
@@ -91,23 +195,30 @@ class Run:
         self.log = log
         self.t_open = self.t_close = None
         self.compiles: list[tuple[float, float]] = []   # (perf_counter, secs)
-        self.sync_mark_perf: float | None = None
-        self.trace_window: tuple[float, float] | None = None
-        self._tracer: threading.Thread | None = None
-        self.trace_error: str | None = None
+        self.stretch: Stretch | None = None
+
+    def step_listener(self, period: int):
+        """What a job hands its ``StepClock`` as ``on_step``: in a traced run
+        the stretch's listener, for a stretch of ``period`` steps (the steps
+        of one epoch: the unit after which the job's work repeats); in an
+        untraced run None, and nothing is installed."""
+        if not self.trace:
+            return None
+        self.stretch = Stretch(self.trace_dir, period)
+        return self.stretch.step_completed
 
     # -- called by the job's clock thread --------------------------------
     def window_opened(self, t: float) -> None:
         self.t_open = t
         log(f"window opened (set-up {t - T_PROCESS_START:.2f}s)")
-        if self.trace:
-            self._tracer = threading.Thread(target=self._trace_stretch,
-                                            name="bench-tracer", daemon=True)
-            self._tracer.start()
+        if self.stretch is not None:
+            self.stretch.start()
 
     def window_closed(self, t: float) -> None:
         self.t_close = t
         log(f"window closed after {t - self.t_open:.3f}s")
+        if self.stretch is not None:
+            self.stretch.window_closed()
 
     def annotate(self, name: str):
         """A span of the benchmark's own in the profiler's trace."""
@@ -115,27 +226,17 @@ class Run:
 
         return jax.profiler.TraceAnnotation("bench." + name)
 
-    def _trace_stretch(self) -> None:
-        import jax
-
-        try:
-            time.sleep(TRACE_DELAY_S)
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
-            self.sync_mark_perf = time.perf_counter()
-            with jax.profiler.TraceAnnotation("bench.sync"):
-                time.sleep(0.001)
-            stretch = min(TRACE_SECONDS, max(self.seconds - 2 * TRACE_DELAY_S, 1.0))
-            time.sleep(stretch)
-            self.trace_window = (self.sync_mark_perf, time.perf_counter())
-            jax.profiler.stop_trace()
-        except Exception as e:  # reported; the per-layer trace metrics drop out
-            self.trace_error = f"{type(e).__name__}: {e}"
-
     def join_tracer(self) -> None:
-        if self._tracer is not None:
-            self._tracer.join()
+        """Wait for the profiler's stop, and say what the stretch cost."""
+        st = self.stretch
+        if st is None:
+            return
+        st.join()
+        if st.window is not None:
+            log(f"traced stretch: {st.steps} steps (period {st.period}) in "
+                f"{st.window[1] - st.window[0]:.3f}s, beginning "
+                f"{st.window[0] - self.t_open:.3f}s into the window; "
+                f"stop_trace() took {st.stop_trace_s:.2f}s")
 
     def compiles_in_window(self) -> list[float]:
         """Seconds of each compile (or compile-cache load) in the window."""
@@ -156,11 +257,13 @@ def reduce_run_trace(run: Run, result: dict, spans: list[dict]):
 
     files = glob.glob(os.path.join(run.trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
-    if run.trace_error or not files:
-        log(f"no trace to reduce ({run.trace_error})")
+    st = run.stretch
+    if st is None or st.error or not files:
+        log(f"no trace to reduce ({st.error if st else 'no step listener'})")
         return None
+    t_reduce = time.perf_counter()
     trace = trace_reduce.load_xplane(files[-1])
-    offset = trace_reduce.sync_offset_ns(trace, run.sync_mark_perf)
+    offset = trace_reduce.sync_offset_ns(trace, st.sync_mark_perf)
     host_spans, background, window = [], [], None
     if offset is not None:
         on_clock = lambda t: t * 1e9 - offset  # noqa: E731
@@ -170,9 +273,12 @@ def reduce_run_trace(run: Run, result: dict, spans: list[dict]):
                       for s in spans if s["name"] in names]
         background = [(s["name"], on_clock(s["t0"]), on_clock(s["t1"]))
                       for s in spans if s["name"] in waits]
-        window = tuple(on_clock(t) for t in run.trace_window)
+        window = tuple(on_clock(t) for t in st.window)
     summary = trace_reduce.reduce_trace(trace, host_spans, window, background)
     shutil.rmtree(run.trace_dir, ignore_errors=True)
+    busy = sum(d["busy_s"] for d in summary["devices"]) if summary else 0.0
+    log(f"the trace's reduction took {time.perf_counter() - t_reduce:.2f}s; "
+        f"device-busy seconds in the stretch, all chips: {busy:.3f}")
     return summary
 
 
@@ -277,7 +383,7 @@ def main(argv=None) -> int:
             out["metrics"] = per_layer_metrics(manifest, cell["name"], {
                 "result": result, "trace": summary, "spans": spans,
                 "window": (run.t_open, run.t_close),
-                "trace_window": run.trace_window,
+                "trace_window": run.stretch and run.stretch.window,
                 "compiles_in_window": len(in_window),
                 "config": config, "workload": workload, "chips": run.chips,
                 "device_kind": devices[0].device_kind,
@@ -292,6 +398,9 @@ def main(argv=None) -> int:
                 device["busy_s"] = summary["busy_s"]
                 device["window_s"] = summary["window_s"]
                 out["breakdown"] = summary["breakdown"]
+        if run.trace:
+            log(f"the line follows, {time.perf_counter() - run.t_close:.2f}s "
+                "after the window closed")
     print(json.dumps(out), flush=True)
     return 0
 

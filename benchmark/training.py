@@ -296,13 +296,18 @@ class StepClock:
     an epoch), so the window holds whole epochs — a fixed amount of work,
     every epoch's turnover stall exactly once — and clips / window has no
     rounding and no phase in it. It overruns ``seconds`` by less than an epoch.
+
+    ``on_step(t)``, if given, is called on the clock's thread with the stamp
+    of every step that completes inside the window, ``(t_open, t_close]``,
+    the closing one before ``on_close``. It must do nothing slow there: the
+    traced stretch (``run.Stretch``) records the time and wakes its thread.
     """
 
     def __init__(self, warmup_steps: int, seconds: float, on_open=None,
-                 on_close=None, period: int = 1, chips: int = 1):
+                 on_close=None, period: int = 1, chips: int = 1, on_step=None):
         self.warmup_steps, self.seconds = int(warmup_steps), float(seconds)
         self.period, self.chips = max(int(period), 1), int(chips)
-        self.on_open, self.on_close = on_open, on_close
+        self.on_open, self.on_close, self.on_step = on_open, on_close, on_step
         # (t_done, clips, t_dispatch, bytes_in_use of the fullest chip then)
         self.done: list[tuple[float, float, float, int]] = []
         self.marks: dict[str, list[float]] = {}    # name -> completion times
@@ -343,6 +348,9 @@ class StepClock:
                     continue
                 self.done.append((t, clips, t_dispatch,
                                   hbm_bytes(self.chips, "bytes_in_use")))
+                if (self.on_step and self.t_open is not None
+                        and self.t_close is None):
+                    self.on_step(t)
                 if len(self.done) % self.period:
                     continue        # not an epoch's last step
                 if self.t_open is None:
