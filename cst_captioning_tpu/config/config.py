@@ -142,13 +142,19 @@ class DataConfig:
     batch_size: int = 64                # global batch (split across data axis)
     seq_per_vid: int = 1                # caption rows sampled per video (XE)
     shuffle_seed: int = 0
-    prefetch: int = 2                   # device prefetch depth
+    # device prefetch depth: batches staged on the device ahead of the step.
+    # It also sizes the host staging ring the training loops collate into
+    # (prefetch + 2 slots of one batch each, reused; data/prefetch.py)
+    prefetch: int = 2
     # keep every video's (padded) features in host RAM after the first h5
-    # read: repeat epochs skip h5py entirely. Opt-in — full MSR-VTT
-    # ResNet+C3D at 28 frames is ~2 GB of f32; size it to the host.
-    # Cached arrays come back READ-ONLY (in-place mutation raises instead of
-    # silently poisoning later epochs); the uncached path returns fresh
-    # writable arrays — consumers that mutate features must copy first
+    # read: repeat epochs skip h5py entirely, and a batch is one gather a
+    # stream. What it holds: one contiguous f32 table a stream, indexed by
+    # record, bytes = n_videos x max_frames x sum(dims) x 4 (full MSR-VTT
+    # ResNet+C3D at 28 frames: 1.86 GB), touched as rows are filled. Opt-in:
+    # size it to the host. Cached features come back READ-ONLY (in-place
+    # mutation raises instead of silently poisoning later epochs); the
+    # uncached path returns fresh writable arrays — consumers that mutate
+    # features must copy first
     cache_features: bool = False
 
     def __post_init__(self):

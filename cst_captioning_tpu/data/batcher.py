@@ -19,6 +19,15 @@ Shuffling is keyed by ``(seed, epoch_index)`` — not a running RNG stream — s
 a resumed run that sets :attr:`Batcher.epoch_index` from the checkpoint epoch
 reproduces the exact batch order of an uninterrupted run (SURVEY.md §3.5
 resume semantics, hardened with determinism the reference never had).
+
+Where a batch's bytes come from and go to: with ``data.cache_features`` the
+features come out of the dataset's contiguous table in one gather a stream,
+else out of h5 a row at a time; they go into arrays the ``Batch`` owns, or,
+when ``epoch`` was handed a staging ring, into the ring's next slot. Who may
+keep a ``Batch``'s arrays: whoever drew it by plain iteration (``iter``,
+``epoch()``), for good; a ``Batch`` drawn with ``epoch(staging=ring)`` only
+until the next one is drawn: the ring then owns them (data/prefetch.py).
+Which bytes a batch holds never depends on any of this.
 """
 
 from __future__ import annotations
@@ -99,6 +108,7 @@ class Batcher:
             raise ValueError(f"host_shard index {idx} not in [0, {count})")
         self.host_shard = (idx, count)
         self.local_batch_size = batch_size // count
+        self._slot: dict | None = None  # set by epoch() before each _collate
 
     def _items(self, rng: np.random.Generator | None) -> list[tuple[int, int]]:
         """List of (record_idx, caption_idx) rows for one epoch."""
@@ -118,7 +128,13 @@ class Batcher:
     def __iter__(self):
         return self.epoch(shuffle=self.mode == "caption")
 
-    def epoch(self, shuffle: bool = True):
+    def epoch(self, shuffle: bool = True, staging=None):
+        """One epoch of batches. ``staging`` (a
+        :class:`~cst_captioning_tpu.data.prefetch.StagingRing`) is handed
+        over only by a caller that reports every upload back to it
+        (``prefetch_to_device(..., staging=ring)``): each batch is then
+        collated into the ring's next slot, whose arrays are rewritten a few
+        batches later. Without it every batch owns fresh arrays."""
         # per-epoch derived RNG: order depends only on (seed, epoch_index);
         # unshuffled epochs (eval, template peeks) consume no epoch index
         rng = None
@@ -149,34 +165,67 @@ class Batcher:
                 # this process's contiguous slice of the global batch
                 chunk = chunk[idx * lb : (idx + 1) * lb]
                 valid = valid[idx * lb : (idx + 1) * lb]
+            # _collate(items, valid) is a seam others replace by name, so its
+            # destination rides on the instance, set anew before every call
+            # (one thread draws a batcher's epochs, as epoch_index assumes)
+            self._slot = staging.acquire() if staging is not None else None
             yield self._collate(chunk, valid)
 
     def _collate(self, items: list[tuple[int, int]], valid: np.ndarray) -> Batch:
+        """Rows -> Batch, written into ``self._slot`` (a staging slot's arrays,
+        reused) or, without one, into arrays the Batch owns. Same bytes
+        either way."""
+        # the slot's fence, if any, was waited on before this span began
+        slot = self._slot if self._slot is not None else {}
         # one span a batch (never one a row), on the caller's thread: the
         # prefetch worker in training
         with obs.span("data.collate", rows=len(items)):
-            bs, T = self.local_batch_size, self.max_len
-            names = list(self.ds.stores)
-            feats = {
-                n: np.zeros((bs, self.ds.max_frames, self.ds.stores[n].dim), np.float32)
-                for n in names
-            }
-            fmasks = {n: np.zeros((bs, self.ds.max_frames), np.float32) for n in names}
-            labels = np.full((bs, T), PAD_ID, dtype=np.int32)
-            mask = np.zeros((bs, T), dtype=np.float32)
-            weights = np.ones((bs,), dtype=np.float32)
+            bs, T, F = self.local_batch_size, self.max_len, self.ds.max_frames
+            dims = {n: store.dim for n, store in self.ds.stores.items()}
+            shape = (bs, T, F, tuple(dims.items()))
+            if slot.get("shape") == shape:
+                obs.counter("data.collate.staged").inc()
+            else:
+                # np.empty: every element below is written for every batch
+                slot.clear()
+                slot.update(
+                    shape=shape,
+                    feats={n: np.empty((bs, F, d), np.float32) for n, d in dims.items()},
+                    fmasks={n: np.empty((bs, F), np.float32) for n in dims},
+                    labels=np.empty((bs, T), np.int32),
+                    mask=np.empty((bs, T), np.float32),
+                    weights=np.empty((bs,), np.float32),
+                )
+                obs.counter("data.collate.fresh").inc()
+            feats, fmasks = dict(slot["feats"]), dict(slot["fmasks"])
+            labels, mask, weights = slot["labels"], slot["mask"], slot["weights"]
+            labels.fill(PAD_ID)
+            mask.fill(0.0)
+            weights.fill(1.0)
+
+            rows = np.fromiter((ri for ri, _ in items), np.intp, len(items))
+            tables = self.ds.feature_tables(rows)
+            if tables is not None:
+                # one gather a stream, in one call that releases the GIL.
+                # mode="clip", not the default "raise": with out= the default
+                # gathers into a temporary first and copies it over (PERF.md
+                # section 6, PR 24); rows are record indices, never out of range
+                for n, (f, fm) in tables.items():
+                    np.take(f, rows, axis=0, out=feats[n], mode="clip")
+                    np.take(fm, rows, axis=0, out=fmasks[n], mode="clip")
             video_ids = []
-            # memoize per-video features within the batch: seq_per_vid>1 and
-            # wrap-padding repeat videos, and h5 reads are the host hot path
-            feat_cache: dict[str, dict] = {}
+            # memoize per-video h5 reads within the batch: seq_per_vid>1 and
+            # wrap-padding repeat videos
+            read: dict[str, dict] = {}
             for b, (ri, ci) in enumerate(items):
                 rec = self.ds.records[ri]
                 video_ids.append(rec.video_id)
-                if rec.video_id not in feat_cache:
-                    feat_cache[rec.video_id] = self.ds.features_for(rec.video_id)
-                for n, (f, fm) in feat_cache[rec.video_id].items():
-                    feats[n][b] = f
-                    fmasks[n][b] = fm
+                if tables is None:
+                    if rec.video_id not in read:
+                        read[rec.video_id] = self.ds.features_for(rec.video_id)
+                    for n, (f, fm) in read[rec.video_id].items():
+                        feats[n][b] = f
+                        fmasks[n][b] = fm
                 if rec.caption_ids:
                     ci = min(ci, len(rec.caption_ids) - 1)
                     labels[b], mask[b] = encode_label_row(rec.caption_ids[ci], T)

@@ -80,6 +80,12 @@ class FeatureStore:
         self._h5.close()
 
 
+def _read_only(a: np.ndarray) -> np.ndarray:
+    v = a.view()
+    v.flags.writeable = False
+    return v
+
+
 class CaptionDataset:
     """Videos of one split with their features, captions, and reward pools."""
 
@@ -123,9 +129,27 @@ class CaptionDataset:
         self._gts_pool: dict[str, list[str]] | None = None
         # opt-in host-RAM feature cache (DataConfig.cache_features): h5 reads
         # are the host hot path on repeat epochs — with the cache, each
-        # video's padded features are read once and every later epoch is a
-        # dict lookup. Memory = n_videos * max_frames * sum(dims) * 4 bytes
-        self._feat_cache: dict[str, dict] | None = {} if cache_features else None
+        # video's padded features are read once, into its row of one
+        # contiguous table a stream, and every later batch is one gather a
+        # stream (Batcher._collate). Memory = n_videos * max_frames *
+        # sum(dims) * 4 bytes, touched a row at a time as rows are filled
+        self._tables: dict[str, tuple[np.ndarray, np.ndarray]] | None = None
+        if cache_features:
+            n = len(self.records)
+            self._tables = {
+                name: (np.zeros((n, max_frames, store.dim), np.float32),
+                       np.zeros((n, max_frames), np.float32))
+                for name, store in self.stores.items()
+            }
+            # what is handed out: the same memory, read-only — an in-place
+            # consumer would silently poison later epochs; make that an
+            # immediate ValueError instead
+            self._tables_ro = {
+                name: (_read_only(f), _read_only(m))
+                for name, (f, m) in self._tables.items()
+            }
+            self._filled = np.zeros((n,), dtype=bool)
+            self._row = {r.video_id: i for i, r in enumerate(self.records)}
         if consensus_weights:
             if not os.path.exists(consensus_weights):
                 raise FileNotFoundError(
@@ -153,22 +177,29 @@ class CaptionDataset:
     def __len__(self) -> int:
         return len(self.records)
 
+    def feature_tables(
+        self, record_indices: np.ndarray
+    ) -> dict[str, tuple[np.ndarray, np.ndarray]] | None:
+        """name -> (feats [n_videos, max_frames, dim], masks [n_videos,
+        max_frames]), read-only and indexed by record index, with the rows of
+        ``record_indices`` filled (from h5 on a video's first read); None
+        without ``cache_features``. Rows nobody asked for yet hold zeros."""
+        if self._tables is None:
+            return None
+        todo = record_indices[~self._filled[record_indices]]
+        for ri in np.unique(todo):
+            vid = self.records[ri].video_id
+            for name, store in self.stores.items():
+                feats, masks = self._tables[name]
+                feats[ri], masks[ri] = store.get(vid)
+            self._filled[ri] = True
+        return self._tables_ro
+
     def features_for(self, video_id: str) -> dict[str, tuple[np.ndarray, np.ndarray]]:
-        if self._feat_cache is not None:
-            hit = self._feat_cache.get(video_id)
-            if hit is None:
-                hit = {
-                    name: store.get(video_id)
-                    for name, store in self.stores.items()
-                }
-                for f, m in hit.values():
-                    # the same arrays are handed out on every hit: an
-                    # in-place consumer would silently poison later epochs —
-                    # make that an immediate ValueError instead
-                    f.flags.writeable = False
-                    m.flags.writeable = False
-                self._feat_cache[video_id] = hit
-            return hit
+        if self._tables is not None:
+            ri = self._row[video_id]
+            tables = self.feature_tables(np.array([ri]))
+            return {name: (f[ri], m[ri]) for name, (f, m) in tables.items()}
         return {name: store.get(video_id) for name, store in self.stores.items()}
 
     def gts_pool(self) -> dict[str, list[str]]:

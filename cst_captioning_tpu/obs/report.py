@@ -63,8 +63,10 @@ _PHASE_ORDER = (
     "ckpt.readback", "ckpt.save", "ckpt.restore",
     "dcn.collective", "degraded_rendezvous",
     # the prefetch worker's (the overlap section): a stage is the pull
-    # (epoch order once, then the collate) and the upload's enqueue
-    "prefetch.stage", "data.epoch_order", "data.collate", "prefetch.h2d",
+    # (epoch order once, the wait for a staging slot's last upload, then
+    # the collate) and the upload's enqueue
+    "prefetch.stage", "data.epoch_order", "prefetch.fence", "data.collate",
+    "prefetch.h2d",
     "profile.window",
 )
 
@@ -224,6 +226,15 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
     phases = rows(spans)
     overlap_rows = rows(overlap)
     covered = sum(p["self_s"] for p in phases)
+
+    # where batches were collated (data/batcher.py): into a staging slot that
+    # already had its arrays, or into arrays allocated for the batch
+    staged = float(counters.get("data.collate.staged", 0))
+    fresh = float(counters.get("data.collate.fresh", 0))
+    collate = None
+    if staged + fresh > 0:
+        collate = {"staged": staged, "fresh": fresh,
+                   "staged_share": staged / (staged + fresh)}
 
     depth = histograms.get("rl.decode.depth")
     decode = None
@@ -437,6 +448,7 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         "complete": t_end is not None,
         "phases": phases,
         "overlap": overlap_rows,
+        "collate": collate,
         "decode": decode,
         "serving": serving,
         "eval": eval_sec,
@@ -522,6 +534,14 @@ def render_report(report: dict[str, Any]) -> str:
                 f"{_fmt_s(p['p50_s'])} {_fmt_s(p['p95_s'])} "
                 f"{_fmt_s(p['max_s'])}"
             )
+    c = report.get("collate")
+    if c:
+        lines.append("")
+        lines.append(
+            f"collate: {int(c['staged'])} batch(es) into reused staging "
+            f"slots, {int(c['fresh'])} into fresh arrays "
+            f"({100.0 * c['staged_share']:.1f}% staged)"
+        )
     d = report.get("decode")
     if d:
         lines.append("")

@@ -48,7 +48,7 @@ from cst_captioning_tpu.ckpt import CheckpointManager, load_params
 from cst_captioning_tpu.config.config import EvalConfig, ExperimentConfig
 from cst_captioning_tpu.data.batcher import Batcher
 from cst_captioning_tpu.data.dataset import CaptionDataset
-from cst_captioning_tpu.data.prefetch import prefetch_to_device
+from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
 from cst_captioning_tpu.eval.evaluator import Evaluator
 from cst_captioning_tpu.metrics.cider import CorpusDF
 from cst_captioning_tpu.models import CaptionModel
@@ -242,6 +242,9 @@ class Trainer:
             seed=cfg.data.shuffle_seed,
             host_shard=multihost.host_shard() if self.use_mesh else (0, 1),
         )
+        # host staging for both phases' prefetched batches (sized by
+        # data.prefetch, kept across epochs: a slot is first touched once)
+        self._staging = StagingRing(cfg.data.prefetch)
         self.steps_per_epoch = self.batcher.num_batches()
         tx = make_optimizer(cfg.train, self.steps_per_epoch)
         sample = next(iter(self.batcher.epoch(shuffle=False)))
@@ -609,13 +612,14 @@ class Trainer:
 
         # mid-epoch resume: drop the first ``skip`` batches of this epoch's
         # (already deterministic) order before any transform/transfer
-        it = itertools.islice(batcher.epoch(), skip, None)
+        it = itertools.islice(batcher.epoch(staging=self._staging), skip, None)
         yield from prefetch_to_device(
             it,
             size=self.cfg.data.prefetch,
             transform=transform,
             place=shardings is None,
             stop_event=stop_event,
+            staging=self._staging,
         )
 
     def _rl_device_batches(self, batcher: Batcher, skip: int = 0,
@@ -638,13 +642,16 @@ class Trainer:
                 feats, masks = jax.device_put((b.feats, b.feat_masks))
             return (feats, masks, b.video_ids, b.valid)
 
-        it = itertools.islice(batcher.epoch(shuffle=True), skip, None)
+        it = itertools.islice(
+            batcher.epoch(shuffle=True, staging=self._staging), skip, None
+        )
         yield from prefetch_to_device(
             it,
             size=self.cfg.data.prefetch,
             transform=transform,
             place=False,
             stop_event=stop_event,
+            staging=self._staging,
         )
 
     # ---- resilience helpers ------------------------------------------------

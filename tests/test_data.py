@@ -1,5 +1,8 @@
 """Data layer tests: vocab, synthetic fixtures, dataset, batcher, preprocess."""
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -140,7 +143,6 @@ def test_prefetch_propagates_errors():
 
 
 def test_prefetch_early_abandon_does_not_leak_worker():
-    import threading
 
     from cst_captioning_tpu.data.prefetch import prefetch_to_device
 
@@ -157,8 +159,6 @@ def test_prefetch_early_abandon_does_not_leak_worker():
     for _ in range(50):
         if threading.active_count() <= n_before:
             break
-        import time
-
         time.sleep(0.05)
     assert threading.active_count() <= n_before
 
@@ -260,3 +260,357 @@ def test_feature_cache_serves_without_h5(synth):
         np.testing.assert_array_equal(f, baseline[v]["resnet"][0])
         np.testing.assert_array_equal(m, baseline[v]["resnet"][1])
     cold.close()
+
+
+# ---- one gather a stream into reused, fenced staging slots -------------------
+
+_BOTH = {"resnet": 32, "c3d": 16}
+
+
+def _open(synth, cache, split="train"):
+    return CaptionDataset(
+        synth["info_json"], {n: synth[n] for n in _BOTH}, split, 6,
+        cache_features=cache,
+    )
+
+
+def _reference_epoch(ds, batch_size, max_len, mode, seq_per_vid, seed, salt,
+                     epoch_index, host_shard):
+    """The batches of one shuffled epoch by the plainest possible row loop:
+    what `Batcher` must produce, byte for byte, wherever it writes them."""
+    key = (seed, epoch_index) if not salt else (seed, salt, epoch_index)
+    rng = np.random.default_rng(key)
+    items = []
+    for ri, rec in enumerate(ds.records):
+        if mode == "video":
+            items.append((ri, 0))
+        else:
+            k = min(seq_per_vid, len(rec.caption_ids))
+            picks = rng.choice(len(rec.caption_ids), size=k, replace=False)
+            items.extend((ri, int(ci)) for ci in picks)
+    rng.shuffle(items)
+    idx, count = host_shard
+    lb = batch_size // count
+    out = []
+    for start in range(0, len(items), batch_size):
+        chunk = items[start:start + batch_size]
+        n_real = len(chunk)
+        chunk = chunk + [chunk[i % n_real] for i in range(batch_size - n_real)]
+        valid = np.arange(batch_size) < n_real
+        chunk, valid = chunk[idx * lb:(idx + 1) * lb], valid[idx * lb:(idx + 1) * lb]
+        ref = {
+            "labels": np.zeros((lb, max_len), np.int32),
+            "mask": np.zeros((lb, max_len), np.float32),
+            "weights": np.ones((lb,), np.float32),
+            "valid": valid,
+            "video_ids": [ds.records[ri].video_id for ri, _ in chunk],
+        }
+        for name, store in ds.stores.items():
+            got = [store.get(v) for v in ref["video_ids"]]
+            ref["feats." + name] = np.stack([f for f, _ in got])
+            ref["feat_masks." + name] = np.stack([m for _, m in got])
+        for b, (ri, ci) in enumerate(chunk):
+            toks = ds.records[ri].caption_ids[ci][:max_len - 1]
+            ref["labels"][b, :len(toks)] = toks
+            ref["labels"][b, len(toks)] = EOS_ID
+            ref["mask"][b, :len(toks) + 1] = 1.0
+            ref["weights"][b] = ds.records[ri].weights[ci]
+        out.append(ref)
+    return out
+
+
+def _flat(batch):
+    out = {"labels": batch.labels, "mask": batch.mask, "weights": batch.weights,
+           "valid": batch.valid, "video_ids": batch.video_ids}
+    for name in batch.feats:
+        out["feats." + name] = batch.feats[name]
+        out["feat_masks." + name] = batch.feat_masks[name]
+    return out
+
+
+def _assert_same(got: dict, ref: dict):
+    assert set(got) == set(ref)
+    for k, want in ref.items():
+        if k == "video_ids":
+            assert list(got[k]) == want
+            continue
+        have = np.asarray(got[k])
+        assert have.dtype == want.dtype and have.shape == want.shape, k
+        np.testing.assert_array_equal(have, want, err_msg=k)
+
+
+@pytest.mark.parametrize("staged", [False, True], ids=["fresh", "staged"])
+@pytest.mark.parametrize("cache", [False, True], ids=["h5", "table"])
+@pytest.mark.parametrize(
+    "mode,seq_per_vid,host_shard",
+    [("video", 1, (0, 1)), ("caption", 2, (0, 1)), ("caption", 3, (1, 2)),
+     ("video", 1, (0, 2))],
+    ids=["video", "caption_spv2", "caption_spv3_shard1of2", "video_shard0of2"],
+)
+def test_batches_equal_row_loop_reference(synth, mode, seq_per_vid, host_shard,
+                                          cache, staged):
+    """Two epochs (so the table is read cold and warm, and every slot is
+    rewritten), a wrap-padded last batch, a salt: each array of each batch
+    is the reference's, whether collated into fresh arrays or a ring."""
+    from cst_captioning_tpu.data.prefetch import StagingRing
+
+    ds, plain = _open(synth, cache), _open(synth, False)
+    kw = dict(batch_size=10, max_len=7, mode=mode, seq_per_vid=seq_per_vid,
+              seed=5)
+    batcher = Batcher(ds, host_shard=host_shard, **kw)
+    batcher.salt = 3
+    ring = StagingRing(1) if staged else None
+    for epoch_index in (0, 1):
+        refs = _reference_epoch(plain, salt=3, epoch_index=epoch_index,
+                                host_shard=host_shard, **kw)
+        assert len(refs) == batcher.num_batches()
+        assert 0 < refs[-1]["valid"].sum() < len(refs[-1]["valid"])
+        n = 0
+        # compared one at a time: a staged Batch is the ring's after the next
+        for batch, ref in zip(batcher.epoch(staging=ring), refs, strict=True):
+            _assert_same(_flat(batch), ref)
+            n += 1
+        assert n == len(refs)
+    ds.close()
+    plain.close()
+
+
+def test_feature_table_is_read_only_and_lazy(synth):
+    ds, plain = _open(synth, True), _open(synth, False)
+    vid = ds.records[3].video_id
+    tables = ds.feature_tables(np.array([3, 3]))
+    f, m = tables["resnet"]
+    assert f.shape == (len(ds), 6, 32) and m.shape == (len(ds), 6)
+    np.testing.assert_array_equal(f[3], plain.features_for(vid)["resnet"][0])
+    assert not f[0].any()                  # nobody asked for row 0 yet
+    with pytest.raises(ValueError):
+        f[3, 0, 0] = 1.0
+    with pytest.raises(ValueError):
+        ds.features_for(vid)["c3d"][1][0] = 0.0
+    assert plain.feature_tables(np.array([3])) is None
+    ds.close()
+    plain.close()
+
+
+@pytest.mark.parametrize("size", [2, 0], ids=["worker", "inline"])
+def test_staged_epoch_through_prefetch_keeps_every_item(synth, size):
+    """The Trainer's wiring on the CPU backend, keeping every yielded item
+    till after the epochs end: none was rewritten under an upload that was
+    unfinished or that took the slot's memory over."""
+    import jax
+
+    from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
+
+    ds, plain = _open(synth, True), _open(synth, False)
+    kw = dict(batch_size=4, max_len=7, mode="caption", seq_per_vid=3, seed=2)
+    batcher = Batcher(ds, **kw)
+    ring = StagingRing(size)
+    kept, refs = [], []
+    for epoch_index in (0, 1):
+        refs += _reference_epoch(plain, salt=0, epoch_index=epoch_index,
+                                 host_shard=(0, 1), **kw)
+        kept += list(prefetch_to_device(
+            batcher.epoch(staging=ring), size=size, staging=ring,
+            transform=lambda b: {k: v for k, v in _flat(b).items()
+                                 if k != "video_ids"},
+        ))
+    assert len(kept) == len(refs) > 2 * (size + 2)
+    assert isinstance(kept[0]["feats.resnet"], jax.Array)
+    for got, ref in zip(kept, refs, strict=True):
+        del ref["video_ids"]
+        _assert_same(jax.device_get(got), ref)
+    ds.close()
+    plain.close()
+
+
+class _SlowUpload:
+    """What a placement returns while the transfer is still reading the host
+    buffer: ready when released."""
+
+    def __init__(self, snapshot):
+        self.snapshot = snapshot
+        self.done = threading.Event()
+        self.waited = False
+
+    def block_until_ready(self):
+        self.waited = True
+        assert self.done.wait(10.0)
+        return self
+
+
+def _wait_for(cond, seconds=5.0):
+    t0 = time.monotonic()
+    while not cond():
+        assert time.monotonic() - t0 < seconds
+        time.sleep(0.005)
+
+
+def test_slot_is_not_rewritten_before_its_upload_completes(synth):
+    from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
+
+    ds = _open(synth, True)
+    batcher = Batcher(ds, batch_size=2, max_len=7, mode="video", seed=1)
+    assert batcher.num_batches() == 6
+    ring = StagingRing(0)                       # two slots
+    host, uploads = [], []
+
+    def transform(b):
+        host.append(b.feats["resnet"])
+        uploads.append(_SlowUpload(b.feats["resnet"].copy()))
+        return uploads[-1]
+
+    it = prefetch_to_device(batcher.epoch(staging=ring), size=1, place=False,
+                            transform=transform, staging=ring)
+    assert next(it) is uploads[0]
+    # batch 1 goes to the other slot; batch 2 wants slot 0 back
+    assert next(it) is uploads[1]
+    _wait_for(lambda: uploads[0].waited)
+    time.sleep(0.1)
+    assert len(uploads) == 2, "collated into a slot whose upload is in flight"
+    np.testing.assert_array_equal(host[0], uploads[0].snapshot)
+    uploads[1].done.set()                       # not the one it waits on
+    time.sleep(0.05)
+    assert len(uploads) == 2
+    uploads[0].done.set()
+    assert next(it) is uploads[2]
+    assert host[2] is host[0]                   # the slot, reused
+    assert not np.array_equal(host[0], uploads[0].snapshot)
+    uploads[2].done.set()
+    for u in it:                                # and does proceed after
+        u.done.set()
+    assert len(uploads) == 6
+    ds.close()
+
+
+def test_ring_gives_up_a_slot_it_cannot_fence(synth):
+    """A placement that reads the slot's memory in place (numpy views let
+    through; the CPU backend's zero-copy device_put) or whose arrays were
+    deleted before the fence: the batch keeps the arrays, the slot starts
+    over, nothing raises."""
+    import jax
+
+    from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
+
+    ds = _open(synth, True)
+    kw = dict(batch_size=4, max_len=7, mode="video", seed=1)
+    refs = _reference_epoch(_open(synth, False), seq_per_vid=1, salt=0,
+                            epoch_index=0, host_shard=(0, 1), **kw)
+
+    # (1) host arrays let through un-placed
+    ring = StagingRing(0)
+    kept = list(prefetch_to_device(Batcher(ds, **kw).epoch(staging=ring),
+                                   size=1, place=False, staging=ring,
+                                   transform=_flat))
+    for got, ref in zip(kept, refs, strict=True):
+        _assert_same(got, ref)
+
+    # (2) a 64-byte-aligned slot array: the CPU backend aliases it
+    ring = StagingRing(0)
+    slot = ring.acquire()
+    raw = np.zeros(4096 + 64, np.uint8)
+    off = (-raw.ctypes.data) % 64
+    slot["a"] = raw[off:off + 4096].view(np.float32)
+    placed = jax.device_put(slot["a"])
+    aliased = placed.unsafe_buffer_pointer() == slot["a"].ctypes.data
+    ring.uploaded(placed)
+    ring.acquire()
+    assert (ring.acquire() == {}) == aliased    # slot 0 again: given up iff read in place
+
+    # (3) deleted before the fence
+    ring = StagingRing(0)
+    slot = ring.acquire()
+    slot["a"] = np.ones((3,), np.float32)       # 12 bytes: copied, not aliased
+    placed = jax.device_put(slot["a"]) + 0
+    ring.uploaded(placed)
+    placed.delete()
+    ring.acquire()
+    assert ring.acquire() == {}
+    ring.settle()
+    ds.close()
+
+
+def test_plain_iteration_owns_its_arrays(synth):
+    ds = _open(synth, True)
+    it = iter(Batcher(ds, batch_size=4, max_len=7, mode="caption", seed=1))
+    first = next(it)
+    snap = {k: np.array(v) for k, v in _flat(first).items() if k != "video_ids"}
+    second = next(it)
+    for k, want in snap.items():
+        have = _flat(first)[k]
+        assert have.flags.writeable
+        assert not np.shares_memory(have, _flat(second)[k])
+        np.testing.assert_array_equal(have, want)
+    first.feats["resnet"][:] = -1.0             # the owner may write
+    third = next(it)
+    assert not (third.feats["resnet"] == -1.0).all()
+    ds.close()
+
+
+def test_prefetch_retires_worker_with_slots_outstanding(synth):
+    """Early abandon and an upstream error, with uploads still unfenced."""
+    from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
+
+    ds = _open(synth, True)
+    n_before = threading.active_count()
+    ring = StagingRing(2)
+    batcher = Batcher(ds, batch_size=2, max_len=7, mode="video", seed=1)
+
+    it = prefetch_to_device(batcher.epoch(staging=ring), size=2, staging=ring,
+                            transform=lambda b: (b.feats, b.feat_masks))
+    next(it)
+    it.close()
+    _wait_for(lambda: threading.active_count() <= n_before)
+    assert ring._pending == [None] * 4          # settled: nothing held on
+
+    def failing():
+        for k, b in enumerate(batcher.epoch(staging=ring)):
+            if k == 2:
+                raise RuntimeError("boom")
+            yield b
+
+    with pytest.raises(RuntimeError, match="boom"):
+        list(prefetch_to_device(failing(), size=2, staging=ring,
+                                transform=lambda b: (b.feats, b.feat_masks)))
+    _wait_for(lambda: threading.active_count() <= n_before)
+    assert ring._pending == [None] * 4
+    # and the ring still serves a whole epoch after both
+    got = list(prefetch_to_device(batcher.epoch(staging=ring), size=2,
+                                  staging=ring,
+                                  transform=lambda b: (b.feats, b.feat_masks)))
+    assert len(got) == batcher.num_batches()
+    ds.close()
+
+
+def test_collate_counters_and_take_mode(synth, monkeypatch):
+    from cst_captioning_tpu import obs
+    from cst_captioning_tpu.data.prefetch import StagingRing
+
+    calls = []
+    real_take = np.take
+
+    def take(a, indices, axis=None, out=None, mode="raise"):
+        calls.append((out is not None, mode))
+        return real_take(a, indices, axis=axis, out=out, mode=mode)
+
+    monkeypatch.setattr(np, "take", take)
+
+    def counts():
+        c = obs.snapshot()["counters"]
+        return (c.get("data.collate.staged", 0), c.get("data.collate.fresh", 0))
+
+    ds = _open(synth, True)
+    batcher = Batcher(ds, batch_size=4, max_len=7, mode="video", seed=1)
+    n = batcher.num_batches()
+    s0, f0 = counts()
+    list(batcher.epoch())                       # plain: all fresh
+    assert counts() == (s0, f0 + n)
+    ring = StagingRing(0)                       # two slots: two first fills
+    list(batcher.epoch(staging=ring))
+    assert counts() == (s0 + n - 2, f0 + n + 2)
+    list(batcher.epoch(staging=ring))           # warm ring: all staged
+    assert counts() == (s0 + 2 * n - 2, f0 + n + 2)
+    # a gather with out= never runs under mode="raise" (it would buffer
+    # the whole batch through a temporary)
+    assert len(calls) == 3 * n * 2 * len(_BOTH)
+    assert all(has_out and mode != "raise" for has_out, mode in calls)
+    ds.close()

@@ -1148,6 +1148,49 @@ def test_input_span_call_sites_are_noops_when_disabled(
     assert list(tmp_path.iterdir()) == []
 
 
+def test_staged_prefetch_records_the_fence_and_the_report_the_share(
+    chaos_datasets, tmp_path,
+):
+    """With a staging ring: one prefetch.fence for every upload that was
+    reported, on the staging thread, before the collate that rewrites the
+    slot (or when the ring is settled); the report gives the staged share."""
+    from cst_captioning_tpu.data.batcher import Batcher
+    from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
+
+    class Uploaded:
+        def block_until_ready(self):
+            return self
+
+    obs.configure(str(tmp_path / "run"), run="t")
+    batcher = Batcher(chaos_datasets, batch_size=4, max_len=8, mode="video")
+    n, ring = batcher.num_batches(), StagingRing(0)
+    assert n > 2
+    for _ in range(2):
+        got = list(prefetch_to_device(
+            batcher.epoch(staging=ring), size=1, place=False, staging=ring,
+            transform=lambda b: Uploaded(),
+        ))
+        assert len(got) == n
+    obs.shutdown()
+    evs = json.load(open(tmp_path / "run" / "trace.json"))["traceEvents"]
+    by = {name: [e for e in evs if e["name"] == name]
+          for name in ("prefetch.stage", "prefetch.fence", "data.collate")}
+    assert len(by["prefetch.fence"]) == 2 * n
+    assert {e["tid"] for e in by["prefetch.fence"]} == {"prefetch"}
+    in_stage = [f for f in by["prefetch.fence"]
+                if any(_inside(f, st) for st in by["prefetch.stage"])]
+    assert len(in_stage) == 2 * (n - 2)         # the rest: settling, 2 an epoch
+    for f in in_stage:
+        stage = next(st for st in by["prefetch.stage"] if _inside(f, st))
+        collate = next(c for c in by["data.collate"] if _inside(c, stage))
+        assert f["ts"] + f["dur"] <= collate["ts"] + 1.0
+    rep = report_run(str(tmp_path / "run"))
+    assert rep["collate"] == {"staged": 2 * n - 2, "fresh": 2,
+                              "staged_share": (2 * n - 2) / (2 * n)}
+    assert f"{2 * n - 2} batch(es) into reused staging slots, 2 into fresh" \
+        in render_report(rep)
+
+
 @pytest.mark.parametrize("enabled", [True, False], ids=["obs_on", "obs_off"])
 def test_rl_epoch_spans_name_the_turnover_and_the_reward_parts(
     chaos_datasets, tmp_path, monkeypatch, enabled,
