@@ -37,7 +37,7 @@ def roofline_share(reading, program: str):
         return None
     shape = dict(reading["result"]["cost_shape"])
     shape["B"] = shape["B"] // reading["chips"]
-    cost = costs.program_cost(reading["config"]["model"], shape)[program]
+    cost = costs.program_cost(reading["config"], shape)[program]
     least_s, _bound = costs.roofline(cost, reading["device_kind"])
     return 100.0 * least_s / (ms / 1e3)
 

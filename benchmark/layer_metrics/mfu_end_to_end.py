@@ -1,4 +1,5 @@
-"""End-to-end utilization: the FLOPs the step's programs need (``costs.py``,
+"""End-to-end utilization: the FLOPs the step's programs need (the
+configuration's cost model through ``costs.py``;
 matrix multiplications only, recomputation not counted) times steps per
 second on the benchmark's clock, over the chip's peak. Not a roofline share:
 idle time is in it."""
@@ -13,7 +14,7 @@ def read(reading):
     shape = dict(reading["result"]["cost_shape"])
     shape["B"] = shape["B"] // reading["chips"]
     flops = sum(c["flops"] for c in costs.program_cost(
-        reading["config"]["model"], shape).values())
+        reading["config"], shape).values())
     t0, t1 = reading["window"]
     peak = costs.peaks(reading["device_kind"])["bf16_flops_per_s"]
     return 100.0 * flops * len(steps) / (t1 - t0) / peak

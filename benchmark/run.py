@@ -12,9 +12,11 @@ metric adds files; it edits none (``README.md``).
 
 Prints what it likes on the way (stderr) and, last on stdout, one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
-metrics, or with ``--trace 1`` its per-layer metrics), ``device``, and with
-``--trace 1`` ``breakdown``. Exits non-zero and prints no result without a
-TPU, with fewer chips than the cell asks for, or without the program.
+metrics, or with ``--trace 1`` its per-layer metrics), ``device``, with
+``--trace 1`` ``breakdown``, and last ``compared``: every number the run
+compared, beside its limit (they are also the last lines on stderr). Exits
+non-zero and prints no result without a TPU, with fewer chips than the cell
+asks for, or without the program.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ T_PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import gc  # noqa: E402
 import glob  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
@@ -305,6 +308,35 @@ def per_layer_metrics(manifest: dict, cell: str, reading: dict) -> dict:
     return out
 
 
+def settle(result: dict, say=log) -> dict:
+    """Decide ``correct``. A job returns ``compared`` (``training.Compared``:
+    every number it held to a limit so far) and may return ``verify``, the
+    comparison it left for now: once the window has closed, the peak has been
+    read and the program's state is freed, so that a reference beside a full
+    optimizer state neither sets the peak nor counts as set-up. ``correct``
+    is that no number is outside its limit; ``failed`` counts those that are."""
+    compared = result["compared"]
+    verify = result.pop("verify", None)
+    if verify is not None:
+        gc.collect()
+        t0 = time.perf_counter()
+        verify()
+        say(f"the comparison left for after the window took "
+            f"{time.perf_counter() - t0:.2f}s")
+    result["correct"] = not compared.failed
+    result["failed"] = len(compared.failed)
+    result["compared"] = compared.rows
+    return result
+
+
+def say_compared(rows: dict) -> None:
+    """Each number compared beside its limit: the run's last lines on stderr."""
+    for name, r in rows.items():
+        print(f"compared {name} = {r['value']!r} {r['rule']} {r['limit']!r}"
+              f"{'' if r['ok'] else '  <-- OUTSIDE ITS LIMIT'}",
+              file=sys.stderr, flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workload", required=True)
@@ -355,10 +387,14 @@ def main(argv=None) -> int:
         f"{devices[0].device_kind}")
     with contextlib.redirect_stdout(sys.stderr):
         result = job.run(run)
-        run.join_tracer()
         from benchmark.training import hbm_bytes
 
+        # the window is closed and the job has let go of the chips: read the
+        # peak, then let the reference run; in a traced run that is beside
+        # the profiler's stop, which the tracer thread is still waiting for
         peak = hbm_bytes(run.chips, "peak_bytes_in_use")
+        result = settle(result)
+        run.join_tracer()
         device = {"platform": devices[0].platform,
                   "kind": devices[0].device_kind, "count": len(devices),
                   "memory_peak_bytes": int(peak)}
@@ -401,6 +437,8 @@ def main(argv=None) -> int:
         if run.trace:
             log(f"the line follows, {time.perf_counter() - run.t_close:.2f}s "
                 "after the window closed")
+        out["compared"] = result["compared"]
+        say_compared(out["compared"])
     print(json.dumps(out), flush=True)
     return 0
 

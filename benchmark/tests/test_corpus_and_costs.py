@@ -1,5 +1,6 @@
-"""The corpus generator is reproducible from its parameters; the cost model
-and the peaks table say what they claim."""
+"""The corpus generator is reproducible from its parameters; the cost models
+(found through the configuration's file) and the peaks table say what they
+claim."""
 
 import hashlib
 import json
@@ -8,8 +9,11 @@ import os
 import numpy as np
 import pytest
 
-from benchmark import corpus, costs
+from benchmark import corpus, costs, training
 from benchmark.tests import tiny
+
+CONFIG = tiny.config_file("msrvtt_attention")
+TINY_CORPUS = CONFIG["tiny"]["corpus"]
 
 
 def _digest(paths):
@@ -24,7 +28,7 @@ def _digest(paths):
 
 
 def test_corpus_is_a_function_of_its_parameters(tmp_path):
-    params = tiny.CONFIG["corpus"]
+    params = TINY_CORPUS
     a = corpus.ensure_corpus(str(tmp_path / "a"), params)
     b = corpus.ensure_corpus(str(tmp_path / "b"), params)
     assert _digest(a) == _digest(b)
@@ -39,7 +43,7 @@ def test_corpus_is_a_function_of_its_parameters(tmp_path):
 def test_corpus_schema_and_lengths(tmp_path):
     import h5py
 
-    params = tiny.CONFIG["corpus"]
+    params = TINY_CORPUS
     paths = corpus.ensure_corpus(str(tmp_path), params)
     info = json.load(open(paths["info_json"]))
     assert info["vocab"][:4] == list(corpus.SPECIAL_TOKENS)
@@ -57,21 +61,81 @@ def test_corpus_schema_and_lengths(tmp_path):
     assert all(params["min_frames"] <= s[0] <= params["max_frames"] for s in shapes)
 
 
-MSRVTT = json.load(open(os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "configs", "msrvtt_attention.json")))["model"]
+MSRVTT = CONFIG["model"]
 
 
 def test_costs_follow_the_stated_conventions():
-    enc, tok = costs.enc_and_per_tok_flops(MSRVTT)
+    lstm = training.config_module(CONFIG, "costs", "program_cost")
+    assert lstm.__file__.endswith("benchmark/cost_models/lstm_captioner.py")
+    enc, tok = lstm.enc_and_per_tok_flops(MSRVTT)
     # 2mnk: frame embeddings + memory projection; attention, LSTM, softmax
     assert enc == 2 * 28 * 2548 * 512 + 2 * 56 * 512 * 256
     assert tok == (2 * 512 * 256 + 2 * 56 * 256 + 2 * 56 * 512
                    + 2 * 1024 * 2048 + 2 * 512 * 2048 + 2 * 512 * 9000)
-    c = costs.program_cost(MSRVTT, {"kind": "cst", "B": 1792, "K": 5, "chunks": 5})
+    c = costs.program_cost(CONFIG, {"kind": "cst", "B": 1792, "K": 5, "chunks": 5})
     assert c["update"]["flops"] == 3 * c["decode"]["flops"]
-    x = costs.program_cost(MSRVTT, {"kind": "xe", "B": 64})["xe"]
+    x = costs.program_cost(CONFIG, {"kind": "xe", "B": 64})["xe"]
     assert x["flops"] == 3 * 64 * (enc + 30 * tok)
-    assert costs.memory_slots(dict(MSRVTT, encoder="meanpool")) == 2
+    assert lstm.memory_slots(dict(MSRVTT, encoder="meanpool")) == 2
+
+
+# what ``costs.program_cost(model, shape)`` returned before the arithmetic
+# moved behind the configuration's ``costs`` module (PR 26's tree): the
+# dispatch has to give the same numbers, to the last digit
+OLD_COSTS = [
+    ({"kind": "cst", "B": 1792, "K": 5, "chunks": 5},
+     {"decode": {"flops": 4419213066240.0, "bytes": 25595273216},
+      "update": {"flops": 13257639198720.0, "bytes": 143630573568.0}}),
+    ({"kind": "cst", "B": 448, "K": 5, "chunks": 5},
+     {"decode": {"flops": 1104803266560.0, "bytes": 7112757248},
+      "update": {"flops": 3314409799680.0, "bytes": 46565044224.0}}),
+    ({"kind": "xe", "B": 64},
+     {"xe": {"flops": 108173721600.0, "bytes": 3837235200.0}}),
+]
+
+
+@pytest.mark.parametrize("shape, old", OLD_COSTS,
+                         ids=["cst_b1792", "cst_b448", "xe_b64"])
+def test_the_dispatch_returns_the_old_numbers(shape, old):
+    assert costs.program_cost(CONFIG, shape) == old
+
+
+def test_no_cost_model_is_an_error_never_a_default(tmp_path):
+    nameless = {k: v for k, v in CONFIG.items() if k != "costs"}
+    with pytest.raises(SystemExit, match="names no 'costs' module"):
+        costs.program_cost(nameless, OLD_COSTS[0][0])
+    with pytest.raises(SystemExit, match="not in this checkout"):
+        costs.program_cost(dict(CONFIG, costs="benchmark/cost_models/none.py"),
+                           OLD_COSTS[0][0])
+    # a module that is there and has no program_cost: the reference, say
+    with pytest.raises(SystemExit, match="has no function program_cost"):
+        costs.program_cost(dict(CONFIG, costs=CONFIG["reference"]),
+                           OLD_COSTS[0][0])
+    with pytest.raises(SystemExit, match="has no function token_logprobs"):
+        training.config_module(dict(CONFIG, reference=CONFIG["costs"]),
+                               "reference", "token_logprobs")
+
+
+def test_the_second_architecture_costs_by_its_own_module():
+    second = tiny.second_architecture()
+    c = costs.program_cost(second, {"kind": "cst", "B": 32, "K": 5, "chunks": 5})
+    own = training.config_module(second, "costs", "program_cost")
+    assert own.__file__.endswith("tests/second_architecture/costs.py")
+    assert c == own.program_cost(second["model"],
+                                 {"kind": "cst", "B": 32, "K": 5, "chunks": 5})
+    assert set(c) == {"decode", "update"} and c["update"]["flops"] > 0
+
+
+def test_a_tolerance_needs_a_value_and_a_reason():
+    assert training.check_value(CONFIG, "logprob_mean_abs_tol") == 0.01
+    assert training.check_value(CONFIG, "mesh_rel_tol") == 0.02
+    assert training.check_value(CONFIG, "loss_abs_tol") == 0.005
+    for broken in ({}, {"value": 1.0}, {"value": 1.0, "reason": ""}):
+        with pytest.raises(SystemExit, match="states no checks.some_tol"):
+            training.check_value(dict(CONFIG, checks={"some_tol": broken}),
+                                 "some_tol")
+    for name, entry in CONFIG["checks"].items():
+        assert entry["reason"] and "value" in entry, name
 
 
 def test_peaks_raise_for_an_unknown_kind():

@@ -96,13 +96,22 @@ def test_every_name_resolves(manifest):
         assert config["name"] == w["config"]
         assert config["source"] == entry["source"]
         assert config["reduced"] == entry["reduced"]
-        assert os.path.exists(os.path.join(ROOT, config["reference"]))
+        # what the configuration brings as files and blocks of its own
+        for key in ("reference", "costs"):
+            assert config[key].startswith("benchmark/"), key
+            assert os.path.isfile(os.path.join(ROOT, config[key])), key
+        assert all(e.get("reason") and "value" in e
+                   for e in config["checks"].values())
+        assert {"model", "overrides", "corpus", "policy", "checks",
+                "params"} <= set(config["tiny"])
+        assert set(config["tiny"]["checks"]) == set(config["checks"])
         with open(os.path.join(BENCH, "workloads", w["name"] + ".json")) as f:
             wl = json.load(f)
         assert w["name"] == f"{w['config']}.{w['traffic']}"
         for key in ("config", "traffic", "chips", "why"):
             assert wl[key] == w[key], (w["name"], key)
         assert os.path.exists(os.path.join(BENCH, "jobs", wl["job"] + ".py"))
+        assert wl["job"] in config["tiny"]["params"]
 
         def of(group):
             return [m for m in manifest[group]

@@ -1,12 +1,17 @@
-"""The CPU rehearsal: every job's ``run`` walked end to end at a tiny
-configuration (``tiny.py``), on one device and, for ``cst``, on four virtual
-devices. It shows control flow and correctness checks; never a speed."""
+"""The CPU rehearsal: every configuration of the manifest walked through its
+cells' jobs at its own ``tiny`` sizes (``tiny.py``), on one device and, for a
+four-chip cell, on four virtual devices: reference, checks, cost model and
+every reader of the cell, through the loaders ``run.py`` uses. A second
+architecture that exists only as files under ``tests/second_architecture``
+walks the same way, with no edit to a shared file. It shows control flow and
+correctness checks; never a speed."""
 
 import importlib
 
 import numpy as np
 import pytest
 
+from benchmark import run as bench_run
 from benchmark.tests import tiny
 
 
@@ -16,29 +21,38 @@ def cache(tmp_path_factory):
 
 
 def _run(workload, config, cache, chips, trace=False):
+    """A job's ``run`` and the harness's ``settle`` behind it, as ``run.py``
+    calls them (without its look for a chip)."""
     ctx = tiny.Ctx(workload, config, cache, chips=chips, trace=trace)
     job = importlib.import_module("benchmark.jobs." + workload["job"])
-    return ctx, job.run(ctx)
+    return ctx, bench_run.settle(job.run(ctx), ctx.log)
 
 
-def _manifest():
-    import json
-    import os
-
-    from benchmark import run as bench_run
-
-    with open(os.path.join(bench_run.ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+def _walks():
+    """(configuration at its tiny sizes, workload, chips, the manifest's cell
+    or None) for every cell of the manifest, and for the second architecture."""
+    out = []
+    for cell in tiny.manifest()["workloads"]:
+        config = tiny.config_file(cell["config"])
+        workload = tiny.workload_file(cell["name"])
+        out.append(pytest.param(
+            (tiny.tiny_config(config),
+             tiny.tiny_workload(config, workload["job"], workload),
+             cell["chips"], cell), id=cell["name"]))
+    second = tiny.second_architecture()
+    out.append(pytest.param((second, tiny.tiny_workload(second, "cst"), 1, None),
+                            id="second_architecture.cst"))
+    return out
 
 
 def _per_layer(ctx, res, wall_minus_perf, compiles=0, cell=None, trace=None):
     """The traced run's per-layer line as ``run.py`` builds it: without a
     device trace (a CPU has none worth reading), or with ``trace`` standing
-    in for the chip's, read as the manifest's cell ``cell``."""
-    from benchmark import run as bench_run
-
-    manifest = _manifest()
-    cell = cell or manifest["workloads"][0]
+    in for the chip's, read as the cell ``cell`` (a cell the manifest does
+    not list gets the metrics that carry no ``workloads`` list: what a new
+    one-chip cell is given)."""
+    manifest = tiny.manifest()
+    cell = cell or {"name": "a_new.one_chip_cell", "chips": 1}
     return bench_run.per_layer_metrics(manifest, cell["name"], {
         "result": res, "trace": trace, "window": (ctx.t_open, ctx.t_close),
         "spans": bench_run.program_spans(ctx.obs_dir, wall_minus_perf),
@@ -51,14 +65,17 @@ def _per_layer(ctx, res, wall_minus_perf, compiles=0, cell=None, trace=None):
     })
 
 
-@pytest.mark.parametrize("chips", [1, 4])
-def test_cst_job(cache, chips):
+@pytest.mark.parametrize("walk", _walks())
+def test_cell_walks_its_job(cache, walk):
     import jax
 
+    config, workload, chips, _cell = walk
     if len(jax.devices()) < chips:
         pytest.skip("needs four virtual devices")
-    ctx, res = _run(tiny.CST, tiny.CONFIG, cache, chips)
-    assert res["correct"] and res["failed"] == 0 and res["attempted"] > 3
+    assert workload["job"] == "cst", "another job's cell brings its own case"
+    ctx, res = _run(workload, config, cache, chips)
+    assert res["correct"] and res["failed"] == 0 and res["attempted"] > 3, \
+        res["compared"]
     assert res["end_to_end"]["clips_per_s_per_chip"] > 0
     steps = res["steps"]
     t0, t1 = ctx.t_open, ctx.t_close
@@ -66,47 +83,65 @@ def test_cst_job(cache, chips):
     assert all(t0 < s[0] <= t1 for s in steps) and steps[-1][0] == t1
     # 96 videos = 3 steps of 32 an epoch; the window holds whole epochs
     assert {s[1] for s in steps} == {32.0} and len(steps) % 3 == 0
-    ch = res["checks"]
-    # f32 model against the f32 reference
-    assert ch["logprob_mean_abs_diff"] < 1e-5
+    ch, cmp = res["checks"], res["compared"]
+    assert all(r["ok"] for r in cmp.values())
+    # an f32 model against the f32 reference, by the configuration's own
+    # reference module and at its own tolerances
+    assert cmp["decode_logprob_mean_abs_diff"]["limit"] == \
+        config["checks"]["logprob_mean_abs_tol"]["value"]
+    assert ch["logprob_mean_abs_diff"] < 2e-5
     assert ch["scorer"] == "native" and ch["trainer_scorer_native"]
     assert ch["params_moved"] > 0
+    # the window's own update, followed by the reference after the window
+    n = config["checks"]["follow_steps"]["value"]
+    assert [k for k in cmp if k.startswith("rl_loss_step")] == [
+        f"rl_loss_step{i}_abs_diff" for i in range(1, n + 1)]
+    assert cmp["rl_loss_step1_abs_diff"]["limit"] == \
+        config["checks"]["rl_loss_abs_tol"]["value"]
+    assert {"first_grad_worst_leaf_gap", "first_grad_rel_diff",
+            "param_change_worst_leaf_gap", "sampled_token_id_max"} <= set(cmp)
+    assert any("the reference followed" in line for line in ctx.lines)
     if chips == 4:
         a, b = ch["mesh_vs_one_grad_norm"]
         assert abs(a - b) <= 1e-4 * abs(b)
+        assert "mesh_vs_one_grad_norm_rel_diff" in cmp
 
 
-@pytest.fixture(scope="module")
-def traced(cache):
-    """One ``--trace 1`` rehearsal of ``cst`` (the program's obs recorder is
-    one per process, so one traced run a module): the job's context and
+@pytest.fixture(scope="module", params=[w for w in _walks()
+                                        if w.values[0][2] == 1],
+                ids=lambda w: w[3]["name"] if w[3] else "second_architecture")
+def traced(cache, request):
+    """One ``--trace 1`` rehearsal of ``cst`` for each architecture (the
+    program's obs recorder is re-pointed by each): the job's context and
     result, the clock offset of its spans, the compiles in its window."""
     import time
 
     from jax import monitoring
 
+    config, workload, _chips, cell = request.param
     compiles = []
     monitoring.register_event_duration_secs_listener(
         lambda event, secs, **_: compiles.append(time.perf_counter())
         if event.endswith("backend_compile_duration") else None)
     wall_minus_perf = time.time() - time.perf_counter()
-    ctx, res = _run(tiny.CST, tiny.CONFIG, cache, 1, trace=True)
+    ctx, res = _run(workload, config, cache, 1, trace=True)
     in_window = [t for t in compiles if ctx.t_open < t <= ctx.t_close]
-    return ctx, res, wall_minus_perf, compiles, in_window
+    return ctx, res, wall_minus_perf, compiles, in_window, cell
 
 
 def test_cst_job_traced_feeds_the_readers(traced):
     """With the program's obs spans on (a ``--trace 1`` run): the host-side
     per-layer metrics read what the job returns, the device-side ones find
     nothing and are left out, and nothing compiled inside the window."""
-    ctx, res, wall_minus_perf, compiles, in_window = traced
-    assert res["correct"]
+    ctx, res, wall_minus_perf, compiles, in_window, _cell = traced
+    assert res["correct"], res["compared"]
     assert compiles and not in_window      # the epoch keys were warmed
     got = _per_layer(ctx, res, wall_minus_perf, len(in_window))
-    assert set(got) == {
-        "compiles_in_window", "input_wait_ms_per_step", "step_p50_ms",
-        "epoch_turnover_ms", "decode_wait_ms_per_step", "reward_ms_per_step",
-        "caption_len_mean"}
+    assert {"compiles_in_window", "input_wait_ms_per_step", "step_p50_ms",
+            "epoch_turnover_ms", "decode_wait_ms_per_step",
+            "reward_ms_per_step", "caption_len_mean"} <= set(got)
+    assert not {"decode_roofline", "update_roofline", "device_idle_share",
+                "decode_device_ms_per_step"} & set(got)
     v = {k: m["value"] for k, m in got.items()}
     n, window_ms = len(res["steps"]), 1e3 * (ctx.t_close - ctx.t_open)
     # every next() of the window, and the n/3 - 1 turnovers inside it, are
@@ -124,44 +159,45 @@ def test_cst_job_traced_feeds_the_readers(traced):
         pytest.approx(mean_span_ms, rel=0.5)
 
 
-@pytest.mark.parametrize("cell", _manifest()["workloads"],
-                         ids=lambda w: w["name"])
-def test_traced_line_has_every_metric_of_the_cell(traced, cell):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_traced_line_has_every_metric_of_the_cell(traced, chips):
     """The driver refuses a ``--trace 1`` line that lacks a per-layer metric
     the manifest gives the cell (PR 22's second round was refused for
     ``allreduce_ms_per_step`` on one chip). So: the rehearsal's result, read
     with a device trace of the cell's shape (its chips, the job's two
-    programs, an all-reduce on a mesh), yields every metric of the cell, and
-    none that the manifest does not give it."""
-    from benchmark import run as bench_run
+    programs, an all-reduce on a mesh) and costed by the configuration's own
+    cost model, yields every metric of the cell, all finite, and none that
+    the manifest does not give it. The second architecture reads as a new
+    one-chip ``cst`` cell: exactly the metrics that carry no ``workloads``."""
     from benchmark import trace_reduce
     from benchmark.tests.test_trace_reduce import _trace
 
-    ctx, res, wall_minus_perf, _, in_window = traced
-    if _workload_file(cell)["job"] != tiny.CST["job"]:
-        pytest.skip("the traced rehearsal walks cst; another job's cell "
-                    "brings a case of its own")
+    ctx, res, wall_minus_perf, _, in_window, own = traced
+    manifest = tiny.manifest()
+    if own is None:
+        if chips != 1:
+            pytest.skip("the second architecture walks one chip")
+        cell = {"name": "pooled_lstm.cst", "chips": 1}
+    else:
+        cells = [w for w in manifest["workloads"]
+                 if w["config"] == own["config"] and w["chips"] == chips
+                 and tiny.workload_file(w["name"])["job"] == "cst"]
+        if not cells:
+            pytest.skip(f"no cst cell of {own['config']} on {chips} chip(s)")
+        cell = cells[0]
     summary = trace_reduce.reduce_trace(_trace(cell["chips"]))
     # a CPU keeps no memory_stats(): the chip's readings stand in
     res = dict(res, hbm={"peak_at_open": 2**32, "peak_at_close": 2**32,
                          "live_max": 2**30})
     got = _per_layer(ctx, res, wall_minus_perf, len(in_window), cell=cell,
                      trace=summary)
-    want = bench_run.metrics_of(_manifest(), "per_layer", cell["name"])
+    want = bench_run.metrics_of(manifest, "per_layer", cell["name"])
     assert set(got) == {m["name"] for m in want}
     assert all(np.isfinite(m["value"]) for m in got.values())
     assert ("allreduce_ms_per_step" in got) == (cell["chips"] > 1)
-
-
-def _workload_file(cell):
-    import json
-    import os
-
-    from benchmark import run as bench_run
-
-    with open(os.path.join(bench_run.HERE, "workloads",
-                           cell["name"] + ".json")) as f:
-        return json.load(f)
+    if own is None:
+        assert {m["name"] for m in want} == {
+            m["name"] for m in manifest["per_layer"] if "workloads" not in m}
 
 
 def training_spans(ctx):
@@ -170,11 +206,22 @@ def training_spans(ctx):
     return training.read_spans(ctx.obs_dir)
 
 
-def test_xe_job(cache):
-    ctx, res = _run(tiny.XE, tiny.meanpool_config(), cache, 1)
-    assert res["correct"] and res["attempted"] > 3
+@pytest.mark.parametrize("which", ["manifest", "second_architecture"])
+def test_xe_job(cache, which):
+    """``jobs/xe.py`` has no cell yet; it stays rehearsed, on the manifest's
+    first configuration and on the second architecture, each by its own
+    reference, tolerance and rows."""
+    if which == "manifest":
+        whole = tiny.config_file(tiny.manifest()["configs"][0]["name"])
+        config = tiny.tiny_config(whole)
+    else:
+        config = whole = tiny.second_architecture()
+    ctx, res = _run(tiny.tiny_workload(whole, "xe"), config, cache, 1)
+    assert res["correct"] and res["attempted"] > 3, res["compared"]
     ch = res["checks"]
-    assert abs(ch["program_loss"] - ch["reference_loss"]) < 1e-5
+    assert abs(ch["program_loss"] - ch["reference_loss"]) < 2e-5
+    assert res["compared"]["xe_loss_abs_diff"]["limit"] == \
+        config["checks"]["loss_abs_tol"]["value"]
     # 96 videos x 5 references = 480 rows = 7 x 64 + 32: the padded last
     # batch of an epoch counts its 32 valid rows only
     assert {s[1] for s in res["steps"]} == {64.0, 32.0}

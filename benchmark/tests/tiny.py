@@ -1,63 +1,63 @@
-"""A tiny configuration and cells for the CPU rehearsal: the same files'
-shapes as ``configs/`` and ``workloads/``, at sizes a CPU walks in seconds.
-Nothing here is ever measured."""
+"""The CPU rehearsal's sizes and context. Every configuration file carries a
+``tiny`` block: its own ``model``, ``overrides``, ``corpus``, ``policy`` and
+``checks`` at sizes a CPU walks in seconds, and ``params`` for each job its
+cells run. :func:`tiny_config` and :func:`tiny_workload` put them in the
+file's and the workload's place; reference, cost model and every other key
+stay the file's. Nothing here is ever measured."""
 
 from __future__ import annotations
 
 import copy
+import json
 import os
 
-MODEL = {
-    "vocab_size": 64, "modalities": [["resnet", 32], ["c3d", 16]],
-    "encoder": "temporal_attention", "d_embed": 32, "d_hidden": 32,
-    "d_att": 16, "num_layers": 1, "max_len": 12, "max_frames": 8,
-    "dtype": "float32", "param_dtype": "float32",
-}
-OVERRIDES = {
-    "model__vocab_size": 64, "model__modalities": (("resnet", 32), ("c3d", 16)),
-    "model__d_embed": 32, "model__d_hidden": 32, "model__d_att": 16,
-    "model__max_len": 12, "model__max_frames": 8, "model__dtype": "float32",
-}
-CONFIG = {
-    "name": "tiny_attention", "model": MODEL, "overrides": OVERRIDES,
-    "corpus": {
-        "videos": 96, "refs_per_video": 5, "caption_len": [3, 6],
-        "vocab_size": 64, "modalities": {"resnet": 32, "c3d": 16},
-        "max_frames": 8, "min_frames": 4, "topics": 4,
-        "templates_per_topic": 2, "template_noise": 0.2,
-        "feature_noise": 0.05, "seed": 7,
-    },
-    "policy": {"preset": "msrvtt_xe_attention", "steps": 150, "batch": 32,
-               "lr": 0.01, "seed": 3},
-}
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+TINY_KEYS = ("model", "overrides", "corpus", "policy", "checks")
 
 
-def meanpool_config() -> dict:
-    cfg = copy.deepcopy(CONFIG)
-    cfg["name"] = "tiny_meanpool"
-    cfg["model"].update(modalities=[["resnet", 32]], encoder="meanpool")
-    cfg["overrides"].update({"model__modalities": (("resnet", 32),)})
-    cfg["corpus"]["modalities"] = {"resnet": 32}
-    cfg["policy"]["preset"] = "msvd_xe_meanpool"
-    return cfg
+def manifest() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
 
 
-CST = {
-    "job": "cst", "params": {
-        "preset": "msrvtt_cst_consensus",
-        "overrides": {"data__batch_size": 32, "rl__update_chunks": 5},
-        "warmup_steps": 4, "epoch_keys_warmed": 200,
-        "policy_check": {"sampled_len_mean": [1.0, 9.0],
-                         "sampled_len_p99_max": 11},
-    },
-}
-XE = {
-    "job": "xe", "params": {
-        "preset": "msvd_xe_meanpool",
-        "overrides": {"data__batch_size": 64, "data__seq_per_vid": 5},
-        "warmup_steps": 3,
-    },
-}
+def config_file(name: str) -> dict:
+    """The configuration ``name`` of the manifest, as its file has it."""
+    entry = {c["name"]: c for c in manifest()["configs"]}[name]
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return json.load(f)
+
+
+def workload_file(cell: str) -> dict:
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        return json.load(f)
+
+
+def tiny_config(config: dict) -> dict:
+    out = copy.deepcopy(config)
+    tiny = out.pop("tiny")
+    out.update({k: tiny[k] for k in TINY_KEYS})
+    out["name"] = "tiny_" + config["name"]
+    return out
+
+
+def tiny_workload(config: dict, job: str, workload: dict | None = None) -> dict:
+    """The workload (a cell's file, or a bare one of ``job``) with the
+    configuration's tiny ``params`` of that job."""
+    out = copy.deepcopy(workload) if workload else {"job": job}
+    out["params"] = copy.deepcopy(config["tiny"]["params"][job])
+    return out
+
+
+SECOND = os.path.join(HERE, "second_architecture")
+
+
+def second_architecture() -> dict:
+    """An architecture that exists only as files under ``tests/``: its
+    configuration, its reference, its cost model, its tolerances."""
+    with open(os.path.join(SECOND, "config.json")) as f:
+        return json.load(f)
 
 
 class Ctx:
@@ -71,7 +71,8 @@ class Ctx:
         # a run directory of its own: the program's obs recorder is one per
         # process and keeps writing where the traced rehearsal pointed it
         self.run_dir = os.path.join(
-            self.cache_dir, "run", f"{workload['job']}-{chips}-{int(trace)}")
+            self.cache_dir, "run",
+            f"{config['name']}-{workload['job']}-{chips}-{int(trace)}")
         os.makedirs(self.run_dir, exist_ok=True)
         self.obs_dir = os.path.join(self.run_dir, "obs")
         self.t_open = self.t_close = None

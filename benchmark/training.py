@@ -12,6 +12,7 @@ the device to finish it (``block_until_ready``) and stamps the time.
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import queue
@@ -45,8 +46,8 @@ def experiment_config(config: dict, traffic: dict, seed: int, run_dir: str,
     from cst_captioning_tpu.config import get_preset
 
     cfg = get_preset(traffic["preset"])
-    over = dict(config.get("overrides", {}))
-    over.update(traffic.get("overrides", {}))
+    over = {k: _tuples(v) for k, v in config.get("overrides", {}).items()}
+    over.update({k: _tuples(v) for k, v in traffic.get("overrides", {}).items()})
     over.update({
         "data__shuffle_seed": int(seed),
         "train__seed": int(seed),
@@ -59,12 +60,120 @@ def experiment_config(config: dict, traffic: dict, seed: int, run_dir: str,
         over.update({"train__obs": True, "train__obs_dir": obs_dir})
     cfg = cfg.override(**over)
     want = config["model"]
-    got = {k: getattr(cfg.model, k) for k in want}
-    got["modalities"] = [list(m) for m in cfg.model.modalities]
+    # the program's values after a JSON round trip, so that a tuple-valued
+    # field compares with the file's list
+    got = json.loads(json.dumps({k: getattr(cfg.model, k, None) for k in want}))
     if got != want:
         raise SystemExit(f"preset {traffic['preset']} does not have the "
                          f"configuration's sizes: {got} != {want}")
     return cfg
+
+
+def _tuples(value):
+    """A JSON value in the program's spelling: its configuration is hashable,
+    so what a file writes as a list is a tuple there."""
+    if isinstance(value, list):
+        return tuple(_tuples(v) for v in value)
+    return value
+
+
+# ---- what a configuration brings as files ------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_MODULES: dict[str, object] = {}
+
+
+def config_module(config: dict, key: str, needs: str):
+    """The module the configuration's file names under ``key`` (``reference``,
+    ``costs``): a path from the checkout's root, loaded from that file and by
+    no import name, so that an architecture is files and no entry in shared
+    code. A configuration that names none, a file that is not there, or a
+    module without the function ``needs`` is an error, never a default."""
+    path = config.get(key)
+    if not path:
+        raise SystemExit(f"configuration {config.get('name')!r} names no "
+                         f"{key!r} module in its file")
+    full = os.path.normpath(os.path.join(ROOT, path))
+    if full not in _MODULES:
+        if not os.path.isfile(full):
+            raise SystemExit(f"configuration {config.get('name')!r}: its "
+                             f"{key} module {path} is not in this checkout")
+        spec = importlib.util.spec_from_file_location(
+            "benchmark_config_" + key + "_" + "".join(
+                c if c.isalnum() else "_" for c in path), full)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _MODULES[full] = module
+    module = _MODULES[full]
+    if not callable(getattr(module, needs, None)):
+        raise SystemExit(f"{path} (the {key} module of configuration "
+                         f"{config.get('name')!r}) has no function {needs}")
+    return module
+
+
+def check_value(config: dict, name: str):
+    """``checks.<name>.value`` of the configuration's file: a tolerance, or
+    the clips or rows a check reads. Each stands there with its ``reason``;
+    none is a constant of a job."""
+    entry = config.get("checks", {}).get(name)
+    if not isinstance(entry, dict) or "value" not in entry or not entry.get("reason"):
+        raise SystemExit(f"configuration {config.get('name')!r} states no "
+                         f"checks.{name} with a value and a reason")
+    return entry["value"]
+
+
+def reference_logprobs(config: dict, params, feats, masks, tokens, rows: int,
+                       forbid_special: bool = False,
+                       precision: str = "float32") -> np.ndarray:
+    """The configuration's reference, ``token_logprobs(params, model, feats,
+    masks, tokens, forbid_special=, precision=)`` with ``model`` the file's
+    ``model`` dict, over ``tokens`` [N, T] in blocks of ``rows`` rows, so that
+    a deep model's reference fits beside what else the chip holds."""
+    import jax
+
+    ref = config_module(config, "reference", "token_logprobs")
+    model = config["model"]
+    fn = jax.jit(lambda p, f, m, t: ref.token_logprobs(
+        p, model, f, m, t, forbid_special=forbid_special, precision=precision))
+    tokens = np.asarray(tokens)
+    out = []
+    for a in range(0, len(tokens), rows):
+        cut = lambda x: x[a:a + rows]  # noqa: E731
+        out.append(np.asarray(fn(params, jax.tree.map(cut, feats),
+                                 jax.tree.map(cut, masks), tokens[a:a + rows])))
+    return np.concatenate(out, axis=0)
+
+
+class Compared:
+    """Every number a run compares, beside its limit: ``name -> {"value",
+    "rule", "limit", "ok"}``. ``run.py`` prints them as the run's last lines
+    on stderr and, under ``compared``, last in the result's line."""
+
+    def __init__(self):
+        self.rows: dict[str, dict] = {}
+
+    def _add(self, name, value, rule, limit, ok) -> bool:
+        self.rows[name] = {"value": value, "rule": rule, "limit": limit,
+                           "ok": bool(ok)}
+        return bool(ok)
+
+    def at_most(self, name: str, value: float, limit: float) -> bool:
+        return self._add(name, float(value), "<=", limit, float(value) <= limit)
+
+    def at_least(self, name: str, value: float, limit: float) -> bool:
+        return self._add(name, float(value), ">=", limit, float(value) >= limit)
+
+    def within(self, name: str, value: float, lo: float, hi: float) -> bool:
+        return self._add(name, float(value), "in", [lo, hi],
+                         lo <= float(value) <= hi)
+
+    def holds(self, name: str, ok: bool) -> bool:
+        """A yes/no finding: 1 where it holds, against the limit 1."""
+        return self.at_least(name, 1.0 if ok else 0.0, 1.0)
+
+    @property
+    def failed(self) -> list[str]:
+        return [k for k, r in self.rows.items() if not r["ok"]]
 
 
 def open_train_split(cfg, paths: dict):
@@ -263,18 +372,21 @@ def caption_lengths(tokens) -> np.ndarray:
     return ((tok != 0) & (tok != 2)).sum(-1).reshape(-1)
 
 
-def check_policy_lengths(sampled, greedy, limits: dict) -> dict:
+def check_policy_lengths(sampled, greedy, limits: dict,
+                         compared: "Compared | None" = None) -> dict:
     """The cell was defined with a policy whose captions end. Fails loudly
     when the policy in use is not that one."""
     s, g = caption_lengths(sampled), caption_lengths(greedy)
     got = {"sampled_len_mean": float(s.mean()),
            "sampled_len_p99": float(np.percentile(s, 99)),
            "greedy_len_min": int(g.min())}
-    lo, hi = limits["sampled_len_mean"]
-    ok = (lo <= got["sampled_len_mean"] <= hi
-          and got["sampled_len_p99"] <= limits["sampled_len_p99_max"]
-          and got["greedy_len_min"] >= 1)
-    if not ok:
+    compared = compared if compared is not None else Compared()
+    ok = [compared.within("sampled_len_mean", got["sampled_len_mean"],
+                          *limits["sampled_len_mean"]),
+          compared.at_most("sampled_len_p99", got["sampled_len_p99"],
+                           limits["sampled_len_p99_max"]),
+          compared.at_least("greedy_len_min", got["greedy_len_min"], 1)]
+    if not all(ok):
         raise SystemExit(f"policy caption lengths {got} outside {limits}: "
                          "the traffic is not the one this cell was defined on")
     return got
