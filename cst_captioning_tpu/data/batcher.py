@@ -18,7 +18,11 @@ stay static while eval stays exact.
 Shuffling is keyed by ``(seed, epoch_index)`` — not a running RNG stream — so
 a resumed run that sets :attr:`Batcher.epoch_index` from the checkpoint epoch
 reproduces the exact batch order of an uninterrupted run (SURVEY.md §3.5
-resume semantics, hardened with determinism the reference never had).
+resume semantics, hardened with determinism the reference never had). An
+epoch's order is therefore known before the epoch before it has been
+trained: the training loops' prefetch worker draws ahead, handing
+``epoch()`` the index (and salt) it draws with instead of reading the
+attribute the main thread pins.
 
 Where a batch's bytes come from and go to: with ``data.cache_features`` the
 features come out of the dataset's contiguous table in one gather a stream,
@@ -32,7 +36,9 @@ Which bytes a batch holds never depends on any of this.
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
@@ -128,23 +134,35 @@ class Batcher:
     def __iter__(self):
         return self.epoch(shuffle=self.mode == "caption")
 
-    def epoch(self, shuffle: bool = True, staging=None):
+    def epoch(self, shuffle: bool = True, staging=None,
+              epoch_index: int | None = None, salt: int | None = None):
         """One epoch of batches. ``staging`` (a
         :class:`~cst_captioning_tpu.data.prefetch.StagingRing`) is handed
-        over only by a caller that reports every upload back to it
-        (``prefetch_to_device(..., staging=ring)``): each batch is then
+        over only by a caller that reports every upload back to it (a
+        ``PrefetchFeed`` with ``staging=ring``): each batch is then
         collated into the ring's next slot, whose arrays are rewritten a few
-        batches later. Without it every batch owns fresh arrays."""
-        # per-epoch derived RNG: order depends only on (seed, epoch_index);
+        batches later. Without it every batch owns fresh arrays.
+
+        ``epoch_index`` / ``salt``: the shuffle's key, from a caller that
+        owns it (the prefetch worker, which draws an epoch while the main
+        thread still trains the one before and pins :attr:`epoch_index` for
+        it). Neither attribute is then read or written. Left out, the epoch
+        is drawn with the batcher's own and :attr:`epoch_index` moves on by
+        one: plain iteration, on the thread that owns the attribute."""
+        # per-epoch derived RNG: order depends only on (seed, salt, index);
         # unshuffled epochs (eval, template peeks) consume no epoch index
         rng = None
         if shuffle:
+            if epoch_index is None:
+                epoch_index = self.epoch_index
+                self.epoch_index += 1
+            if salt is None:
+                salt = self.salt
             key = (
-                (self.seed, self.epoch_index) if not self.salt
-                else (self.seed, self.salt, self.epoch_index)
+                (self.seed, epoch_index) if not salt
+                else (self.seed, salt, epoch_index)
             )
             rng = np.random.default_rng(key)
-            self.epoch_index += 1
         with obs.span("data.epoch_order"):  # the row list and its shuffle
             items = self._items(rng)
         bs = self.batch_size
@@ -166,8 +184,9 @@ class Batcher:
                 chunk = chunk[idx * lb : (idx + 1) * lb]
                 valid = valid[idx * lb : (idx + 1) * lb]
             # _collate(items, valid) is a seam others replace by name, so its
-            # destination rides on the instance, set anew before every call
-            # (one thread draws a batcher's epochs, as epoch_index assumes)
+            # destination rides on the instance, set anew before every call:
+            # one thread at a time draws a batcher's epochs (in training the
+            # prefetch worker, across epoch ends too)
             self._slot = staging.acquire() if staging is not None else None
             yield self._collate(chunk, valid)
 
@@ -246,3 +265,32 @@ class Batcher:
         if self.drop_last:
             return n // self.batch_size
         return -(-n // self.batch_size)
+
+
+class EpochKey(NamedTuple):
+    """One shuffled epoch of a :class:`Batcher` as a prefetch feed names it
+    (``data/prefetch.py``): what decides its batches, and where the run of
+    epochs it belongs to ends. Two keys are equal when the batches staged
+    for one are the other's."""
+
+    batcher: Batcher
+    salt: int
+    index: int      # the shuffle's epoch index
+    skip: int       # leading batches left out (a mid-epoch resume)
+    until: int      # the run's last epoch has index ``until`` - 1
+
+    def following(self) -> "EpochKey | None":
+        """The epoch after this one, whole; None past the run's last."""
+        nxt = self.index + 1
+        return self._replace(index=nxt, skip=0) if nxt < self.until else None
+
+    def batches(self, staging=None):
+        """The epoch's batches, by the key's own index and salt: the
+        batcher's attributes are neither read nor moved."""
+        return itertools.islice(
+            self.batcher.epoch(
+                shuffle=True, staging=staging,
+                epoch_index=self.index, salt=self.salt,
+            ),
+            self.skip, None,
+        )

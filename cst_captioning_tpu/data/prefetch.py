@@ -7,6 +7,11 @@ the flax ``prefetch_to_device`` pattern, generalized to our Batch pytrees and
 to explicit shardings (so prefetch lands per-device shards directly when a
 Mesh is in play).
 
+The thread serves a whole run of epochs (:class:`PrefetchFeed`): while the
+consumer drains one epoch (queued updates, the state's read-back) it stages
+the first batches of the next, so that no epoch begins by waiting for a
+collate. :func:`prefetch_to_device` is the same feed over a single iterable.
+
 The host side of a staged batch is a slot of a :class:`StagingRing`: the
 training loops collate every batch into memory that is reused, and rewritten
 only once the upload made from it is known to be over (the fence). What is
@@ -45,11 +50,14 @@ class StagingRing:
     A slot is a dict the collate keeps its arrays in (``Batcher.epoch(...,
     staging=ring)`` fills it; this class never looks inside but to see whose
     memory a placed array reads). The ring holds ``depth`` + 2 of them: the
-    batches staged in the queue, the one being collated and the one the
-    consumer holds, so that in the steady state a slot's last upload finished
-    long before its turn comes again and the fence below is a formality.
+    batches staged in the queue (at most ``depth``: an epoch's end marker
+    takes a place in the queue and no slot), the one being collated and the
+    one the consumer holds, so that in the steady state a slot's last upload
+    finished long before its turn comes again and the fence below is a
+    formality. That count does not care which epoch a batch belongs to, so
+    it holds for a worker that stages across an epoch's end as well.
 
-    The fence: ``prefetch_to_device(..., staging=ring)`` reports what it
+    The fence: a :class:`PrefetchFeed` (``staging=ring``) reports what it
     placed from the newest slot (:meth:`uploaded`); before that slot is
     handed out again (:meth:`acquire`, on the staging thread) those arrays are
     waited on. A slot whose arrays somebody else can still read or whose
@@ -59,7 +67,12 @@ class StagingRing:
     arrays and the slot starts over with fresh ones. A slot that was collated
     but never placed (a batch skipped on resume) is simply reused.
 
-    One ring serves one live ``prefetch_to_device`` at a time."""
+    One ring serves one staging thread at a time (``acquire`` and
+    ``uploaded`` alternate on it): one live feed, which joins a worker before
+    it starts the next. The worker settles the ring when it retires (the
+    run's last epoch staged, ``stop_event``, an error, staged batches
+    dropped), not at every epoch's end: between epochs the fence before each
+    slot's reuse is the safety, as it is within one."""
 
     def __init__(self, depth: int):
         self._slots: list[dict] = [{} for _ in range(max(depth, 0) + 2)]
@@ -128,6 +141,280 @@ def _reads_host(leaf: Any, spans: list[tuple[int, int]]) -> bool:
     return any(lo <= p < hi for p in starts for lo, hi in spans)
 
 
+class _Mark:
+    """What the staging thread queues beside the staged items."""
+
+
+class _Failed(_Mark):
+    """In place of the item the staging thread failed on: the consumer
+    raises it when it comes to that item."""
+
+    def __init__(self, error: BaseException):
+        self.error = error
+
+
+_END = _Mark()      # an epoch's items are all staged
+_HALT = _Mark()     # the worker stopped on ``stop_event``: nothing follows
+
+
+class _Worker:
+    """One staging thread of a :class:`PrefetchFeed` and what is its own:
+    the queue it fills and the flag that tells it the consumer is gone (its
+    own, so that a worker which outlived its join cannot fill the queue of
+    the worker started after it)."""
+
+    def __init__(self, size: int):
+        self.q: queue.Queue = queue.Queue(maxsize=size)
+        self.gone = threading.Event()
+        self.unqueued = 0   # items staged that found the consumer gone
+        self.thread: threading.Thread | None = None
+
+    def put(self, x) -> bool:
+        """put that gives up when the consumer abandoned the worker."""
+        while not self.gone.is_set():
+            try:
+                self.q.put(x, timeout=0.1)
+                return True
+            except queue.Full:
+                continue
+        return False
+
+
+class PrefetchFeed:
+    """One background staging worker for a run of epochs.
+
+    ``draw(key)`` gives the items of the epoch ``key`` names (it is called,
+    and iterated, on the staging thread); ``following(key)`` names the epoch
+    after it, or None where the run ends. The consumer asks for one epoch at
+    a time (:meth:`epoch`) and gets an iterator that ends with that epoch's
+    last item. The worker does not end there: having staged an epoch's last
+    item it goes on to the first items of ``following(key)``, held back only
+    by the queue (``size`` items staged, one more in its hands, whether or
+    not an epoch's end lies among them), so that the next epoch does not
+    begin by waiting for a collate and an upload. It retires (joined, the
+    staging ring settled) where ``following`` says the run ends, when
+    ``stop_event`` is set, on an error, when an epoch's iterator is closed
+    or dropped before its end, and in :meth:`close`.
+
+    What was staged ahead is good for the epoch it was made for and no other:
+    when the next epoch asked for is not the ``following`` of the last one
+    (``key`` compares unequal), the staged items are dropped, the ring is
+    settled and a new worker starts on the epoch asked for, through the same
+    code as a run's first epoch. Keys are compared, never inspected: put in
+    one whatever decides an epoch's items and their placement.
+
+    An error belongs to the item it happened on: it is raised when the
+    consumer comes to that item, after everything staged before it, be that
+    in the epoch during which the worker hit it or in the next.
+
+    Counters: ``prefetch.epoch.carried`` / ``prefetch.epoch.cold`` (epochs
+    that found the worker already on them / that started one, or ran
+    inline), ``prefetch.dropped`` (items staged and thrown away).
+
+    ``size``, ``sharding``, ``transform``, ``place``, ``stall_warn_s`` and
+    ``staging`` are :func:`prefetch_to_device`'s. ``size`` < 1 stages inline
+    in the consumer's thread: no worker, nothing ahead.
+    """
+
+    def __init__(
+        self,
+        draw: Callable[[Any], Iterable[Any]],
+        following: Callable[[Any], Any] | None = None,
+        *,
+        size: int = 2,
+        sharding: Any | None = None,
+        transform: Callable[[Any], Any] | None = None,
+        place: bool = True,
+        stop_event: threading.Event | None = None,
+        stall_warn_s: float = 5.0,
+        staging: StagingRing | None = None,
+    ):
+        self._draw = draw
+        self._following = following if following is not None else lambda key: None
+        self._size = size
+        self._transform = transform
+        if not place:
+            self._place = lambda x: x
+        elif sharding is not None:
+            self._place = lambda x: jax.device_put(x, sharding)
+        else:
+            self._place = jax.device_put
+        self._stop_event = stop_event
+        self._stall_warn_s = stall_warn_s
+        self._staging = staging
+        # the queue depth the consumer sees: pinned at 0 it is the
+        # "input-bound" smoking gun next to a fat prefetch.wait in the report
+        self._depth = obs.gauge("prefetch.queue_depth")
+        self._worker: _Worker | None = None
+        self._ahead: Any = None     # the epoch the live worker stages next
+
+    # ---- the staging thread's side ------------------------------------------
+
+    def _h2d(self, x):
+        def put():
+            chaos.visit("prefetch.h2d")
+            return self._place(x)
+
+        return retry_call(
+            put,
+            policy=_H2D_RETRY,
+            on_retry=lambda info: (
+                obs.counter("resilience.h2d_retry").inc(),
+                obs.event("h2d_retry", **info),
+            ),
+        )
+
+    def _stage(self, items: Iterator[Any]):
+        """Pull the next item of ``items`` and stage it; ``_END`` when they
+        are exhausted. One ``prefetch.stage`` span a batch, on the staging
+        thread's own trace track, from before the pull (upstream's collate
+        runs in it) to after the upload; the pull that finds the epoch at
+        its end records none. ``prefetch.h2d`` inside it covers
+        ``transform`` and the placement: the *enqueue* of ``device_put`` /
+        ``put_global``, not the transfer's completion (no sync is added to
+        learn that)."""
+        stage = obs.span("prefetch.stage").begin()
+        leave = stage.end
+        try:
+            try:
+                x = next(items)
+            except StopIteration:
+                leave = stage.cancel
+                return _END
+            x = chaos.visit("prefetch.stage", x)
+            with obs.span("prefetch.h2d"):
+                x = self._transform(x) if self._transform is not None else x
+                x = self._h2d(x)
+            if self._staging is not None:
+                self._staging.uploaded(x)
+            return x
+        finally:
+            leave()
+
+    def _settle(self) -> None:
+        if self._staging is not None:
+            self._staging.settle()
+
+    def _work(self, w: _Worker, key: Any) -> None:
+        try:
+            items = None
+            while not w.gone.is_set():
+                if self._stop_event is not None and self._stop_event.is_set():
+                    # preempting: what is staged is still the consumer's,
+                    # nothing more is collated, no further epoch is begun
+                    w.put(_HALT)
+                    return
+                if items is None:
+                    items = iter(self._draw(key))
+                x = self._stage(items)
+                if x is _END:
+                    key, items = self._following(key), None
+                    if not w.put(_END) or key is None:
+                        return
+                elif not w.put(x):
+                    w.unqueued += 1     # consumer gone: free the buffers
+                    return
+                self._depth.set(w.q.qsize())
+        except BaseException as e:  # propagate into the consumer, in its place
+            w.put(_Failed(e))
+        finally:
+            self._settle()
+
+    # ---- the consumer's side ------------------------------------------------
+
+    def _get(self, w: _Worker):
+        """q.get that reports (once per episode) when the worker starves the
+        step loop past ``stall_warn_s`` — the wedged-prefetch signature."""
+        if self._stall_warn_s <= 0:
+            return w.q.get()
+        reported = False
+        waited = 0.0
+        while True:
+            try:
+                return w.q.get(timeout=self._stall_warn_s)
+            except queue.Empty:
+                waited += self._stall_warn_s
+                if not reported:
+                    reported = True
+                    obs.counter("resilience.prefetch_stall").inc()
+                    obs.event(
+                        "prefetch_stall",
+                        waited_s=round(waited, 3),
+                        queue_depth=w.q.qsize(),
+                        worker_alive=w.thread.is_alive(),
+                    )
+
+    def epoch(self, key: Any) -> Iterator[Any]:
+        """The staged items of the epoch ``key`` names, ending with its last.
+        One epoch at a time: ask for the next when this iterator has ended
+        or been closed."""
+        if self._size < 1:
+            obs.counter("prefetch.epoch.cold").inc()
+            items = iter(self._draw(key))
+            try:
+                while (x := self._stage(items)) is not _END:
+                    yield x
+            finally:
+                self._settle()
+            return
+
+        w = self._worker
+        if w is not None and key == self._ahead:
+            obs.counter("prefetch.epoch.carried").inc()
+        else:
+            self.close()    # what was staged ahead was made for another epoch
+            obs.counter("prefetch.epoch.cold").inc()
+            w = self._worker = _Worker(self._size)
+            w.thread = threading.Thread(
+                target=self._work, args=(w, key), daemon=True, name="prefetch",
+            )
+            w.thread.start()
+        ended = False
+        try:
+            while True:
+                # the consumer standing still for the input pipeline: every
+                # get, the one that returns an epoch's end marker included
+                with obs.span("prefetch.wait"):
+                    x = self._get(w)
+                # depth as the CONSUMER sees it post-get: 0 here while the
+                # worker is mid-stage means the step loop is input-bound
+                self._depth.set(w.q.qsize())
+                if x is _END:
+                    ended = True
+                    return
+                if x is _HALT:
+                    return
+                if isinstance(x, _Failed):
+                    raise x.error
+                yield x
+        finally:
+            # the worker carries on only past an epoch that was consumed to
+            # its end, and only where the run has a next one; a consumer that
+            # broke out early (or errored) retires it with what it staged
+            self._ahead = self._following(key) if ended else None
+            if self._ahead is None:
+                self.close()
+
+    def close(self) -> None:
+        """Retire the worker: unblock it, throw away what it staged, join
+        it. It settles the ring on its way out. Safe to call twice."""
+        w, self._worker, self._ahead = self._worker, None, None
+        if w is None:
+            return
+        w.gone.set()
+        dropped = 0
+        while True:
+            try:
+                x = w.q.get_nowait()
+            except queue.Empty:
+                break
+            dropped += not isinstance(x, _Mark)
+        w.thread.join(timeout=2.0)
+        dropped += w.unqueued
+        if dropped:
+            obs.counter("prefetch.dropped").inc(dropped)
+
+
 def prefetch_to_device(
     it: Iterable[Any],
     size: int = 2,
@@ -138,7 +425,9 @@ def prefetch_to_device(
     stall_warn_s: float = 5.0,
     staging: StagingRing | None = None,
 ) -> Iterator[Any]:
-    """Iterate ``it``, staging ``size`` elements ahead onto device.
+    """Iterate ``it``, staging ``size`` elements ahead onto device: a
+    :class:`PrefetchFeed` whose run is the one epoch ``it``. The worker
+    starts with the first item asked for and retires with the last.
 
     ``transform`` runs on the host thread before the transfer (e.g. Batch ->
     device-ready pytree); ``sharding`` is forwarded to ``jax.device_put`` so
@@ -151,7 +440,8 @@ def prefetch_to_device(
     ``stop_event`` (optional) makes the staging thread quit before its next
     collate/transfer once set — the preemption path: when SIGTERM lands, the
     grace window should go to the checkpoint fsync, not to prefetching
-    batches that will never run. Items already staged are still yielded.
+    batches that will never run. Items already staged are still yielded, and
+    the iterator ends after them.
 
     ``stall_warn_s``: when the consumer waits longer than this on an empty
     queue while the worker is still alive (a wedged prefetch thread, a
@@ -162,153 +452,14 @@ def prefetch_to_device(
 
     ``staging``: the :class:`StagingRing` that ``it`` collates into
     (``Batcher.epoch(..., staging=ring)``). Every placement is reported to it,
-    and when staging ends the ring is settled. Who may keep what: the yielded
-    (placed) items are the consumer's for as long as it likes; the host
-    arrays of a staged ``Batch`` are the ring's, and ``transform`` must not
-    let them through un-placed unless it is content with the ring noticing
-    and giving that slot up.
+    and when the worker retires the ring is settled. Who may keep what: the
+    yielded (placed) items are the consumer's for as long as it likes; the
+    host arrays of a staged ``Batch`` are the ring's, and ``transform`` must
+    not let them through un-placed unless it is content with the ring
+    noticing and giving that slot up.
     """
-    if not place:
-        _place = lambda x: x
-    elif sharding is not None:
-        _place = lambda x: jax.device_put(x, sharding)
-    else:
-        _place = jax.device_put
-
-    # the queue depth the consumer sees: pinned at 0 it is the "input-bound"
-    # smoking gun next to a fat prefetch.wait in the run report
-    depth = obs.gauge("prefetch.queue_depth")
-    it = iter(it)
-    _END = object()
-
-    def _h2d(x):
-        def put():
-            chaos.visit("prefetch.h2d")
-            return _place(x)
-
-        return retry_call(
-            put,
-            policy=_H2D_RETRY,
-            on_retry=lambda info: (
-                obs.counter("resilience.h2d_retry").inc(),
-                obs.event("h2d_retry", **info),
-            ),
-        )
-
-    def _stage():
-        """Pull the next item from upstream and stage it; ``_END`` when
-        upstream is exhausted. One ``prefetch.stage`` span a batch, on the
-        staging thread's own trace track, from before the pull (upstream's
-        collate runs in it) to after the upload; the pull that finds
-        upstream exhausted records none. ``prefetch.h2d`` inside it covers
-        ``transform`` and the placement: the *enqueue* of ``device_put`` /
-        ``put_global``, not the transfer's completion (no sync is added to
-        learn that)."""
-        stage = obs.span("prefetch.stage").begin()
-        leave = stage.end
-        try:
-            try:
-                x = next(it)
-            except StopIteration:
-                leave = stage.cancel
-                return _END
-            x = chaos.visit("prefetch.stage", x)
-            with obs.span("prefetch.h2d"):
-                x = transform(x) if transform is not None else x
-                x = _h2d(x)
-            if staging is not None:
-                staging.uploaded(x)
-            return x
-        finally:
-            leave()
-
-    def _retire():
-        if staging is not None:
-            staging.settle()
-
-    if size < 1:
-        try:
-            while (x := _stage()) is not _END:
-                yield x
-        finally:
-            _retire()
-        return
-
-    q: queue.Queue = queue.Queue(maxsize=size)
-    err: list[BaseException] = []
-    stop = threading.Event()
-
-    def _put(x) -> bool:
-        """put that gives up when the consumer abandoned the generator."""
-        while not stop.is_set():
-            try:
-                q.put(x, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
-
-    def worker():
-        try:
-            while True:
-                if stop_event is not None and stop_event.is_set():
-                    return  # preempting: yield only what's already staged
-                x = _stage()
-                if x is _END:
-                    return
-                if not _put(x):
-                    return  # consumer gone: drop staged work, free buffers
-                depth.set(q.qsize())
-        except BaseException as e:  # propagate into the consumer
-            err.append(e)
-        finally:
-            _put(_END)
-            _retire()
-
-    def _get_with_stall_watchdog():
-        """q.get that reports (once per episode) when the worker starves the
-        step loop past ``stall_warn_s`` — the wedged-prefetch signature."""
-        if stall_warn_s <= 0:
-            return q.get()
-        reported = False
-        waited = 0.0
-        while True:
-            try:
-                return q.get(timeout=stall_warn_s)
-            except queue.Empty:
-                waited += stall_warn_s
-                if not reported:
-                    reported = True
-                    obs.counter("resilience.prefetch_stall").inc()
-                    obs.event(
-                        "prefetch_stall",
-                        waited_s=round(waited, 3),
-                        queue_depth=q.qsize(),
-                        worker_alive=t.is_alive(),
-                    )
-
-    t = threading.Thread(target=worker, daemon=True, name="prefetch")
-    t.start()
-    try:
-        while True:
-            # the consumer standing still for the input pipeline: every
-            # get, the one that returns the end marker included
-            with obs.span("prefetch.wait"):
-                x = _get_with_stall_watchdog()
-            # depth as the CONSUMER sees it post-get: 0 here while the
-            # worker is mid-stage means the step loop is input-bound
-            depth.set(q.qsize())
-            if x is _END:
-                if err:
-                    raise err[0]
-                return
-            yield x
-    finally:
-        # consumer broke out early (or errored): unblock and retire the worker
-        stop.set()
-        while True:
-            try:
-                q.get_nowait()
-            except queue.Empty:
-                break
-        t.join(timeout=2.0)
+    return PrefetchFeed(
+        lambda key: it, size=size, sharding=sharding, transform=transform,
+        place=place, stop_event=stop_event, stall_warn_s=stall_warn_s,
+        staging=staging,
+    ).epoch(0)

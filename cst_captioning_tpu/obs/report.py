@@ -236,6 +236,15 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         collate = {"staged": staged, "fresh": fresh,
                    "staged_share": staged / (staged + fresh)}
 
+    # how the prefetch feed took the epochs' ends (data/prefetch.py): epochs
+    # that found the worker already on them, epochs that started it, staged
+    # batches thrown away
+    feed = {k: float(counters.get(name, 0)) for k, name in (
+        ("carried", "prefetch.epoch.carried"), ("cold", "prefetch.epoch.cold"),
+        ("dropped", "prefetch.dropped"))}
+    if not any(feed.values()):
+        feed = None
+
     depth = histograms.get("rl.decode.depth")
     decode = None
     if depth and depth.get("count"):
@@ -449,6 +458,7 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         "phases": phases,
         "overlap": overlap_rows,
         "collate": collate,
+        "prefetch": feed,
         "decode": decode,
         "serving": serving,
         "eval": eval_sec,
@@ -541,6 +551,16 @@ def render_report(report: dict[str, Any]) -> str:
             f"collate: {int(c['staged'])} batch(es) into reused staging "
             f"slots, {int(c['fresh'])} into fresh arrays "
             f"({100.0 * c['staged_share']:.1f}% staged)"
+        )
+    f = report.get("prefetch")
+    if f:
+        if not c:
+            lines.append("")
+        lines.append(
+            f"prefetch: {int(f['carried'])} epoch(s) found their first "
+            f"batches staged by the worker of the epoch before, "
+            f"{int(f['cold'])} started it cold; {int(f['dropped'])} staged "
+            "batch(es) dropped"
         )
     d = report.get("decode")
     if d:

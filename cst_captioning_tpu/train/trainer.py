@@ -31,10 +31,8 @@ training (``'degraded'``).
 from __future__ import annotations
 
 import io
-import itertools
 import json
 import os
-import threading
 
 import jax
 import numpy as np
@@ -46,9 +44,9 @@ from cst_captioning_tpu.obs import flops as _flops
 from cst_captioning_tpu.obs import recorder as flight
 from cst_captioning_tpu.ckpt import CheckpointManager, load_params
 from cst_captioning_tpu.config.config import EvalConfig, ExperimentConfig
-from cst_captioning_tpu.data.batcher import Batcher
+from cst_captioning_tpu.data.batcher import Batcher, EpochKey
 from cst_captioning_tpu.data.dataset import CaptionDataset
-from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
+from cst_captioning_tpu.data.prefetch import PrefetchFeed, StagingRing
 from cst_captioning_tpu.eval.evaluator import Evaluator
 from cst_captioning_tpu.metrics.cider import CorpusDF
 from cst_captioning_tpu.models import CaptionModel
@@ -245,6 +243,10 @@ class Trainer:
         # host staging for both phases' prefetched batches (sized by
         # data.prefetch, kept across epochs: a slot is first touched once)
         self._staging = StagingRing(cfg.data.prefetch)
+        # the prefetch worker of the phase that is running, and the (phase,
+        # mesh) its transform places for: _staged_epoch
+        self._feed: PrefetchFeed | None = None
+        self._feed_for: tuple | None = None
         self.steps_per_epoch = self.batcher.num_batches()
         tx = make_optimizer(cfg.train, self.steps_per_epoch)
         sample = next(iter(self.batcher.epoch(shuffle=False)))
@@ -402,9 +404,10 @@ class Trainer:
         )
 
     def close(self) -> None:
-        """Stop background machinery (the health watchdog, the flight
-        recorder). Safe to call twice; the monitor thread is a daemon
-        either way."""
+        """Stop background machinery (the prefetch worker, the health
+        watchdog, the flight recorder). Safe to call twice; the threads are
+        daemons either way."""
+        self._close_feed()
         if self.health is not None:
             self.health.stop()
         if self._stats:
@@ -592,7 +595,10 @@ class Trainer:
         return batch_sharding(self.mesh)
 
     def _device_batches(self, batcher: Batcher, skip: int = 0,
-                        stop_event: threading.Event | None = None):
+                        epochs: int = 1):
+        """The XE epoch ``batcher.epoch_index``, staged to device, less its
+        first ``skip`` batches; ``epochs`` is how many epochs the phase still
+        has to run, this one included (:meth:`_staged_epoch`)."""
         shardings = self._batch_sharding()
 
         def transform(b):
@@ -610,23 +616,15 @@ class Trainer:
             )
             return multihost.put_global(shardings, arrays)
 
-        # mid-epoch resume: drop the first ``skip`` batches of this epoch's
-        # (already deterministic) order before any transform/transfer
-        it = itertools.islice(batcher.epoch(staging=self._staging), skip, None)
-        yield from prefetch_to_device(
-            it,
-            size=self.cfg.data.prefetch,
-            transform=transform,
-            place=shardings is None,
-            stop_event=stop_event,
-            staging=self._staging,
+        return self._staged_epoch(
+            "xe", batcher, skip, epochs, transform, place=shardings is None
         )
 
     def _rl_device_batches(self, batcher: Batcher, skip: int = 0,
-                           stop_event: threading.Event | None = None):
+                           epochs: int = 1):
         """Prefetched RL batches: arrays staged to device (sharded when a mesh
         is in play), video ids + valid mask staying host-side (this process's
-        rows) for the reward."""
+        rows) for the reward. Arguments as :meth:`_device_batches`'s."""
         sharding = self._batch_sharding()
         if sharding is not None and self.sp:
             sharding = (sharding[0], sharding[1])  # (feats, masks) only
@@ -642,17 +640,39 @@ class Trainer:
                 feats, masks = jax.device_put((b.feats, b.feat_masks))
             return (feats, masks, b.video_ids, b.valid)
 
-        it = itertools.islice(
-            batcher.epoch(shuffle=True, staging=self._staging), skip, None
+        return self._staged_epoch(
+            "rl", batcher, skip, epochs, transform, place=False
         )
-        yield from prefetch_to_device(
-            it,
-            size=self.cfg.data.prefetch,
-            transform=transform,
-            place=False,
-            stop_event=stop_event,
-            staging=self._staging,
+
+    def _staged_epoch(self, phase: str, batcher: Batcher, skip: int,
+                      epochs: int, transform, place: bool):
+        """One epoch's batches from the phase's prefetch feed: one worker
+        for the whole phase, which stages the next epoch's first batches
+        while this one drains (data/prefetch.py). The epoch is named by what
+        decides its batches: the batcher, its salt, the epoch index the main
+        thread pinned, ``skip`` (a mid-epoch resume drops the first batches
+        of the, already deterministic, order before any transform or
+        transfer), and the index at which the phase ends. A rollback's
+        re-salt and rewind, a rebuilt batcher or a resume therefore ask for
+        another epoch than the one staged ahead, and the feed starts over."""
+        made_for = (phase, self.mesh)   # what ``transform`` places for
+        if self._feed is None or self._feed_for != made_for:
+            self._close_feed()
+            self._feed = PrefetchFeed(
+                lambda key: key.batches(self._staging), EpochKey.following,
+                size=self.cfg.data.prefetch, transform=transform, place=place,
+                staging=self._staging,
+            )
+            self._feed_for = made_for
+        index = batcher.epoch_index
+        return self._feed.epoch(
+            EpochKey(batcher, batcher.salt, index, skip, index + epochs)
         )
+
+    def _close_feed(self) -> None:
+        feed, self._feed, self._feed_for = self._feed, None, None
+        if feed is not None:
+            feed.close()
 
     # ---- resilience helpers ------------------------------------------------
 
@@ -1153,28 +1173,40 @@ class Trainer:
         sentinel = self._make_sentinel("xe")
         last_val = None
         run = {"first_step": True}  # compile-step meter exclusion, phase-wide
-        with PreemptionHandler() as pre:
-            while self.xe_epochs < target:
-                try:
-                    last_val = self._xe_epoch(meter, profiler, sentinel, pre, run)
-                except RollbackRequested as e:
-                    self._apply_rollback("xe", e, sentinel)
-                except PeerLost as e:
-                    # strict keeps today's abort-and-full-restart (the saved
-                    # drain resumes bit-exactly on the full mesh); degraded
-                    # shrinks the mesh and keeps training on the survivors
-                    if self.cfg.train.elastic != "degraded":
-                        raise
-                    self._continue_degraded("xe", e)
-                    run["first_step"] = True  # recompile on the shrunk mesh
-                except health_mod.HostRejoin as e:
-                    if self._continue_regrown("xe", e):
-                        run["first_step"] = True  # recompile on the full mesh
+        try:
+            with PreemptionHandler() as pre:
+                while self.xe_epochs < target:
+                    try:
+                        last_val = self._xe_epoch(
+                            meter, profiler, sentinel, pre, run,
+                            epochs_left=target - self.xe_epochs,
+                        )
+                    except RollbackRequested as e:
+                        self._apply_rollback("xe", e, sentinel)
+                    except PeerLost as e:
+                        # strict keeps today's abort-and-full-restart (the
+                        # saved drain resumes bit-exactly on the full mesh);
+                        # degraded shrinks the mesh and keeps training on
+                        # the survivors
+                        if self.cfg.train.elastic != "degraded":
+                            raise
+                        self._continue_degraded("xe", e)
+                        run["first_step"] = True  # recompile on the shrunk mesh
+                    except health_mod.HostRejoin as e:
+                        if self._continue_regrown("xe", e):
+                            run["first_step"] = True  # recompile on the full mesh
+        finally:
+            # left by an exception raised past an epoch's last batch (a save
+            # at the epoch's end): the worker is on the next epoch by then
+            self._close_feed()
         return last_val
 
-    def _xe_epoch(self, meter, profiler, sentinel, pre, run) -> float | None:
+    def _xe_epoch(self, meter, profiler, sentinel, pre, run,
+                  epochs_left: int = 1) -> float | None:
         """One XE epoch (possibly a resumed remainder): step loop, sentinel,
-        mid-epoch saves, epoch-end validation + checkpoint."""
+        mid-epoch saves, epoch-end validation + checkpoint. ``epochs_left``:
+        the phase's epochs still to run, this one included (the prefetch
+        worker stages ahead into the next one, and not past the last)."""
         cfg = self.cfg
         weighted = cfg.train.loss == "wxe"
         log_every = cfg.train.log_every_steps
@@ -1192,15 +1224,15 @@ class Trainer:
             obs.set_context(phase="xe", epoch=self.epoch + 1)
         meter.begin_epoch()
         losses = []
-        stop = threading.Event()
+        batches = self._device_batches(self.batcher, skip=skip,
+                                       epochs=epochs_left)
         # xe.step spans cover the loop body (dispatch + bookkeeping) and
         # prefetch.wait the host's wait on the input pipeline, so the report
         # splits compute-bound from data-bound epochs; the xe.epoch span's
         # SELF time is the unattributed remainder (the epoch-end flushes)
         with obs.span("xe.epoch"):
             try:
-                for arrays in self._device_batches(self.batcher, skip=skip,
-                                                   stop_event=stop):
+                for arrays in batches:
                     with obs.span("xe.step"):
                         feats, masks, labels, mask, weights, valid = arrays
                         # invalid rows get zero weight -> excluded from loss
@@ -1269,7 +1301,10 @@ class Trainer:
                             sentinel.flush()
                             self._save_step_ckpt("xe", step_no, batch_no)
             finally:
-                stop.set()
+                # left before its end (a preemption or peer-loss save, a
+                # rollback, an error): the prefetch worker retires with what
+                # it staged; consumed to its end: it is on the next epoch
+                batches.close()
             profiler.stop()
             # a SIGTERM that lands between the last step and here must not let
             # the epoch counters advance past the state actually saved
@@ -1419,7 +1454,7 @@ class Trainer:
                     try:
                         last_val = self._rl_epoch(
                             scst, rl_batcher, meter, profiler, sentinel, pre,
-                            run,
+                            run, epochs_left=target - self.rl_epochs,
                         )
                     except RollbackRequested as e:
                         self._apply_rollback("rl", e, sentinel)
@@ -1440,11 +1475,13 @@ class Trainer:
                             run["first_step"] = True
         finally:
             self._rl_batcher = None
+            self._close_feed()      # as in train_xe
         return last_val
 
     def _rl_epoch(self, scst, rl_batcher, meter, profiler, sentinel, pre,
-                  run) -> float | None:
-        """One RL epoch (possibly a resumed remainder)."""
+                  run, epochs_left: int = 1) -> float | None:
+        """One RL epoch (possibly a resumed remainder); ``epochs_left`` as
+        :meth:`_xe_epoch`'s."""
         cfg = self.cfg
         log_every = cfg.train.log_every_steps
         # keyed off the global epoch so a resumed RL phase replays the same
@@ -1537,7 +1574,8 @@ class Trainer:
         # to device by a host thread. pipelined=False: strict on-policy.
         # should_stop: a SIGTERM stops consuming at the next batch boundary
         # and the pipeline drains, so state == batch_counter steps exactly
-        stop = threading.Event()
+        batches = self._rl_device_batches(rl_batcher, skip=skip,
+                                          epochs=epochs_left)
         # the rl.epoch span's self time is what no span inside it claims
         # (rl.decode/reward/update, prefetch.wait, rl.epoch.drain): rng
         # splits, step bookkeeping, the epoch's unattributed remainder
@@ -1549,8 +1587,7 @@ class Trainer:
             try:
                 self.state, _ = scst.train_epoch(
                     self.state,
-                    self._rl_device_batches(rl_batcher, skip=skip,
-                                            stop_event=stop),
+                    batches,
                     ep_rng,
                     on_step=on_step,
                     pipelined=cfg.rl.pipelined,
@@ -1561,7 +1598,7 @@ class Trainer:
                     seam_sink=seam_sink if seam_capable else None,
                 )
             finally:
-                stop.set()
+                batches.close()     # as in _xe_epoch
             profiler.stop()
             if pre.requested:
                 self._preempt_save(

@@ -614,3 +614,273 @@ def test_collate_counters_and_take_mode(synth, monkeypatch):
     assert len(calls) == 3 * n * 2 * len(_BOTH)
     assert all(has_out and mode != "raise" for has_out, mode in calls)
     ds.close()
+
+
+# ---- one prefetch feed for a run of epochs -----------------------------------
+
+_FEED_KW = dict(batch_size=4, max_len=7, mode="caption", seq_per_vid=3, seed=2)
+_FEED_COUNTERS = ("prefetch.epoch.cold", "prefetch.epoch.carried",
+                  "prefetch.dropped")
+
+
+def _feed_counts():
+    from cst_captioning_tpu import obs
+
+    return np.array([obs.counter(n).snapshot() for n in _FEED_COUNTERS])
+
+
+def _placed(b):
+    """The RL transform's shape: arrays placed, the ids left on the host."""
+    import jax
+
+    flat = dict(_flat(b))
+    ids = flat.pop("video_ids")
+    return jax.device_put(flat), list(ids)
+
+
+def _cold_epoch(ds, salt, epoch_index, skip=0, **kw):
+    """The batches of a cold ``Batcher.epoch()`` drawn the old way: a fresh
+    batcher whose own attributes carry the key."""
+    b = Batcher(ds, **{**_FEED_KW, **kw})
+    b.salt, b.epoch_index = salt, epoch_index
+    out = [_flat(x) for x in b.epoch()][skip:]
+    assert b.epoch_index == epoch_index + 1
+    return out
+
+
+def _assert_epoch(got, refs):
+    import jax
+
+    assert len(got) == len(refs)
+    for (arrays, ids), ref in zip(got, refs, strict=True):
+        _assert_same({**jax.device_get(arrays), "video_ids": ids}, ref)
+
+
+def _feed(ring, size, **kw):
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import PrefetchFeed
+
+    return PrefetchFeed(lambda key: key.batches(ring), EpochKey.following,
+                        size=size, transform=_placed, place=False,
+                        staging=ring, **kw)
+
+
+def _no_prefetch_thread(n_before):
+    _wait_for(lambda: threading.active_count() <= n_before)
+    assert not [t for t in threading.enumerate() if t.name == "prefetch"]
+
+
+@pytest.mark.parametrize("ringed", [True, False], ids=["ring", "fresh"])
+@pytest.mark.parametrize("size", [0, 1, 2])
+def test_feed_yields_each_cold_epochs_batches(synth, size, ringed):
+    """Three epochs through one feed, every item kept to the end: epoch by
+    epoch exactly the batches (ids, valid, feature and label bytes) of three
+    cold Batcher.epoch() generators with the same (seed, salt, epoch_index);
+    the batcher's own index is left alone; one worker served all three."""
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import StagingRing
+
+    ds, plain = _open(synth, True), _open(synth, False)
+    n_before = threading.active_count()
+    batcher = Batcher(ds, **_FEED_KW)
+    batcher.epoch_index = 99                    # the main thread's: not used
+    ring = StagingRing(size) if ringed else None
+    feed = _feed(ring, size)
+    c0 = _feed_counts()
+    got = [list(feed.epoch(EpochKey(batcher, 1, e, 0, 8))) for e in (5, 6, 7)]
+    assert batcher.epoch_index == 99
+    _no_prefetch_thread(n_before)               # index 7 was the run's last
+    for e, epoch in zip((5, 6, 7), got):
+        _assert_epoch(epoch, _cold_epoch(plain, salt=1, epoch_index=e))
+    assert len(got[0]) > size + 2
+    # cold once (inline: every epoch), carried ever after, nothing thrown away
+    want = [1, 2, 0] if size else [3, 0, 0]
+    assert (_feed_counts() - c0).tolist() == want
+    if ring is not None:
+        assert ring._pending == [None] * (size + 2)
+    ds.close()
+    plain.close()
+
+
+@pytest.mark.parametrize("changed", ["salt", "epoch_index", "skip", "batcher"])
+def test_feed_drops_what_was_staged_for_another_epoch(synth, changed):
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import StagingRing
+
+    ds, plain = _open(synth, True), _open(synth, False)
+    n_before = threading.active_count()
+    batcher = Batcher(ds, **_FEED_KW)
+    ring = StagingRing(2)
+    settles = []
+    settle = ring.settle
+    ring.settle = lambda: (settles.append(1), settle())[1]
+    feed = _feed(ring, 2)
+    key = EpochKey(batcher, 0, 0, 0, 10)
+    c0 = _feed_counts()
+    first = list(feed.epoch(key))
+    _assert_epoch(first, _cold_epoch(plain, salt=0, epoch_index=0))
+    # the worker is on epoch 1 by now: let it fill the queue
+    _wait_for(lambda: feed._worker.q.full())
+    assert not settles
+    asked = {
+        "salt": key._replace(salt=3, index=1),          # a rollback's re-salt
+        "epoch_index": key,                             # ... and its rewind
+        "skip": key._replace(index=1, skip=2),          # a resume mid-epoch
+        "batcher": key._replace(index=1, batcher=Batcher(ds, **_FEED_KW)),
+    }[changed]
+    got = list(feed.epoch(asked))
+    _assert_epoch(got, _cold_epoch(plain, salt=asked.salt,
+                                   epoch_index=asked.index, skip=asked.skip))
+    cold, carried, dropped = (_feed_counts() - c0).tolist()
+    assert (cold, carried) == (2, 0)
+    assert 2 <= dropped <= 3                    # the queue's two, one in hand
+    assert settles                              # by the worker that was retired
+    # and from there the feed carries on as from any first epoch
+    nxt = asked.following()
+    _assert_epoch(list(feed.epoch(nxt)),
+                  _cold_epoch(plain, salt=nxt.salt, epoch_index=nxt.index))
+    assert (_feed_counts() - c0).tolist()[:2] == [2, 1]
+    feed.close()
+    _no_prefetch_thread(n_before)
+    assert ring._pending == [None] * 4
+    ds.close()
+    plain.close()
+
+
+def test_feed_stops_at_the_last_epoch_and_on_stop_event(synth):
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import PrefetchFeed, StagingRing
+
+    ds = _open(synth, True)
+    n_before = threading.active_count()
+    batcher = Batcher(ds, **_FEED_KW)
+    n = batcher.num_batches()
+    ring = StagingRing(1)
+    drawn, pulled = [], []
+
+    def draw(key):
+        drawn.append(key.index)
+        for b in key.batches(ring):
+            pulled.append(key.index)
+            yield b
+
+    # (a) told that index 1 is the last: nothing of index 2 is drawn
+    feed = PrefetchFeed(draw, EpochKey.following, size=1, transform=_placed,
+                        place=False, staging=ring)
+    for e in (0, 1):
+        assert len(list(feed.epoch(EpochKey(batcher, 0, e, 0, 2)))) == n
+    _no_prefetch_thread(n_before)
+    assert drawn == [0, 1] and feed._worker is None
+    assert ring._pending == [None] * 3
+
+    # (b) stop_event while epoch 0 is being consumed: what is staged is
+    # still yielded, the epoch then ends, epoch 1 is never begun
+    del drawn[:], pulled[:]
+    stop = threading.Event()
+    feed = PrefetchFeed(draw, EpochKey.following, size=1, transform=_placed,
+                        place=False, staging=ring, stop_event=stop)
+    c0 = _feed_counts()
+    it = feed.epoch(EpochKey(batcher, 0, 0, 0, 5))
+    next(it)
+    _wait_for(lambda: len(pulled) == 3)         # one queued, one in hand
+    stop.set()
+    assert len(list(it)) == 2
+    _no_prefetch_thread(n_before)
+    assert drawn == [0] and pulled == [0] * 3
+    assert (_feed_counts() - c0).tolist() == [1, 0, 0]
+    assert ring._pending == [None] * 3
+    ds.close()
+
+
+def test_feed_raises_an_error_on_the_batch_it_happened_on(synth):
+    """Staging epoch 1's first batch fails while epoch 0 is being consumed:
+    epoch 0 ends cleanly, the error comes when epoch 1 asks for that batch,
+    and the feed serves the epoch after a repair."""
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import PrefetchFeed, StagingRing
+
+    ds, plain = _open(synth, True), _open(synth, False)
+    n_before = threading.active_count()
+    batcher = Batcher(ds, **_FEED_KW)
+    ring = StagingRing(2)
+    broken = {1}
+    failed = threading.Event()
+
+    def draw(key):
+        for k, b in enumerate(key.batches(ring)):
+            if key.index in broken and k == 0:
+                failed.set()
+                raise RuntimeError("boom")
+            yield b
+
+    feed = PrefetchFeed(draw, EpochKey.following, size=2, transform=_placed,
+                        place=False, staging=ring)
+    key = EpochKey(batcher, 0, 0, 0, 3)
+    it = feed.epoch(key)
+    head = [next(it) for _ in range(batcher.num_batches() - 1)]
+    assert failed.wait(5.0)     # on the worker, epoch 0's last batch not taken
+    _assert_epoch(head + list(it), _cold_epoch(plain, salt=0, epoch_index=0))
+    c0 = _feed_counts()
+    with pytest.raises(RuntimeError, match="boom"):
+        next(feed.epoch(key.following()))
+    assert (_feed_counts() - c0).tolist() == [0, 1, 0]
+    _no_prefetch_thread(n_before)
+    assert ring._pending == [None] * 4
+    broken.clear()
+    _assert_epoch(list(feed.epoch(key.following())),
+                  _cold_epoch(plain, salt=0, epoch_index=1))
+    feed.close()
+    _no_prefetch_thread(n_before)
+    ds.close()
+    plain.close()
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_feed_counts_one_cold_epoch_and_the_rest_carried(synth, n):
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import StagingRing
+
+    ds = _open(synth, True)
+    batcher = Batcher(ds, batch_size=4, max_len=7, mode="video", seed=1)
+    ring = StagingRing(2)
+    feed = _feed(ring, 2)
+    c0 = _feed_counts()
+    for e in range(n):
+        got = list(feed.epoch(EpochKey(batcher, 0, e, 0, n)))
+        assert len(got) == batcher.num_batches()
+    assert (_feed_counts() - c0).tolist() == [1, n - 1, 0]
+    assert feed._worker is None                 # retired with the last epoch
+    ds.close()
+
+
+def test_feed_keeps_every_epochs_items_under_a_short_switch_interval():
+    """Many short epochs (some empty) with the interpreter switching threads
+    every few bytecodes, the consumer now and then asking for another epoch
+    than the one staged ahead or leaving one early: every epoch yields its
+    own items in order, and no worker is left behind."""
+    import sys
+
+    from cst_captioning_tpu.data.prefetch import PrefetchFeed
+
+    n_before = threading.active_count()
+    items = lambda key: [(key, i) for i in range(key % 4)]
+    feed = PrefetchFeed(items, lambda key: key + 1 if key % 50 else None,
+                        size=2, place=False, stall_warn_s=0)
+    rng = np.random.default_rng(0)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        key, deadline = 1, time.monotonic() + 20.0
+        for _ in range(400):
+            assert time.monotonic() < deadline
+            it = feed.epoch(key)
+            if rng.random() < 0.1 and key % 4:
+                assert next(it) == (key, 0)     # left early: staged, dropped
+                it.close()
+            else:
+                assert list(it) == items(key)
+            key = key + 1 if rng.random() < 0.85 else int(rng.integers(1, 500))
+        feed.close()
+    finally:
+        sys.setswitchinterval(interval)
+    _no_prefetch_thread(n_before)

@@ -1267,6 +1267,66 @@ def test_rl_epoch_spans_name_the_turnover_and_the_reward_parts(
     assert count["prefetch.wait"] == steps + epochs
 
 
+def test_one_prefetch_worker_serves_a_phase_and_the_report_says_so(
+    chaos_datasets, tmp_path,
+):
+    """XE then RL through the Trainer: each phase's first epoch starts the
+    prefetch worker cold and every later one finds it on its batches; the
+    worker draws an epoch's order once, stops at the phase's last epoch and
+    is gone after it; the report's overlap section carries the line."""
+    import threading
+
+    from cst_captioning_tpu.config.config import (
+        DataConfig,
+        EvalConfig,
+        ExperimentConfig,
+        ModelConfig,
+        RLConfig,
+        TrainConfig,
+    )
+    from cst_captioning_tpu.train.trainer import Trainer
+
+    train_ds = chaos_datasets
+    ckpt, run_dir = str(tmp_path / "ckpt"), str(tmp_path / "obs")
+    xe, rl = 2, 3
+    cfg = ExperimentConfig(
+        name="feed",
+        model=ModelConfig(
+            vocab_size=len(train_ds.vocab), modalities=(("resnet", 16),),
+            d_embed=16, d_hidden=16, d_att=8, encoder="meanpool",
+            dropout=0.0, max_len=8, max_frames=4, dtype="float32",
+        ),
+        data=DataConfig(batch_size=4, seq_per_vid=2),
+        train=TrainConfig(
+            lr=5e-3, ckpt_dir=ckpt, seed=0, epochs=xe, eval_every_epochs=100,
+            obs=True, obs_dir=run_dir,
+        ),
+        rl=RLConfig(enabled=True, num_rollouts=2, lr=1e-3, epochs=rl,
+                    pipelined=True),
+        eval=EvalConfig(beam_size=1, max_len=8),
+    )
+    tr = Trainer(cfg, train_ds, None, log_path=ckpt + "/ev.jsonl",
+                 use_mesh=False)
+    tr.train_xe()
+    assert not [t for t in threading.enumerate() if t.name == "prefetch"]
+    tr.train_rl()
+    assert not [t for t in threading.enumerate() if t.name == "prefetch"]
+    assert tr.batcher.epoch_index == xe - 1     # pinned by the main thread only
+    tr.close()
+    obs.shutdown()
+    sp = spans_of(read_events(run_dir))
+    # (the one more is Trainer.__init__'s sample batch, on the main thread)
+    orders = collections.Counter(s["parent"] for s in sp
+                                 if s["name"] == "data.epoch_order")
+    assert orders == {"prefetch.stage": xe + rl, "setup": 1}
+    rep = report_run(run_dir)
+    assert rep["prefetch"] == {"carried": xe - 1 + rl - 1, "cold": 2,
+                               "dropped": 0}
+    assert ("prefetch: 3 epoch(s) found their first batches staged by the "
+            "worker of the epoch before, 2 started it cold; 0 staged "
+            "batch(es) dropped") in render_report(rep)
+
+
 def test_spans_land_in_a_profiler_trace(tmp_path):
     """While a recorder is installed a nesting span is also a profiler
     annotation: a jax.profiler trace taken meanwhile holds host events of
