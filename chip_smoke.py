@@ -43,7 +43,8 @@ import traceback
 
 import numpy as np
 
-# eval lanes-vs-reference bar: scripts/bench_gate.py's tie-noise floor
+# eval lanes-vs-reference bar: under bf16 near-tie argmax flips cost a few
+# tokens in a hundred; a wrong program costs most of them
 TIE_NOISE_FLOOR = 0.9
 # served-vs-offline and kernel-vs-XLA token parity are REPORTED (the model is
 # barely trained, bf16, and the kernels compute in f32: near-tie argmax flips
@@ -70,8 +71,8 @@ class Scale:
     val_videos: int = 64
     test_videos: int = 64
     batch: int = 64
-    large_batch: int = 1792                 # bench.py BATCH / DEFAULT_CHUNKS:
-    large_chunks: int = 5                   # the first benchmark cell's point
+    large_batch: int = 1792                 # the benchmark cells' operating
+    large_chunks: int = 5                   # point (benchmark/workloads/)
     serve_requests: int = 32
     serve_capacity: int = 8
     serve_frames: tuple[int, ...] = (5, 20)
@@ -258,7 +259,7 @@ def _close(a: float, b: float, rel: float = 2e-2, floor: float = 0.0) -> bool:
 
 
 def _match(a, b) -> float:
-    """Token match fraction, bench_decode.py's spelling."""
+    """Token match fraction."""
     return float(np.mean(np.asarray(a) == np.asarray(b)))
 
 

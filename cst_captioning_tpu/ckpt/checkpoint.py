@@ -57,6 +57,15 @@ def _keys_to_data(tree):
     )
 
 
+def host_copy(state):
+    """The explicit device->host read-back of a state about to be saved.
+    Typed keys leave the device as raw key data: ``jax.device_get`` of a
+    typed key wraps what it read into a new key *on the device*, an implicit
+    host->device transfer (``jax.transfer_guard`` vetoes it in the epoch
+    loops) for a value :func:`save_state` unwraps again anyway."""
+    return jax.device_get(_keys_to_data(state))
+
+
 def _data_to_keys(loaded, template):
     """Re-wrap raw key data as typed keys wherever the template has them."""
     return jax.tree.map(
@@ -88,7 +97,7 @@ def save_state(ckpt_dir: str, name: str, state: TrainState,
         shutil.rmtree(tmp)
     os.makedirs(tmp, exist_ok=True)
     # fully materialize on host before serializing
-    host_state = _keys_to_data(jax.device_get(state))
+    host_state = host_copy(state)
     state_bytes = serialization.to_bytes(host_state)
     infos_bytes = json.dumps(infos or {}, indent=2, default=float).encode()
     write_bytes_durable(os.path.join(tmp, STATE_FILE), state_bytes)

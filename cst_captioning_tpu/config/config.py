@@ -58,10 +58,10 @@ class ModelConfig:
     seq_axis: str = ""
     # temporal-attention context implementation: "xla" (the fused composite
     # XLA compiles, default) or "pallas" (ops/attention_pallas.py — blockwise
-    # online softmax over the frame axis; parity-tested. Measured on v5e:
-    # XLA ties or beats it (within ±10%) at every M up to 8192 — see
-    # BENCH_ATTENTION.json — so "xla" is recommended everywhere; the kernel
-    # is long-context insurance)
+    # online softmax over the frame axis; parity-tested. A round-4 builder's
+    # run on a v5e had XLA tying or beating it at every M up to 8192
+    # (BASELINE.md), no cell reaches it, so "xla" is recommended everywhere
+    # and ROADMAP.md D2 lists the kernel for deletion)
     attention_impl: str = "xla"
     # decode-step implementation for the greedy/sampling/fused RL decode
     # loops (README "Decode fast path"): "xla" (the composite the loops'
@@ -70,8 +70,8 @@ class ModelConfig:
     # stack + output projection with the decoder weights resident in VMEM
     # across the row grid). Decode is inference-only (REINFORCE gradients go
     # through the teacher-forced update path), so the kernel has no VJP;
-    # parity-swept against the XLA step in tests/test_ops_decode_pallas.py,
-    # benchmarked by bench_decode.py (BENCH_DECODE.json)
+    # parity-swept against the XLA step in tests/test_ops_decode_pallas.py;
+    # on the chip not measured (no cell reaches it, PERF.md section 7)
     decode_impl: str = "xla"
     # fused RL decode stride: steps per driving-loop iteration (and per
     # pallas_call when decode_impl="pallas" — the multi-step kernel keeps

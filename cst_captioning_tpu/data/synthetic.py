@@ -22,7 +22,7 @@ Two caption styles:
   mirrors real caption pools — many roughly-agreeing captions around a few
   central phrasings — so the consensus reward points at structure that
   GENERALIZES to held-out videos of the same topic. Use for any XE-vs-CST
-  quality comparison (bench_recipe.py).
+  quality comparison (chip_smoke.py does).
 """
 
 from __future__ import annotations
@@ -61,7 +61,7 @@ def make_synthetic_dataset(
                                      # CAN memorize per-video targets through
                                      # it; pass ~0.05 for generalization
                                      # studies where that channel must be
-                                     # closed (bench_recipe.py)
+                                     # closed
 ) -> dict[str, str]:
     """Writes h5 + info.json under ``out_dir``; returns the path map.
 

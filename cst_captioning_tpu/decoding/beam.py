@@ -29,8 +29,7 @@ Two implementations share that candidate math (``_topk_expand``):
   the seam where decoding/fused.py compacts finished columns.
 
 Lane-vs-reference token- and score-bit-exactness at beam∈{1,3,5} is pinned
-in tests/test_decoding.py and re-asserted in every bench_eval.py run (the
-parity block in BENCH_EVAL_E2E.json). The guarantee rests on per-row bit-
+in tests/test_decoding.py. The guarantee rests on per-row bit-
 stability of the decode step across batch layouts (vmap lanes over [B] vs
 one flat [B*W] batch) — the same property that makes the fused loop's
 greedy lane bit-exact against the two-loop reference.

@@ -174,10 +174,15 @@ def _stride_decode(model, params, enc: EncoderOutput, step_keys, B, T, S, K,
             # loop — with it, the step body sees plain arrays and compiles
             # to the exact same program, which is what makes compaction
             # bit-exact rather than merely close (a gather is a copy
-            # anyway, so the barrier costs nothing extra)
-            carry, token, finished, enc_c = jax.lax.optimization_barrier(
-                (carry, token, finished, enc_c)
+            # anyway, so the barrier costs nothing extra). The state and
+            # the encoder bank go through barriers of their own: one barrier
+            # types every output varying over the union of its operands'
+            # axes, and under sequence parallelism the frame-sharded bank
+            # would mark the 'seq'-replicated loop state as 'seq'-varying
+            carry, token, finished = jax.lax.optimization_barrier(
+                (carry, token, finished)
             )
+            enc_c = jax.lax.optimization_barrier(enc_c)
         else:
             perm = None
             n_active = jnp.int32(B)
@@ -257,7 +262,7 @@ def fused_decode(
 
     ``decode_stride`` / ``compact`` default from ``model.cfg``
     (``decode_stride`` / ``decode_compact``); pass explicit values to
-    override per call (the parity tests and bench sweep do). Stride 1
+    override per call (the parity tests do). Stride 1
     without compaction is the per-step loop every other combination is
     pinned token/logprob-exact against.
     """

@@ -3,9 +3,9 @@
 The RL reward already scores CIDEr-D at ~6.5 µs/row via ``native/creward.cpp``
 (flat-array merge joins, parity-pinned against the Python ``metrics.CiderD``
 oracle in tests/test_rl.py). The eval path ran the pure-Python scorer — and
-round-5's end-to-end eval measurement (`BENCH_EVAL_E2E.json`) put host metric
-scoring at 71% of the whole config-5 pipeline, with CIDEr/CIDEr-D the largest
-single shares. This adapter lets :class:`metrics.scorer.CaptionScorer` route
+a round-5 builder's end-to-end eval run (BASELINE.md, "Eval end-to-end") put
+host metric scoring at 71% of the whole config-5 pipeline, with CIDEr/CIDEr-D
+the largest single shares. This adapter lets :class:`metrics.scorer.CaptionScorer` route
 its CIDEr-D column through the same kernel:
 
 - scoring stays in *string space*: reference and hypothesis words are

@@ -1,46 +1,38 @@
-"""Analytic matmul-FLOP cost models + chip peak tables (pure stdlib).
+"""Analytic matmul-FLOP cost models + the chip peak table (pure stdlib).
 
-One source of truth for the numbers three consumers previously duplicated
-or could not share:
+One source of truth for the numbers its consumers would otherwise duplicate:
 
-- ``bench.py`` / ``bench_decode.py`` — roofline MFU / bw_util columns;
 - the trainer / SCST loop — per-step ``flops.<phase>`` counters feeding the
   run report's MFU column (``obs/report.py``);
 - ``cli.obs_report`` — which must aggregate WITHOUT importing jax, hence
   everything here is plain arithmetic over ints.
 
-Conventions (unchanged from bench.py's original model): FLOPs count matmuls
-only as ``2*m*n*k`` — elementwise/softmax work is ignored (the model is
-matmul-dominated); the backward pass is taken as 2x the forward (3x
-overall). ``E`` below is the encoder output dim (== ``d_embed``: every
-modality is embedded to ``d_embed`` and concatenated on the frame axis, so
-``M = n_modalities * F``).
+Conventions: FLOPs count matmuls only as ``2*m*n*k`` — elementwise/softmax
+work is ignored (the model is matmul-dominated); the backward pass is taken
+as 2x the forward (3x overall). ``E`` below is the encoder output dim
+(== ``d_embed``: every modality is embedded to ``d_embed`` and concatenated
+on the frame axis, so ``M = n_modalities * F``).
 """
 
 from __future__ import annotations
 
-# peak dense bf16 FLOP/s and HBM bandwidth per chip by device kind (public
-# TPU specs); the match is substring-based and callers carry the assumed
-# values in their JSON so they cannot be misread as measured. A kind that is
-# not in the table has no peak: a utilization against a guessed peak is a
-# number about nothing, so the lookups raise instead of defaulting
+# peak dense bf16 FLOP/s per chip by device kind (public TPU specs); the
+# match is substring-based. A kind that is not in the table has no peak: a
+# utilization against a guessed peak is a number about nothing, so the
+# lookup raises instead of defaulting
 PEAK_BF16_FLOPS = (
     ("v6e", 918e12), ("v6 lite", 918e12),
     ("v5p", 459e12),
     ("v5e", 197e12), ("v5 lite", 197e12), ("v5litepod", 197e12),
     ("v4", 275e12),
 )
-PEAK_HBM_BYTES = (
-    ("v6e", 1640e9), ("v6 lite", 1640e9),
-    ("v5p", 2765e9),
-    ("v5e", 819e9), ("v5 lite", 819e9), ("v5litepod", 819e9),
-    ("v4", 1228e9),
-)
 
 
-def _peak(table, device_kind: str) -> float:
+def peak_flops(device_kind: str) -> float:
+    """Published peak dense bf16 FLOP/s for a ``device_kind`` string;
+    raises ``KeyError`` for a kind the table does not hold (e.g. "cpu")."""
     kind = device_kind.lower()
-    for frag, peak in table:
+    for frag, peak in PEAK_BF16_FLOPS:
         if frag in kind:
             return peak
     raise KeyError(
@@ -48,18 +40,6 @@ def _peak(table, device_kind: str) -> float:
         "against it is not measured (add the chip to obs/flops.py with its "
         "source to measure it)"
     )
-
-
-def peak_flops(device_kind: str) -> float:
-    """Published peak dense bf16 FLOP/s for a ``device_kind`` string;
-    raises ``KeyError`` for a kind the table does not hold (e.g. "cpu")."""
-    return _peak(PEAK_BF16_FLOPS, device_kind)
-
-
-def peak_hbm(device_kind: str) -> float:
-    """Published peak HBM bytes/s for a ``device_kind`` string; raises
-    ``KeyError`` for a kind the table does not hold."""
-    return _peak(PEAK_HBM_BYTES, device_kind)
 
 
 def enc_and_per_tok_flops(
@@ -174,7 +154,7 @@ def xe_flops_per_row(
 # The analytic counters above are matmul-only estimates; XLA's own HLO cost
 # analysis counts the COMPILED program (every fused op, the real
 # elementwise/softmax work, rematerialization). When a jitted callable and
-# its example arguments are at hand — benches, the serving engine — prefer
+# its example arguments are at hand — the SCST update does — prefer
 # compiled-program FLOPs for the MFU ledger and fall back to the analytic
 # model when the backend can't report them (interpret-mode Pallas calls,
 # older runtimes, lowerings without cost data). jax imports stay INSIDE the

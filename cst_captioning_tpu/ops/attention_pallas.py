@@ -11,14 +11,14 @@ streams the frame axis through VMEM in blocks with a flash-attention-style
 online softmax: running (row max, denominator, weighted-sum accumulator)
 scratch, one pass over M, and only [B, E] ever written back.
 
-PERF STATUS (measured round 4, TPU v5e, `bench_attention.py` /
-BENCH_ATTENTION.json): the XLA composite ties or beats this kernel (within
-±10%) at every resolvable M in {40..8192} x {f32, bf16} — both run at ~730 GB/s of
-HBM, i.e. the op is bandwidth-bound on its inputs and current XLA already
-fuses the [B, M, d_att] tanh intermediate instead of materializing it (the
-original motivation for this kernel). Kept as opt-in
-(model.attention_impl="pallas") long-context insurance against XLA fusion
-regressions; there is no configuration where it is recommended today.
+PERF STATUS: no benchmark cell reaches this kernel, so on today's code it is
+not measured. A builder's run of round 4 (one TPU v5e, code older than PR 1;
+BASELINE.md, "Pallas attention kernel") found the XLA composite tying or
+beating it (within ±10%) at every M in {40..8192} x {f32, bf16}: the op is
+bandwidth-bound on its inputs and XLA already fuses the [B, M, d_att] tanh
+intermediate instead of materializing it (the original motivation for this
+kernel). Opt-in (model.attention_impl="pallas"); there is no configuration
+where it is recommended, and ROADMAP.md D2 lists it as the next deletion.
 
 Numerics match the reference composite exactly in structure: masked slots
 participate with score -1e9 (so a fully-masked row degrades to the same

@@ -12,15 +12,20 @@ cross-shard merges:
   come out bit-exact, logprobs within a few f32 ulps of the one-shot
   reduction (reassociated sum);
 - **argmax selection** (greedy + Gumbel lanes): each shard reports its
-  local first-max (value, GLOBAL index); the all-gathered maxima resolve
-  ties to the lowest shard, which — because the slices are disjoint and
-  order-consistent — IS the global first-index argmax the replicated
-  ``jnp.argmax`` computes. Bit-exact, not approximately;
+  local first-max (value, GLOBAL index); among the shards holding the
+  ``pmax`` value ``pmin`` takes the lowest index, which — because the
+  slices are disjoint and order-consistent — IS the global first-index
+  argmax the replicated ``jnp.argmax`` computes. Bit-exact, not
+  approximately;
 - **top-W candidates** (beam): each shard top-Ws its ``[B, W * V_s]``
   slice, rebases local flat ids ``w * V_s + v`` into the replicated
   kernel's ``w * V + off + v`` namespace, and an explicit W-pass merge
-  over the all-gathered ``mp * W`` candidates keeps ``lax.top_k``'s
+  over the gathered ``mp * W`` candidates keeps ``lax.top_k``'s
   tie-to-lower-flat-id order exactly.
+
+Every merge ends in a reduction (``psum`` / ``pmax`` / ``pmin``), never in
+``all_gather``: a reduction's result is typed replicated over the axis, so
+the programs keep ``check_vma`` on with ``out_specs`` ``P()``.
 
 The next-token embedding under a row-sharded table is a masked LOCAL
 gather (rows outside the shard contribute zeros) followed by one psum —
@@ -99,15 +104,25 @@ def _psum_embed(table, token, off, axis: str):
 def _merge_argmax(vals, off, axis: str):
     """Global first-index argmax over vocab-sharded ``vals [..., V_s]``.
 
-    Ties across shards resolve to the lowest shard (jnp.argmax over the
-    gathered shard axis), which is the lowest global index because the
-    slices are ordered — matching the replicated ``jnp.argmax``."""
+    Every shard offers its local first-max (value, GLOBAL index); the shards
+    that hold the ``pmax`` value bid their index and ``pmin`` takes the
+    lowest — the global first index, because the slices are ordered —
+    matching the replicated ``jnp.argmax``. Both reductions are typed
+    replicated over ``axis``, which ``out_specs`` ``P()`` requires under
+    ``check_vma``."""
     lv = jnp.max(vals, axis=-1)
     li = jnp.argmax(vals, axis=-1).astype(jnp.int32) + off
-    avs = jax.lax.all_gather(lv, axis)          # [mp, ...]
-    ais = jax.lax.all_gather(li, axis)
-    sel = jnp.argmax(avs, axis=0)
-    return jnp.take_along_axis(ais, sel[None], axis=0)[0]
+    bid = jnp.where(lv == jax.lax.pmax(lv, axis), li, jnp.iinfo(jnp.int32).max)
+    return jax.lax.pmin(bid, axis)
+
+
+def _gather_replicated(x, axis: str, mp: int):
+    """``all_gather(x [B, W], axis, axis=1)`` as ``[B, mp, W]`` typed
+    replicated over ``axis`` (``all_gather`` itself is typed varying): every
+    shard writes its rows into its own slot of zeros and one psum assembles
+    them — exact, each slot has one non-zero addend."""
+    mine = jnp.arange(mp)[None, :, None] == jax.lax.axis_index(axis)
+    return jax.lax.psum(jnp.where(mine, x[:, None, :], 0), axis)
 
 
 def _merge_lse(logits, axis: str):
@@ -292,8 +307,8 @@ def _beam_body(cell, carry, token, finished, scores, memory, memory_proj,
     ts, fl = jax.lax.top_k(total.reshape(B, W * vs), W)
     # local flat w * V_s + v -> the replicated kernel's w * V + off + v
     gf = (fl // vs) * V + off + (fl % vs)
-    pool_s = jax.lax.all_gather(ts, axis, axis=1).reshape(B, mp * W)
-    pool_f = jax.lax.all_gather(gf, axis, axis=1).reshape(B, mp * W)
+    pool_s = _gather_replicated(ts, axis, mp).reshape(B, mp * W)
+    pool_f = _gather_replicated(gf, axis, mp).reshape(B, mp * W)
     top_scores, top_flat = _merge_topw(pool_s, pool_f, W)
     return carry, top_scores, top_flat
 

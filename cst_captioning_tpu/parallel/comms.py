@@ -26,8 +26,8 @@ Tensors* insight (PAPERS.md, arXiv 1905.04035):
   bit-exact reference is the EAGER per-chunk-reduce spelling (identical
   float order, no double buffer); note overlap reduces every chunk's full
   param-shaped tree, so its wire volume is (chunks+1)x the unoverlapped
-  payload — a latency-hiding trade the ``bench_comms.py`` ledger reports
-  honestly.
+  payload — a latency-hiding trade :func:`ledger` accounts for
+  (``reductions=chunks+1``).
 
 ``reduce_tree`` is the single entry point the six step/update factories
 call inside their shard_map bodies; ``comm=None`` keeps the exact pre-PR
@@ -215,7 +215,8 @@ def plan_buckets(tree, comm: CommConfig) -> BucketPlan:
 
 def per_leaf_f32_bytes(tree) -> int:
     """Analytic bytes-on-wire of the pre-PR spelling: one f32-sized psum
-    per leaf (the baseline the BENCH_COMMS ratio is taken against)."""
+    per leaf (the baseline :func:`ledger`'s ``bytes_ratio`` is taken
+    against)."""
     import jax
 
     return sum(
@@ -298,7 +299,7 @@ def ledger(tree, comm: CommConfig | None, reductions: int = 1,
     ``tree``-shaped payload ``reductions`` times (1 for the fused/chunked
     unoverlapped update; chunks+1 for the overlapped chunked update, which
     reduces every chunk's param-shaped grads plus the encoder cotangent
-    fold) — the BENCH_COMMS.json row shape.
+    fold).
 
     ``mp_devices>1`` accounts the flagship-XL dp-allreduce: mp-sharded
     leaves (embedding, vocab projection, LSTM gates) reduce only their

@@ -62,9 +62,8 @@ def compaction_stats(greedy_np, samples_np, stride: int, budget: int,
     ledger here is ``sum(min(len, depth))`` stepped out of ``G*B*depth``
     total (block granularity makes the realized kernel savings slightly
     lower — a block dies only when its last row does). Without ``compact``
-    every lane rides to the global early exit. Shared by ``SCSTTrainer``
-    (the ``rl.decode.compaction`` counter pair) and ``bench_decode.py``
-    (the tokens-stepped-saved column), so the two reports can't drift.
+    every lane rides to the global early exit. Feeds ``SCSTTrainer``'s
+    ``rl.decode.compaction`` counter pair.
     """
     lanes = []
     if greedy_np is not None and np.asarray(greedy_np).size:
@@ -118,8 +117,7 @@ def make_rl_decode(model, num_rollouts: int, temperature: float = 1.0,
     (decoding/fused.py), eliminating the second loop's encoder pass, its
     per-step fixed overhead, and the duplicate attention/LSTM dispatch.
     ``fused=False`` is the two-loop reference the fused path is pinned
-    bit-exact against (tests/test_rl.py) and the baseline ``bench_decode.py``
-    measures speedup over.
+    bit-exact against (tests/test_rl.py).
 
     ``with_greedy=False`` skips the greedy rollout (``greedy`` is None):
     only the 'greedy' baseline consumes it, so the scb/none baselines save
@@ -260,7 +258,7 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
     - ``"eager"`` — reduce each chunk's grads in its own iteration; no
       buffering, nothing to overlap. Float-order-identical to "defer"
       (defer merely adds a leading ``+ psum(zeros)``, a bitwise no-op), so
-      it serves as its bit-exact parity reference in tests/bench.
+      it serves as its bit-exact parity reference in tests.
 
     When overlap is active the returned gradients are ALREADY reduced over
     ``vary_axis`` (axis-invariant); the caller must not psum them again —
@@ -268,7 +266,7 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
     per-chunk reductions move (chunks+1)x the payload of the single fused
     reduction (each chunk reduces a full params-shaped tree, plus the
     encoder-cotangent fold at the end) — that is the latency-for-bandwidth
-    trade, ledgered honestly by bench_comms.py.
+    trade, accounted by ``parallel.comms.ledger``.
     """
 
     K, B, T = samples.shape
@@ -548,7 +546,7 @@ class SCSTTrainer:
     ):
         """``donate=True`` makes the REINFORCE update consume its input state
         (buffer donation — see :func:`make_rl_update`); the production
-        Trainer/bench path enables it, tests that replay a state don't.
+        Trainer path enables it, tests that replay a state don't.
         ``guard=True`` adds the on-device non-finite update guard.
         ``retry`` is the backoff policy for the (host-side, fallible in
         production) reward scorer; ``on_event(event, **fields)`` receives
@@ -789,9 +787,8 @@ class SCSTTrainer:
 
     def _update_flops_inc(self, n_rows, args) -> float:
         """Per-process FLOPs to count for one update dispatch. Prefers the
-        COMPILED program's own cost (obs/flops.compiled_cost — the number
-        bench_comms.py ledgers, so ``cli.obs_report`` MFU and the bench
-        agree); falls back to the analytic per-clip model when XLA exposes
+        COMPILED program's own cost (obs/flops.compiled_cost); falls back
+        to the analytic per-clip model when XLA exposes
         no cost or obs is off (probing forces a lower+compile walk — free
         on the hot path only because the jit cache already holds this
         program, so don't pay it when nothing reads the counter). Either
@@ -994,10 +991,13 @@ class SCSTTrainer:
             # key would otherwise be implicitly re-replicated device-to-
             # device on EVERY batch's dispatch (the sanitizer gate's
             # transfer_guard vetoes that); every split below inherits the
-            # replicated placement. Bit-identical — placement only.
-            from jax.sharding import NamedSharding
+            # replicated placement. Bit-identical — placement only. A mesh
+            # that spans processes takes the key through multihost's global
+            # placement (device_put refuses devices of another process);
+            # one process gets the same device_put as ever.
+            from cst_captioning_tpu.train.mesh import replicate
 
-            rng = jax.device_put(rng, NamedSharding(self.mesh, P()))
+            rng = replicate(self.mesh, rng)
         out = []
 
         def emit(m):

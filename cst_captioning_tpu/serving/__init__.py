@@ -10,9 +10,8 @@ package productionizes it into a request-serving layer (README "Serving"):
 - :mod:`serving.engine`  — :class:`CaptionService`: request queue +
   admission/batch-former loop slotting new clips into decode lanes freed
   between strides (continuous batching), with drain/snapshot/restore for
-  preemption and a static-batching reference policy for the bench;
-- :mod:`serving.traffic` — seeded, replayable Poisson/bursty traffic traces
-  (the bench_serving.py workload generator).
+  preemption;
+- :mod:`serving.traffic` — seeded, replayable Poisson/bursty traffic traces.
 
 Every request decodes on its OWN fold_in RNG stream, so a request admitted
 mid-flight is token- and logprob-bit-identical to the same clip decoded
@@ -34,7 +33,6 @@ from cst_captioning_tpu.serving.engine import (
     ServeReport,
     load_snapshot,
     request_drain,
-    static_batch_serve,
 )
 from cst_captioning_tpu.serving.pages import OutOfPages, PageBank
 from cst_captioning_tpu.serving.traffic import Trace, TrafficSpec, make_trace
@@ -51,5 +49,4 @@ __all__ = [
     "load_snapshot",
     "make_trace",
     "request_drain",
-    "static_batch_serve",
 ]

@@ -533,8 +533,8 @@ class CaptionService:
         obs.event("serving_regrow", capacity=new_b, grown=grown)
 
     def set_slo(self, target_s: float) -> None:
-        """(Re)arm the SLO monitor with a latency target — the bench calls
-        this after calibrating a target from solo-request latency. Window
+        """(Re)arm the SLO monitor with a latency target, e.g. one
+        calibrated from solo-request latency. Window
         history restarts; ``target_s <= 0`` disarms."""
         self._slo = (
             SloMonitor(target_s, **self._slo_kw) if target_s > 0 else None
@@ -664,7 +664,7 @@ class CaptionService:
     ) -> ServeReport:
         """Run the admission/decode loop until the queue drains (or a drain
         is requested). ``realtime=True`` honors each request's ``arrival_s``
-        against the wall clock (the bench's open-loop mode); otherwise every
+        against the wall clock (open-loop traffic); otherwise every
         submitted request is immediately admissible."""
         global _ACTIVE
         for req in sorted(requests, key=lambda r: r.arrival_s):
@@ -799,22 +799,12 @@ class CaptionService:
             # counted, not raised: drains run on the unwind path
             obs.counter("serving.drain_postmortem_error").inc()
 
-    def stride_cost(self) -> dict | None:
-        """XLA HLO cost analysis of ONE compiled stride program
-        (``obs/flops.compiled_cost``) — the serving MFU ledger's
-        compiled-program FLOPs source, analytic fallback when None.
-        Available once the service has admitted at least one request (the
-        pools and lane state exist then)."""
-        from cst_captioning_tpu.obs.flops import compiled_cost
-
-        args = self._stride_args()
-        return None if args is None else compiled_cost(self._stride_fn, *args)
-
     def stride_program_text(self) -> str | None:
         """Compiled text of the stride program as the backend built it —
         what a caller reads to verify which kernels the program REALLY
         holds (a ``tpu_custom_call`` is a Mosaic kernel; a flag is a wish).
-        Available under the same condition as :meth:`stride_cost`."""
+        Available once the service has admitted at least one request (the
+        pools and lane state exist then)."""
         args = self._stride_args()
         if args is None:
             return None
@@ -1453,111 +1443,3 @@ def _health_monitor():
     from cst_captioning_tpu.resilience import health
 
     return health.active_monitor()
-
-
-# ---- the static-batching reference policy -----------------------------------
-
-
-def static_batch_serve(
-    model: CaptionModel,
-    params,
-    requests: list[ClipRequest],
-    *,
-    capacity: int = 8,
-    num_rollouts: int = 2,
-    temperature: float = 1.0,
-    max_len: int | None = None,
-    min_len: int = 0,
-    vocab=None,
-    service_seed: int = 0,
-    realtime: bool = False,
-    clock: Callable[[], float] = time.monotonic,
-    idle_wait_s: float = 0.002,
-    decode_fn=None,
-) -> ServeReport:
-    """The policy continuous batching is benchmarked against: wait until
-    ``capacity`` requests are queued (or no more are coming), decode the
-    whole batch offline through ``fused_decode``, return everyone together.
-
-    Every request pays batch-formation wait plus the full batch's decode
-    (the slowest member gates all), which is exactly the latency-tail cost
-    the continuous engine removes. Same hardware, same model, same K lanes,
-    same NPAD best-lane selection — only the batching policy differs. The
-    batch shares one rng (requests are NOT per-request deterministic here;
-    this is the throughput baseline, not the parity subject).
-
-    Batches are FIXED-SHAPE: a final partial batch pads with repeats of its
-    first row (outputs discarded), so the whole run is one compiled program
-    — static batch servers run fixed shapes, that is the point of the
-    policy. ``decode_fn`` lets the bench pass a pre-warmed jitted decode so
-    neither policy's measurements pay compile time.
-    """
-    from cst_captioning_tpu.decoding.fused import fused_decode
-
-    T = int(max_len or model.cfg.max_len)
-    F = model.cfg.max_frames
-    pending = deque(sorted(requests, key=lambda r: r.arrival_s))
-    report = ServeReport(submitted=len(pending))
-    t0 = clock()
-    now = lambda: clock() - t0  # noqa: E731
-    decode = decode_fn or compile_fn(
-        lambda p, f, m, r: fused_decode(
-            model, p, f, m, r, num_rollouts=num_rollouts,
-            temperature=temperature, max_len=T, min_len=min_len,
-        ),
-        CompilePlan(),
-    )
-    batch_idx = 0
-    service_key = jax.random.key(service_seed)
-    while pending:
-        arrived = [r for r in pending if (not realtime)
-                   or r.arrival_s <= now()]
-        if len(arrived) < min(capacity, len(pending)):
-            # batch former: wait for a full batch while more is coming
-            time.sleep(idle_wait_s)
-            continue
-        batch = [pending.popleft() for _ in range(min(capacity,
-                                                      len(pending)))]
-        rows_pad = capacity - len(batch)
-        feats = {}
-        masks = {}
-        for name, _ in model.cfg.modalities:
-            rows, mrows = [], []
-            for req in batch:
-                x = np.asarray(req.feats[name], np.float32)
-                mk = np.asarray(req.masks[name], np.float32)
-                pad = F - x.shape[0]
-                rows.append(np.pad(x, ((0, pad), (0, 0))))
-                mrows.append(np.pad(mk, ((0, pad),)))
-            rows += rows[:1] * rows_pad
-            mrows += mrows[:1] * rows_pad
-            feats[name] = jax.device_put(np.stack(rows))
-            masks[name] = jax.device_put(np.stack(mrows))
-        rng = jax.random.fold_in(service_key, batch_idx)
-        batch_idx += 1
-        g, gl, s, sl = jax.device_get(
-            decode(params, feats, masks, rng)
-        )
-        t_done = now()
-        for i, req in enumerate(batch):
-            tok = np.concatenate([g[i][None], s[:, i]], axis=0)
-            lp = np.concatenate([gl[i][None], sl[:, i]], axis=0)
-            best = int(npad_best_lane_index(lp))
-            ids: list[int] = []
-            for t in tok[best]:
-                t = int(t)
-                if t in (EOS_ID, PAD_ID):
-                    break
-                ids.append(t)
-            latency = max(t_done - (req.arrival_s if realtime else 0.0), 0.0)
-            report.results[req.req_id] = CaptionResult(
-                req_id=req.req_id, tokens=tok, logprobs=lp, best_lane=best,
-                caption_ids=ids,
-                caption=vocab.decode(tok[best]) if vocab is not None else None,
-                latency_s=latency,
-                phases={"queue_wait": 0.0, "encode": 0.0,
-                        "decode": latency, "detok": 0.0},
-            )
-    report.wall_s = now()
-    report.completed = len(report.results)
-    return report

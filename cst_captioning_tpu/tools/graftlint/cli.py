@@ -95,8 +95,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("paths", nargs="*",
                     help="files/directories to lint (default: "
-                         f"{' '.join(_DEFAULT_PATHS)} under --root, plus "
-                         "repo-level bench*.py)")
+                         f"{' '.join(_DEFAULT_PATHS)} under --root)")
     ap.add_argument("--root", default="",
                     help="repo root (default: auto-detected from cwd)")
     ap.add_argument("--baseline", default="",
@@ -159,10 +158,6 @@ def main(argv: list[str] | None = None) -> int:
         paths = [
             os.path.join(root, p) for p in _DEFAULT_PATHS
             if os.path.exists(os.path.join(root, p))
-        ]
-        paths += [
-            os.path.join(root, n) for n in sorted(os.listdir(root))
-            if n.startswith("bench") and n.endswith(".py")
         ]
     if not paths:
         print("graftlint: nothing to lint", file=sys.stderr)

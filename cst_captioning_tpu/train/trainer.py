@@ -43,6 +43,7 @@ from cst_captioning_tpu.obs import anomaly as _anomaly
 from cst_captioning_tpu.obs import flops as _flops
 from cst_captioning_tpu.obs import recorder as flight
 from cst_captioning_tpu.ckpt import CheckpointManager, load_params
+from cst_captioning_tpu.ckpt.checkpoint import host_copy
 from cst_captioning_tpu.config.config import EvalConfig, ExperimentConfig
 from cst_captioning_tpu.data.batcher import Batcher, EpochKey
 from cst_captioning_tpu.data.dataset import CaptionDataset
@@ -735,7 +736,7 @@ class Trainer:
                 }
             with obs.span("ckpt", kind="step"):
                 with obs.span("ckpt.readback"):
-                    host_state = jax.device_get(self.state)
+                    host_state = host_copy(self.state)
                 self.ckpt.save_step(
                     host_state, step_no,
                     self._ckpt_infos(phase, batch_index, step_no),
@@ -1658,7 +1659,7 @@ class Trainer:
             # the read-back apart from the write: it waits for whatever the
             # device still has queued, then the device idles for the copy
             with obs.span("ckpt.readback"):
-                host_state = jax.device_get(self.state)
+                host_state = host_copy(self.state)
             is_best = self.ckpt.save(
                 host_state,
                 value,

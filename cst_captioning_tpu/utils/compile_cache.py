@@ -3,8 +3,7 @@
 Compiling is a large part of a cold run on the chip (the RL decode and
 update programs take tens of seconds each), and every process start pays it
 again unless executables persist. One rule, applied by every entry point
-(``cli.train``, ``cli.eval``, ``bench.py``, ``chip_smoke.py``) before its
-first compile:
+(``cli.train``, ``cli.eval``, ``chip_smoke.py``) before its first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: jax reads the variable itself, and
   nothing is set in code — whoever runs the program places the cache.

@@ -9,7 +9,7 @@
 # (--fix-check: the repair is mechanical, so run
 # `python -m cst_captioning_tpu.tools.graftlint --fix` and commit), or the
 # two-pass lint exceeding its 3 s budget; (b) any file that doesn't
-# byte-compile; (c) the obs_report / decode / sanitizer smokes failing.
+# byte-compile; (c) the obs_report / chaos / sanitizer smokes failing.
 # tier-1 runs the same graftlint check via tests/test_graftlint.py
 # (test_repo_is_graftlint_clean), so CI cannot drift from this script.
 set -euo pipefail
@@ -32,18 +32,12 @@ python -m cst_captioning_tpu.tools.graftlint --changed-only --timings
 # full-tree line stays the authoritative gate — --changed-only above is
 # only the fast path.
 python -m cst_captioning_tpu.tools.graftlint \
-    cst_captioning_tpu tests scripts \
-    bench.py bench_attention.py bench_comms.py bench_decode.py \
-    bench_eval.py bench_recipe.py bench_rl_async.py bench_rl_online.py \
-    bench_scaling.py bench_serving.py chip_smoke.py \
+    cst_captioning_tpu tests scripts chip_smoke.py \
     --fix-check --check-stale --timings --budget 3
 
 # catches syntax errors in files graftlint may not reach (non-.py-suffixed
 # entry points aside, this is the whole tree)
-python -m compileall -q cst_captioning_tpu tests scripts \
-    bench.py bench_attention.py bench_comms.py bench_decode.py \
-    bench_eval.py bench_recipe.py bench_rl_async.py bench_rl_online.py \
-    bench_scaling.py bench_serving.py chip_smoke.py
+python -m compileall -q cst_captioning_tpu tests scripts chip_smoke.py
 
 # obs_report smoke check: the report CLI must aggregate a known-good run dir
 # without a jax import or backend init (it is part of the operator loop for
@@ -63,61 +57,6 @@ python -m cst_captioning_tpu.cli.obs_report \
     --postmortem tests/fixtures/postmortem_fleet > /dev/null
 python -m cst_captioning_tpu.cli.obs_report \
     --postmortem tests/fixtures/postmortem_fleet --list > /dev/null
-
-# bench-JSON gate: every committed BENCH_*.json must parse and keep the
-# invariants it promises (parity booleans true, token-match fractions
-# over the tie-noise floor, acceptance measured or machine-checkably
-# skipped, round ledgers rc==0, non-TPU runs carrying the rerun note)
-python scripts/bench_gate.py
-
-# decode fast-path smoke: tiny-dims CPU run of all three decode impls
-# (two-loop / fused one-loop / Pallas kernel) with the fused-vs-two-loop
-# bit-exactness gate inside — keeps bench_decode.py and the kernel from
-# rotting without a TPU in CI (README "Decode fast path")
-JAX_PLATFORMS=cpu python bench_decode.py --smoke > /dev/null
-
-# comms smoke: tiny-dims CPU run of all allreduce rungs (per-leaf /
-# bucketed / bucketed+bf16 / overlapped) with the in-run parity block
-# inside — keeps bench_comms.py and parallel/comms.py honest without a
-# TPU in CI (README "Gradient communication")
-JAX_PLATFORMS=cpu python bench_comms.py --smoke > /dev/null
-
-# serving smoke: tiny seeded Poisson+bursty traces through the continuous
-# engine AND the static-batching reference — asserts goodput > 0, the
-# served-vs-offline bit-parity block, AND the in-kernel paged-attention
-# gate: the paged_inkernel rung must be token+logprob bit-exact vs its
-# dense-gather twin, and the stress pool's page high-water mark must
-# exceed the dense-bank footprint the gather path refuses (fatal on
-# mismatch — README "Serving")
-JAX_PLATFORMS=cpu python bench_serving.py --smoke > /dev/null
-
-# scaling smoke: tiny-dims CPU run of the flagship-XL mp rungs (mp=1
-# replicated stride vs mp=2 vocab-sharded mp_decode_stride + one sharded
-# beam step) with the in-run parity gate inside (tokens and beam
-# candidates bit-exact, logprobs within f32 ulps) — keeps
-# bench_scaling.py and ops/decode_mp.py honest without a TPU in CI
-# (README "Model parallelism (flagship-XL)")
-JAX_PLATFORMS=cpu python bench_scaling.py --smoke > /dev/null
-
-# decoupled-RL smoke: tiny-dims CPU run of the sync/strict/decoupled
-# topology ladder through the real train_epoch, with the strict-parity
-# gate inside (ring replay bit-identical to the sync schedule: params AND
-# every scored token row) — README "Decoupled actor/learner RL"
-JAX_PLATFORMS=cpu python bench_rl_async.py --smoke > /dev/null
-
-# online-RL smoke: tiny-dims CPU run of the serving-as-actor closed loop
-# (frozen vs online rung over the same seeded trace) with the swap-parity
-# gate inside (every request token-bit-exact vs fused_decode under its
-# admission-pinned version, fresh-service replay fully bit-exact, two
-# seeded runs -> bit-identical learner params) — README "Online RL from
-# served traffic"
-JAX_PLATFORMS=cpu python bench_rl_online.py --smoke > /dev/null
-
-# eval fast-path smoke: tiny-dims CPU run of the serial/pipelined/NPAD
-# eval ladder with the in-run parity gate inside (lane beam bit-exact vs
-# reference, pipelined metric tables bit-identical to serial, NPAD
-# monotone vs greedy) — README "Eval fast path"
-JAX_PLATFORMS=cpu python bench_eval.py --smoke > /dev/null
 
 # elastic chaos smoke: seeded shrink->regrow scenario on 2 simulated
 # hosts — kill host 1 mid-RL-epoch, re-admit it through the rejoin

@@ -90,7 +90,7 @@ def test_failed_phase_is_reported_and_never_swallowed(tmp_path, capsys):
 def test_preset_scale_is_the_presets_own_widths():
     """The chip run overrides NO model field: the corpus it writes matches
     the presets' vocab, modalities and frame budget, and its large batch is
-    bench.py's operating point."""
+    the benchmark cells' operating point."""
     mc = get_preset("msrvtt_cst_consensus").model
     assert cs.PRESET.model_sets == ()
     assert cs.PRESET.vocab_words + 4 == mc.vocab_size == 9000
@@ -145,15 +145,13 @@ def test_cache_dir_defaults_to_fixed_path_in_checkout(monkeypatch,
 # ---- no default peak ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("lookup", [flops.peak_flops, flops.peak_hbm])
-def test_peak_lookup_raises_for_a_kind_without_a_published_peak(lookup):
+def test_peak_lookup_raises_for_a_kind_without_a_published_peak():
     with pytest.raises(KeyError, match="cpu"):
-        lookup("cpu")
+        flops.peak_flops("cpu")
 
 
 def test_peak_lookup_knows_the_v5e():
     assert flops.peak_flops("TPU v5 lite") == 197e12
-    assert flops.peak_hbm("TPU v5 lite") == 819e9
 
 
 # ---- the phases, walked at tiny widths (slow: ~70 s of tiny compiles) --------

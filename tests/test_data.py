@@ -186,8 +186,8 @@ def test_dataset_rejects_missing_weights_and_empty_captions(synth, tmp_path):
 def test_synthetic_template_style(tmp_path):
     """caption_style="template": same-topic videos share consensus n-gram
     structure (noisy realizations of the topic's canonical phrases) while
-    different topics share none — the precondition bench_recipe.py's
-    XE-vs-CST comparison rests on. feature_noise scales the per-video
+    different topics share none — the precondition an XE-vs-CST quality
+    comparison rests on. feature_noise scales the per-video
     fingerprint amplitude."""
     import collections
     import json as _json
@@ -520,7 +520,7 @@ def test_ring_gives_up_a_slot_it_cannot_fence(synth):
     ring = StagingRing(0)
     slot = ring.acquire()
     slot["a"] = np.ones((3,), np.float32)       # 12 bytes: copied, not aliased
-    placed = jax.device_put(slot["a"]) + 0
+    placed = jax.device_put(slot["a"]).copy()
     ring.uploaded(placed)
     placed.delete()
     ring.acquire()

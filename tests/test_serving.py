@@ -30,7 +30,6 @@ from cst_captioning_tpu.serving import (
     TrafficSpec,
     load_snapshot,
     make_trace,
-    static_batch_serve,
 )
 from cst_captioning_tpu.serving.traffic import synth_request_features
 
@@ -93,12 +92,21 @@ def _offline(model, params, req, K=2, min_len=0):
             np.concatenate([gl, sl[:, 0]], axis=0))
 
 
-def _assert_parity(model, params, report, reqs, K=2, min_len=0):
+def _assert_parity(model, params, report, reqs, K=2, min_len=0, lp_ulp=0):
+    """Tokens bit-equal to the offline decode, always. Log-probabilities
+    bit-equal too, except where the engine's encode runs a one-row matrix
+    product that the offline [1, max_frames] encode runs with eight rows
+    (``lp_ulp=2``): XLA's CPU backend takes a vector-matrix kernel at M=1
+    that rounds the last bit differently from its M>=2 product (4.8e-7 on
+    values near 2.6), a property of the backend and not of the engine."""
     for req in reqs:
         tok, lp = _offline(model, params, req, K=K, min_len=min_len)
         res = report.results[req.req_id]
         np.testing.assert_array_equal(res.tokens, tok, err_msg=req.req_id)
-        np.testing.assert_array_equal(res.logprobs, lp, err_msg=req.req_id)
+        if lp_ulp:
+            np.testing.assert_array_max_ulp(res.logprobs, lp, maxulp=lp_ulp)
+        else:
+            np.testing.assert_array_equal(res.logprobs, lp, err_msg=req.req_id)
 
 
 # ---- traffic ----------------------------------------------------------------
@@ -220,8 +228,10 @@ def test_serving_pallas_kernel_parity(setup):
 def test_page_table_stress_adversarial_ragged(setup):
     """1-frame and max-frame clips interleaved through a pool deliberately
     too small to hold the working set: admission backpressures on pages,
-    every request still completes with bit-exact output, and the bank
-    drains back to empty."""
+    every request still completes with bit-exact tokens, and the bank
+    drains back to empty. ``frame_bucket=1`` encodes a 1-frame clip as one
+    row where the oracle pads it to eight: log-probabilities to 2 ulp
+    (``_assert_parity``)."""
     model, params = setup
     frames = [1, 8, 1, 8, 1, 8, 1, 8, 1, 8]
     reqs = _requests(frames=frames, seed0=7000)
@@ -231,7 +241,7 @@ def test_page_table_stress_adversarial_ragged(setup):
     )
     report = svc.serve(reqs)
     assert report.completed == len(reqs)
-    _assert_parity(model, params, report, reqs, K=1)
+    _assert_parity(model, params, report, reqs, K=1, lp_ulp=2)
     assert svc.bank.pages_in_use == 0
     assert svc.bank.pages_hwm <= 6
 
@@ -345,14 +355,17 @@ def test_npad_best_lane_selection(setup):
 
 def test_batched_admission_encode_group_parity(setup):
     """admit_group > 1 batches same-bucket admission encodes into one pass;
-    at f32 the encoder gemm is row-stable, so parity must hold bit-for-bit
-    (the knob's contract — bf16-on-CPU is documented out)."""
+    at f32 the encoder gemm is row-stable over M >= 2, so tokens hold
+    bit-for-bit (the knob's contract — bf16-on-CPU is documented out). The
+    oracle's B=1 encode runs the carry init ``[1, E] @ [E, H]`` as a
+    vector-matrix product, the group's as ``[4, E] @ [E, H]``:
+    log-probabilities to 2 ulp (``_assert_parity``)."""
     model, params = setup
     reqs = _requests(frames=(8, 8, 8, 8), seed0=5000)
     report = CaptionService(
         model, params, capacity=4, num_rollouts=2, admit_group=4,
     ).serve(reqs)
-    _assert_parity(model, params, report, reqs)
+    _assert_parity(model, params, report, reqs, lp_ulp=2)
 
 
 # ---- drain / snapshot / recovery --------------------------------------------
@@ -501,16 +514,6 @@ def test_sigterm_drains_the_loop(setup, tmp_path):
     assert report.drained and report.drain_reason == "sigterm"
     assert report.completed < len(reqs)
     assert len(load_snapshot(snap)) == len(reqs) - report.completed
-
-
-def test_static_batch_serve_completes_all(setup):
-    model, params = setup
-    reqs = _requests()
-    report = static_batch_serve(model, params, reqs, capacity=2,
-                                num_rollouts=2)
-    assert report.completed == len(reqs)
-    for res in report.results.values():
-        assert res.tokens.shape == (3, T)
 
 
 # ---- zero-sync discipline ---------------------------------------------------
