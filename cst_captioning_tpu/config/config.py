@@ -384,7 +384,11 @@ class RLConfig:
     # the update teacher-forces K*B sequences at once, which caps the batch
     # size under HBM; update_chunks=C (dividing K) re-runs forward+backward
     # on K/C rollouts at a time — the same total gradient up to float
-    # summation order, NOT bit-equal to the fused path (1 = fused)
+    # summation order, NOT bit-equal to the fused path (1 = fused). It
+    # divides K, never the batch: a chunk is K/C rollouts of EVERY row a
+    # device holds. The rows are cut too, but not from here: with C > 1 the
+    # update runs in equal row blocks chosen from the traced shape
+    # (rl/scst.py::_chunked_loss_grads, _ROW_BLOCK_CAP), no field for it
     update_chunks: int = 1
     # ---- decoupled actor/learner knobs (train.rl_topology="decoupled";
     # rl/async_scst.py, README "Decoupled actor/learner RL") ----

@@ -34,7 +34,8 @@ sections (PR 4):
   (scan steps the EOS early-exit loop actually ran per batch, observed
   host-side from the decoded tokens) against the ``rl.decode.budget``
   gauge (the T step budget) — what ``scan_until_finished`` saves per
-  epoch.
+  epoch; beside it the ``rl.update.row_blocks`` / ``rl.update.block_rows``
+  gauges: how the RL update was cut into row blocks when it was traced.
 """
 
 from __future__ import annotations
@@ -272,6 +273,13 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             ),
         }
 
+    # how the RL update was cut into row blocks when it was traced
+    # (rl/scst.py::_chunked_loss_grads): blocks a rollout chunk, rows a block
+    update = None
+    if gauges.get("rl.update.row_blocks"):
+        update = {"row_blocks": float(gauges["rl.update.row_blocks"]),
+                  "block_rows": float(gauges.get("rl.update.block_rows", 0.0))}
+
     # serving section (serving/engine.py): request funnel counters + the
     # per-request phase histograms (queue-wait / encode / decode / detok)
     # and the paged-bank gauges. None when the run never served.
@@ -460,6 +468,7 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         "collate": collate,
         "prefetch": feed,
         "decode": decode,
+        "update": update,
         "serving": serving,
         "eval": eval_sec,
         "rl_async": rl_async,
@@ -579,6 +588,14 @@ def render_report(report: dict[str, Any]) -> str:
                 f"({100.0 * d['compaction_saved_frac']:.1f}% of lane-steps "
                 "compacted away)"
             )
+    u = report.get("update")
+    if u:
+        if not d:
+            lines.append("")
+        lines.append(
+            f"update row blocks: {int(u['row_blocks'])} block(s) of "
+            f"{int(u['block_rows'])} row(s) a rollout chunk and device"
+        )
     sv = report.get("serving")
     if sv:
         lines.append("")

@@ -22,7 +22,7 @@ Tensors* insight (PAPERS.md, arXiv 1905.04035):
   f32 path remains the bit-exact reference.
 - **Overlap** (``comm_overlap``, rides ``rl.update_chunks``): each chunk's
   grads start their psum while the next chunk's backward runs (the
-  double-buffered carry lives in ``rl/scst._chunked_loss_grads``). The
+  double-buffered carry lives in ``rl/scst._block_loss_grads``). The
   bit-exact reference is the EAGER per-chunk-reduce spelling (identical
   float order, no double buffer); note overlap reduces every chunk's full
   param-shaped tree, so its wire volume is (chunks+1)x the unoverlapped

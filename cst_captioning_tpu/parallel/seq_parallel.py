@@ -261,7 +261,7 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
         # batch only. Frame-axis leaves that don't depend on the sharded
         # feats (e.g. an all-ones memory_mask) are device-invariant and would
         # violate their varying out_specs — the varying-zero trick from
-        # rl/scst._chunked_loss_grads makes those three leaves uniformly
+        # rl/scst._block_loss_grads makes those three leaves uniformly
         # varying (zv carries exactly the feats' vma = the f_spec axes); its
         # transpose lands in the discarded feats cotangent. The carry is NOT
         # touched: its out_spec is batch-only (it sits downstream of the

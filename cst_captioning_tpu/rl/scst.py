@@ -229,9 +229,129 @@ def _decode_loss_sums(model, params, enc_tiled, tokens_flat, advantage_flat,
     return num, den
 
 
+# Teacher-forced rows of one rollout chunk that a device runs as one block.
+# The update's backward scan keeps two accumulators for the cotangent of the
+# attention bank, bf16 [rows, slots, d] (`memory`) and [rows, slots, d_att]
+# (`memory_proj`), read, added to and written on every one of its T steps;
+# the rows decide whether the compiler holds them in the chip's fast memory
+# or in HBM, while the weight gradients' accumulators cost the same whatever
+# the rows and are paid once more with every block. Readings of
+# `scripts/update_row_sweep.py` on one TPU v5e, the update alone at B=1792,
+# K=5, update_chunks=5, preset 4's widths (my chip runs, PR 32; PERF.md
+# section 5), block rows -> ms an update, accumulators' memory:
+#   1792 -> 370.5 (both in HBM)      896 -> 324.4 (`memory` in fast memory)
+#    448 -> 313.4 (`memory` fast)    256 -> 320.9 (both fast)
+#    224 -> 291.5 (both fast)        128 -> 336.4 (both fast)
+# 448 it is: a data-parallel shard of 448 rows (B=1792 on four chips) keeps
+# the program it had, and one chip runs that program four times. 224 reads
+# 7 % better still; it would cut the four-chip program too (ROADMAP S1(b)).
+_ROW_BLOCK_CAP = 448
+
+
+def _varying(tree, axis: str | None):
+    """Every leaf typed varying over the shard_map ``axis``; the tree as it
+    is outside shard_map (``axis`` None)."""
+    if axis is None:
+        return tree
+    return jax.tree.map(
+        lambda x: jax.lax.pcast(x, axis, to="varying"), tree
+    )
+
+
+def _row_block(rows: int, cap: int) -> int:
+    """Rows of one block: ``rows`` itself when it is at or under ``cap``,
+    else the largest divisor of ``rows`` in (cap/2, cap] (blocks are equal),
+    else ``rows`` again: a row count with no such divisor is not cut."""
+    if rows <= cap:
+        return rows
+    for block in range(cap, cap // 2, -1):
+        if rows % block == 0:
+            return block
+    return rows
+
+
 def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
                         valid, chunks: int, vary_axis: str | None = None,
                         comm=None):
+    """REINFORCE loss sums + gradients of one update: ``(num, den, g_sum)``,
+    accumulated over ``chunks`` slices of the K rollout axis
+    (:func:`_block_loss_grads`, which has the mechanism and the ``comm``
+    overlap contract) and, where the shape asks for it, over blocks of rows.
+
+    ``rl.update_chunks`` divides K, not the batch: a chunk teacher-forces
+    K/chunks rollouts of EVERY row the device holds. Rows never interact
+    before the final sums (encoder, teacher forcing, loss numerator and
+    denominator are all per row), so when a chunk's K/chunks x B rows exceed
+    ``_ROW_BLOCK_CAP`` the whole computation, encoder included, runs over
+    consecutive equal row blocks (:func:`_row_block`) in a ``lax.scan`` and
+    the blocks' f32 sums are added: the same mathematics in another
+    summation order, the very program a data-parallel shard of that many
+    rows runs, less the all-reduce. The block comes from the traced shapes
+    alone; there is no knob. Rows at or under the cap, and row counts with
+    no divisor between half the cap and the cap, take exactly the unblocked
+    path (the same lowered text as before row blocks existed). The gauges
+    ``rl.update.row_blocks`` / ``rl.update.block_rows`` are set when the
+    program is traced and say what was chosen.
+
+    With ``comm`` overlap each block reduces its own chunks' gradients
+    inside its scan and hands back reduced gradients, and the sum of the
+    blocks' reduced gradients is the reduced sum: the row loop composes
+    with the per-chunk reduction ("defer" stays bit-equal to "eager"), at
+    ``blocks`` times the reductions.
+    """
+    K, B, T = samples.shape
+    if K % chunks:
+        raise ValueError(f"update_chunks {chunks} must divide K={K} rollouts")
+    overlap = comm is not None and comm.overlap != "off"
+    if overlap and vary_axis is None:
+        raise ValueError(
+            "comm overlap needs vary_axis (the per-chunk reduction runs "
+            "inside shard_map); single-device updates have nothing to "
+            "overlap"
+        )
+    if vary_axis is not None:
+        # per-shard LOCAL grads below; the caller (or the overlap path
+        # there) owns the one explicit reduction over the axis
+        params = local_params(params, vary_axis)
+
+    block = _row_block(B, max(_ROW_BLOCK_CAP // (K // chunks), 1))
+    blocks = B // block
+    obs.gauge("rl.update.row_blocks").set(float(blocks))
+    obs.gauge("rl.update.block_rows").set(float(block))
+
+    def run(f, m, s, a, v):
+        return _block_loss_grads(
+            model, params, f, m, s, a, v, chunks, vary_axis, comm
+        )
+
+    if blocks == 1:
+        return run(feats, masks, samples, advantage, valid)
+
+    rows = lambda x: x.reshape((blocks, block) + x.shape[1:])
+    cols = lambda x: jnp.moveaxis(
+        x.reshape((K, blocks, block) + x.shape[2:]), 1, 0
+    )
+
+    def body(acc, x):
+        return jax.tree.map(jnp.add, acc, run(*x)), None
+
+    # the carry's types: a block's sums vary over the batch axis, and so do
+    # its gradients unless the overlap path has reduced them already
+    num = den = _varying(jnp.zeros(()), vary_axis)
+    g_sum = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
+    if not overlap:
+        g_sum = _varying(g_sum, vary_axis)
+    xs = (
+        jax.tree.map(rows, feats), jax.tree.map(rows, masks),
+        cols(samples), cols(advantage), rows(valid),
+    )
+    acc, _ = jax.lax.scan(body, (num, den, g_sum), xs)
+    return acc
+
+
+def _block_loss_grads(model, params, feats, masks, samples, advantage,
+                      valid, chunks: int, vary_axis: str | None = None,
+                      comm=None):
     """REINFORCE loss sums + gradients, accumulated over ``chunks`` slices
     of the K rollout axis — with ONE encoder pass shared by every chunk.
 
@@ -270,13 +390,7 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
     """
 
     K, B, T = samples.shape
-    if K % chunks:
-        raise ValueError(f"update_chunks {chunks} must divide K={K} rollouts")
     kc = K // chunks
-    if vary_axis is not None:
-        # per-shard LOCAL grads below; the caller (or the overlap path
-        # here) owns the one explicit reduction over the axis
-        params = local_params(params, vary_axis)
 
     def enc_fn(p):
         e = model.apply(p, feats, masks, method=CaptionModel.encode)
@@ -303,12 +417,6 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
         )
 
     overlap = comm is not None and comm.overlap != "off"
-    if overlap and vary_axis is None:
-        raise ValueError(
-            "comm overlap needs vary_axis (the per-chunk reduction runs "
-            "inside shard_map); single-device updates have nothing to "
-            "overlap"
-        )
 
     def chunk_grads(x):
         return jax.value_and_grad(sums_fn, argnums=(0, 1), has_aux=True)(
@@ -328,14 +436,9 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
         lambda x: jnp.zeros(x.shape, jnp.promote_types(x.dtype, jnp.float32)),
         enc,
     )
-    if vary_axis is not None:
-        # inside shard_map the per-chunk grads/sums vary over the batch
-        # axis; the scan carry init must carry the same varying-axis type
-        vary = lambda t: jax.tree.map(
-            lambda x: jax.lax.pcast(x, vary_axis, to="varying"), t
-        )
-    else:
-        vary = lambda t: t
+    # inside shard_map the per-chunk grads/sums vary over the batch axis;
+    # the scan carry init must carry the same varying-axis type
+    vary = lambda t: _varying(t, vary_axis)
 
     if overlap:
         # gp_acc accumulates the REDUCED (axis-invariant) per-chunk grads;

@@ -36,6 +36,7 @@ from cst_captioning_tpu.ops.decode_pallas import (
     fused_decode_stride,
     fused_decode_stride_paged,
 )
+from cst_captioning_tpu.rl import scst
 from cst_captioning_tpu.rl.scst import make_rl_decode
 from cst_captioning_tpu.train.schedule import make_optimizer
 from cst_captioning_tpu.train.state import create_train_state
@@ -195,6 +196,39 @@ def test_paged_stride_refuses_unaligned_page_on_chip(chip, shapes):
         args[i] = _sds((p.shape[0], 14) + p.shape[2:], p.dtype)
     with pytest.raises(ValueError, match="page_size"):
         _compile(fn, chip, *args)
+
+
+def test_row_blocked_update_compiles_for_v5e_in_half_the_memory(
+        chip, shapes, monkeypatch):
+    """The north-star update (B=1792, K=5, update_chunks=5, donated and
+    guarded as the Trainer builds it) for one described chip: cut into row
+    blocks by the shape rule it compiles, and the compiler plans no more
+    than half the temporaries of the uncut program (7.65 GB). Two compiles
+    of about 9 s."""
+    cfg, model = shapes["cfg"], shapes["model"]
+    mc, rows = cfg.model, 1792
+    feats = {n: _sds((rows, mc.max_frames, d), jnp.float32)
+             for n, d in mc.modalities}
+    masks = {n: _sds((rows, mc.max_frames), jnp.float32)
+             for n, _ in mc.modalities}
+    tx = make_optimizer(cfg.train, steps_per_epoch=4)
+    state = jax.eval_shape(
+        lambda f, m, lab: create_train_state(model, tx, (f, m, lab)),
+        feats, masks, _sds((rows, mc.max_len), jnp.int32),
+    )
+    args = (state, feats, masks, _sds((K, rows, mc.max_len), jnp.int32),
+            _sds((K, rows), jnp.float32), _sds((rows,), jnp.float32))
+
+    def temp_bytes():
+        update = scst.make_rl_update(model, chunks=K, donate=True, guard=True)
+        return _compile(update, chip, *args).memory_analysis(
+        ).temp_size_in_bytes
+
+    assert scst._row_block(rows, scst._ROW_BLOCK_CAP) < rows
+    cut = temp_bytes()
+    monkeypatch.setattr(scst, "_ROW_BLOCK_CAP", rows)
+    uncut = temp_bytes()
+    assert 0 < cut <= uncut / 2, (cut, uncut)
 
 
 # The two default-path step programs take ~10 s each to compile — a price

@@ -621,6 +621,36 @@ def test_report_decode_compaction_counters():
     assert d["compaction_saved_frac"] == pytest.approx(0.25)
     text = render_report(rep)
     assert "decode compaction" in text and "25.0% of lane-steps" in text
+    assert rep["update"] is None and "update row blocks" not in text
+
+
+def test_report_update_row_blocks():
+    """The ``rl.update.row_blocks`` / ``rl.update.block_rows`` gauges
+    (rl/scst.py sets them when the update is traced) reach the report and
+    its text, beside the decode early-exit section or without one."""
+    metrics = lambda gauges, **kw: {  # noqa: E731
+        "ts": 1.0, "event": "metrics", "counters": {}, "gauges": gauges,
+        "histograms": {}, **kw,
+    }
+    blocks = {"rl.update.row_blocks": 4.0, "rl.update.block_rows": 448.0}
+    events = [
+        {"ts": 0.0, "event": "run_start", "run": "blocks", "thread": "main"},
+        metrics(blocks),
+        {"ts": 2.0, "event": "run_end", "run": "blocks"},
+    ]
+    rep = build_report(events)
+    assert rep["update"] == {"row_blocks": 4.0, "block_rows": 448.0}
+    assert rep["decode"] is None
+    text = render_report(rep)
+    assert "update row blocks: 4 block(s) of 448 row(s)" in text
+    depth = {"rl.decode.depth": {"buckets": [10.0, 20.0, 30.0],
+                                 "counts": [0, 1, 0, 0],
+                                 "sum": 15.0, "count": 1, "max": 15.0}}
+    events[1] = metrics({**blocks, "rl.decode.budget": 30.0},
+                        histograms=depth)
+    lines = render_report(build_report(events)).splitlines()
+    at = next(i for i, ln in enumerate(lines) if "decode early-exit" in ln)
+    assert lines[at + 1].startswith("update row blocks: 4 block(s)")
 
 
 def test_scst_records_compaction_counters(tmp_path):

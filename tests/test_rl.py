@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from cst_captioning_tpu import obs
 from cst_captioning_tpu.config.config import EOS_ID, ModelConfig, RLConfig, TrainConfig
 from cst_captioning_tpu.data.vocab import Vocab
 from cst_captioning_tpu.models import CaptionModel
@@ -217,6 +218,104 @@ def test_chunked_rl_update_matches_fused(model_setup, chunks):
 
     with pytest.raises(ValueError, match="must divide"):
         make_rl_update(model, chunks=2)(state, feats, masks, samples, adv, valid)
+
+
+@pytest.mark.parametrize("rows,cap,block", [
+    (1792, 448, 448),    # the one-chip cell: four blocks
+    (1792, 896, 896),
+    (1792, 300, 256),    # the largest divisor under the cap
+    (448, 448, 448),     # a shard of four chips: at the cap, not cut
+    (1137, 448, 379),
+    (898, 448, 898),     # 2 x 449: no divisor in (224, 448], not cut
+    (7, 3, 7),
+])
+def test_row_block_is_an_equal_divisor_under_the_cap(rows, cap, block):
+    from cst_captioning_tpu.rl.scst import _row_block
+
+    assert _row_block(rows, cap) == block
+    assert rows % block == 0 and (block == rows or cap // 2 < block <= cap)
+
+
+# (rows B, cap on a block's rows, valid mask, devices of the mesh or 0)
+_WRAP_PADDED = [1, 1, 1, 1, 1, 0, 0, 0]
+_ROW_BLOCK_CASES = {
+    "blocks_of_2": (8, 2, [1] * 8, 0),
+    "blocks_of_4": (8, 4, [1] * 8, 0),
+    # the valid rows end inside a block, and a whole block is padding
+    "invalid_rows_across_a_boundary": (8, 2, _WRAP_PADDED, 0),
+    "sharded_2_devices_blocks_of_2": (8, 2, _WRAP_PADDED, 2),
+    # not cut: bit-identical to the program without row blocks
+    "rows_at_the_cap": (8, 8, _WRAP_PADDED, 0),
+    "no_divisor_under_the_cap": (7, 3, _WRAP_PADDED[1:], 0),
+    "sharded_rows_under_the_cap": (8, 4, _WRAP_PADDED, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_BLOCK_CASES))
+def test_row_blocked_rl_update_matches_unblocked(model_setup, monkeypatch, case):
+    """The chunked update cut into row blocks (rl/scst._chunked_loss_grads:
+    the block comes from the shape and a module constant, shrunk here so
+    that a tiny batch is cut) gives the loss and the post-update parameters
+    of the uncut update to f32 summation order, on one device and inside
+    shard_map; where the shape is not cut the program is the uncut one,
+    text and results."""
+    from cst_captioning_tpu.rl import scst
+
+    model, state, feats, masks = model_setup
+    B, cap, valid, devices = _ROW_BLOCK_CASES[case]
+    K, T, chunks = 3, 5, 3
+    rng = np.random.default_rng(6)
+    samples = jnp.asarray(rng.integers(2, V, size=(K, B, T)), jnp.int32)
+    adv = jnp.asarray(rng.normal(size=(K, B)), jnp.float32)
+    valid = jnp.asarray(valid, jnp.float32)
+    feats, masks = jax.tree.map(lambda x: x[:B], (feats, masks))
+    args = (state, feats, masks, samples, adv, valid)
+    if devices:
+        mesh = make_mesh(devices)
+        kb = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(None, "data")
+        )
+        args = (
+            replicate(mesh, state), *shard_batch(mesh, (feats, masks)),
+            jax.device_put(samples, kb), jax.device_put(adv, kb),
+            shard_batch(mesh, valid),
+        )
+        build = lambda: make_parallel_rl_update(model, mesh, chunks=chunks)
+    else:
+        build = lambda: make_rl_update(model, chunks=chunks)
+
+    uncut = build()            # the cap as shipped: far above 8 rows
+    u_state, u_m = uncut(*args)
+    uncut_text = uncut.lower(*args).as_text()
+    monkeypatch.setattr(scst, "_ROW_BLOCK_CAP", cap)
+    cut = build()
+    c_state, c_m = cut(*args)
+
+    local_rows = B // max(devices, 1)
+    block = scst._row_block(local_rows, cap)
+    # the gauges say what the trace of `cut` chose
+    assert obs.gauge("rl.update.block_rows").value == block
+    assert obs.gauge("rl.update.row_blocks").value == local_rows // block
+    same_text = uncut_text == cut.lower(*args).as_text()
+    if block == local_rows:
+        assert same_text
+        assert float(u_m["rl_loss"]) == float(c_m["rl_loss"])
+        for a, b in zip(jax.tree.leaves(u_state.params),
+                        jax.tree.leaves(c_state.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        return
+    assert not same_text
+    np.testing.assert_allclose(
+        float(u_m["rl_loss"]), float(c_m["rl_loss"]), rtol=1e-6
+    )
+    np.testing.assert_allclose(
+        float(u_m["grad_norm"]), float(c_m["grad_norm"]), rtol=1e-5
+    )
+    for a, b in zip(jax.tree.leaves(u_state.params),
+                    jax.tree.leaves(c_state.params)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
+        )
 
 
 def test_train_step_zero_weights_invalid_rows(model_setup):
