@@ -8,7 +8,7 @@ Seam to the program: ``Trainer`` and its public attributes (``state``,
 callback (the job chains its own behind the Trainer's), its ``batches``
 argument (handed on with every ``next()`` timed) and the trainer's ``decode``
 and ``update`` callables (``decode`` wrapped to stamp when the rollouts are
-ready; ``update`` tapped for the run's first steps, in set-up, to keep what
+ready and to keep hold of the newest samples; ``update`` tapped for the run's first steps, in set-up, to keep what
 the reference will follow: ``following.py``);
 ``train/state.py``'s ``device_key`` / ``device_fold_in``; SIGTERM to stop.
 
@@ -27,7 +27,7 @@ import time
 
 import numpy as np
 
-from benchmark import following, training
+from benchmark import costs, following, training
 
 
 def run(ctx) -> dict:
@@ -66,6 +66,7 @@ def run(ctx) -> dict:
             def decode(*args):
                 d = self.bench_decode(*args)
                 clock.mark(d[1], "decode_ready")
+                first.last = d[1]       # read once the window has closed
                 return d
 
             self.decode = decode
@@ -116,9 +117,14 @@ def run(ctx) -> dict:
         "followed": first,      # a control reads it (tests/read_limits.py)
         "checks": checks,
         "caption_len_mean": checks["sampled_len_mean"],
+        # the work the sampled captions need: from the host copies of the
+        # followed steps' samples, the window's own decode's (set-up), so
+        # that nothing is read back inside the window
         "cost_shape": {"kind": "cst", "B": cfg.data.batch_size,
                        "K": cfg.rl.num_rollouts,
-                       "chunks": cfg.rl.update_chunks},
+                       "chunks": cfg.rl.update_chunks,
+                       "profile": first.profile(cfg.rl.update_chunks, ctx.log)},
+        "main_thread": threading.current_thread().name,
         "modules": {"decode": r"decode", "update": r"update"},
         "background_spans": ("prefetch.stage",),
         "step_spans": ("rl.decode", "rl.reward", "rl.update"),
@@ -152,6 +158,7 @@ class _FirstSteps:
         self.steps: list[dict] = []
         self.metrics: list[dict] = []
         self.moment = self.change = None
+        self.last = None    # the newest decode's samples, still on the device
         self.program: dict = {}
         self.copy_s = 0.0
 
@@ -197,6 +204,31 @@ class _FirstSteps:
             "change_leaf": following.host(self.change),
         }
         self.metrics, self.moment, self.change = [], None, None
+
+    def profile(self, chunks: int, log) -> dict:
+        """``costs.caption_profile`` of the followed steps' samples."""
+        tokens = np.stack([s["samples"] for s in self.steps])   # [n, K, B, T]
+        p = costs.caption_profile(tokens, chunks)
+        _, K, B, T = tokens.shape
+        log(f"profile of the {len(tokens)} followed steps' samples ({K} x {B} "
+            f"lanes of {T} steps): {sum(p['lanes']) / (K * B):.3f} tokens a "
+            f"lane with EOS, a batch's longest caption "
+            f"{sum(p['steps']):.2f} steps; lanes alive a step "
+            f"{[round(x) for x in p['lanes']]}; clips with one "
+            f"{[round(x) for x in p['clips']]}")
+        if self.last is not None:
+            # what the count above does not see: the policy moves between the
+            # followed steps (set-up) and the window's end, some tens of
+            # updates later. Said beside it, every run; it changes no count
+            import jax
+
+            w = costs.caption_profile(np.asarray(jax.device_get(self.last))[None],
+                                      chunks)
+            self.last = None
+            log(f"the window's last decode, for the drift since: "
+                f"{sum(w['lanes']) / (K * B):.3f} tokens a lane with EOS, "
+                f"longest caption {sum(w['steps']):.0f} steps")
+        return p
 
     def control(self, precision: str, log) -> "training.Compared":
         """The control: the configuration's reference with every matrix

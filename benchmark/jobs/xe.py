@@ -11,6 +11,7 @@ clip's features teacher-forced against one reference.
 
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
@@ -92,12 +93,32 @@ def run(ctx) -> dict:
         "compared": compared,
         "checks": checks,
         "caption_len_mean": checks["label_len_mean"],
-        "cost_shape": {"kind": "xe", "B": B},
+        "cost_shape": {"kind": "xe", "B": B,
+                       "profile": _profile(ds, cfg, B, per_epoch)},
+        "main_thread": threading.current_thread().name,
         "modules": {"xe": r"step"},
         "background_spans": ("prefetch.stage",),
         "step_spans": ("xe.step",),
     })
     return out
+
+
+def _profile(ds, cfg, B: int, per_epoch: int) -> dict:
+    """The work a batch of reference rows needs, in ``costs.caption_profile``'s
+    keys, from the corpus' own lengths (each video's first ``seq_per_vid``
+    references; which ones an epoch draws is the seed's, their lengths are
+    the corpus'): one lane a row, EOS included. ``p_t`` is the share of rows
+    that hold a token at ``t``; a batch of ``B`` rows drawn from them holds
+    one with probability ``1 - (1 - p_t)^B``, and the epoch's mean batch
+    (the padded last one by its valid rows) has ``rows / per_epoch`` rows."""
+    T = cfg.model.max_len
+    lens = np.array([min(len(c) + 1, T) for r in ds.records
+                     for c in r.caption_ids[:cfg.data.seq_per_vid]])
+    p = (np.arange(T)[None, :] < lens[:, None]).mean(0)
+    rows = [float(x) for x in p * len(lens) / per_epoch]
+    steps = [float(x) for x in 1.0 - (1.0 - p) ** B]
+    return {"lanes": rows, "clips": rows, "chunk_clips": rows,
+            "steps": steps, "chunk_steps": steps}
 
 
 def _checks_before(ctx, cfg, ds, trainer, compared) -> dict:

@@ -35,8 +35,7 @@ def roofline_share(reading, program: str):
     ms = device_ms_per_run(reading, program)
     if ms is None:
         return None
-    shape = dict(reading["result"]["cost_shape"])
-    shape["B"] = shape["B"] // reading["chips"]
+    shape = costs.chip_share(reading["result"]["cost_shape"], reading["chips"])
     cost = costs.program_cost(reading["config"], shape)[program]
     least_s, _bound = costs.roofline(cost, reading["device_kind"])
     return 100.0 * least_s / (ms / 1e3)

@@ -14,12 +14,17 @@ from __future__ import annotations
 from benchmark.layer_metrics._common import window_spans
 
 # spans that only enclose other work: they say nothing about where time went
-UMBRELLAS = ("rl.epoch", "xe.epoch", "setup")
+UMBRELLAS = ("rl.epoch", "xe.epoch", "eval", "setup")
 
 
 def main_threads(reading) -> set[str]:
-    """The thread that runs the training loop: the ``rl.reward`` spans'."""
-    return {s["thread"] for s in reading["spans"] if s["name"] == "rl.reward"}
+    """The thread that runs the job's loop, as the job hands it over
+    (``main_thread`` in its result: the name of the thread it drove the
+    program's loop on, which is the one its loop timer and its step
+    submissions run on). No job of the program is named here: a job that
+    hands none over has no main thread to read."""
+    name = reading["result"].get("main_thread")
+    return {name} if name else set()
 
 
 def _inside(reading, name: str, threads=None):
@@ -28,7 +33,7 @@ def _inside(reading, name: str, threads=None):
     if reading["trace_window"] is None:
         return None
     if threads is not None and not threads:
-        return None     # no training loop to be the main thread of
+        return None     # no loop to be the main thread of
     if not any(s["name"] == name for s in reading["spans"]):
         return None
     return [s for s in window_spans(reading, (name,))

@@ -12,8 +12,10 @@ through two dense layers (the program's choice), and PAD/BOS are forbidden at
 decode time (``forbid_special=True`` reproduces the decode loops'
 distribution; teacher forcing leaves them in).
 
-The one entry every job calls, and every configuration's reference module
-has, is :func:`token_logprobs`; it is differentiable in ``params``.
+The one entry every training job calls, and every configuration's reference
+module has, is :func:`token_logprobs`; it is differentiable in ``params``.
+The ``eval`` job calls :func:`beam_logprobs`, the same walk read for what a
+beam search may be held to, and :func:`beam_search`, the search itself.
 ``precision`` is the type the operands of every matrix product are rounded
 to (the sums stay float32): ``float32`` is the reference; ``bfloat16`` and
 ``float8_e4m3fn`` (scaled to the tensor's largest magnitude) are the
@@ -99,30 +101,123 @@ def _step(p, carry, token, memory, proj, mask, r):
     return (c, h), _dense(p["out_proj"], h, r)
 
 
-def token_logprobs(params, model: dict, feats, masks, tokens,
-                   forbid_special: bool = False, precision: str = "float32"):
-    """Per-position log-probability of ``tokens`` [B, T] under teacher
-    forcing (inputs are ``tokens`` shifted right behind BOS); positions
-    after a row's EOS read 0. One LSTM layer, as the configuration has."""
+def _teacher_forced(params, model, feats, masks, tokens, forbid_special,
+                    precision, read):
+    """One plain walk over ``tokens`` [B, T] under teacher forcing (inputs
+    are ``tokens`` shifted right behind BOS): at every position ``read(logp
+    [B, V], position)`` picks what the caller wants of the distribution;
+    whatever it returns is zeroed after a row's EOS. -> its outputs as
+    ``[B, T]`` arrays."""
     r = rounder(precision)
     with jax.default_matmul_precision("highest"):
         params = _f32(params)
         memory, proj, mask, carry = encode(params, model, feats, masks, r)
         tokens = jnp.asarray(tokens, jnp.int32)
 
-        def position(state, tok):
+        def position(state, at):
+            tok, t = at
             carry, prev, alive = state
             carry, logits = _step(params["params"]["cell"], carry, prev,
                                   memory, proj, mask, r)
             if forbid_special:
                 logits = logits.at[:, PAD_ID].set(-1.0e9).at[:, BOS_ID].set(-1.0e9)
-            lp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
-                                     tok[:, None], axis=-1)[:, 0]
-            out = jnp.where(alive, lp, 0.0)
+            out = jax.tree.map(lambda x: jnp.where(alive, x, jnp.zeros_like(x)),
+                               read(jax.nn.log_softmax(logits, axis=-1), t))
             alive = alive & (tok != EOS_ID) & (tok != PAD_ID)
             return (carry, tok, alive), out
 
-        B = tokens.shape[0]
+        B, T = tokens.shape
         start = (carry, jnp.full((B,), BOS_ID, jnp.int32), jnp.ones((B,), bool))
-        _, out = jax.lax.scan(position, start, tokens.T)
-        return out.T
+        _, out = jax.lax.scan(position, start, (tokens.T, jnp.arange(T)))
+        return jax.tree.map(lambda x: x.T, out)
+
+
+def _picked(logp, tok):
+    return jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0]
+
+
+def token_logprobs(params, model: dict, feats, masks, tokens,
+                   forbid_special: bool = False, precision: str = "float32"):
+    """Per-position log-probability of ``tokens`` [B, T] under teacher
+    forcing (inputs are ``tokens`` shifted right behind BOS); positions
+    after a row's EOS read 0. One LSTM layer, as the configuration has."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    return _teacher_forced(params, model, feats, masks, tokens, forbid_special,
+                           precision, lambda logp, t: _picked(logp, tokens[:, t]))
+
+
+def beam_logprobs(params, model: dict, feats, masks, tokens, beam: int,
+                  precision: str = "float32"):
+    """What a beam search of width ``beam`` that emitted ``tokens`` [B, T]
+    may be held to, position by position under teacher forcing along
+    ``tokens`` (PAD and BOS forbidden, as the decode loops have it):
+    ``(logp, edge)``, each [B, T] and 0 after a row's EOS. ``logp`` is the
+    log-probability of the token, ``edge`` that of the ``beam``-th most
+    probable token there. A beam keeps ``beam`` candidates a step out of
+    ``beam * V``, so every token of a hypothesis it kept is among the
+    ``beam`` most probable after its prefix: ``logp >= edge`` wherever the
+    search and this reference agree on the arithmetic. The ``eval`` job's
+    reference; only that job calls it."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+
+    def read(logp, t):
+        return _picked(logp, tokens[:, t]), jax.lax.top_k(logp, beam)[0][:, -1]
+
+    return _teacher_forced(params, model, feats, masks, tokens, True,
+                           precision, read)
+
+
+def beam_search(params, model: dict, feats, masks, beam: int, max_len: int,
+                length_penalty: float = 0.0, precision: str = "float32"):
+    """The plain beam search of width ``beam`` that the ``eval`` job holds the
+    program's to: -> (tokens [B, max_len], PAD after a caption's EOS; score
+    [B], the caption's summed log-probability, over ``length ** penalty``
+    where a penalty is given). Written from the algorithm: every clip keeps
+    ``beam`` hypotheses; a step scores each hypothesis' every next token
+    (PAD and BOS forbidden), a hypothesis that has ended goes on with PAD at
+    no cost, and the ``beam`` best of the ``beam * V`` candidates are kept;
+    the first step has one live hypothesis. The caption is the best-scoring
+    hypothesis after ``max_len`` steps. Only the ``eval`` job calls it, in
+    blocks of rows; at a lower ``precision`` it is that job's control."""
+    r = rounder(precision)
+    W = int(beam)
+    with jax.default_matmul_precision("highest"):
+        params = _f32(params)
+        bank = encode(params, model, feats, masks, r)
+        # a clip's hypotheses lie side by side: row b * W + w
+        memory, proj, mask, c, h = (jnp.repeat(x, W, axis=0)
+                                    for x in (*bank[:3], *bank[3]))
+        B = memory.shape[0] // W
+        clip = jnp.arange(B)[:, None] * W
+
+        def step(state, t):
+            (c, h), prev, score, done, tokens = state
+            (c, h), logits = _step(params["params"]["cell"], (c, h), prev,
+                                   memory, proj, mask, r)
+            logits = logits.at[:, PAD_ID].set(-1.0e9).at[:, BOS_ID].set(-1.0e9)
+            logp = jax.nn.log_softmax(logits, axis=-1).reshape(B, W, -1)
+            V = logp.shape[-1]
+            ended = jnp.full((V,), -1.0e9).at[PAD_ID].set(0.0)
+            logp = jnp.where(done[:, :, None], ended, logp)
+            score, flat = jax.lax.top_k(
+                (score[:, :, None] + logp).reshape(B, W * V), W)
+            parent, tok = flat // V, (flat % V).astype(jnp.int32)
+            rows = (clip + parent).reshape(-1)
+            tokens = jnp.take_along_axis(tokens, parent[:, :, None], axis=1)
+            tokens = tokens.at[:, :, t].set(tok)
+            done = jnp.take_along_axis(done, parent, axis=1) | (tok == EOS_ID)
+            return ((c[rows], h[rows]), tok.reshape(-1), score, done,
+                    tokens), None
+
+        start = ((c, h), jnp.full((B * W,), BOS_ID, jnp.int32),
+                 jnp.full((B, W), -1.0e9).at[:, 0].set(0.0),
+                 jnp.zeros((B, W), bool),
+                 jnp.full((B, W, max_len), PAD_ID, jnp.int32))
+        (_, _, score, _, tokens), _ = jax.lax.scan(step, start,
+                                                   jnp.arange(max_len))
+        if length_penalty > 0.0:
+            length = jnp.maximum((tokens != PAD_ID).sum(-1), 1)
+            score = score / length.astype(jnp.float32) ** length_penalty
+        best = jnp.argmax(score, axis=1)
+        return (jnp.take_along_axis(tokens, best[:, None, None], axis=1)[:, 0],
+                jnp.take_along_axis(score, best[:, None], axis=1)[:, 0])

@@ -7,8 +7,10 @@ Everything about a cell is data the harness finds by name: the cell in
 ``BENCHMARK.json``, its parameters in ``workloads/<cell>.json``, its sizes in
 the configuration's file, the code that drives the program in
 ``jobs/<job>.py``, and each per-layer metric's reader in
-``layer_metrics/<metric>.py``. Adding a cell, a configuration, a job or a
-metric adds files; it edits none (``README.md``).
+``layer_metrics/<metric>.py`` (``reader_of``: a metric named
+``<metric>.<anything>`` is the same quantity in other cells and has the same
+reader). Adding a cell, a configuration, a job or a metric adds files; it
+edits none (``README.md``).
 
 Prints what it likes on the way (stderr) and, last on stdout, one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
@@ -78,6 +80,17 @@ def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
 def metrics_of(manifest: dict, group: str, cell: str) -> list[dict]:
     return [m for m in manifest[group]
             if "workloads" not in m or cell in m["workloads"]]
+
+
+def reader_of(metric: str):
+    """The module that reads a per-layer metric: ``layer_metrics/<the name up
+    to its first dot>.py``. An entry may not be edited once it is there, so
+    the cells a quantity is read in later come as entries of their own,
+    ``decode_roofline.<what tells them apart>`` with their own ``workloads``
+    list, and no file: the reader takes the program and its cost from what
+    the cell's job hands over (``modules``, ``cost_shape``), not from a name."""
+    return importlib.import_module(
+        "benchmark.layer_metrics." + metric.split(".", 1)[0])
 
 
 class Stretch:
@@ -293,9 +306,8 @@ def per_layer_metrics(manifest: dict, cell: str, reading: dict) -> dict:
     out = {}
     wanted = metrics_of(manifest, "per_layer", cell)
     for m in wanted:
-        reader = importlib.import_module("benchmark.layer_metrics." + m["name"])
         try:
-            value = reader.read(reading)
+            value = reader_of(m["name"]).read(reading)
         except Exception as e:  # said, and the metric left out of the line
             log(f"reader {m['name']} failed: {type(e).__name__}: {e}")
             value = None

@@ -28,6 +28,10 @@ def program_cost(model, shape):
     if shape["kind"] == "xe":
         return {"xe": {"flops": 3 * B * (encoder + T * token),
                        "bytes": 3 * T * _bytes(model, B)}}
+    if shape["kind"] == "eval":
+        rows = shape["beam"] * B
+        return {"eval_decode": {"flops": B * encoder + rows * T * token,
+                                "bytes": T * _bytes(model, rows)}}
     rows = shape["K"] * B
     forward = B * encoder + rows * T * token
     return {"decode": {"flops": forward, "bytes": T * _bytes(model, rows)},

@@ -100,6 +100,94 @@ def test_the_dispatch_returns_the_old_numbers(shape, old):
     assert costs.program_cost(CONFIG, shape) == old
 
 
+def _tokens(lengths, K, T=30):
+    """[1, K, B, T] sampled rows of the given lengths (EOS included), PAD
+    after: lane ``k`` of clip ``b`` is ``lengths[k][b]`` long."""
+    lengths = np.asarray(lengths).reshape(K, -1)
+    return (np.arange(T)[None, None, :] < lengths[:, :, None]).astype(np.int32)[None] * 7
+
+
+@pytest.mark.parametrize("shape, old", OLD_COSTS,
+                         ids=["cst_b1792", "cst_b448", "xe_b64"])
+def test_captions_of_full_length_cost_what_was_counted_before(shape, old):
+    """The count of PR 34 sums over the tokens that ran; with every caption
+    ``max_len`` long that is the old count, to the last digit: from the
+    profile the cost model builds itself, and from one taken off tokens."""
+    K = shape.get("K", 1)
+    full = costs.caption_profile(_tokens([30] * (K * shape["B"]), K),
+                                 shape.get("chunks", 1))
+    assert costs.program_cost(CONFIG, dict(shape, profile=full)) == old
+    four = costs.chip_share(dict(shape, B=4 * shape["B"], profile={
+        k: v if k.endswith("steps") else [4 * x for x in v]
+        for k, v in full.items()}), 4)
+    assert costs.program_cost(CONFIG, four) == old
+
+
+def test_the_count_is_monotone_in_the_profile_and_stops_at_the_longest():
+    shape = {"kind": "cst", "B": 8, "K": 5, "chunks": 5}
+    rng = np.random.default_rng(0)
+    lengths = rng.integers(3, 19, size=(5, 8))
+    short = costs.caption_profile(_tokens(lengths, 5), 5)
+    longer = costs.caption_profile(_tokens(lengths + (lengths < 10), 5), 5)
+    assert sum(short["steps"]) == lengths.max() and short["lanes"][0] == 40
+    assert short["clips"][17] == (lengths.max(0) > 17).sum()
+    assert short["chunk_clips"] == short["lanes"]     # one lane a slice
+    assert short["chunk_steps"][17] == (lengths.max(1) > 17).sum()
+    a, b, full = (costs.program_cost(CONFIG, dict(shape, profile=p))
+                  for p in (short, longer, None))
+    for program in ("decode", "update"):
+        for key in ("flops", "bytes"):
+            assert a[program][key] < b[program][key] < full[program][key]
+    # nothing beyond the batch's longest caption: the same lanes in a model
+    # that may write 40 tokens cost the same scan
+    wide = dict(CONFIG, model=dict(MSRVTT, max_len=40))
+    p40 = costs.caption_profile(_tokens(lengths, 5, T=40), 5)
+    assert costs.program_cost(wide, dict(shape, profile=p40)) == a
+    # one slice for all the lanes reads a clip's bank once a step
+    one = costs.caption_profile(_tokens(lengths, 5), 1)
+    assert one["chunk_clips"] == one["clips"] and one["chunk_steps"] == one["steps"]
+    with pytest.raises(ValueError, match="profile has 40 steps"):
+        costs.program_cost(CONFIG, dict(shape, profile=p40))
+
+
+@pytest.mark.parametrize("program", ["decode", "update"])
+def test_a_step_timed_at_the_counted_bytes_over_the_peak_reads_100(program):
+    """The roofline reader on a toy step: device time = the counted bytes
+    over the published bandwidth reads exactly 100 %, whatever the profile;
+    and a program that skipped the padding cannot read more by it, because
+    the padding was never counted."""
+    from benchmark.layer_metrics import _common
+
+    lengths = np.random.default_rng(1).integers(4, 20, size=(5, 1792))
+    shape = {"kind": "cst", "B": 1792, "K": 5, "chunks": 5,
+             "profile": costs.caption_profile(_tokens(lengths, 5), 5)}
+    for chips in (1, 4):
+        cost = costs.program_cost(CONFIG, costs.chip_share(shape, chips))[program]
+        least, bound = costs.roofline(cost, "TPU v5 lite")
+        assert bound == "hbm"
+        reading = {"result": {"cost_shape": shape, "modules": {program: program}},
+                   "trace": {"devices": [{"module_runs_s": {
+                       "jit_" + program: [least] * 4}}] * chips},
+                   "chips": chips, "config": CONFIG, "device_kind": "TPU v5 lite"}
+        assert _common.roofline_share(reading, program) == pytest.approx(100.0)
+        full = dict(shape, profile=None)
+        assert _common.roofline_share(dict(reading, result=dict(
+            reading["result"], cost_shape=full)), program) > 200.0
+
+
+def test_the_eval_program_costs_a_beam_of_lanes():
+    shape = {"kind": "eval", "B": 256, "beam": 5}
+    c = costs.program_cost(CONFIG, shape)
+    d = costs.program_cost(CONFIG, {"kind": "cst", "B": 256, "K": 5, "chunks": 5})
+    assert c == {"eval_decode": d["decode"]}
+    lengths = np.random.default_rng(2).integers(4, 20, size=(1, 256))
+    tokens = np.repeat(_tokens(lengths, 1), 5, 1)
+    short = costs.program_cost(CONFIG, dict(
+        shape, profile=costs.caption_profile(tokens)))["eval_decode"]
+    assert short["flops"] < c["eval_decode"]["flops"]
+    assert short["bytes"] < c["eval_decode"]["bytes"]
+
+
 def test_no_cost_model_is_an_error_never_a_default(tmp_path):
     nameless = {k: v for k, v in CONFIG.items() if k != "costs"}
     with pytest.raises(SystemExit, match="names no 'costs' module"):

@@ -1,11 +1,19 @@
-"""BENCHMARK.json against the files it names and the contract's limits."""
+"""BENCHMARK.json against the files it names and the contract's limits; and
+the same rules over the manifests later PRs would make of it by adding cells
+as files and entries (``tiny.GROWN``: a cell of job ``eval`` and another cell
+of job ``cst``, both on ``tests/second_architecture``), with no entry that is
+there edited. No test here takes the committed cells to be the only ones: a
+later PR commits cells and may not edit this file."""
 
-import importlib
+import copy
 import json
 import os
 import re
 
 import pytest
+
+from benchmark import run as bench_run
+from benchmark.tests import tiny
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -14,10 +22,19 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+@pytest.fixture(scope="module", params=list(tiny.GROWN))
+def manifest(request):
+    return tiny.GROWN[request.param](tiny.manifest())
+
+
+def _workload_file(cell: str) -> str:
+    """``workloads/<cell>.json``; the made-up cell's lies beside its
+    configuration under ``tests/``, where no run finds it."""
+    for base in (os.path.join(BENCH, "workloads"), tiny.SECOND):
+        path = os.path.join(base, cell + ".json")
+        if os.path.exists(path):
+            return path
+    raise AssertionError(f"no workload file for {cell}")
 
 
 def _cells(m):
@@ -102,10 +119,14 @@ def test_every_name_resolves(manifest):
             assert os.path.isfile(os.path.join(ROOT, config[key])), key
         assert all(e.get("reason") and "value" in e
                    for e in config["checks"].values())
-        assert {"model", "overrides", "corpus", "policy", "checks",
-                "params"} <= set(config["tiny"])
-        assert set(config["tiny"]["checks"]) == set(config["checks"])
-        with open(os.path.join(BENCH, "workloads", w["name"] + ".json")) as f:
+        # only a file under ``tests/``, at tiny sizes itself and never run,
+        # may bring ``params`` alone
+        if entry["file"].startswith("benchmark/tests/"):
+            assert set(config["tiny"]) == {"params"}
+        else:
+            assert set(tiny.TINY_KEYS) | {"params"} <= set(config["tiny"])
+            assert set(config["tiny"]["checks"]) == set(config["checks"])
+        with open(_workload_file(w["name"])) as f:
             wl = json.load(f)
         assert w["name"] == f"{w['config']}.{w['traffic']}"
         for key in ("config", "traffic", "chips", "why"):
@@ -121,8 +142,58 @@ def test_every_name_resolves(manifest):
         assert of("per_layer")
     assert used == set(configs)
     for m in manifest["per_layer"]:
-        reader = importlib.import_module("benchmark.layer_metrics." + m["name"])
-        assert callable(reader.read)
+        assert callable(bench_run.reader_of(m["name"]).read)
+
+
+@pytest.mark.parametrize("grown", [g for g in tiny.GROWN if g != "as_committed"])
+def test_a_new_cell_is_files_and_entries(grown):
+    """The proof the next ``model_config`` PR needs: a cell of an
+    architecture that exists only as files, of job ``eval`` or of job
+    ``cst``, comes in as one configuration entry, one workload entry and
+    per-layer entries of its own, and every entry that was there stays as it
+    was, letter for letter (the fixture above holds the result to every rule
+    of this file). Each new cell is given the metrics that carry no list and
+    its own, and none that lists other cells."""
+    committed = tiny.manifest()
+    made = tiny.GROWN[grown](copy.deepcopy(committed))
+    for group, entries in committed.items():
+        if group in ("configs", "workloads", "per_layer"):
+            assert made[group][:len(entries)] == entries, group
+            assert len(made[group]) > len(entries), group
+        else:
+            assert made[group] == entries, group
+    listless = {m["name"] for m in committed["per_layer"]
+                if "workloads" not in m}
+    added = made["per_layer"][len(committed["per_layer"]):]
+    for w in made["workloads"][len(committed["workloads"]):]:
+        given = {m["name"] for m in bench_run.metrics_of(made, "per_layer",
+                                                         w["name"])}
+        assert given == listless | {m["name"] for m in added
+                                    if w["name"] in m["workloads"]}
+        assert given - listless, "the new cell reads something of its own"
+        assert not {"decode_roofline", "update_roofline", "epoch_turnover_ms",
+                    "reward_ms_per_step", "allreduce_ms_per_step"} & given
+
+
+def test_the_rl_steps_metrics_say_where_they_exist(manifest):
+    """Every entry that reads the RL step, the epoch turnover or the prefetch
+    feed carries a list; the entries of the two accepted ``cst`` cells list
+    both, and whatever an entry lists is a cell of job ``cst`` (by its
+    workload file). Cells that later PRs commit, of any job, leave this
+    true: they are in no list that was there."""
+    jobs = {}
+    for w in manifest["workloads"]:
+        with open(_workload_file(w["name"])) as f:
+            jobs[w["name"]] = json.load(f)["job"]
+    names = {m["name"] for m in manifest["per_layer"]}
+    for m in manifest["per_layer"]:
+        if re.match(r"(decode|update|reward|epoch)_|h2d_|prefetch_|allreduce_",
+                    m["name"]):
+            assert m.get("workloads"), m["name"]
+            assert {jobs[c] for c in m["workloads"]} == {"cst"}, m["name"]
+            if "." not in m["name"] and not m["name"].startswith("allreduce_"):
+                assert set(tiny.ACCEPTED_CST) <= set(m["workloads"]), m["name"]
+    assert {"decode_roofline", "update_roofline", "epoch_turnover_ms"} <= names
 
 
 def test_files_under_paths_are_named_from_the_allowed_characters():

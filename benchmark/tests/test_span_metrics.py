@@ -14,13 +14,15 @@ def _span(name, t0, t1, thread=MAIN):
     return {"name": name, "t0": t0, "t1": t1, "dur": t1 - t0, "thread": thread}
 
 
-def _reading(spans=None, traced=True, steps=4):
+def _reading(spans=None, traced=True, steps=4, main=MAIN):
     if spans is None:
         spans = SPANS
     return {
         "spans": spans, "window": (10.0, 20.0),
         "trace_window": (11.0, 15.0) if traced else None,
-        "result": {"steps": [(12.0 + i, 32.0, 0.0, 0) for i in range(steps)]},
+        # the job hands over the thread it drove the program's loop on
+        "result": {"steps": [(12.0 + i, 32.0, 0.0, 0) for i in range(steps)],
+                   "main_thread": main},
     }
 
 
@@ -115,27 +117,66 @@ def test_per_epoch_readers_give_zero_where_the_window_holds_none():
         assert got == (None if name == "epoch_readback_ms" else 0.0)
 
 
-def test_main_thread_is_the_reward_spans_thread():
-    """Renamed threads: the readers follow rl.reward, not a thread's name."""
+MAIN_THREAD_READERS = ("prefetch_wait_ms_per_step",
+                       "host_unattributed_ms_per_step")
+
+
+@pytest.mark.parametrize("name", MAIN_THREAD_READERS)
+def test_main_thread_is_the_one_the_job_hands_over(name):
+    """The readers name no span of a job to find the loop's thread by (up to
+    PR 33 it was ``rl.reward``'s, and every other job read nothing): renamed
+    threads are followed through ``main_thread``, a loop that records no
+    ``rl.reward`` reads as well, and a job that hands no thread over has no
+    main thread to read."""
     swap = {MAIN: "trainer-0", WORKER: "stager"}
     spans = [dict(s, thread=swap.get(s["thread"], s["thread"])) for s in SPANS]
-    for name in ("prefetch_wait_ms_per_step", "host_unattributed_ms_per_step"):
-        assert _read(name, _reading(spans)) == pytest.approx(WANT[name])
-    no_loop = [s for s in SPANS if s["name"] != "rl.reward"]
-    for name in ("prefetch_wait_ms_per_step", "host_unattributed_ms_per_step"):
-        assert _read(name, _reading(no_loop)) is None
+    assert _read(name, _reading(spans, main="trainer-0")) == \
+        pytest.approx(WANT[name])
+    assert _read(name, _reading(spans)) != pytest.approx(WANT[name])
+    no_reward = [s for s in SPANS if not s["name"].startswith("rl.reward")]
+    got = _read(name, _reading(no_reward))
+    if name == "prefetch_wait_ms_per_step":
+        assert got == pytest.approx(WANT[name])
+    else:   # the reward's second and a half are no longer named
+        assert got == pytest.approx(WANT[name] + 1e3 * (1.0 + 0.8) / 4)
+    assert _read(name, _reading(main=None)) is None
 
 
-def test_the_eight_are_in_the_manifest_as_span_metrics_of_every_cell():
-    import json
-    import os
+def test_an_eval_pass_reads_under_its_own_umbrella():
+    """Job ``eval``'s spans: the umbrella ``eval`` encloses a pass and names
+    nothing; ``eval.score`` is the corpus scorers at the pass's drain."""
+    spans = [_span("eval", 9.0, 14.0), _span("data.collate", 10.0, 10.5),
+             _span("data.collate", 11.0, 11.5),
+             _span("eval.pipeline.drain", 12.0, 14.0),
+             _span("eval.score", 12.5, 14.0), _span("eval", 14.0, 21.0),
+             _span("data.collate", 14.5, 15.5),
+             _span("eval.score", 19.5, 20.5)]       # crosses the end
+    r = _reading(spans)
+    assert _read("eval_score_ms_per_step", r) == pytest.approx(1e3 * 1.5 / 4)
+    assert _read("collate_ms_per_step", r) == pytest.approx(1e3 * 2.0 / 4)
+    assert _read("host_unattributed_ms_per_step", r) == pytest.approx(
+        1e3 * (10.0 - (0.5 + 0.5 + 2.0 + 1.0 + 0.5)) / 4)
+    assert _read("eval_score_ms_per_step", _reading(SPANS)) is None
+    assert _read("reward_score_ms_per_step", r) is None
 
-    from benchmark import run as bench_run
 
-    with open(os.path.join(bench_run.ROOT, "BENCHMARK.json")) as f:
-        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
+@pytest.mark.parametrize("grown", ["as_committed", "with_both"])
+def test_the_eight_are_in_the_manifest_as_span_metrics(grown):
+    """Each says where it exists: the readers of spans that every job's loop
+    records carry no list; those of the RL step's, the epoch turnover's and
+    the prefetch feed's spans (a job that decodes without a feed has none)
+    list the two ``cst`` cells the benchmark was accepted with. Read off the
+    manifest as committed and as later PRs' cells would leave it: no cell
+    that is added is in a list that was there."""
+    from benchmark.tests import tiny
+
+    manifest = tiny.GROWN[grown](tiny.manifest())
+    entries = {m["name"]: m for m in manifest["per_layer"]}
     for name in WANT:
         m = entries[name]
         assert (m["source"], m["unit"], m["better"], m["moves"]) == (
             "program_span", "ms", "lower", "clips_per_s_per_chip")
-        assert "workloads" not in m
+        if name in ("collate_ms_per_step", "host_unattributed_ms_per_step"):
+            assert "workloads" not in m
+        else:
+            assert set(tiny.ACCEPTED_CST) <= set(m["workloads"]), name

@@ -176,13 +176,14 @@ class Compared:
         return [k for k, r in self.rows.items() if not r["ok"]]
 
 
-def open_train_split(cfg, paths: dict):
+def open_train_split(cfg, paths: dict, split: str = "train"):
+    """The corpus' ``split`` (``corpus.make_corpus`` writes ``train`` only)."""
     from cst_captioning_tpu.data.dataset import CaptionDataset
 
     return CaptionDataset(
         paths["info_json"],
         {n: paths[n] for n in cfg.model.modality_names},
-        split="train", max_frames=cfg.model.max_frames,
+        split=split, max_frames=cfg.model.max_frames,
         cache_features=cfg.data.cache_features,
     )
 
