@@ -8,7 +8,9 @@
 ``<run_dir>`` is the directory ``train.obs_dir`` (or ``--obs``) pointed a
 run at — it must contain the run's ``events.jsonl``. Prints the phase table
 (per-phase totals, self-time %-of-wall-clock, mfu with its FLOPs-source tag,
-p50/p95/max), the decode early-exit summary (scan depth vs the T budget),
+p50/p95/max), the decode early-exit summary (scan depth vs the T budget), the
+beam decode's state (its cache; a routed-expert decoder's held experts, local
+assignment share and rows a held expert),
 the serving funnel + SLO burn rates, and the resilience summary (nan-skips,
 rollbacks, retries, chaos faults).
 

@@ -88,8 +88,56 @@ class ModelConfig:
     # token-exact either way). No-op at decode_stride=1 — compaction only
     # pays between strides. Off = step every row until the global exit
     decode_compact: bool = True
+    # decoder kind: "lstm" (the attention-LSTM cell above, every field so
+    # far) or "latent_moe" (models/latent_moe.py: a pre-norm residual stack
+    # over a video prefix — latent attention (MLA) with a compressed cache,
+    # one leading dense layer, then sigmoid-routed expert layers of which
+    # this chip holds a share). The fields below are that stack's sizes
+    # under the key names of the published config.json they are read from
+    # (benchmark/configs/kimi_k2_ep32.json); the LSTM path reads none
+    decoder: str = "lstm"
+    hidden_size: int = 0
+    num_hidden_layers: int = 0          # dense + expert layers held here
+    first_k_dense_replace: int = 1      # leading layers with a dense FFN
+    intermediate_size: int = 0          # the dense FFN's width
+    moe_intermediate_size: int = 0      # one expert's width
+    n_routed_experts: int = 0           # the router's width
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 0
+    routed_scaling_factor: float = 1.0
+    num_attention_heads: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    # YaRN: (key, value) pairs of the published ``rope_scaling`` group
+    # (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim); pairs so that the config stays hashable
+    rope_scaling: tuple[tuple[str, float], ...] = ()
+    initializer_range: float = 0.02
+    # the chip's share of each expert layer (expert parallelism): it holds
+    # ``experts_held`` consecutive routed experts starting at
+    # ``expert_share_index * experts_held``, routes over all
+    # ``n_routed_experts`` and computes its own experts' part of the result
+    experts_held: int = 0
+    expert_share_index: int = 0
 
     def __post_init__(self):
+        if self.decoder not in ("lstm", "latent_moe"):
+            raise ValueError(
+                f"unknown decoder: {self.decoder!r} "
+                "(expected 'lstm' or 'latent_moe')"
+            )
+        object.__setattr__(
+            self, "rope_scaling",
+            tuple((str(k), v) for k, v in (
+                self.rope_scaling.items()
+                if isinstance(self.rope_scaling, Mapping)
+                else self.rope_scaling)),
+        )
         if isinstance(self.modalities, Mapping):
             object.__setattr__(self, "modalities", _freeze_modalities(self.modalities))
         else:
@@ -514,6 +562,14 @@ class ExperimentConfig:
                 "decode_impl='pallas' is not implemented for the "
                 "sequence-parallel ('seq_devices > 1') path; use one or the "
                 "other"
+            )
+        if self.rl.enabled and self.model.decoder != "lstm":
+            # rl/scst.py's decode, update and FLOP ledger unroll the LSTM
+            # cell from one encoder pass; fail here, not at the first step
+            raise ValueError(
+                f"rl.enabled needs model.decoder='lstm' (got "
+                f"{self.model.decoder!r}): the SCST path has no "
+                "teacher-forced update for another decoder kind yet"
             )
         if self.rl.enabled and (
             self.rl.update_chunks < 1

@@ -12,6 +12,17 @@ SURVEY.md §2 rows 1/12):
                                5 Monte-Carlo rollouts, self-consensus (SCB) baseline.
 5. ``msrvtt_eval_beam5``     — MSR-VTT eval: beam search (beam=5) + COCO metrics.
 
+Two more run the second decoder kind (``model.decoder = "latent_moe"``,
+models/latent_moe.py) at the published widths of Kimi-K2-Instruct, cut to one
+chip's share of an expert-parallel deployment (``_KIMI_K2_EP32``):
+
+6. ``kimi_k2_ep32_xe``       — the stack behind the MSR-VTT frame features,
+                               XE; bfloat16 parameters and plain SGD (no
+                               moments), so that constructing it fits a chip.
+                               The benchmark makes its seeded policy from it.
+7. ``kimi_k2_ep32_eval_beam5`` — the same model, beam-5 eval through the
+                               ``Evaluator`` (beams flattened into the batch).
+
 Paper CST variant names map onto presets as: XE -> 1/2; CST_GT_None/SCST -> 3;
 CST_MS_SCB -> 4 (with ``rl.baseline="scb"``); WXE is preset 2 with
 ``train.loss="wxe"``.
@@ -112,12 +123,78 @@ def _msrvtt_eval_beam5() -> ExperimentConfig:
     )
 
 
+# Kimi-K2-Instruct (huggingface.co/moonshotai/Kimi-K2-Instruct, config.json):
+# every width as published; depth, experts held and vocabulary are one chip's
+# share — 1 dense + 6 expert layers of the 61 (the rest are further pipeline
+# stages), experts 0-11 of each layer's 384 (32 chips share a layer by expert
+# parallelism; attention, shared expert, router and dense layer whole on
+# each), 20480 rows of the 163840-word vocabulary. 56 prefix slots + 30
+# caption positions of the 131072 the source allows.
+_KIMI_K2_EP32 = ModelConfig(
+    decoder="latent_moe",
+    vocab_size=20480,
+    modalities=(("resnet", 2048), ("c3d", 500)),
+    max_len=30,
+    max_frames=28,
+    dropout=0.0,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+    hidden_size=7168,
+    num_hidden_layers=7,
+    first_k_dense_replace=1,
+    intermediate_size=18432,
+    moe_intermediate_size=2048,
+    n_routed_experts=384,
+    n_shared_experts=1,
+    num_experts_per_tok=8,
+    routed_scaling_factor=2.827,
+    num_attention_heads=64,
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    rms_norm_eps=1e-6,
+    rope_theta=50000.0,
+    rope_scaling=(
+        ("beta_fast", 1), ("beta_slow", 1), ("factor", 32), ("mscale", 1),
+        ("mscale_all_dim", 1), ("original_max_position_embeddings", 4096),
+    ),
+    initializer_range=0.02,
+    experts_held=12,
+    expert_share_index=0,
+)
+
+
+def _kimi_k2_ep32_xe() -> ExperimentConfig:
+    return ExperimentConfig(
+        name="kimi_k2_ep32_xe",
+        model=_KIMI_K2_EP32,
+        data=DataConfig(dataset="msrvtt", batch_size=8),
+        # SGD keeps no moments: the state is the bfloat16 parameters alone
+        train=TrainConfig(loss="xe", optimizer="sgd", lr=1e-4, epochs=1),
+    )
+
+
+def _kimi_k2_ep32_eval_beam5() -> ExperimentConfig:
+    return dataclasses.replace(
+        _kimi_k2_ep32_xe(),
+        name="kimi_k2_ep32_eval_beam5",
+        # beams flattened into the batch: the routed experts walk one list
+        # of rows a step, where "lanes" would vmap the walk over the beams
+        eval=EvalConfig(beam_size=5, max_len=30, split="test",
+                        beam_impl="reference"),
+    )
+
+
 PRESETS = {
     "msvd_xe_meanpool": _msvd_xe_meanpool,
     "msrvtt_xe_attention": _msrvtt_xe_attention,
     "msrvtt_scst": _msrvtt_scst,
     "msrvtt_cst_consensus": _msrvtt_cst_consensus,
     "msrvtt_eval_beam5": _msrvtt_eval_beam5,
+    "kimi_k2_ep32_xe": _kimi_k2_ep32_xe,
+    "kimi_k2_ep32_eval_beam5": _kimi_k2_ep32_eval_beam5,
 }
 
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from cst_captioning_tpu.config.config import BOS_ID, EOS_ID, PAD_ID
 from cst_captioning_tpu.decoding.common import (
     apply_min_len,
+    carry_tally,
     forbid_special,
     lane_decode_step,
     row_logprobs,
@@ -107,19 +108,27 @@ def _topk_expand(scores, finished, logp, pad_row, B: int, W: int, V: int):
     return top_scores, parent, tok
 
 
-def _state0(carry0, B: int, W: int, T: int):
-    """(carry, tokens, scores, finished, last): beam 0 alone live at t=0."""
+def _state0(carry0, B: int, W: int, T: int, tally):
+    """(carry, tokens, scores, finished, last, tally): beam 0 alone live at
+    t=0; ``tally`` is what the encoder pass counted (``carry_tally``: ``()``
+    for a decoder that counts nothing)."""
     return (
         carry0,
         jnp.full((B, W, T), PAD_ID, jnp.int32),
         jnp.concatenate([jnp.zeros((B, 1)), jnp.full((B, W - 1), _NEG)], axis=1),
         jnp.zeros((B, W), bool),
         jnp.full((B, W), BOS_ID, jnp.int32),
+        tally,
     )
+
+
+def _add(tally, more):
+    return jax.tree.map(jnp.add, tally, more)
 
 
 def _run_reference(model, params, enc, B, V, W, T, min_len, batch_axes):
     """The sequential spelling: beams flattened into the batch ([B*W] rows)."""
+    tally0 = carry_tally(enc.carry)         # once a clip, before the tiling
     enc_tiled = _tile_beam(enc, W)          # leaves [B*W, ...]
     carry0 = enc_tiled.carry
     enc_tiled = EncoderOutput(
@@ -128,7 +137,7 @@ def _run_reference(model, params, enc, B, V, W, T, min_len, batch_axes):
     pad_row = _pad_row(V)
 
     def step(state, t):
-        carry, tokens, scores, finished, last = state
+        carry, tokens, scores, finished, last, tally = state
         carry, logits = model.apply(
             params,
             carry,
@@ -136,6 +145,7 @@ def _run_reference(model, params, enc, B, V, W, T, min_len, batch_axes):
             enc_tiled,
             method=CaptionModel.decode_step,
         )
+        tally = _add(tally, carry_tally(carry))
         logits = apply_min_len(forbid_special(logits), t, min_len)
         logp = row_logprobs(logits).reshape(B, W, V)
         top_scores, parent, tok = _topk_expand(
@@ -148,7 +158,7 @@ def _run_reference(model, params, enc, B, V, W, T, min_len, batch_axes):
         tok = jnp.where(finished, jnp.full_like(tok, PAD_ID), tok)
         tokens = tokens.at[:, :, t].set(tok)
         finished = finished | (tok == EOS_ID)
-        return (carry, tokens, top_scores, finished, tok), None
+        return (carry, tokens, top_scores, finished, tok, tally), None
 
     # Early exit once every beam of every row is finished — bit-identical to
     # the full T-step unroll: with all beams finished, every continuation row
@@ -158,10 +168,11 @@ def _run_reference(model, params, enc, B, V, W, T, min_len, batch_axes):
     # index = lower beam), the next top_k re-selects the beams in their
     # current order: parent is the identity, tok is PAD everywhere, and the
     # whole state is a fixed point of ``step``.
-    (_, tokens, scores, _, _), _ = scan_until_finished(
-        step, _state0(carry0, B, W, T), T, lambda s: s[3], None, batch_axes
+    (_, tokens, scores, _, _, tally), _ = scan_until_finished(
+        step, _state0(carry0, B, W, T, tally0), T, lambda s: s[3], None,
+        batch_axes
     )
-    return tokens, scores
+    return tokens, scores, tally
 
 
 def _run_lanes(model, params, enc, B, V, W, T, min_len, batch_axes):
@@ -173,7 +184,7 @@ def _run_lanes(model, params, enc, B, V, W, T, min_len, batch_axes):
     use_kernel = getattr(model.cfg, "decode_impl", "xla") == "pallas"
 
     def step(state, t):
-        carry, tokens, scores, finished, last = state  # carry [W, B, ...]
+        carry, tokens, scores, finished, last, tally = state  # carry [W, B, ...]
         if use_kernel:
             from cst_captioning_tpu.ops.decode_pallas import fused_beam_step
 
@@ -192,6 +203,7 @@ def _run_lanes(model, params, enc, B, V, W, T, min_len, batch_axes):
             top_scores = top_scores.astype(scores.dtype)
         else:
             carry, logits = lane_decode_step(model, params, carry, last, enc)
+            tally = _add(tally, carry_tally(carry))
             logits = apply_min_len(forbid_special(logits), t, min_len)
             logp = row_logprobs(logits).transpose(1, 0, 2)   # [B, W, V]
             top_scores, parent, tok = _topk_expand(
@@ -204,16 +216,18 @@ def _run_lanes(model, params, enc, B, V, W, T, min_len, batch_axes):
         tok = jnp.where(finished, jnp.full_like(tok, PAD_ID), tok)
         tokens = tokens.at[:, :, t].set(tok)
         finished = finished | (tok == EOS_ID)
-        return (carry, tokens, top_scores, finished, tok.T), None
+        return (carry, tokens, top_scores, finished, tok.T, tally), None
 
     # the lane-major state0: last tokens live as [W, B]
-    carry, tokens, scores, finished, last = _state0(carry0, B, W, T)
-    state0 = (carry, tokens, scores, finished, last.T)
+    carry, tokens, scores, finished, last, tally = _state0(
+        carry0, B, W, T, carry_tally(enc.carry)
+    )
+    state0 = (carry, tokens, scores, finished, last.T, tally)
     # same all-finished fixed point as the reference (see _run_reference)
-    (_, tokens, scores, _, _), _ = scan_until_finished(
+    (_, tokens, scores, _, _, tally), _ = scan_until_finished(
         step, state0, T, lambda s: s[3], None, batch_axes
     )
-    return tokens, scores
+    return tokens, scores, tally
 
 
 def beam_search(
@@ -228,8 +242,14 @@ def beam_search(
     return_all: bool = False,
     batch_axes: tuple[str, ...] = (),
     beam_impl: str = "lanes",
+    return_tally: bool = False,
 ):
     """-> (tokens [B, T], scores [B]) — or [B, W, T] / [B, W] if return_all.
+
+    ``return_tally`` appends what the decoder counted over the search
+    (``decoding.common.carry_tally`` summed over the encoder pass and every
+    step run: a routed-expert decoder's token-expert assignments, this
+    shard's; ``()`` for the LSTM).
 
     ``length_penalty`` α rescales final scores by ``1/len^α`` (α=0 matches the
     reference's pure sum-logprob ranking). ``beam_impl`` picks the lane-
@@ -248,7 +268,10 @@ def beam_search(
     V = model.cfg.vocab_size
 
     run = _run_lanes if beam_impl == "lanes" else _run_reference
-    tokens, scores = run(model, params, enc, B, V, W, T, min_len, batch_axes)
+    tokens, scores, tally = run(
+        model, params, enc, B, V, W, T, min_len, batch_axes
+    )
+    more = (tally,) if return_tally else ()
 
     if length_penalty > 0.0:
         lengths = jnp.maximum((tokens != PAD_ID).sum(axis=-1), 1).astype(jnp.float32)
@@ -260,8 +283,9 @@ def beam_search(
         return (
             jnp.take_along_axis(tokens, order[:, :, None], axis=1),
             jnp.take_along_axis(ranked, order, axis=1),
+            *more,
         )
     best = jnp.argmax(ranked, axis=1)                           # [B]
     best_tokens = jnp.take_along_axis(tokens, best[:, None, None], axis=1)[:, 0]
     best_scores = jnp.take_along_axis(ranked, best[:, None], axis=1)[:, 0]
-    return best_tokens, best_scores
+    return (best_tokens, best_scores, *more)

@@ -137,6 +137,19 @@ def lane_decode_step(model, params, carry, token, enc):
     return jax.vmap(one_lane)(carry, token)
 
 
+def carry_tally(carry):
+    """What a decoder's last call counted for the decode loop to sum, off a
+    carry whose leaves lead with batch (and lane) axes: the ``routed``
+    leaf of a routed-expert decoder's state (models/latent_moe.py: token-
+    expert assignments, rows a held expert) summed over every axis but its
+    last two; ``()`` for a carry that counts nothing (the LSTM's), which
+    adds no leaf to the loop's state."""
+    routed = getattr(carry, "routed", None)
+    if routed is None:
+        return ()
+    return routed.sum(axis=tuple(range(routed.ndim - 2)))
+
+
 def pcast_varying(tree, axes: tuple[str, ...]):
     """pcast every leaf to "varying" over ``axes`` it isn't already varying on.
 

@@ -54,6 +54,10 @@ from cst_captioning_tpu.train.mesh import batch_sharding
 from cst_captioning_tpu.train.steps import batch_arrays
 
 
+# rows a held expert of one layer took in one decoded batch (prefill + steps)
+_EXPERT_ROW_BUCKETS = tuple(float(2 ** i) for i in range(4, 21))
+
+
 class Evaluator:
     """With a ``mesh``, the decode is shard_map-parallel: every device
     beam-decodes its batch shard, and the generated token ids are gathered
@@ -120,6 +124,13 @@ class Evaluator:
         )
         W, T, lp = self.cfg.beam_size, self.cfg.max_len, self.cfg.length_penalty
         ml = self.cfg.min_len
+        # whether the compiled decode returns counts beside the tokens, and
+        # the counts of the batches dispatched and not yet collected
+        self._counts = False
+        self._tallies: list = []
+        # the host parameters last handed over and their placed copy
+        self._placed: tuple | None = None
+        self._observed = False      # the decode-state gauges are set
 
         dec_model = model
         if self.sp and not model.cfg.seq_axis:
@@ -139,11 +150,16 @@ class Evaluator:
                 max_len=T, min_len=ml, batch_axes=bx,
             )[0]
         elif W > 1:
+            # a routed-expert decoder's search also returns what it counted
+            # (decoding.common.carry_tally); on a mesh the count would be a
+            # shard's, so there the decode returns tokens alone
+            self._counts = model.cfg.decoder == "latent_moe" and mesh is None
+            pick = slice(0, None, 2) if self._counts else 0
             decode = lambda p, f, m, r: beam_search(
                 dec_model, p, f, m, beam_size=W, max_len=T, min_len=ml,
                 length_penalty=lp, batch_axes=bx,
-                beam_impl=self.cfg.beam_impl,
-            )[0]
+                beam_impl=self.cfg.beam_impl, return_tally=self._counts,
+            )[pick]
         else:
             decode = lambda p, f, m, r: greedy_decode(
                 dec_model, p, f, m, max_len=T, min_len=ml, batch_axes=bx
@@ -165,10 +181,78 @@ class Evaluator:
             plan = CompilePlan(
                 mesh=mesh, in_specs=in_specs, out_specs=P("data")
             )
-        self._decode = compile_fn(decode, plan)
+        compiled = compile_fn(decode, plan)
+        if self._counts:
+            tallies = self._tallies     # not ``self``: no cycle through it
+
+            def tokens_only(*args):
+                tokens, tally = compiled(*args)
+                tallies.append(tally)
+                return tokens
+
+            self._decode = tokens_only
+        else:
+            self._decode = compiled
+
+    def _on_device(self, params):
+        """``params`` as device arrays. Host arrays (what ``load_params``
+        returns) would be uploaded again by every dispatch of the compiled
+        decode — unnoticed at 58 MB, seconds a batch at 10 GB — so they are
+        placed once and the placed copy is kept for as long as the caller
+        hands over the same arrays (one copy: the one before is dropped
+        first). Device arrays pass through, and so does whatever a mesh's
+        caller hands over (``cli/eval.py`` replicates before it calls)."""
+        leaves = jax.tree.leaves(params)
+        if self.mesh is not None or all(isinstance(x, jax.Array) for x in leaves):
+            return params
+        if self._placed is not None and len(self._placed[0]) == len(leaves) \
+                and all(a is b for a, b in zip(self._placed[0], leaves)):
+            return self._placed[1]
+        self._placed = None
+        with obs.span("eval.params.place"):
+            placed = jax.block_until_ready(jax.device_put(params))
+        self._placed = (leaves, placed)
+        return placed
+
+    def _observe_decode(self, params, feats, masks) -> None:
+        """Gauges of the decode's state, from shapes alone (once): the
+        beam's cache (every carry leaf of a batch's encoder pass, a beam of
+        them) and, for a routed-expert decoder, the experts this chip holds."""
+        if not obs.enabled() or self._observed:
+            return
+        self._observed = True
+        from cst_captioning_tpu.models.captioner import CaptionModel
+
+        carry = jax.eval_shape(
+            lambda p, f, m: self.model.apply(
+                p, f, m, method=CaptionModel.encode).carry,
+            params, feats, masks)
+        lanes = max(self.cfg.beam_size, 1) if not self.cfg.npad_lanes \
+            else 1 + self.cfg.npad_lanes
+        obs.gauge("decode.cache_bytes").set(lanes * sum(
+            x.size * x.dtype.itemsize for x in jax.tree.leaves(carry)))
+        if self.model.cfg.decoder == "latent_moe":
+            obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
+
+    def _count(self) -> None:
+        """The oldest uncollected batch's counts, read where its tokens are
+        (the decode that produced both has finished): counters
+        ``moe.assignments`` / ``moe.assignments.local`` and the rows each
+        held expert of each layer took (histogram ``moe.expert_rows``)."""
+        if not self._tallies:
+            return
+        tally = np.asarray(jax.device_get(self._tallies.pop(0)))
+        if not obs.enabled():
+            return
+        obs.counter("moe.assignments").inc(float(tally[:, -1].sum()))
+        obs.counter("moe.assignments.local").inc(float(tally[:, :-1].sum()))
+        rows = obs.histogram("moe.expert_rows", _EXPERT_ROW_BUCKETS)
+        for n in tally[:, :-1].reshape(-1):
+            rows.observe(float(n))
 
     def _dispatch(self, params, batch, bi: int):
         """Collate-upload batch ``bi`` and launch its decode (async)."""
+        params = self._on_device(params)
         if self._fm_shardings is not None:
             # numpy straight into the target sharding (single transfer)
             put = (
@@ -180,6 +264,7 @@ class Evaluator:
             )
         else:
             feats, masks, *_ = batch_arrays(batch)
+        self._observe_decode(params, feats, masks)
         tokens = self._decode(
             params, feats, masks, jax.random.fold_in(self._decode_key, bi)
         )
@@ -214,6 +299,7 @@ class Evaluator:
                 tok = multihost.to_host_local(tokens, self.mesh, P("data"))
             else:
                 tok = jax.device_get(tokens)
+            self._count()
             for i, ok in enumerate(batch.valid):
                 if ok:
                     out[batch.video_ids[i]] = self.ds.vocab.decode(tok[i])
@@ -288,6 +374,7 @@ class Evaluator:
                 nonlocal decode_total
                 t0 = time.perf_counter()
                 tok = jax.device_get(tokens)
+                self._count()
                 dt = time.perf_counter() - t0
                 decode_total += dt
                 dec_hist.observe(dt)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from cst_captioning_tpu.config.config import BOS_ID, ModelConfig
 from cst_captioning_tpu.models.decoder import Carry, DecoderCell
+from cst_captioning_tpu.models.latent_moe import LatentMoEDecoder
 from cst_captioning_tpu.models.encoders import (
     MeanPoolEncoder,
     TemporalAttentionEncoder,
@@ -83,6 +84,12 @@ class CaptionModel(nn.Module):
 
     def setup(self):
         cfg = self.cfg
+        if cfg.decoder == "latent_moe":
+            # the second decoder kind (models/latent_moe.py): the frame
+            # embedding is the projector of a video prefix and the state is
+            # a compressed attention cache; nothing of the LSTM is built
+            self.decoder = LatentMoEDecoder(cfg, name="decoder")
+            return
         if cfg.encoder == "meanpool":
             self.encoder = MeanPoolEncoder(cfg, name="encoder")
         else:
@@ -107,6 +114,12 @@ class CaptionModel(nn.Module):
     def encode(
         self, feats: dict[str, jnp.ndarray], masks: dict[str, jnp.ndarray]
     ) -> EncoderOutput:
+        if self.cfg.decoder == "latent_moe":
+            # the prefill: the bank is the cache riding in ``carry``; the
+            # prefix mask is the memory mask, the other two fields are empty
+            valid, carry = self.decoder.prefill(feats, masks)
+            none = jnp.zeros((valid.shape[0], 0), jnp.dtype(self.cfg.dtype))
+            return EncoderOutput(none, none, valid, carry)
         memory, mmask = self.encoder(feats, masks)
         memory_proj = self.cell.project_memory(memory)
         ctx0 = masked_mean(memory, mmask, axis=1, axis_name=self.cfg.seq_axis)
@@ -125,11 +138,22 @@ class CaptionModel(nn.Module):
         enc: EncoderOutput,
         deterministic: bool = True,
     ) -> tuple[Carry, jnp.ndarray]:
+        if self.cfg.decoder == "latent_moe":
+            return self.decoder.step(carry, token, enc.memory_mask)
         return self.cell(
             carry, token, enc.memory, enc.memory_proj, enc.memory_mask, deterministic
         )
 
     # ---- teacher forcing -----------------------------------------------------
+
+    def _lstm_only(self, what: str) -> None:
+        if self.cfg.decoder != "lstm":
+            raise NotImplementedError(
+                f"CaptionModel.{what} unrolls the LSTM cell from an encoder "
+                f"pass; decoder={self.cfg.decoder!r} teacher-forces through "
+                "__call__ (the REINFORCE update that tiles one encoder pass "
+                "over K rollouts, rl/scst.py, has no such path yet)"
+            )
 
     def decode_logits(
         self,
@@ -143,6 +167,7 @@ class CaptionModel(nn.Module):
         for many label rows (the REINFORCE update teacher-forces K rollouts
         per clip against TILED memory — rl/scst.py) pay the encoder once
         instead of per row."""
+        self._lstm_only("decode_logits")
         inputs = shift_right(labels)
         scan = nn.scan(
             functools.partial(_scan_step, deterministic=not train),
@@ -169,6 +194,7 @@ class CaptionModel(nn.Module):
         stack — at the flagship dims that array is ~2 GB of f32 per REINFORCE
         chunk whose only use is a gather + logsumexp, pure HBM traffic the
         in-scan form avoids (rl/scst.py's update path)."""
+        self._lstm_only("teacher_force_logps")
         inputs = shift_right(labels)
         scan = nn.scan(
             functools.partial(_scan_step_logp, deterministic=not train),
@@ -191,4 +217,6 @@ class CaptionModel(nn.Module):
         train: bool = False,
     ) -> jnp.ndarray:
         """-> logits [B, T, V] (f32); logits[:, t] predicts labels[:, t]."""
+        if self.cfg.decoder == "latent_moe":
+            return self.decoder(feats, masks, labels)
         return self.decode_logits(self.encode(feats, masks), labels, train)

@@ -149,6 +149,53 @@ def xe_flops_per_row(
     return float(3 * (enc + T * per_tok))
 
 
+# ---- the latent-attention, routed-expert decoder (models/latent_moe.py) -------
+
+
+def latent_moe_per_tok_flops(mc, context: int) -> float:
+    """Matmul FLOPs one token costs in the ``decoder="latent_moe"`` stack
+    with ``context`` positions to attend over, head excluded: the low-rank
+    query and compressed key/value projections, attention in the absorbed
+    form (scores and values against the ``kv_lora_rank + rope`` cache, the
+    two halves of ``kv_b_proj`` once a token), the output projection, the
+    dense layers' FFN, and an expert layer's router, shared experts and held
+    experts by expectation (a token's ``num_experts_per_tok`` choices fall
+    on this chip's ``experts_held`` of ``n_routed_experts`` uniformly)."""
+    h, H = mc.hidden_size, mc.num_attention_heads
+    nope, rot, vd = mc.qk_nope_head_dim, mc.qk_rope_head_dim, mc.v_head_dim
+    rank = mc.kv_lora_rank
+    attn = 2 * (h * mc.q_lora_rank + mc.q_lora_rank * H * (nope + rot)
+                + h * (rank + rot) + H * nope * rank + H * rank * vd
+                + H * vd * h)
+    attn += 2 * context * H * ((rank + rot) + rank)
+    dense = 2 * 3 * h * mc.intermediate_size
+    held = mc.num_experts_per_tok * mc.experts_held / max(mc.n_routed_experts, 1)
+    moe = 2 * h * mc.n_routed_experts + 2 * 3 * h * mc.moe_intermediate_size * (
+        mc.n_shared_experts + held)
+    n_dense = min(mc.first_k_dense_replace, mc.num_hidden_layers)
+    return float(mc.num_hidden_layers * attn + n_dense * dense
+                 + (mc.num_hidden_layers - n_dense) * moe)
+
+
+def model_xe_flops_per_row(mc) -> float:
+    """Matmul FLOPs of one teacher-forced XE row (forward + backward as 3x
+    forward) of the model ``mc`` (a ``ModelConfig``) describes, by its
+    decoder kind: what ``Trainer`` feeds the ``flops.xe.step`` counter."""
+    feat_dims = tuple(d for _, d in mc.modalities)
+    if mc.decoder == "latent_moe":
+        n_prefix = len(feat_dims) * mc.max_frames
+        fwd = 2.0 * mc.max_frames * sum(feat_dims) * mc.hidden_size
+        fwd += sum(latent_moe_per_tok_flops(mc, p + 1)
+                   for p in range(n_prefix + mc.max_len))
+        fwd += mc.max_len * 2.0 * mc.hidden_size * mc.vocab_size
+        return float(3 * fwd)
+    return xe_flops_per_row(
+        T=mc.max_len, F=mc.max_frames, d_embed=mc.d_embed,
+        d_hidden=mc.d_hidden, d_att=mc.d_att, V=mc.vocab_size,
+        feat_dims=feat_dims, num_layers=mc.num_layers,
+    )
+
+
 # ---- XLA HLO cost-analysis backend ------------------------------------------
 #
 # The analytic counters above are matmul-only estimates; XLA's own HLO cost

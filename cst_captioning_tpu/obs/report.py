@@ -58,7 +58,7 @@ _PHASE_ORDER = (
     "prefetch.wait", "rl.decode", "rl.reward", "rl.reward.readback",
     "rl.reward.observe", "rl.reward.score", "rl.update", "rl.epoch.drain",
     "rl.actor.decode", "rl.actor.broadcast", "rl.learner.step",
-    "eval", "eval.pipeline.fill", "eval.pipeline.drain",
+    "eval", "eval.params.place", "eval.pipeline.fill", "eval.pipeline.drain",
     "eval.score", "serving.admit", "serving.encode",
     "serving.stride", "serving.detok", "obs.snapshot", "ckpt",
     "ckpt.readback", "ckpt.save", "ckpt.restore",
@@ -280,6 +280,23 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         update = {"row_blocks": float(gauges["rl.update.row_blocks"]),
                   "block_rows": float(gauges.get("rl.update.block_rows", 0.0))}
 
+    # what a beam-search decode held and routed (eval/evaluator.py): the
+    # beam's cache, and for a routed-expert decoder (models/latent_moe.py)
+    # the experts this chip holds, the token-expert assignments that fell on
+    # them, and the rows each held expert took a batch
+    decode_state = None
+    if gauges.get("decode.cache_bytes"):
+        rows = histograms.get("moe.expert_rows") or {}
+        decode_state = {
+            "cache_bytes": float(gauges["decode.cache_bytes"]),
+            "experts_held": gauges.get("moe.experts_held"),
+            "assignments": counters.get("moe.assignments", 0.0),
+            "assignments_local": counters.get("moe.assignments.local", 0.0),
+            "expert_rows_max": float(rows.get("max", 0.0)),
+            "expert_rows_mean": (
+                rows["sum"] / rows["count"] if rows.get("count") else 0.0),
+        }
+
     # serving section (serving/engine.py): request funnel counters + the
     # per-request phase histograms (queue-wait / encode / decode / detok)
     # and the paged-bank gauges. None when the run never served.
@@ -469,6 +486,7 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         "prefetch": feed,
         "decode": decode,
         "update": update,
+        "decode_state": decode_state,
         "serving": serving,
         "eval": eval_sec,
         "rl_async": rl_async,
@@ -596,6 +614,24 @@ def render_report(report: dict[str, Any]) -> str:
             f"update row blocks: {int(u['row_blocks'])} block(s) of "
             f"{int(u['block_rows'])} row(s) a rollout chunk and device"
         )
+    ds = report.get("decode_state")
+    if ds:
+        if not (d or u):
+            lines.append("")
+        lines.append(
+            f"decode state: the beam's cache holds "
+            f"{ds['cache_bytes'] / 2**20:.1f} MiB"
+        )
+        if ds["assignments"]:
+            lines.append(
+                f"routed experts: {int(ds['experts_held'] or 0)} held; "
+                f"{int(ds['assignments_local'])} of "
+                f"{int(ds['assignments'])} token-expert assignments fell on "
+                f"them ({100.0 * ds['assignments_local'] / ds['assignments']:.2f}%); "
+                f"rows a held expert took a batch: max "
+                f"{ds['expert_rows_max']:.0f}, mean "
+                f"{ds['expert_rows_mean']:.1f}"
+            )
     sv = report.get("serving")
     if sv:
         lines.append("")

@@ -292,6 +292,14 @@ class CaptionService:
         feedback: Callable[[ClipRequest, CaptionResult, int], None] | None = None,
     ):
         cfg = model.cfg
+        if cfg.decoder != "lstm":
+            # the paged bank holds the LSTM's encoder memory and the lane
+            # state is its (c, h): a growing attention cache has no pages here
+            raise NotImplementedError(
+                f"CaptionService serves decoder='lstm' only (got "
+                f"{cfg.decoder!r}); evaluate such a model through "
+                "eval.Evaluator / cli.eval"
+            )
         self.model = model
         self.params = params
         self.vocab = vocab

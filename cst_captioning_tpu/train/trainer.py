@@ -138,13 +138,7 @@ class Trainer:
         self.log = EventLogger(log_path)
         # analytic FLOPs per teacher-forced XE row (obs/flops.py) — feeds
         # the run report's MFU column via the flops.xe.step counter
-        mc = cfg.model
-        self._xe_flops_per_row = _flops.xe_flops_per_row(
-            T=mc.max_len, F=mc.max_frames, d_embed=mc.d_embed,
-            d_hidden=mc.d_hidden, d_att=mc.d_att, V=mc.vocab_size,
-            feat_dims=tuple(d for _, d in mc.modalities),
-            num_layers=mc.num_layers,
-        )
+        self._xe_flops_per_row = _flops.model_xe_flops_per_row(cfg.model)
         if cfg.train.obs:
             obs_dir = cfg.train.obs_dir or os.path.join(
                 cfg.train.ckpt_dir, "obs"
