@@ -303,6 +303,17 @@ def mask_from_tokens(tokens: jnp.ndarray) -> jnp.ndarray:
     return (tokens != PAD_ID).astype(jnp.float32)
 
 
+def caption_depth(tokens: jnp.ndarray) -> jnp.ndarray:
+    """[.., T] decoded tokens -> int32 scalar: positions up to and including
+    the last one at which any row holds a token (:func:`mask_from_tokens`
+    is 0.0 at every position from there on); 0 when every row is PAD. For
+    left-aligned captions it is the longest caption's length, EOS included.
+    """
+    T = tokens.shape[-1]
+    held = jnp.any(tokens.reshape(-1, T) != PAD_ID, axis=0)
+    return jnp.max(jnp.where(held, jnp.arange(1, T + 1, dtype=jnp.int32), 0))
+
+
 def apply_min_len(logits: jnp.ndarray, t, min_len: int) -> jnp.ndarray:
     """Suppress EOS while step ``t`` < ``min_len`` (prevents empty captions).
 

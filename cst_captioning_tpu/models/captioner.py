@@ -60,23 +60,103 @@ def _scan_step(mdl, carry, token, memory, memory_proj, memory_mask, deterministi
     return mdl.cell(carry, token, memory, memory_proj, memory_mask, deterministic)
 
 
-def _scan_step_logp(mdl, carry, tokens, memory, memory_proj, memory_mask,
-                    deterministic):
-    """One teacher-forced step emitting only the TARGET token's logprob.
+def scan_positions(labels: jnp.ndarray) -> jnp.ndarray:
+    """int32 ``[positions run, positions]`` of
+    :meth:`CaptionModel.teacher_force_logps` over ``labels`` [B, T]: the
+    batch's depth (``decoding.common.caption_depth``), of T."""
+    from cst_captioning_tpu.decoding.common import caption_depth
 
-    The per-step ``[B, V]`` logits are consumed immediately (logsumexp +
-    gather fuse into the step), so the ``[B, T, V]`` stack never reaches
-    HBM — the point of :meth:`CaptionModel.teacher_force_logps`. Shares
-    ``selected_logprob`` with the decode loops: the REINFORCE logprobs and
-    the decode-time logprobs are the same association order by construction.
+    return jnp.stack([caption_depth(labels), jnp.int32(labels.shape[-1])])
+
+
+def _bounded_logps(cell, params, carry, bank, inputs, targets, depth):
+    """Teacher forcing over the first ``depth`` of T positions: the target
+    tokens' logprobs ``[T, B]`` (0.0 from ``depth`` on) of the unbound
+    ``cell`` under ``params``, from ``inputs`` / ``targets`` ``[T, B]`` and
+    the ``bank`` every position attends over (``memory``, ``memory_proj``,
+    ``memory_mask``).
+
+    ``depth`` is a traced scalar and both passes are loops whose trip count
+    it is (``fori_loop(0, depth)``: the count is known before the loop
+    starts and compared with the loop's own counter, so no iteration waits
+    for a predicate computed from vector data), which reverse-mode
+    differentiation cannot walk by itself: the backward pass is written
+    here. The forward pass keeps each position's incoming carry, ``[T, B,
+    d]`` a layer, and nothing else; the backward pass walks ``depth - 1 ..
+    0`` and differentiates each position's step where it stands, forward
+    again from the kept carry, adding the parameters' and the bank's
+    cotangents in the order a reversed ``scan`` adds them. So a position
+    past the depth costs nothing in either pass, and a step's ``[B, V]``
+    logits live inside the step in both: no ``[T, B, V]`` residual is
+    written to HBM and read back (the scan this replaced kept one in f32;
+    PERF.md section 5). A step shares ``selected_logprob`` with the decode
+    loops: the REINFORCE logprobs and the decode-time logprobs are the same
+    association order by construction.
     """
     from cst_captioning_tpu.decoding.common import selected_logprob
 
-    token_in, token_tgt = tokens
-    carry, logits = mdl.cell(
-        carry, token_in, memory, memory_proj, memory_mask, deterministic
-    )
-    return carry, selected_logprob(logits.astype(jnp.float32), token_tgt)
+    def step(p, carry, bank, tokens, t):
+        inputs, targets = tokens
+        carry, logits = cell.apply({"params": p}, carry, inputs[t], *bank)
+        return carry, selected_logprob(logits.astype(jnp.float32), targets[t])
+
+    def put(buffer, x, t):
+        return jax.lax.dynamic_update_index_in_dim(buffer, x, t, 0)
+
+    def forward(p, carry, bank, tokens, depth):
+        def body(t, loop):
+            carry, carries, logps = loop
+            carries = jax.tree.map(lambda b, c: put(b, c, t), carries, carry)
+            carry, logp = step(p, carry, bank, tokens, t)
+            return carry, carries, put(logps, logp, t)
+
+        # zeros made from the operands: inside shard_map they vary over the
+        # mesh axes the values written into them vary over
+        T = tokens[0].shape[:1]
+        carries = jax.tree.map(
+            lambda c: jnp.zeros_like(c, shape=T + c.shape), carry
+        )
+        logps = jnp.zeros_like(
+            carry[0][0], jnp.float32, shape=tokens[0].shape
+        )
+        _, carries, logps = jax.lax.fori_loop(
+            0, depth, body, (carry, carries, logps)
+        )
+        return logps, carries
+
+    # the tokens and the depth are arguments and not closed over: where the
+    # gradient is taken outside shard_map (parallel/seq_parallel.py) the
+    # backward pass is traced apart from the forward pass
+    @jax.custom_vjp
+    def run(p, carry, bank, tokens, depth):
+        return forward(p, carry, bank, tokens, depth)[0]
+
+    def run_fwd(p, carry, bank, tokens, depth):
+        logps, carries = forward(p, carry, bank, tokens, depth)
+        return logps, (p, carries, bank, tokens, depth)
+
+    def run_bwd(kept, d_logps):
+        p, carries, bank, tokens, depth = kept
+
+        def body(i, loop):
+            t = depth - 1 - i
+            d_carry, d_rest = loop
+            _, step_vjp = jax.vjp(
+                lambda *args: step(*args, tokens, t),
+                p, jax.tree.map(lambda b: b[t], carries), bank,
+            )
+            g_p, d_carry, g_bank = step_vjp((d_carry, d_logps[t]))
+            return d_carry, jax.tree.map(jnp.add, d_rest, (g_p, g_bank))
+
+        zeros = lambda tree: jax.tree.map(jnp.zeros_like, tree)  # noqa: E731
+        d_carry, (d_p, d_bank) = jax.lax.fori_loop(
+            0, depth, body,
+            (zeros(jax.tree.map(lambda b: b[0], carries)), zeros((p, bank))),
+        )
+        return d_p, d_carry, d_bank, None, None   # integers: no cotangent
+
+    run.defvjp(run_fwd, run_bwd)
+    return run(params, carry, bank, (inputs, targets), depth)
 
 
 class CaptionModel(nn.Module):
@@ -185,29 +265,29 @@ class CaptionModel(nn.Module):
         self,
         enc: EncoderOutput,
         labels: jnp.ndarray,
-        train: bool = False,
     ) -> jnp.ndarray:
-        """Per-position logprob of ``labels`` under teacher forcing: [B, T].
+        """Per-position logprob of ``labels`` under teacher forcing, without
+        dropout: [B, T], 0.0 from the batch's depth on.
 
-        Equals ``sequence_log_probs(decode_logits(enc, labels), labels)``
-        (pinned by test) but never materializes the ``[B, T, V]`` logits
-        stack — at the flagship dims that array is ~2 GB of f32 per REINFORCE
-        chunk whose only use is a gather + logsumexp, pure HBM traffic the
-        in-scan form avoids (rl/scst.py's update path)."""
+        Equals ``sequence_log_probs(decode_logits(enc, labels), labels)`` at
+        every position up to the last at which a row of ``labels`` holds a
+        token (``decoding.common.caption_depth``; values and gradients
+        pinned by test). The positions from there on are run neither forward
+        nor backward (:func:`_bounded_logps`): T stays the static shape and
+        the depth is data, so one compiled program serves every depth, and
+        captions that fill all T positions run all of them. A caller masks
+        by the tokens (a PAD position carries no loss), so the 0.0 is what
+        those positions came to after the mask; rl/scst.py's update is the
+        caller. Neither pass hands anyone a ``[B, T, V]`` logits stack."""
         self._lstm_only("teacher_force_logps")
-        inputs = shift_right(labels)
-        scan = nn.scan(
-            functools.partial(_scan_step_logp, deterministic=not train),
-            variable_broadcast="params",
-            split_rngs={"params": False, "dropout": True},
-            in_axes=((1, 1), nn.broadcast, nn.broadcast, nn.broadcast),
-            out_axes=1,
+        from cst_captioning_tpu.decoding.common import caption_depth
+
+        logps = _bounded_logps(
+            DecoderCell(self.cfg, parent=None), self.cell.variables["params"],
+            enc.carry, (enc.memory, enc.memory_proj, enc.memory_mask),
+            shift_right(labels).T, labels.T, caption_depth(labels),
         )
-        _, logps = scan(
-            self, enc.carry, (inputs, labels), enc.memory, enc.memory_proj,
-            enc.memory_mask,
-        )
-        return logps
+        return logps.T
 
     def __call__(
         self,

@@ -35,7 +35,10 @@ sections (PR 4):
   host-side from the decoded tokens) against the ``rl.decode.budget``
   gauge (the T step budget) — what ``scan_until_finished`` saves per
   epoch; beside it the ``rl.update.row_blocks`` / ``rl.update.block_rows``
-  gauges: how the RL update was cut into row blocks when it was traced.
+  gauges: how the RL update was cut into row blocks when it was traced,
+  and the ``rl.update.positions.run`` / ``rl.update.positions`` counters:
+  the share of its teacher-forcing scan's positions that held a token of
+  the rows they were run for, and so were run.
 """
 
 from __future__ import annotations
@@ -275,10 +278,17 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
 
     # how the RL update was cut into row blocks when it was traced
     # (rl/scst.py::_chunked_loss_grads): blocks a rollout chunk, rows a block
+    # and what its teacher-forcing scans ran of their positions, summed over
+    # chunks, blocks and devices (models/captioner.py::teacher_force_logps)
     update = None
     if gauges.get("rl.update.row_blocks"):
         update = {"row_blocks": float(gauges["rl.update.row_blocks"]),
                   "block_rows": float(gauges.get("rl.update.block_rows", 0.0))}
+        positions = float(counters.get("rl.update.positions", 0))
+        if positions:
+            run = float(counters.get("rl.update.positions.run", 0))
+            update.update(positions=positions, positions_run=run,
+                          positions_run_share=run / positions)
 
     # what a beam-search decode held and routed (eval/evaluator.py): the
     # beam's cache, and for a routed-expert decoder (models/latent_moe.py)
@@ -610,9 +620,18 @@ def render_report(report: dict[str, Any]) -> str:
     if u:
         if not d:
             lines.append("")
+        scan = ""
+        if "positions" in u:
+            scan = (
+                f"; their scans ran {int(u['positions_run'])} of "
+                f"{int(u['positions'])} position(s) "
+                f"({100.0 * u['positions_run_share']:.1f}%: the rest held "
+                "no token)"
+            )
         lines.append(
             f"update row blocks: {int(u['row_blocks'])} block(s) of "
             f"{int(u['block_rows'])} row(s) a rollout chunk and device"
+            + scan
         )
     ds = report.get("decode_state")
     if ds:

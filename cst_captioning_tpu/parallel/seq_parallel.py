@@ -244,6 +244,8 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
     """
     del comm  # no grad allreduce on this path — see docstring
     from cst_captioning_tpu.models.captioner import EncoderOutput
+    # lazy like sharded_sums' import below: scst imports this package
+    from cst_captioning_tpu.rl.scst import _positions, _zero_tally
 
     f_spec, m_spec = sp_batch_specs(model.cfg, data_axis, seq_axis)
     b = data_axis if data_axis else None
@@ -291,16 +293,19 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
         from cst_captioning_tpu.rl.scst import _decode_loss_sums, _tile_enc
 
         K, Bl, T = samples.shape
-        num, den = _decode_loss_sums(
+        # tally: the denominator and teacher forcing's positions (run, all);
+        # the tokens, hence the time loops' trip count, are the same on
+        # every 'seq' shard, so the shards that meet in the attention's
+        # reduction over 'seq' run the same positions
+        num, tally = _decode_loss_sums(
             model, params, _tile_enc(enc, K),
             samples.reshape(K * Bl, T),
             advantage.reshape(K * Bl),
             jnp.tile(valid, (K,)),
         )
         if data_axis:
-            num = jax.lax.psum(num, data_axis)
-            den = jax.lax.psum(den, data_axis)
-        return num, den
+            num, tally = jax.lax.psum((num, tally), data_axis)
+        return num, tally
 
     def update(state: TrainState, feats, masks, samples, advantage, valid):
         K = samples.shape[0]
@@ -318,7 +323,7 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
             return partition(sharded_sums, CompilePlan(
                 mesh=mesh,
                 in_specs=(P(), enc_spec, P(None, b), P(None, b), P(b)),
-                out_specs=(P(), P()),
+                out_specs=(P(), (P(), P())),
             ))(p, e, sam_c, adv_c, valid)
 
         if chunks > 1:
@@ -332,8 +337,8 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
             enc, enc_vjp = jax.vjp(enc_fn, state.params)
 
             def body(acc, x):
-                gp_acc, ge_acc, num_acc, den_acc = acc
-                (num, den), (gp, ge) = jax.value_and_grad(
+                gp_acc, ge_acc, num_acc, tally_acc = acc
+                (num, tally), (gp, ge) = jax.value_and_grad(
                     sums, argnums=(0, 1), has_aux=True
                 )(state.params, enc, *x)
                 return (
@@ -343,7 +348,7 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
                         lambda a_, g: a_ + g.astype(a_.dtype), ge_acc, ge
                     ),
                     num_acc + num,
-                    den_acc + den,
+                    jax.tree.map(jnp.add, tally_acc, tally),
                 ), None
 
             init = (
@@ -355,9 +360,11 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
                     enc,
                 ),
                 jnp.zeros(()),
-                jnp.zeros(()),
+                _zero_tally(),
             )
-            (gp, ge, num, den), _ = jax.lax.scan(body, init, (sam, adv))
+            (gp, ge, num, (den, positions)), _ = jax.lax.scan(
+                body, init, (sam, adv)
+            )
             ge = jax.tree.map(lambda g, x: g.astype(x.dtype), ge, enc)
             (g_enc,) = enc_vjp(ge)
             g_sum = jax.tree.map(jnp.add, gp, g_enc)
@@ -366,13 +373,16 @@ def make_sp_rl_update(model: CaptionModel, mesh: Mesh, data_axis: str = "data",
             grads = jax.tree.map(lambda g: g / den, g_sum)
         else:
             def loss_fn(p):
-                num, den = sums(p, enc_fn(p), samples, advantage)
-                return num / jnp.maximum(den, 1.0)
+                num, (den, positions) = sums(p, enc_fn(p), samples, advantage)
+                return num / jnp.maximum(den, 1.0), positions
 
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
+            (loss, positions), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params)
         gnorm = optax.global_norm(grads)
-        return _apply(state, grads, loss, gnorm, guard, key="rl_loss",
-                      stats=stats)
+        state, metrics = _apply(state, grads, loss, gnorm, guard,
+                                key="rl_loss", stats=stats)
+        return state, _positions(metrics, positions)
 
     return compile_fn(
         update, CompilePlan(donate_argnums=(0,) if donate else ())

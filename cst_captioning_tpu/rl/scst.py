@@ -37,7 +37,7 @@ from cst_captioning_tpu.decoding import fused_decode, greedy_decode, sample_deco
 from cst_captioning_tpu.decoding.common import _exit_stride, mask_from_tokens
 from cst_captioning_tpu.obs import flops as _flops
 from cst_captioning_tpu.losses import reinforce_loss, sequence_log_probs
-from cst_captioning_tpu.models.captioner import CaptionModel
+from cst_captioning_tpu.models.captioner import CaptionModel, scan_positions
 from cst_captioning_tpu.parallel.comms import local_params, reduce_tree
 from cst_captioning_tpu.parallel.compile import CompilePlan, compile_fn
 from cst_captioning_tpu.resilience import chaos
@@ -211,14 +211,20 @@ def _tile_enc(enc, K):
 
 def _decode_loss_sums(model, params, enc_tiled, tokens_flat, advantage_flat,
                       valid_tiled):
-    """(numerator, denominator) REINFORCE sums from tiled encoder output.
+    """``(numerator, (denominator, positions))``: the REINFORCE sums from
+    tiled encoder output, and beside them what teacher forcing ran for
+    these rows (``models.captioner.scan_positions``: int32 ``[positions
+    run, positions]``).
 
     ``valid_tiled`` zeroes wrap-padded duplicate rows from short final
     batches so they carry no gradient weight and don't dilute the
-    normalization. Uses the in-scan ``teacher_force_logps`` path: the full
-    [rows, T, V] logits stack (~2 GB f32 at the flagship dims) is never
-    materialized — each step's logits are reduced to the target-token
-    logprob in place."""
+    normalization. Uses the ``teacher_force_logps`` path: each step's
+    logits are reduced to the target-token logprob in the step, in the
+    forward pass and again in the backward pass, which keeps the carries and
+    no ``[T, rows, V]`` residual; and neither pass runs a position past the
+    last at which one of THESE rows holds a token, so the bound is per
+    rollout chunk, per row block and, inside ``shard_map``, per shard, with
+    no collective."""
 
     logp = model.apply(
         params, enc_tiled, tokens_flat, method=CaptionModel.teacher_force_logps
@@ -226,7 +232,7 @@ def _decode_loss_sums(model, params, enc_tiled, tokens_flat, advantage_flat,
     mask = mask_from_tokens(tokens_flat) * valid_tiled[:, None]
     den = jnp.sum(mask)
     num = reinforce_loss(logp, mask, advantage_flat) * jnp.maximum(den, 1.0)
-    return num, den
+    return num, (den, scan_positions(tokens_flat))
 
 
 # Teacher-forced rows of one rollout chunk that a device runs as one block.
@@ -245,7 +251,25 @@ def _decode_loss_sums(model, params, enc_tiled, tokens_flat, advantage_flat,
 # 448 it is: a data-parallel shard of 448 rows (B=1792 on four chips) keeps
 # the program it had, and one chip runs that program four times. 224 reads
 # 7 % better still; it would cut the four-chip program too (ROADMAP S1(b)).
+# Since PR 37 teacher forcing keeps no residual and stops at the block's
+# longest caption; the same sweep at depth 20 of 30 (my chip runs, PR 37):
+#   1792 -> 242.0    896 -> 211.6    448 -> 213.4    224 -> 187.9
+# with both accumulators in fast memory at 448: the order stands.
 _ROW_BLOCK_CAP = 448
+
+
+def _zero_tally():
+    """What :func:`_decode_loss_sums` returns beside the numerator, at zero:
+    the denominator and the teacher-forcing scan's ``[positions run,
+    positions]``."""
+    return jnp.zeros(()), jnp.zeros((2,), jnp.int32)
+
+
+def _positions(metrics: dict, positions) -> dict:
+    """The update's metrics with the scan's tally beside them, one scalar
+    each so that whatever reads a step's metrics reads them too."""
+    return {**metrics, "positions_run": positions[0],
+            "positions": positions[1]}
 
 
 def _varying(tree, axis: str | None):
@@ -337,7 +361,7 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
 
     # the carry's types: a block's sums vary over the batch axis, and so do
     # its gradients unless the overlap path has reduced them already
-    num = den = _varying(jnp.zeros(()), vary_axis)
+    num, tally = _varying((jnp.zeros(()), _zero_tally()), vary_axis)
     g_sum = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
     if not overlap:
         g_sum = _varying(g_sum, vary_axis)
@@ -345,7 +369,7 @@ def _chunked_loss_grads(model, params, feats, masks, samples, advantage,
         jax.tree.map(rows, feats), jax.tree.map(rows, masks),
         cols(samples), cols(advantage), rows(valid),
     )
-    acc, _ = jax.lax.scan(body, (num, den, g_sum), xs)
+    acc, _ = jax.lax.scan(body, (num, tally, g_sum), xs)
     return acc
 
 
@@ -446,12 +470,12 @@ def _block_loss_grads(model, params, feats, masks, samples, advantage,
         # unreduced (varying) grads, drained one iteration late so its
         # psum can fly while this iteration's backward computes
         def body(acc, x):
-            gp_acc, gp_pend, ge_acc, num_acc, den_acc = acc
+            gp_acc, gp_pend, ge_acc, num_acc, tally_acc = acc
             if comm.overlap == "defer":
                 gp_acc = jax.tree.map(
                     jnp.add, gp_acc, reduce_tree(gp_pend, vary_axis, comm)
                 )
-            (num, den), (gp, ge) = chunk_grads(x)
+            (num, tally), (gp, ge) = chunk_grads(x)
             if comm.overlap == "eager":
                 gp_acc = jax.tree.map(
                     jnp.add, gp_acc, reduce_tree(gp, vary_axis, comm)
@@ -459,14 +483,16 @@ def _block_loss_grads(model, params, feats, masks, samples, advantage,
                 gp = gp_pend  # buffer unused: stays the zeros it came in as
             return (
                 gp_acc, gp, accum_ge(ge_acc, ge),
-                num_acc + num, den_acc + den,
+                num_acc + num, jax.tree.map(jnp.add, tally_acc, tally),
             ), None
 
         init = (
             zeros_p, vary(zeros_p), vary(zeros_e),
-            vary(jnp.zeros(())), vary(jnp.zeros(())),
+            vary(jnp.zeros(())), vary(_zero_tally()),
         )
-        (gp, gp_pend, ge, num, den), _ = jax.lax.scan(body, init, (sam, adv))
+        (gp, gp_pend, ge, num, tally), _ = jax.lax.scan(
+            body, init, (sam, adv)
+        )
         if comm.overlap == "defer":
             # flush: the last chunk's grads are still in the buffer ("defer"
             # is bit-equal to "eager" — its extra leading `+ psum(zeros)`
@@ -476,15 +502,15 @@ def _block_loss_grads(model, params, feats, masks, samples, advantage,
             )
     else:
         def body(acc, x):
-            gp_acc, ge_acc, num_acc, den_acc = acc
-            (num, den), (gp, ge) = chunk_grads(x)
+            gp_acc, ge_acc, num_acc, tally_acc = acc
+            (num, tally), (gp, ge) = chunk_grads(x)
             return (
                 jax.tree.map(jnp.add, gp_acc, gp), accum_ge(ge_acc, ge),
-                num_acc + num, den_acc + den,
+                num_acc + num, jax.tree.map(jnp.add, tally_acc, tally),
             ), None
 
-        init = vary((zeros_p, zeros_e, jnp.zeros(()), jnp.zeros(())))
-        (gp, ge, num, den), _ = jax.lax.scan(body, init, (sam, adv))
+        init = vary((zeros_p, zeros_e, jnp.zeros(()), _zero_tally()))
+        (gp, ge, num, tally), _ = jax.lax.scan(body, init, (sam, adv))
 
     # vjp cotangents must match the primal dtype
     ge = jax.tree.map(lambda g, x: g.astype(x.dtype), ge, enc)
@@ -494,7 +520,7 @@ def _block_loss_grads(model, params, feats, masks, samples, advantage,
         # reduced too, so the caller skips its own grad psum entirely
         g_enc = reduce_tree(g_enc, vary_axis, comm)
     g_sum = jax.tree.map(jnp.add, gp, g_enc)
-    return num, den, g_sum
+    return num, tally, g_sum
 
 
 def make_rl_update(model, chunks: int = 1, donate: bool = False,
@@ -519,7 +545,7 @@ def make_rl_update(model, chunks: int = 1, donate: bool = False,
 
     def update(state: TrainState, feats, masks, samples, advantage, valid):
         if chunks > 1:
-            num, den, g_sum = _chunked_loss_grads(
+            num, (den, positions), g_sum = _chunked_loss_grads(
                 model, state.params, feats, masks, samples, advantage, valid,
                 chunks,
             )
@@ -536,15 +562,18 @@ def make_rl_update(model, chunks: int = 1, donate: bool = False,
             def loss_fn(p):
                 # one encoder pass per clip; memory tiled over rollouts
                 enc = model.apply(p, feats, masks, method=CaptionModel.encode)
-                num, den = _decode_loss_sums(
+                num, (den, positions) = _decode_loss_sums(
                     model, p, _tile_enc(enc, K), tokens, adv, valid_f
                 )
-                return num / jnp.maximum(den, 1.0)
+                return num / jnp.maximum(den, 1.0), positions
 
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
+            (loss, positions), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params)
         gnorm = optax.global_norm(grads)
-        return _apply(state, grads, loss, gnorm, guard, key="rl_loss",
-                      stats=stats)
+        state, metrics = _apply(state, grads, loss, gnorm, guard,
+                                key="rl_loss", stats=stats)
+        return state, _positions(metrics, positions)
 
     return compile_fn(
         update, CompilePlan(donate_argnums=(0,) if donate else ())
@@ -576,7 +605,7 @@ def make_parallel_rl_update(model, mesh: Mesh, axis: str = "data",
 
     def device_update(state, feats, masks, samples, advantage, valid):
         if chunks > 1:
-            num, den, grads_num = _chunked_loss_grads(
+            num, (den, positions), grads_num = _chunked_loss_grads(
                 model, state.params, feats, masks, samples, advantage, valid,
                 chunks, vary_axis=axis, comm=comm,
             )
@@ -593,7 +622,7 @@ def make_parallel_rl_update(model, mesh: Mesh, axis: str = "data",
                     model, p, _tile_enc(enc, K), tokens, adv, valid_f
                 )
 
-            (num, den), grads_num = jax.value_and_grad(
+            (num, (den, positions)), grads_num = jax.value_and_grad(
                 local_num, has_aux=True
             )(local_params(state.params, axis))
         den_total = jax.lax.psum(den, axis)
@@ -608,8 +637,10 @@ def make_parallel_rl_update(model, mesh: Mesh, axis: str = "data",
         gnorm = optax.global_norm(grads)
         # psum'd grads/loss are device-invariant: the guarded select picks
         # the same branch on every shard, so state stays replicated
-        return _apply(state, grads, loss, gnorm, guard, key="rl_loss",
-                      stats=stats)
+        state, metrics = _apply(state, grads, loss, gnorm, guard,
+                                key="rl_loss", stats=stats)
+        # each shard bounds its own rows: the tally is the shards' sum
+        return state, _positions(metrics, jax.lax.psum(positions, axis))
 
     return compile_fn(device_update, CompilePlan(
         mesh=mesh,
@@ -701,6 +732,9 @@ class SCSTTrainer:
         # probed, False = XLA exposed no cost (analytic fallback), float =
         # whole-update FLOPs from the compiled program
         self._update_cost = None
+        # the updates' positions tallies, device scalars until
+        # observe_update_positions reads them (never in _apply)
+        self._positions_pending = []
         obs.gauge("rl.decode.budget").set(float(self._depth_budget))
         # decode FLOPs are always the analytic per-clip model (the early-exit
         # loop's realized cost isn't a fixed compiled number)
@@ -944,8 +978,28 @@ class SCSTTrainer:
             else:
                 state, metrics = self.update(*args)
         metrics = dict(metrics)
+        if obs.enabled():
+            self._positions_pending.append(
+                (metrics["positions_run"], metrics["positions"])
+            )
         metrics.update(host_metrics)
         return state, metrics
+
+    def observe_update_positions(self) -> None:
+        """Count what the updates' teacher-forcing scans ran since the last
+        call: ``rl.update.positions.run`` of ``rl.update.positions``, summed
+        over rollout chunks, row blocks and devices (``cli.obs_report``'s
+        ``update row blocks:`` line has the share). One read of the
+        scalars the updates returned beside their metrics, so call it where
+        the steps' metrics are read already (the Trainer: in the epoch's
+        drain, after the sentinel's flush has waited for the last update);
+        nothing is held while obs is off."""
+        pending, self._positions_pending = self._positions_pending, []
+        if not pending:
+            return
+        run, total = np.sum(jax.device_get(pending), axis=0)
+        obs.counter("rl.update.positions.run").inc(float(run))
+        obs.counter("rl.update.positions").inc(float(total))
 
     def _finish(self, state, greedy, samples, feats, masks, video_ids, valid_np):
         """Score a decoded batch and apply the REINFORCE update."""

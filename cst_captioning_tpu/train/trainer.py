@@ -1615,6 +1615,7 @@ class Trainer:
             with obs.span("rl.epoch.drain"):
                 flight.flush()
                 sentinel.flush()
+                scst.observe_update_positions()
         self.epoch += 1
         self.rl_epochs += 1
         n_valid = float(np.sum(valid_rows)) if valid_rows else 0.0

@@ -1,15 +1,23 @@
 """The sweep `rl/scst.py::_ROW_BLOCK_CAP` is set from: the RL update program
 alone, on the chip, at preset 4's widths (B=1792, K=5, `update_chunks`=5,
-donated and guarded as the Trainer builds it), once for each row cap given.
+donated and guarded as the Trainer builds it), once for each row cap given
+and, under each cap's one compiled program, for each depth of the samples.
 
     python scripts/update_row_sweep.py 1792 896 448 256 224
+    python scripts/update_row_sweep.py --depth 30,20,12 448
 
-For each cap one JSON line: the block the shape rule chose, seconds to
-compile, the compiler's temporaries, the memory space of the backward scan's
-two bank-cotangent accumulators in the compiled text (`S(1)` on a layout is
-the chip's fast memory; none is HBM), and the milliseconds of one update:
-the host clock around RUNS executions enqueued back to back and waited for
-once, so the device is never idle between them. The lines are also written
+The depth is the longest sampled caption (of T = 30) of every rollout chunk
+and row block: lengths are drawn from 4 to the depth, so each of a block's
+hundreds of rows reaches it somewhere. The update's teacher forcing runs no
+position past it (`models/captioner.py::teacher_force_logps`); the default,
+17, is what this script's samples always were. For each cap and depth one
+JSON line: the block the shape rule chose, seconds to compile, the
+compiler's temporaries, the memory space of the backward pass's two
+bank-cotangent accumulators in the compiled text where it names them as the
+scan before PR 37 did (`S(1)` on a layout is the chip's fast memory; none is
+HBM), the positions the update reports it ran, and the milliseconds of one
+update: the host clock around RUNS executions enqueued back to back and
+waited for once, so the device is never idle between them. The lines are also written
 to `chiprun_out/update_row_sweep.jsonl`. Exits 1 without a TPU: a CPU gives
 no time worth the name. The cap is a private constant of the program and
 only this script sets it, to measure; nothing a user runs does.
@@ -34,31 +42,35 @@ from cst_captioning_tpu.train.schedule import make_optimizer  # noqa: E402
 from cst_captioning_tpu.train.state import create_train_state  # noqa: E402
 
 B, K, CHUNKS, RUNS = 1792, 5, 5, 10
-# the two accumulators by what they accumulate (the fusions' numbers change
-# from one program to the next, the op_name does not)
-ACCUMULATORS = {
-    "memory": ("bm,bme->be/add_any", 512),
-    "memory_proj": ("attention/add_any", 256),
-}
+# the two accumulators by the width of what they accumulate (the fusions'
+# names and numbers change from one program to the next)
+ACCUMULATORS = {"memory": 512, "memory_proj": 256}
+# what names teacher forcing in an op_name: since PR 37, and before it
+BACKWARD_OF = ("teacher_force_logps", "_scan_step_logp")
 
 
 def accumulator_spaces(text: str) -> dict:
     """-> {"memory": "fast" | "hbm", "memory_proj": ...} from the compiled
-    text: the first output of the `select_add_fusion` whose op_name ends in
-    the accumulation and whose shape is bf16 [rows, slots, width]."""
+    text: the fusions of teacher forcing's backward pass that add into an
+    operand in place and put out a bf16 [rows, slots, width] array."""
     found = {}
+    shape = re.compile(r"(bf16\[\d+,\d+,(\d+)\]\{[^}]*\})")
     for line in text.splitlines():
-        m = re.match(r"\s*%?select_add_fusion[.\d]* = \((bf16\[\d+,\d+,(\d+)\]"
-                     r"\{[^}]*\})", line)
-        if not m:
+        outputs, fusion, rest = line.partition(" fusion(")
+        if not (fusion and "transpose(jvp(" in rest
+                and any(name in rest for name in BACKWARD_OF)
+                and '"aliasing_operands":{"lists":[{' in rest):
             continue
-        for name, (op, width) in ACCUMULATORS.items():
-            if int(m.group(2)) == width and op + '"' in line:
-                found[name] = "fast" if "S(1)" in m.group(1) else "hbm"
+        first = {}      # the first output of each width is the accumulator
+        for layout, width in shape.findall(outputs):
+            first.setdefault(int(width), layout)
+        for name, width in ACCUMULATORS.items():
+            if width in first:
+                found[name] = "fast" if "S(1)" in first[width] else "hbm"
     return found
 
 
-def main(caps):
+def main(caps, depths=(17,)):
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"no TPU here ({dev.platform}): nothing to measure")
@@ -71,11 +83,13 @@ def main(caps):
              for n, d in mc.modalities}
     masks = {n: jnp.ones((B, mc.max_frames), jnp.float32)
              for n, _ in mc.modalities}
-    lens = rng.integers(4, 18, size=(K, B, 1))
-    samples = rng.integers(4, mc.vocab_size, size=(K, B, mc.max_len))
-    samples = jnp.asarray(
-        np.where(np.arange(mc.max_len) < lens, samples, 0), jnp.int32
-    )
+    tokens = rng.integers(4, mc.vocab_size, size=(K, B, mc.max_len))
+    samples = {}
+    for depth in depths:
+        lens = rng.integers(min(4, depth), depth + 1, size=(K, B, 1))
+        samples[depth] = jnp.asarray(
+            np.where(np.arange(mc.max_len) < lens, tokens, 0), jnp.int32
+        )
     adv = jnp.asarray(rng.normal(size=(K, B)), jnp.float32)
     valid = jnp.ones((B,), jnp.float32)
     tx = make_optimizer(cfg.train, steps_per_epoch=4)
@@ -84,41 +98,53 @@ def main(caps):
     for cap in caps:
         scst._ROW_BLOCK_CAP = cap
         state = create_train_state(
-            model, tx, (feats, masks, samples[0]), seed=1
+            model, tx, (feats, masks, samples[depths[0]][0]), seed=1
         )
         update = scst.make_rl_update(model, chunks=CHUNKS, donate=True,
                                      guard=True)
         t0 = time.perf_counter()
         compiled = update.lower(
-            state, feats, masks, samples, adv, valid
+            state, feats, masks, samples[depths[0]], adv, valid
         ).compile()
         compile_s = time.perf_counter() - t0
-        for _ in range(2):
-            state, metrics = compiled(state, feats, masks, samples, adv, valid)
-        jax.block_until_ready(state)
-        t0 = time.perf_counter()
-        for _ in range(RUNS):
-            state, metrics = compiled(state, feats, masks, samples, adv, valid)
-        jax.block_until_ready(state)
-        ms = (time.perf_counter() - t0) / RUNS * 1e3
-        line = {
-            "cap": cap,
-            "block_rows": scst._row_block(B, cap),
-            "update_ms": round(ms, 3),
-            "compile_s": round(compile_s, 1),
-            "temp_gb": round(
-                compiled.memory_analysis().temp_size_in_bytes / 1e9, 3
-            ),
-            "accumulators": accumulator_spaces(compiled.as_text()),
-            "rl_loss": float(metrics["rl_loss"]),
-            "device": dev.device_kind,
-        }
-        print(json.dumps(line), flush=True)
-        out.write(json.dumps(line) + "\n")
-        out.flush()
+        for depth in depths:
+            args = (feats, masks, samples[depth], adv, valid)
+            for _ in range(2):
+                state, metrics = compiled(state, *args)
+            jax.block_until_ready(state)
+            t0 = time.perf_counter()
+            for _ in range(RUNS):
+                state, metrics = compiled(state, *args)
+            jax.block_until_ready(state)
+            ms = (time.perf_counter() - t0) / RUNS * 1e3
+            line = {
+                "cap": cap,
+                "block_rows": scst._row_block(B, cap),
+                "depth": depth,
+                "update_ms": round(ms, 3),
+                "compile_s": round(compile_s, 1),
+                "temp_gb": round(
+                    compiled.memory_analysis().temp_size_in_bytes / 1e9, 3
+                ),
+                "accumulators": accumulator_spaces(compiled.as_text()),
+                # the parent of PR 37 reports none
+                "positions_run": int(metrics.get("positions_run", -1)),
+                "positions": int(metrics.get("positions", -1)),
+                "rl_loss": float(metrics["rl_loss"]),
+                "device": dev.device_kind,
+            }
+            print(json.dumps(line), flush=True)
+            out.write(json.dumps(line) + "\n")
+            out.flush()
         del state, compiled, update
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main([int(c) for c in sys.argv[1:]] or [1792, 896, 448, 256, 224]))
+    argv = sys.argv[1:]
+    depths = (17,)
+    if argv[:1] == ["--depth"]:
+        depths = tuple(int(d) for d in argv[1].split(","))
+        argv = argv[2:]
+    sys.exit(main([int(c) for c in argv] or [1792, 896, 448, 256, 224],
+                  depths))

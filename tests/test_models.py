@@ -191,22 +191,58 @@ def test_sequence_log_probs_gather():
     np.testing.assert_allclose(lp, np.log(0.7), rtol=1e-4)
 
 
+# (positions T, the batch's longest caption): every row PAD, one token, and
+# around the decode loop's exit granularity (``_exit_stride``: 5 of 30) one
+# short of a stride's end, at it, one past it, every position held; and a T
+# that ``_exit_stride`` does not divide (31)
+_DEPTH_CASES = [(30, 0), (30, 1), (30, 7), (30, 19), (30, 20), (30, 21),
+                (30, 30), (31, 9), (31, 31)]
+
+
 @pytest.mark.parametrize("encoder", ["temporal_attention", "meanpool"])
-def test_teacher_force_logps_matches_full_logits(encoder):
-    """The in-scan target-logp path (the RL update's memory-lean form) must
-    equal gather(log_softmax(decode_logits)) exactly — same math, the [B,T,V]
-    stack just never materializes."""
+@pytest.mark.parametrize("length,depth", _DEPTH_CASES)
+def test_teacher_force_logps_matches_full_logits(encoder, length, depth):
+    """The target-logp path (the RL update's form) bounds both its passes
+    by the batch's longest caption: at every position up to it the values
+    equal gather(log_softmax(decode_logits)), past it they are 0.0 (what
+    the token mask makes of them anyway), and the gradients of the masked
+    sum w.r.t. the parameters and the encoder output are the full scan's."""
+    from cst_captioning_tpu.models.captioner import scan_positions
+
     cfg = tiny_cfg(encoder=encoder)
     model = CaptionModel(cfg)
-    feats, masks, labels = make_batch(3)
+    feats, masks, _ = make_batch(3)
+    rng = np.random.default_rng(depth)
+    # left-aligned, PAD after the end; row 0 is the longest
+    lens = rng.integers(0, depth + 1, size=(B, 1))
+    lens[0] = depth
+    labels = jnp.asarray(
+        np.where(np.arange(length) < lens,
+                 rng.integers(4, V, size=(B, length)), 0), jnp.int32)
     params = model.init(jax.random.key(0), feats, masks, labels)
     enc = model.apply(params, feats, masks, method=CaptionModel.encode)
-    full = sequence_log_probs(
-        model.apply(params, enc, labels, method=CaptionModel.decode_logits),
-        labels,
-    )
-    lean = model.apply(
-        params, enc, labels, method=CaptionModel.teacher_force_logps
-    )
-    np.testing.assert_allclose(np.asarray(lean), np.asarray(full),
+    mask = (labels != 0).astype(jnp.float32)
+
+    def full(p, e):
+        return sequence_log_probs(
+            model.apply(p, e, labels, method=CaptionModel.decode_logits),
+            labels,
+        )
+
+    def lean(p, e):
+        return model.apply(
+            p, e, labels, method=CaptionModel.teacher_force_logps
+        )
+
+    want, got = np.asarray(full(params, enc)), np.asarray(lean(params, enc))
+    np.testing.assert_allclose(got[:, :depth], want[:, :depth],
                                rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(got[:, depth:], 0.0)
+    assert list(np.asarray(scan_positions(labels))) == [depth, length]
+
+    masked = lambda f: lambda p, e: jnp.sum(f(p, e) * mask)  # noqa: E731
+    g_want = jax.grad(masked(full), argnums=(0, 1))(params, enc)
+    g_got = jax.grad(masked(lean), argnums=(0, 1))(params, enc)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)

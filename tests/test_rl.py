@@ -318,6 +318,201 @@ def test_row_blocked_rl_update_matches_unblocked(model_setup, monkeypatch, case)
         )
 
 
+def _padded_samples(lens, T, seed=7):
+    """[K, B] caption lengths -> left-aligned [K, B, T] tokens, PAD after."""
+    lens = np.asarray(lens)
+    tokens = np.random.default_rng(seed).integers(2, V, size=lens.shape + (T,))
+    return jnp.asarray(
+        np.where(np.arange(T) < lens[..., None], tokens, 0), jnp.int32
+    )
+
+
+def _full_scan_logps(self, enc, labels, train=False):
+    """The teacher forcing the update ran before its scan was bounded:
+    every one of the T positions, through the materialised logits."""
+    from cst_captioning_tpu.losses import sequence_log_probs
+
+    return sequence_log_probs(self.decode_logits(enc, labels, train), labels)
+
+
+# (update_chunks, cap on a block's rows or 0, devices of the mesh or 0)
+_BOUNDED_SCAN_CASES = {
+    "one_device_unchunked": (1, 0, 0),
+    "one_device_chunked": (3, 0, 0),
+    "row_blocks_of_2": (3, 2, 0),
+    "shard_map_2_devices": (3, 0, 2),
+    "shard_map_4_devices": (3, 0, 4),
+    "shard_map_2_devices_row_blocks": (3, 2, 2),
+    "shard_map_4_devices_unchunked": (1, 0, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(_BOUNDED_SCAN_CASES))
+def test_bounded_scan_rl_update_matches_full_scan(model_setup, monkeypatch,
+                                                  case):
+    """One update on samples padded to T (teacher forcing runs only the
+    positions up to the longest caption of the rows it is given:
+    models/captioner.py)
+    gives the loss and the post-update parameters of the same samples
+    through the full scan, kept here as the reference: on one device, cut
+    into row blocks, and inside shard_map where every shard, chunk and
+    block has another depth."""
+    from cst_captioning_tpu.rl import scst
+
+    model, state, feats, masks = model_setup
+    chunks, cap, devices = _BOUNDED_SCAN_CASES[case]
+    K, B, T = 3, 8, 12
+    # longest caption by clip, another in every shard of 2 and of 4, every
+    # rollout a little shorter than the one before
+    longest = np.asarray([2, 1, 11, 6, 4, 3, 8, 12])
+    lens = np.stack([np.maximum(longest - k, 0) for k in range(K)])
+    samples = _padded_samples(lens, T)
+    adv = jnp.asarray(np.random.default_rng(8).normal(size=(K, B)),
+                      jnp.float32)
+    valid = jnp.ones((B,), jnp.float32)
+    args = (state, feats, masks, samples, adv, valid)
+    if devices:
+        mesh = make_mesh(devices)
+        kb = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(None, "data")
+        )
+        args = (
+            replicate(mesh, state), *shard_batch(mesh, (feats, masks)),
+            jax.device_put(samples, kb), jax.device_put(adv, kb),
+            shard_batch(mesh, valid),
+        )
+        build = lambda: make_parallel_rl_update(model, mesh, chunks=chunks)
+    else:
+        build = lambda: make_rl_update(model, chunks=chunks)
+    if cap:
+        monkeypatch.setattr(scst, "_ROW_BLOCK_CAP", cap)
+
+    b_state, b_m = build()(*args)
+    with monkeypatch.context() as patch:
+        patch.setattr(CaptionModel, "teacher_force_logps", _full_scan_logps)
+        f_state, f_m = build()(*args)
+
+    np.testing.assert_allclose(
+        float(b_m["rl_loss"]), float(f_m["rl_loss"]), rtol=1e-6
+    )
+    np.testing.assert_allclose(
+        float(b_m["grad_norm"]), float(f_m["grad_norm"]), rtol=1e-5
+    )
+    for a, b in zip(jax.tree.leaves(b_state.params),
+                    jax.tree.leaves(f_state.params)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
+        )
+    # the tally beside the metrics, worked out by hand: a scan for every
+    # rollout chunk of every row block of every shard, each run up to the
+    # longest caption it holds
+    rows = B // max(devices, 1)
+    block = scst._row_block(rows, max(cap // (K // chunks), 1)) if (
+        cap and chunks > 1) else rows
+    run = sum(
+        lens[k:k + K // chunks, b:b + block].max()
+        for k in range(0, K, K // chunks) for b in range(0, B, block)
+    )
+    assert int(b_m["positions_run"]) == run
+    assert int(b_m["positions"]) == chunks * (B // block) * T
+
+
+@pytest.mark.parametrize("devices", [0, 2])
+def test_bounded_scan_compiles_once_for_every_depth(model_setup, devices):
+    """The depth is data: updates on samples whose longest caption is 5, 12
+    and 30 of T = 30 leave the jitted update with one compiled entry."""
+    model, state, feats, masks = model_setup
+    K, B, T = 3, 8, 30
+    adv = jnp.ones((K, B), jnp.float32)
+    valid = jnp.ones((B,), jnp.float32)
+    if devices:
+        mesh = make_mesh(devices)
+        kb = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(None, "data")
+        )
+        place = lambda x: jax.device_put(x, kb)  # noqa: E731
+        state = replicate(mesh, state)
+        feats, masks, valid = shard_batch(mesh, (feats, masks, valid))
+        adv = place(adv)
+        update = make_parallel_rl_update(model, mesh, chunks=3)
+    else:
+        place = lambda x: x  # noqa: E731
+        update = make_rl_update(model, chunks=3)
+    ran = []
+    for depth in (5, 12, 30):
+        lens = np.full((K, B), 3)
+        lens[1, 5] = depth
+        _, m = update(state, feats, masks,
+                      place(_padded_samples(lens, T)), adv, valid)
+        ran.append(int(m["positions_run"]))
+        assert int(m["positions"]) == 3 * max(devices, 1) * T
+    assert update._cache_size() == 1
+    # every scan runs its 3 positions, the one that holds the long caption
+    # runs up to it
+    others = 3 * max(devices, 1) - 1
+    assert ran == [others * 3 + 5, others * 3 + 12, others * 3 + 30]
+
+
+def test_update_positions_counter_and_report(model_setup, tmp_path):
+    """``SCSTTrainer.observe_update_positions`` turns the scalars the
+    updates returned beside their metrics into the counters
+    ``rl.update.positions.run`` / ``rl.update.positions``, and
+    ``cli.obs_report`` prints their share on its ``update row blocks:``
+    line; samples that fill all T positions read a share of 1."""
+    from cst_captioning_tpu.obs.report import build_report, render_report
+
+    model, state, feats, masks = model_setup
+    K, B, T = 2, 8, 10
+    cfg = RLConfig(enabled=True, num_rollouts=K, baseline="none",
+                   update_chunks=2)
+    obs.REGISTRY.reset()
+    obs.configure(str(tmp_path / "obs"), run="positions")
+    try:
+        trainer = SCSTTrainer(model, TokenReward(target=7), cfg, max_len=T)
+        host = {"reward_mean": 0.0}
+        ones = np.ones((B,), np.float32)
+        adv = np.ones((K, B), np.float32)
+
+        def apply(lens):
+            return trainer._apply(state, adv, host,
+                                  _padded_samples(lens, T), feats, masks, ones)
+
+        # one scan a rollout chunk: the first chunk's longest caption is 7,
+        # the second's 3
+        lens = np.full((K, B), 2)
+        lens[0, 3], lens[1, 6] = 7, 3
+        _, m = apply(lens)
+        assert "positions_run" in m and "rl_loss" in m
+        snap = obs.snapshot()["counters"]
+        assert "rl.update.positions" not in snap    # nothing read in _apply
+        trainer.observe_update_positions()
+        snap = obs.snapshot()["counters"]
+        assert snap["rl.update.positions.run"] == 7 + 3
+        assert snap["rl.update.positions"] == 2 * T
+        # a second update, every position held: its share is 1
+        apply(np.full((K, B), T))
+        trainer.observe_update_positions()
+        trainer.observe_update_positions()          # nothing pending: no-op
+        snap = obs.snapshot()
+        assert snap["counters"]["rl.update.positions.run"] == 10 + 2 * T
+        assert snap["counters"]["rl.update.positions"] == 4 * T
+        events = [
+            {"ts": 0.0, "event": "run_start", "run": "positions",
+             "thread": "main"},
+            {"ts": 1.0, "event": "metrics", "histograms": {},
+             "counters": snap["counters"], "gauges": snap["gauges"]},
+            {"ts": 2.0, "event": "run_end", "run": "positions"},
+        ]
+        rep = build_report(events)
+        assert rep["update"]["positions_run_share"] == pytest.approx(30 / 40)
+        assert ("update row blocks: 1 block(s) of 8 row(s) a rollout chunk "
+                "and device; their scans ran 30 of 40 position(s) (75.0%"
+                in render_report(rep))
+    finally:
+        obs.shutdown()
+        obs.REGISTRY.reset()
+
+
 def test_train_step_zero_weights_invalid_rows(model_setup):
     """Wrap-padded rows (valid=False) must not change the update."""
     model, state, feats, masks = model_setup
