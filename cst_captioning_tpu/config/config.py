@@ -124,13 +124,47 @@ class ModelConfig:
     # ``n_routed_experts`` and computes its own experts' part of the result
     experts_held: int = 0
     expert_share_index: int = 0
+    # decoder kind "sparse_linear" (models/sparse_linear.py: a pre-norm
+    # residual stack over a video prefix whose layers mix tokens one of two
+    # ways, ``mixer_types`` says which: "minicpm4" is grouped-query softmax
+    # attention over the key blocks each query selects, "lightning-attn"
+    # causal linear attention with a per-head decay). Sizes under the key
+    # names of the published config.json (benchmark/configs/
+    # minicpm_sala_8l.json); it also reads hidden_size, num_hidden_layers,
+    # intermediate_size, num_attention_heads, rms_norm_eps, rope_theta and
+    # initializer_range above
+    mixer_types: tuple[str, ...] = ()
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    lightning_nh: int = 0
+    lightning_head_dim: int = 0
+    # muP: token embeddings times ``scale_emb``, every residual branch times
+    # ``scale_depth / sqrt(published_layers)``, logits over ``hidden_size /
+    # dim_model_base``. ``published_layers`` is the depth of the model the
+    # held layers are cut from, ``first_layer_index`` where in it they start
+    # (the linear layers' decay slopes scale with the published depth)
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    dim_model_base: int = 0
+    published_layers: int = 0
+    first_layer_index: int = 0
+    # the sparse layers' selection (MiniCPM4's ``sparse_config``)
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_window_size: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_dense_len: int = 8192
 
     def __post_init__(self):
-        if self.decoder not in ("lstm", "latent_moe"):
+        if self.decoder not in ("lstm", "latent_moe", "sparse_linear"):
             raise ValueError(
                 f"unknown decoder: {self.decoder!r} "
-                "(expected 'lstm' or 'latent_moe')"
+                "(expected 'lstm', 'latent_moe' or 'sparse_linear')"
             )
+        object.__setattr__(self, "mixer_types",
+                           tuple(str(m) for m in self.mixer_types))
         object.__setattr__(
             self, "rope_scaling",
             tuple((str(k), v) for k, v in (
@@ -494,6 +528,12 @@ class EvalConfig:
     # bit-identical to the serial path (eval/evaluator.py)
     pipelined: bool = True
     score_workers: int = 4        # tokenizer threads feeding the drain
+    # run the encoder pass (for a language-model decoder the prefill of the
+    # video prefix) as a compiled program of its own, ``eval_prefill``, and
+    # the beam search from its output as a second: a device trace then tells
+    # the prefix from the caption's steps by the programs' names. Beam
+    # search on one device only (eval/evaluator.py)
+    prefill_program: bool = False
 
     def __post_init__(self):
         if self.beam_impl not in ("lanes", "reference"):

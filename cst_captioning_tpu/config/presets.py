@@ -23,6 +23,18 @@ chip's share of an expert-parallel deployment (``_KIMI_K2_EP32``):
 7. ``kimi_k2_ep32_eval_beam5`` — the same model, beam-5 eval through the
                                ``Evaluator`` (beams flattened into the batch).
 
+Two more run the third (``model.decoder = "sparse_linear"``,
+models/sparse_linear.py) at the published widths of MiniCPM-SALA, eight of
+its 32 layers (``_MINICPM_SALA_8L``):
+
+8. ``minicpm_sala_8l_xe``    — the stack behind a 16384-slot video prefix of
+                               patch tokens; bfloat16 parameters and plain
+                               SGD. The benchmark makes its seeded policy
+                               from it (0 steps).
+9. ``minicpm_sala_8l_eval_beam5`` — the same model, beam-5 eval through the
+                               ``Evaluator``, beams on lanes: a clip's beams
+                               share one copy of its prefix.
+
 Paper CST variant names map onto presets as: XE -> 1/2; CST_GT_None/SCST -> 3;
 CST_MS_SCB -> 4 (with ``rl.baseline="scb"``); WXE is preset 2 with
 ``train.loss="wxe"``.
@@ -187,6 +199,70 @@ def _kimi_k2_ep32_eval_beam5() -> ExperimentConfig:
     )
 
 
+# MiniCPM-SALA (huggingface.co/openbmb/MiniCPM-SALA, config.json): every width
+# as published; the depth is the published layers 9-16 (one sparse layer, six
+# linear, one sparse: the model's own ratio of 1 to 3), one of four pipeline
+# stages of eight layers. The muP depth scale and the linear layers' decay
+# slopes keep the published depth (32) and the layers' published indices
+# (first_layer_index 8). One modality of 16384 patch tokens: 256 frames x 64
+# pooled patches. The selection's sizes are MiniCPM4's ``sparse_config``.
+_MINICPM_SALA_8L = ModelConfig(
+    decoder="sparse_linear",
+    vocab_size=73448,
+    modalities=(("patch", 1024),),
+    max_len=30,
+    max_frames=16384,
+    dropout=0.0,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+    hidden_size=4096,
+    num_hidden_layers=8,
+    intermediate_size=16384,
+    num_attention_heads=32,
+    num_key_value_heads=2,
+    head_dim=128,
+    lightning_nh=32,
+    lightning_head_dim=128,
+    mixer_types=("minicpm4",) + ("lightning-attn",) * 6 + ("minicpm4",),
+    rms_norm_eps=1e-6,
+    rope_theta=10000.0,
+    initializer_range=0.02,
+    scale_emb=12.0,
+    scale_depth=1.4,
+    dim_model_base=256,
+    published_layers=32,
+    first_layer_index=8,
+    sparse_kernel_size=32,
+    sparse_kernel_stride=16,
+    sparse_block_size=64,
+    sparse_topk=64,
+    sparse_window_size=2048,
+    sparse_init_blocks=1,
+    sparse_dense_len=8192,
+)
+
+
+def _minicpm_sala_8l_xe() -> ExperimentConfig:
+    return ExperimentConfig(
+        name="minicpm_sala_8l_xe",
+        model=_MINICPM_SALA_8L,
+        data=DataConfig(dataset="msrvtt", batch_size=2),
+        train=TrainConfig(loss="xe", optimizer="sgd", lr=1e-4, epochs=1),
+    )
+
+
+def _minicpm_sala_8l_eval_beam5() -> ExperimentConfig:
+    return dataclasses.replace(
+        _minicpm_sala_8l_xe(),
+        name="minicpm_sala_8l_eval_beam5",
+        # beams on lanes: the lane step closes over the encoder output, so
+        # the prefix's keys are read from one copy a clip ("reference" would
+        # tile them a lane)
+        eval=EvalConfig(beam_size=5, max_len=30, split="test",
+                        beam_impl="lanes", prefill_program=True),
+    )
+
+
 PRESETS = {
     "msvd_xe_meanpool": _msvd_xe_meanpool,
     "msrvtt_xe_attention": _msrvtt_xe_attention,
@@ -195,6 +271,8 @@ PRESETS = {
     "msrvtt_eval_beam5": _msrvtt_eval_beam5,
     "kimi_k2_ep32_xe": _kimi_k2_ep32_xe,
     "kimi_k2_ep32_eval_beam5": _kimi_k2_ep32_eval_beam5,
+    "minicpm_sala_8l_xe": _minicpm_sala_8l_xe,
+    "minicpm_sala_8l_eval_beam5": _minicpm_sala_8l_eval_beam5,
 }
 
 

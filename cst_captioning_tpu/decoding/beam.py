@@ -243,8 +243,13 @@ def beam_search(
     batch_axes: tuple[str, ...] = (),
     beam_impl: str = "lanes",
     return_tally: bool = False,
+    enc: EncoderOutput | None = None,
 ):
     """-> (tokens [B, T], scores [B]) — or [B, W, T] / [B, W] if return_all.
+
+    ``enc`` is the encoder pass where the caller has run it already
+    (``CaptionModel.encode`` as a program of its own: ``feats`` and ``masks``
+    are then not read).
 
     ``return_tally`` appends what the decoder counted over the search
     (``decoding.common.carry_tally`` summed over the encoder pass and every
@@ -263,8 +268,9 @@ def beam_search(
         )
     W = beam_size
     T = max_len or model.cfg.max_len
-    enc: EncoderOutput = model.apply(params, feats, masks, method=CaptionModel.encode)
-    B = enc.memory.shape[0]
+    if enc is None:
+        enc = model.apply(params, feats, masks, method=CaptionModel.encode)
+    B = enc.memory_mask.shape[0]
     V = model.cfg.vocab_size
 
     run = _run_lanes if beam_impl == "lanes" else _run_reference

@@ -141,13 +141,16 @@ def carry_tally(carry):
     """What a decoder's last call counted for the decode loop to sum, off a
     carry whose leaves lead with batch (and lane) axes: the ``routed``
     leaf of a routed-expert decoder's state (models/latent_moe.py: token-
-    expert assignments, rows a held expert) summed over every axis but its
-    last two; ``()`` for a carry that counts nothing (the LSTM's), which
-    adds no leaf to the loop's state."""
-    routed = getattr(carry, "routed", None)
-    if routed is None:
-        return ()
-    return routed.sum(axis=tuple(range(routed.ndim - 2)))
+    expert assignments, rows a held expert) or the ``counted`` leaf of the
+    sparse/linear decoder's (models/sparse_linear.py: keys its sparse layers'
+    queries saw and attended to), summed over every axis but its last two;
+    ``()`` for a carry that counts nothing (the LSTM's), which adds no leaf
+    to the loop's state."""
+    for leaf in ("routed", "counted"):
+        counts = getattr(carry, leaf, None)
+        if counts is not None:
+            return counts.sum(axis=tuple(range(counts.ndim - 2)))
+    return ()
 
 
 def pcast_varying(tree, axes: tuple[str, ...]):

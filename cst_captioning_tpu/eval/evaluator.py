@@ -150,16 +150,19 @@ class Evaluator:
                 max_len=T, min_len=ml, batch_axes=bx,
             )[0]
         elif W > 1:
-            # a routed-expert decoder's search also returns what it counted
-            # (decoding.common.carry_tally); on a mesh the count would be a
+            # a language-model decoder's search also returns what it counted
+            # (decoding.common.carry_tally: routed assignments, or keys the
+            # sparse layers attended to); on a mesh the count would be a
             # shard's, so there the decode returns tokens alone
-            self._counts = model.cfg.decoder == "latent_moe" and mesh is None
+            self._counts = model.cfg.decoder != "lstm" and mesh is None
             pick = slice(0, None, 2) if self._counts else 0
-            decode = lambda p, f, m, r: beam_search(
+            search = lambda p, f, m, enc=None: beam_search(  # noqa: E731
                 dec_model, p, f, m, beam_size=W, max_len=T, min_len=ml,
                 length_penalty=lp, batch_axes=bx,
                 beam_impl=self.cfg.beam_impl, return_tally=self._counts,
+                enc=enc,
             )[pick]
+            decode = lambda p, f, m, r: search(p, f, m)
         else:
             decode = lambda p, f, m, r: greedy_decode(
                 dec_model, p, f, m, max_len=T, min_len=ml, batch_axes=bx
@@ -181,7 +184,26 @@ class Evaluator:
             plan = CompilePlan(
                 mesh=mesh, in_specs=in_specs, out_specs=P("data")
             )
-        compiled = compile_fn(decode, plan)
+        if self.cfg.prefill_program:
+            if mesh is not None or W < 2 or self.cfg.npad_lanes:
+                raise ValueError(
+                    "eval.prefill_program runs a beam search on one device: "
+                    "no mesh, beam_size >= 2, npad_lanes 0")
+            from cst_captioning_tpu.models.captioner import CaptionModel
+
+            def eval_prefill(p, f, m):
+                return dec_model.apply(p, f, m, method=CaptionModel.encode)
+
+            prefill = jax.jit(eval_prefill)
+            from_enc = jax.jit(lambda p, enc: search(p, None, None, enc))
+
+            def compiled(p, f, m, r):
+                with obs.span("eval.prefill"):
+                    enc = prefill(p, f, m)
+                with obs.span("eval.decode"):
+                    return from_enc(p, enc)
+        else:
+            compiled = compile_fn(decode, plan)
         if self._counts:
             tallies = self._tallies     # not ``self``: no cycle through it
 
@@ -215,34 +237,64 @@ class Evaluator:
         return placed
 
     def _observe_decode(self, params, feats, masks) -> None:
-        """Gauges of the decode's state, from shapes alone (once): the
-        beam's cache (every carry leaf of a batch's encoder pass, a beam of
-        them) and, for a routed-expert decoder, the experts this chip holds."""
+        """Gauges of the decode's state, from shapes alone (once):
+        ``decode.cache_bytes``, all a batch's search holds (every carry leaf
+        of its encoder pass, a beam of them, and what a clip's lanes share
+        once a clip); for a routed-expert decoder the experts this chip
+        holds; for the sparse/linear decoder the three kinds of state apart:
+        ``decode.kv_bytes`` (keys and values: the prefix's once a clip, a
+        caption's a lane), ``decode.index_bytes`` (the compressed keys the
+        selection scores, once a clip), ``decode.state_bytes`` (the linear
+        layers' recurrent states, a lane)."""
         if not obs.enabled() or self._observed:
             return
         self._observed = True
         from cst_captioning_tpu.models.captioner import CaptionModel
 
-        carry = jax.eval_shape(
+        enc = jax.eval_shape(
             lambda p, f, m: self.model.apply(
-                p, f, m, method=CaptionModel.encode).carry,
+                p, f, m, method=CaptionModel.encode),
             params, feats, masks)
         lanes = max(self.cfg.beam_size, 1) if not self.cfg.npad_lanes \
             else 1 + self.cfg.npad_lanes
-        obs.gauge("decode.cache_bytes").set(lanes * sum(
-            x.size * x.dtype.itemsize for x in jax.tree.leaves(carry)))
-        if self.model.cfg.decoder == "latent_moe":
+        size = lambda tree: sum(  # noqa: E731
+            x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+        kind = self.model.cfg.decoder
+        if kind == "sparse_linear":
+            # "reference" tiles the encoder output a lane; "lanes" shares it
+            shared = 1 if self.cfg.beam_impl == "lanes" else lanes
+            kv = shared * size(enc.memory) + lanes * size(
+                (enc.carry.k, enc.carry.v))
+            index = shared * size(enc.memory_proj)
+            state = lanes * size(enc.carry.state)
+            obs.gauge("decode.kv_bytes").set(kv)
+            obs.gauge("decode.index_bytes").set(index)
+            obs.gauge("decode.state_bytes").set(state)
+            obs.gauge("decode.cache_bytes").set(kv + index + state)
+            return
+        obs.gauge("decode.cache_bytes").set(lanes * size(enc.carry))
+        if kind == "latent_moe":
             obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
 
     def _count(self) -> None:
         """The oldest uncollected batch's counts, read where its tokens are
         (the decode that produced both has finished): counters
         ``moe.assignments`` / ``moe.assignments.local`` and the rows each
-        held expert of each layer took (histogram ``moe.expert_rows``)."""
+        held expert of each layer took (histogram ``moe.expert_rows``), or
+        the sparse layers' ``sparse.keys_visible`` / ``sparse.keys_selected``
+        / ``sparse.dense_fallback_queries``."""
         if not self._tallies:
             return
         tally = np.asarray(jax.device_get(self._tallies.pop(0)))
         if not obs.enabled():
+            return
+        if self.model.cfg.decoder == "sparse_linear":
+            # [sparse layers, 3]: a key/value group a query, keys seen, keys
+            # attended to, queries under the dense length
+            seen, took, dense = tally.sum(axis=0, dtype=np.float64)
+            obs.counter("sparse.keys_visible").inc(float(seen))
+            obs.counter("sparse.keys_selected").inc(float(took))
+            obs.counter("sparse.dense_fallback_queries").inc(float(dense))
             return
         obs.counter("moe.assignments").inc(float(tally[:, -1].sum()))
         obs.counter("moe.assignments.local").inc(float(tally[:, :-1].sum()))

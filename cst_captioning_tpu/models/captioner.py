@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from cst_captioning_tpu.config.config import BOS_ID, ModelConfig
 from cst_captioning_tpu.models.decoder import Carry, DecoderCell
 from cst_captioning_tpu.models.latent_moe import LatentMoEDecoder
+from cst_captioning_tpu.models.sparse_linear import SparseLinearDecoder
 from cst_captioning_tpu.models.encoders import (
     MeanPoolEncoder,
     TemporalAttentionEncoder,
@@ -170,6 +171,11 @@ class CaptionModel(nn.Module):
             # a compressed attention cache; nothing of the LSTM is built
             self.decoder = LatentMoEDecoder(cfg, name="decoder")
             return
+        if cfg.decoder == "sparse_linear":
+            # the third (models/sparse_linear.py): sparse-softmax and linear-
+            # attention layers over a long video prefix
+            self.decoder = SparseLinearDecoder(cfg, name="decoder")
+            return
         if cfg.encoder == "meanpool":
             self.encoder = MeanPoolEncoder(cfg, name="encoder")
         else:
@@ -200,6 +206,15 @@ class CaptionModel(nn.Module):
             valid, carry = self.decoder.prefill(feats, masks)
             none = jnp.zeros((valid.shape[0], 0), jnp.dtype(self.cfg.dtype))
             return EncoderOutput(none, none, valid, carry)
+        if self.cfg.decoder == "sparse_linear":
+            # what a clip's lanes share rides where the memory bank does: the
+            # sparse layers' prefix keys and values (``memory``) and their
+            # compressed keys (``memory_proj``), the mask saying how many of
+            # the compacted slots exist; what a lane owns is the carry
+            (keys, values, pooled), n, carry = self.decoder.prefill(feats, masks)
+            slots = len(self.cfg.modalities) * self.cfg.max_frames
+            live = (jnp.arange(slots)[None, :] < n[:, None]).astype(jnp.float32)
+            return EncoderOutput((keys, values), pooled, live, carry)
         memory, mmask = self.encoder(feats, masks)
         memory_proj = self.cell.project_memory(memory)
         ctx0 = masked_mean(memory, mmask, axis=1, axis_name=self.cfg.seq_axis)
@@ -220,6 +235,10 @@ class CaptionModel(nn.Module):
     ) -> tuple[Carry, jnp.ndarray]:
         if self.cfg.decoder == "latent_moe":
             return self.decoder.step(carry, token, enc.memory_mask)
+        if self.cfg.decoder == "sparse_linear":
+            return self.decoder.step(
+                carry, token, (*enc.memory, enc.memory_proj),
+                enc.memory_mask.sum(axis=-1).astype(jnp.int32))
         return self.cell(
             carry, token, enc.memory, enc.memory_proj, enc.memory_mask, deterministic
         )
@@ -297,6 +316,6 @@ class CaptionModel(nn.Module):
         train: bool = False,
     ) -> jnp.ndarray:
         """-> logits [B, T, V] (f32); logits[:, t] predicts labels[:, t]."""
-        if self.cfg.decoder == "latent_moe":
+        if self.cfg.decoder in ("latent_moe", "sparse_linear"):
             return self.decoder(feats, masks, labels)
         return self.decode_logits(self.encode(feats, masks), labels, train)

@@ -177,16 +177,48 @@ def latent_moe_per_tok_flops(mc, context: int) -> float:
                  + (mc.num_hidden_layers - n_dense) * moe)
 
 
+# ---- the sparse-softmax / linear-attention decoder (models/sparse_linear.py) --
+
+
+def sparse_linear_per_tok_flops(mc, context: int) -> float:
+    """Matmul FLOPs one token costs in the ``decoder="sparse_linear"`` stack
+    at ``context`` positions seen, head excluded: a layer's q/k/v/gate/output
+    projections and gated FFN; a sparse layer's scores and values over the
+    keys its rule attends to (all under ``sparse_dense_len``; else the
+    ``sparse_topk`` blocks, the first and the window, at most the context)
+    and its selection over the compressed keys; a linear layer's state
+    update and read (``2 * 2 * head_dim^2`` a head)."""
+    h, m = mc.hidden_size, mc.intermediate_size
+    total = 0.0
+    for kind in mc.mixer_types:
+        if kind == "minicpm4":
+            H, G, d = mc.num_attention_heads, mc.num_key_value_heads, mc.head_dim
+            keys = context if context < mc.sparse_dense_len else min(
+                context, (mc.sparse_topk + mc.sparse_init_blocks)
+                * mc.sparse_block_size + mc.sparse_window_size)
+            pooled = 0 if context < mc.sparse_dense_len else \
+                context // mc.sparse_kernel_stride
+            mix = 2 * 2 * H * d * keys + 2 * H * d * pooled
+        else:
+            H = G = mc.lightning_nh
+            d = mc.lightning_head_dim
+            mix = 2 * 2 * H * d * d
+        total += 2 * h * (2 * H * d + 2 * G * d) + 2 * H * d * h + mix \
+            + 2 * 3 * h * m
+    return float(total)
+
+
 def model_xe_flops_per_row(mc) -> float:
     """Matmul FLOPs of one teacher-forced XE row (forward + backward as 3x
     forward) of the model ``mc`` (a ``ModelConfig``) describes, by its
     decoder kind: what ``Trainer`` feeds the ``flops.xe.step`` counter."""
     feat_dims = tuple(d for _, d in mc.modalities)
-    if mc.decoder == "latent_moe":
+    per_tok = {"latent_moe": latent_moe_per_tok_flops,
+               "sparse_linear": sparse_linear_per_tok_flops}.get(mc.decoder)
+    if per_tok is not None:
         n_prefix = len(feat_dims) * mc.max_frames
         fwd = 2.0 * mc.max_frames * sum(feat_dims) * mc.hidden_size
-        fwd += sum(latent_moe_per_tok_flops(mc, p + 1)
-                   for p in range(n_prefix + mc.max_len))
+        fwd += sum(per_tok(mc, p + 1) for p in range(n_prefix + mc.max_len))
         fwd += mc.max_len * 2.0 * mc.hidden_size * mc.vocab_size
         return float(3 * fwd)
     return xe_flops_per_row(

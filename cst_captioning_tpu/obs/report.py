@@ -305,6 +305,15 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             "expert_rows_max": float(rows.get("max", 0.0)),
             "expert_rows_mean": (
                 rows["sum"] / rows["count"] if rows.get("count") else 0.0),
+            # the sparse/linear decoder (models/sparse_linear.py): the three
+            # kinds of state apart, and what its sparse layers' queries saw
+            "kv_bytes": gauges.get("decode.kv_bytes"),
+            "index_bytes": gauges.get("decode.index_bytes"),
+            "state_bytes": gauges.get("decode.state_bytes"),
+            "keys_visible": counters.get("sparse.keys_visible", 0.0),
+            "keys_selected": counters.get("sparse.keys_selected", 0.0),
+            "dense_fallback_queries": counters.get(
+                "sparse.dense_fallback_queries", 0.0),
         }
 
     # serving section (serving/engine.py): request funnel counters + the
@@ -650,6 +659,20 @@ def render_report(report: dict[str, Any]) -> str:
                 f"rows a held expert took a batch: max "
                 f"{ds['expert_rows_max']:.0f}, mean "
                 f"{ds['expert_rows_mean']:.1f}"
+            )
+        if ds.get("kv_bytes") is not None:
+            lines.append(
+                f"of it keys and values {ds['kv_bytes'] / 2**20:.1f} MiB, "
+                f"compressed keys {(ds['index_bytes'] or 0) / 2**20:.1f} MiB, "
+                f"recurrent states {(ds['state_bytes'] or 0) / 2**20:.1f} MiB"
+            )
+        if ds.get("keys_visible"):
+            lines.append(
+                f"sparse layers: queries attended to "
+                f"{int(ds['keys_selected'])} of {int(ds['keys_visible'])} "
+                f"keys they saw "
+                f"({100.0 * ds['keys_selected'] / ds['keys_visible']:.2f}%); "
+                f"{int(ds['dense_fallback_queries'])} under the dense length"
             )
     sv = report.get("serving")
     if sv:

@@ -268,3 +268,38 @@ def test_xe_step_compiles_for_v5e(chip, shapes):
         shapes["labels"], mask, weights,
     )
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+# ---- the sparse/linear decoder's two prefix kernels (PR 38) -------------------
+
+
+@pytest.mark.parametrize("mixer", ["sparse_attn_prefill", "linear_attn_prefill"])
+def test_prefix_mixer_kernel_compiles_for_v5e_at_the_published_widths(mixer, chip):
+    """MiniCPM-SALA's two mixers over a 16384-position prefix of two clips,
+    bfloat16, at the preset's tiles: Mosaic accepts each, and the custom call
+    carries the kernel's name — which is how the benchmark's readers find its
+    operations in a device trace (``layer_metrics/_kernels.py``)."""
+    from cst_captioning_tpu.models.sparse_linear import LINEAR_CHUNK, sparse_spec
+    from cst_captioning_tpu.ops import linear_attention, sparse_attention
+
+    mc = get_preset("minicpm_sala_8l_eval_beam5").model
+    rows, P, bf16 = 2, mc.max_frames, jnp.bfloat16
+    n = _sds((rows,), jnp.int32)
+    if mixer == "sparse_attn_prefill":
+        spec = sparse_spec(mc)
+        H, G, d = mc.num_attention_heads, mc.num_key_value_heads, mc.head_dim
+        kv = _sds((rows, P, G, d), bf16)
+        ck = _sds((rows, sparse_attention.n_compressed(P, spec), G, d), jnp.float32)
+        compiled = _compile(
+            lambda q, k, v, c, n: sparse_attention.sparse_prefill(
+                q, k, v, c, n, spec, impl="pallas"),
+            chip, _sds((rows, P, H, d), bf16), kv, kv, ck, n)
+    else:
+        H, d = mc.lightning_nh, mc.lightning_head_dim
+        x = _sds((rows, P, H, d), bf16)
+        compiled = _compile(
+            lambda q, k, v, s, n: linear_attention.chunked_linear_attention(
+                q, k, v, s, n, LINEAR_CHUNK, "pallas"),
+            chip, x, x, x, _sds((H,), jnp.float32), n)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{mixer}" in text
