@@ -106,6 +106,126 @@ def gumbel_step_noise(step_keys_t: jax.Array, shape: tuple[int, ...],
     return jax.vmap(lambda k: jax.random.gumbel(k, shape, dtype))(step_keys_t)
 
 
+def _cdf_block(V: int) -> int:
+    """Columns a block of :func:`inverse_cdf_index`, from the shape: the
+    largest multiple of 8 up to 128 that divides V (9000 = 75 x 120), so
+    that cutting V into blocks is a view of the logits where V runs along
+    the tiles' 8 sublanes (rows on the 128 lanes); where V has no such
+    divisor, ``min(128, V)``, and the last block is padded."""
+    for w in range(128, 7, -8):
+        if V % w == 0:
+            return w
+    return min(128, V)
+
+
+def _first_over(mass: jnp.ndarray, cum: jnp.ndarray,
+                target: jnp.ndarray) -> jnp.ndarray:
+    """[..., N] terms and their cumulative sums, [...] targets -> [...]
+    int32: the first position that HAS mass and whose cumulative sum passes
+    the target; where none does, the last position that has mass. The test
+    on the term itself is what makes "never a term of zero mass" hold by
+    construction, whatever order a parallel prefix sum associated in."""
+    n = mass.shape[-1]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    has = mass > 0
+    over = has & (cum > target[..., None])
+    first = jnp.min(jnp.where(over, pos, n), axis=-1)
+    last = jnp.max(jnp.where(has, pos, 0), axis=-1)
+    return jnp.where(first < n, first, last)
+
+
+# what the running maximum of :func:`_max_and_sumexp` starts from, and what
+# pads a short last block: finite, so that the difference of two of them is
+# 0.0 and not the NaN two infinities give
+_LOWEST = -3.0e38
+
+
+def _max_and_sumexp(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """[..., w] -> ([...] max, [...] sum of ``exp(x - max)``) over the last
+    axis in ONE reduction: the pair (m, s) is carried and rescaled as the
+    maximum rises, ``(m1, s1) + (m2, s2) = (m, s1 e^(m1 - m) + s2 e^(m2 -
+    m))`` with ``m = max(m1, m2)``, so the logits are read once where a
+    maximum and then a sum read them twice."""
+
+    def merge(a, b):
+        (m1, s1), (m2, s2) = a, b
+        m = jnp.maximum(m1, m2)
+        return m, s1 * jnp.exp(m1 - m) + s2 * jnp.exp(m2 - m)
+
+    return jax.lax.reduce(
+        (x, jnp.ones_like(x)),
+        (jnp.asarray(_LOWEST, x.dtype), jnp.asarray(0.0, x.dtype)),
+        merge, (x.ndim - 1,),
+    )
+
+
+def inverse_cdf_index(tl: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
+    """[..., V] masked, tempered logits and [...] uniforms in [0, 1) ->
+    [...] int32: each row's sample of ``softmax(tl)`` by the inverse CDF,
+    the first column whose cumulative probability passes ``u``.
+
+    One draw a row where Gumbel-max (:func:`gumbel_step_noise`) makes V.
+    Two levels, so that nothing of the logits' size is written and no scan
+    runs over V, and two reads of the logits in all. First each block of
+    consecutive columns (:func:`_cdf_block`) gives its maximum and its sum
+    of exponentials (:func:`_max_and_sumexp`); scaled to the row's maximum
+    these are the block sums of ``e = exp(tl - max)``, whose cumulative sum
+    gives ``target = u * total`` and the block it falls in. Then that one
+    block of *logits* a row is exponentiated again and summed cumulatively
+    from the block's base. Both reads take the logits as ``[..., blocks,
+    width]``, a view of them as the chip lays them out at the RL decode's
+    batch (rows on the minor-most axis, V along the tiles), and do their
+    arithmetic there, so each is one fused reduction; the row's block is
+    picked out by a masked sum over the blocks and not by a gather, which
+    along V would first copy all the logits into another layout.
+
+    Guarantees: the column returned has ``e > 0``, so a column masked to
+    ``-1e9`` (``forbid_special``, ``apply_min_len``) is never emitted; and
+    where rounding between a block's sum and the sum inside it would run
+    past the block's end, the row takes the block's last column with
+    ``e > 0`` (:func:`_first_over`, at both levels). ``u < 1`` keeps
+    ``target`` under the total.
+
+    Fidelity: exact to the resolution of one f32 uniform. The grain is
+    2^-24 of the total, so no token of probability under about 6e-8 is
+    drawn, which Gumbel-max from f32 uniforms does not do either (its noise
+    is capped near 16.6 nats). No precision is lowered and no row skipped.
+    """
+    V = tl.shape[-1]
+    w = _cdf_block(V)
+    blocks = -(-V // w)
+    if blocks * w != V:
+        tl = jnp.pad(tl, [(0, 0)] * (tl.ndim - 1) + [(0, blocks * w - V)],
+                     constant_values=_LOWEST)
+    x = tl.reshape(tl.shape[:-1] + (blocks, w))
+    block_max, block_sum = _max_and_sumexp(x)
+    m = jnp.max(block_max, axis=-1, keepdims=True)
+    sums = block_sum * jnp.exp(block_max - m)
+    cum = jnp.cumsum(sums, axis=-1)
+    target = u * cum[..., -1]
+    block = _first_over(sums, cum, target)
+    at = jnp.arange(blocks, dtype=jnp.int32) - block[..., None]
+    base = jnp.sum(jnp.where(at == -1, cum, 0.0), axis=-1)
+    e_in = jnp.exp(
+        jnp.sum(jnp.where((at == 0)[..., None], x, 0.0), axis=-2) - m
+    )
+    inside = _first_over(e_in, base[..., None] + jnp.cumsum(e_in, axis=-1),
+                         target)
+    return block * w + inside
+
+
+def sample_lanes(step_keys_t: jax.Array, tl: jnp.ndarray) -> jnp.ndarray:
+    """[K] keys and [K, B, V] masked, tempered logits -> [K, B] int32
+    tokens: rollout ``k`` draws ONE uniform a row from its key and takes the
+    row's token by :func:`inverse_cdf_index`, so a row's stream depends on
+    its key and its place among the rows it was drawn with, never on their
+    logits."""
+    u = jax.vmap(
+        lambda k: jax.random.uniform(k, tl.shape[1:-1], tl.dtype)
+    )(step_keys_t)
+    return inverse_cdf_index(tl, u)
+
+
 def lane_decode_step(model, params, carry, token, enc):
     """One decoder step over a LANE-batched state: [G, B, ...] -> [G, B, V].
 

@@ -10,12 +10,19 @@ dispatched*, not FLOPs.
 
 Here the greedy baseline is folded in as lane 0 of a single (1+K)-lane
 scan: lane 0 takes the argmax of its untempered logits, lanes 1..K sample
-``categorical(fold_in(fold_in(rng, k), t), logits/temperature)`` — exactly
-``sample_decode``'s key stream, spelled in its bit-identical Gumbel-max
-form (``gumbel_step_noise``) so the same streams drive every path below.
-One encoder pass feeds all lanes; the loop exits once EVERY lane of every
-clip has emitted EOS. Pinned bit-exact against the two-loop reference in
-tests/test_decoding.py and tests/test_rl.py.
+``categorical(fold_in(fold_in(rng, k), t), logits/temperature)`` in its
+bit-identical Gumbel-max form (``gumbel_step_noise``), so the same streams
+drive every path below: the noise is data here (gathered through the
+compaction permutation, argmaxed inside the stride kernel). The keys are
+``sample_decode``'s (``rollout_step_keys``), the draws are not: since PR 39
+``sample_decode`` draws one uniform a lane and takes the token by the
+inverse CDF (``common.sample_lanes``), which the RL decode without a greedy
+lane runs, so the two families sample the same distribution with different
+tokens under one key. One encoder pass feeds all lanes; the loop exits once
+EVERY lane of every clip has emitted EOS. Pinned bit-exact in
+tests/test_decoding.py and tests/test_rl.py: the greedy lane against
+``greedy_decode``, the sampled lanes against the Gumbel-max loop
+``sample_decode`` was (tests/_gumbel_sample.py).
 
 On top of the one-loop structure sit the two decode-endgame levers
 (``ModelConfig.decode_stride`` / ``decode_compact``):

@@ -7,11 +7,18 @@ once, closed over by the rollout-vmapped decode step); all K×B sequences
 decode in ONE XLA program — the fused "one launch" design of §7 step 5 —
 whose loop exits as soon as every rollout of every clip has emitted EOS.
 
-RNG discipline: rollout k at step t uses ``fold_in(fold_in(key, k), t)``,
-drawn per-rollout over its [B, V] logits block — reproducible regardless of
-batch sharding or rollout count. The whole [T, K] key array is precomputed
+RNG discipline: rollout k at step t uses ``fold_in(fold_in(key, k), t)``
+for ONE uniform a row, [B] of them, and each row takes its token by the
+inverse CDF of ``softmax(masked logits / temperature)``
+(``common.sample_lanes``): K x B draws a step where Gumbel-max over the
+vocabulary made K x B x V (the gauge ``rl.decode.sampler_draws``, set when
+the loop is traced, says how many) — reproducible regardless of batch
+sharding or rollout count. The whole [T, K] key array is precomputed
 outside the scan (``rollout_step_keys``); the step body gathers row ``t``
 instead of re-folding K keys per iteration — the same stream bit-for-bit.
+The ``fused_decode`` family (decoding/fused.py) folds the same keys but
+draws Gumbel noise from them, which it needs as data: the two draw from
+the same distribution, not the same tokens.
 """
 
 from __future__ import annotations
@@ -19,13 +26,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from cst_captioning_tpu import obs
 from cst_captioning_tpu.config.config import BOS_ID, PAD_ID
 from cst_captioning_tpu.decoding.common import (
     apply_min_len,
     forbid_special,
-    gumbel_step_noise,
     lane_decode_step,
     rollout_step_keys,
+    sample_lanes,
     scan_until_finished,
     selected_logprob,
     step_outputs,
@@ -61,17 +69,13 @@ def sample_decode(
     # flat [K*B]-row layout with tiled memory was measured 80% slower at the
     # flagship dims, round 5 — the tile defeats that fusion.)
     step_keys = rollout_step_keys(rng, K, T)  # [T, K]
+    obs.gauge("rl.decode.sampler_draws").set(float(K * B))
 
     def step(state, t):
         carry, token, finished = state  # carry leaves [K, B, ...]; [K, B]
         carry, logits = lane_decode_step(model, params, carry, token, enc)
         logits = apply_min_len(forbid_special(logits), t, min_len)  # [K,B,V]
-        # Gumbel-max form of ``categorical(key, logits / temperature)`` —
-        # bit-identical (gumbel_step_noise docstring), and the same selection
-        # the fused stride paths run, so every sampler shares one spelling
-        tl = logits / temperature
-        noise = gumbel_step_noise(step_keys[t], tl.shape[1:], tl.dtype)
-        nxt = jnp.argmax(tl + noise, axis=-1).astype(jnp.int32)
+        nxt = sample_lanes(step_keys[t], logits / temperature)
         lp = selected_logprob(logits, nxt)
         nxt, lp, finished = step_outputs(nxt, lp, finished)
         return (carry, nxt, finished), (nxt, lp)

@@ -34,7 +34,10 @@ sections (PR 4):
   (scan steps the EOS early-exit loop actually ran per batch, observed
   host-side from the decoded tokens) against the ``rl.decode.budget``
   gauge (the T step budget) — what ``scan_until_finished`` saves per
-  epoch; beside it the ``rl.update.row_blocks`` / ``rl.update.block_rows``
+  epoch, and the ``rl.decode.sampler_draws`` gauge (decoding/sample.py
+  sets it when ``sample_decode`` is traced): random draws a decode step
+  and device, which says which sampler the run had; beside it the
+  ``rl.update.row_blocks`` / ``rl.update.block_rows``
   gauges: how the RL update was cut into row blocks when it was traced,
   and the ``rl.update.positions.run`` / ``rl.update.positions`` counters:
   the share of its teacher-forcing scan's positions that held a token of
@@ -274,6 +277,9 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
                 skipped / (stepped + skipped) if stepped + skipped > 0
                 else 0.0
             ),
+            # random draws a step and device of sample_decode's loop, set
+            # when it was traced (0.0: the run traced none)
+            "sampler_draws": float(gauges.get("rl.decode.sampler_draws", 0.0)),
         }
 
     # how the RL update was cut into row blocks when it was traced
@@ -624,6 +630,11 @@ def render_report(report: dict[str, Any]) -> str:
                 f"computed, {int(d['lanes_skipped'])} skipped "
                 f"({100.0 * d['compaction_saved_frac']:.1f}% of lane-steps "
                 "compacted away)"
+            )
+        if d["sampler_draws"]:
+            lines.append(
+                f"decode sampler: {int(d['sampler_draws'])} random draw(s) a "
+                "step and device (one a lane)"
             )
     u = report.get("update")
     if u:

@@ -15,12 +15,19 @@ from cst_captioning_tpu.decoding import (
     sample_decode,
 )
 from cst_captioning_tpu.decoding.common import (
+    _cdf_block,
+    _first_over,
+    apply_min_len,
     forbid_special,
+    inverse_cdf_index,
     rollout_step_keys,
+    sample_lanes,
     selected_logprob,
 )
 from cst_captioning_tpu.models import CaptionModel
 from cst_captioning_tpu.models.captioner import CaptionModel as CM
+
+from _gumbel_sample import gumbel_sample_decode
 
 B, F, T, V = 4, 5, 6, 11
 
@@ -225,7 +232,8 @@ def test_rollout_step_keys_is_the_fold_in_chain():
 
 def test_sample_matches_manual_per_step_folding(setup):
     """sample_decode (precomputed key array) decodes bit-identical tokens to
-    a manual loop that re-folds the K keys inside every step body."""
+    a manual loop that re-folds the K keys inside every step body and takes
+    each lane's token by the inverse CDF of its one uniform."""
     model, params, feats, masks = setup
     K = 3
     rng = jax.random.key(5)
@@ -243,9 +251,10 @@ def test_sample_matches_manual_per_step_folding(setup):
         )(carry, tok)
         logits = forbid_special(logits)
         step_keys = jax.vmap(lambda k_: jax.random.fold_in(k_, t))(keys)
-        nxt = np.asarray(jax.vmap(
-            lambda k_, l_: jax.random.categorical(k_, l_, axis=-1)
-        )(step_keys, logits)).astype(np.int32)
+        u = jax.vmap(
+            lambda k_: jax.random.uniform(k_, (B,), logits.dtype)
+        )(step_keys)
+        nxt = np.array(inverse_cdf_index(logits, u), np.int32)
         nxt[finished] = PAD_ID
         finished |= nxt == EOS_ID
         manual.append(nxt)
@@ -253,15 +262,147 @@ def test_sample_matches_manual_per_step_folding(setup):
     np.testing.assert_array_equal(np.asarray(tokens), np.stack(manual, -1))
 
 
+# ---- the inverse-CDF selection (common.inverse_cdf_index) --------------------
+
+def _skewed_logits(V, rows=1, seed=0):
+    """[rows, V] logits with a few heavy columns and a long thin tail."""
+    r = np.random.default_rng(seed)
+    return jnp.asarray(
+        r.normal(size=(rows, V)) * 2.0 + np.linspace(3.0, -3.0, V), jnp.float32
+    )
+
+
+def _cdf_reference(tl, u):
+    """The same selection in float64 on the host: first column whose
+    cumulative probability passes u."""
+    p = np.exp(np.asarray(tl, np.float64))
+    cdf = np.cumsum(p, -1)
+    target = np.asarray(u, np.float64) * cdf[..., -1]
+    return (cdf <= target[..., None]).sum(-1)
+
+
+@pytest.mark.parametrize("temperature", [1.0, 0.7])
+def test_inverse_cdf_frequencies_match_softmax(temperature):
+    """Some 2e5 draws through ``sample_lanes`` (K keys, one uniform a row)
+    land on each column as often as softmax(masked logits / temperature)
+    says: chi-square over the columns that expect at least 5 draws, and
+    never a masked column. V = 200 is 5 blocks of 40."""
+    V, K, rows = 200, 4, 50_000
+    assert _cdf_block(V) == 40
+    logits = apply_min_len(forbid_special(_skewed_logits(V)), 0, 1)
+    tl = jnp.broadcast_to(logits / temperature, (K, rows, V))
+    keys = jax.random.split(jax.random.key(7), K)
+    got = np.asarray(jax.jit(sample_lanes)(keys, tl)).ravel()
+    counts = np.bincount(got, minlength=V)
+    assert counts[[PAD_ID, BOS_ID, EOS_ID]].sum() == 0
+    want = np.asarray(jax.nn.softmax(logits[0] / temperature), np.float64) * got.size
+    seen = want >= 5.0
+    chi2 = ((counts[seen] - want[seen]) ** 2 / want[seen]).sum()
+    df = int(seen.sum()) - 1
+    assert chi2 < df + 5.0 * np.sqrt(2.0 * df), (chi2, df)
+    assert counts[~seen].sum() <= 3.0 * want[~seen].sum() + 10
+
+
+@pytest.mark.parametrize("V", [200, 300, 11])
+def test_inverse_cdf_never_emits_a_masked_column(V):
+    """Over a grid of u that holds 0.0 and the largest float under 1.0, with
+    PAD/BOS forbidden and ``min_len`` holding EOS back, no row of any skew
+    ever takes a masked column or one past V (300 = 2 x 128 + 44: the last
+    block is padded; 11 is one block)."""
+    u = jnp.asarray(np.concatenate([
+        [0.0, np.nextafter(np.float32(1.0), np.float32(0.0))],
+        np.linspace(0.0, 1.0, 255, endpoint=False),
+    ]), jnp.float32)
+    logits = _skewed_logits(V, rows=6, seed=V) * jnp.asarray(
+        [[0.1], [1.0], [1.0], [5.0], [20.0], [60.0]], jnp.float32)
+    tl = apply_min_len(forbid_special(logits), 0, 3)
+    got = np.asarray(inverse_cdf_index(
+        jnp.broadcast_to(tl[:, None], (6, u.size, V)),
+        jnp.broadcast_to(u, (6, u.size)),
+    ))
+    assert got.min() >= 0 and got.max() < V
+    assert not np.isin(got, [PAD_ID, BOS_ID, EOS_ID]).any()
+    # the column taken has mass in f32, and u = 0 takes the first that has
+    tl64 = np.asarray(tl, np.float64)
+    e = np.exp(tl64 - tl64.max(-1, keepdims=True))
+    assert (np.take_along_axis(e.astype(np.float32), got, -1) > 0).all()
+    for row, first in zip(e, got[:, 0]):
+        assert (row[:first] < 1e-37).all()
+
+
+def test_inverse_cdf_clamps_to_the_last_column_with_mass():
+    """Where no cumulative sum passes the target (rounding between a block's
+    sum and the sum inside it), the row takes the last term WITH mass, never
+    a zero term behind it and never one past the end; end to end, a row
+    whose mass sits in the first column of the last block, or in the last
+    column of a V that does not fill its last block, lands there for every u."""
+    mass = jnp.asarray([[0.5, 0.5, 0.0, 0.0], [0.0, 0.25, 0.0, 0.75]], jnp.float32)
+    cum = jnp.cumsum(mass, -1)
+    # a prefix sum that lost monotonicity on a zero term must not win it
+    cum = cum.at[0, 2].set(1.5)
+    np.testing.assert_array_equal(
+        np.asarray(_first_over(mass, cum, jnp.asarray([1.0, 1.0]))), [1, 3])
+    np.testing.assert_array_equal(
+        np.asarray(_first_over(mass, cum, jnp.asarray([0.0, 0.1]))), [0, 1])
+
+    V = 300
+    assert _cdf_block(V) == 128
+    u = jnp.asarray([0.0, 0.3, 0.999, np.nextafter(np.float32(1), np.float32(0))])
+    for col in (256, V - 1):
+        tl = jnp.full((V,), -1.0e9, jnp.float32).at[col].set(2.0)
+        got = inverse_cdf_index(jnp.broadcast_to(tl, (u.size, V)), u)
+        np.testing.assert_array_equal(np.asarray(got), [col] * u.size)
+
+
+def test_inverse_cdf_hand_checked_on_both_sides_of_a_block_edge():
+    """Uniform logits over V = 256 (two blocks of 128, every sum exact in
+    f32): u = 1/2 is the first column of the second block, the float under
+    it the last of the first; and a skewed row agrees with the float64 CDF
+    wherever u is not within rounding of a column's edge."""
+    V = 256
+    assert _cdf_block(V) == 128
+    half = np.float32(0.5)
+    u = jnp.asarray([0.0, 127.5 / 256, np.nextafter(half, np.float32(0)),
+                     half, 128.5 / 256, 255.5 / 256], jnp.float32)
+    got = inverse_cdf_index(jnp.zeros((u.size, V), jnp.float32), u)
+    np.testing.assert_array_equal(np.asarray(got), [0, 127, 127, 128, 128, 255])
+
+    V = 200
+    tl = jax.nn.log_softmax(_skewed_logits(V, rows=1, seed=3)[0])
+    u = jnp.asarray(np.random.default_rng(4).uniform(size=4096), jnp.float32)
+    want = _cdf_reference(jnp.broadcast_to(tl, (u.size, V)), u)
+    got = np.asarray(inverse_cdf_index(jnp.broadcast_to(tl, (u.size, V)), u))
+    cdf = np.cumsum(np.exp(np.asarray(tl, np.float64)))
+    near_edge = np.abs(cdf[None, :] - np.asarray(u, np.float64)[:, None]).min(-1) < 1e-5
+    np.testing.assert_array_equal(got[~near_edge], want[~near_edge])
+    assert (np.abs(got - want) <= 1).all()
+
+
+def test_inverse_cdf_is_rowwise_and_jit_stable():
+    """Bit-identical under jit, and a batch equals its two halves selected
+    apart: a row's token depends on its own logits and uniform alone."""
+    V = 300
+    tl = forbid_special(_skewed_logits(V, rows=3 * 8, seed=5).reshape(3, 8, V))
+    u = jax.random.uniform(jax.random.key(1), (3, 8))
+    whole = np.asarray(inverse_cdf_index(tl, u))
+    np.testing.assert_array_equal(np.asarray(jax.jit(inverse_cdf_index)(tl, u)), whole)
+    halves = np.concatenate([
+        np.asarray(inverse_cdf_index(tl[:, :4], u[:, :4])),
+        np.asarray(inverse_cdf_index(tl[:, 4:], u[:, 4:])),
+    ], axis=1)
+    np.testing.assert_array_equal(halves, whole)
+
+
 def test_fused_decode_matches_two_loop_bitexact(setup):
     """The fused one-loop decode is BIT-EXACT against the two-loop reference
     under a fixed rng: greedy tokens/logprobs (lane 0 vs greedy_decode) and
-    sampled tokens/logprobs (lanes 1..K vs sample_decode)."""
+    sampled tokens/logprobs (lanes 1..K vs the Gumbel-max loop the family
+    shares its stream with, tests/_gumbel_sample.py)."""
     model, params, feats, masks = setup
     K = 3
     rng = jax.random.key(42)
     tg, lg = greedy_decode(model, params, feats, masks)
-    ts, ls = sample_decode(model, params, feats, masks, rng, num_rollouts=K)
+    ts, ls = gumbel_sample_decode(model, params, feats, masks, rng, num_rollouts=K)
     fg, flg, fs, fls = fused_decode(
         model, params, feats, masks, rng, num_rollouts=K
     )
@@ -282,7 +423,7 @@ def test_fused_decode_temperature_and_padding(setup):
     and every lane honors PAD-after-EOS / zero-logprob padding."""
     model, params, feats, masks = setup
     rng = jax.random.key(3)
-    ts, _ = sample_decode(
+    ts, _ = gumbel_sample_decode(
         model, params, feats, masks, rng, num_rollouts=2, temperature=0.5
     )
     fg, flg, fs, fls = fused_decode(

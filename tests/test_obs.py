@@ -653,6 +653,50 @@ def test_report_update_row_blocks():
     assert lines[at + 1].startswith("update row blocks: 4 block(s)")
 
 
+def test_report_decode_sampler_draws():
+    """The ``rl.decode.sampler_draws`` gauge (decoding/sample.py sets it
+    when ``sample_decode`` is traced: K x B, one uniform a lane) reaches the
+    report's decode section and its text; a run that traced no
+    ``sample_decode`` shows no such line."""
+    import jax
+    import jax.numpy as jnp
+
+    from cst_captioning_tpu.config.config import ModelConfig
+    from cst_captioning_tpu.decoding import sample_decode
+    from cst_captioning_tpu.models import CaptionModel
+
+    depth = {"rl.decode.depth": {"buckets": [10.0, 20.0, 30.0],
+                                 "counts": [0, 1, 0, 0],
+                                 "sum": 15.0, "count": 1, "max": 15.0}}
+    events = lambda gauges: [  # noqa: E731
+        {"ts": 0.0, "event": "run_start", "run": "draws", "thread": "main"},
+        {"ts": 1.0, "event": "metrics", "counters": {}, "gauges": gauges,
+         "histograms": depth},
+        {"ts": 2.0, "event": "run_end", "run": "draws"},
+    ]
+    rep = build_report(events({"rl.decode.budget": 30.0,
+                               "rl.decode.sampler_draws": 8960.0}))
+    assert rep["decode"]["sampler_draws"] == 8960.0
+    assert "decode sampler: 8960 random draw(s) a step" in render_report(rep)
+    rep = build_report(events({"rl.decode.budget": 30.0}))
+    assert rep["decode"]["sampler_draws"] == 0.0
+    assert "decode sampler" not in render_report(rep)
+
+    # the program's side: tracing sample_decode sets K x B
+    cfg = ModelConfig(vocab_size=11, modalities=(("resnet", 8),), d_embed=8,
+                      d_hidden=8, d_att=4, max_len=4, max_frames=3,
+                      dtype="float32")
+    model = CaptionModel(cfg)
+    feats = {"resnet": jnp.zeros((6, 3, 8), jnp.float32)}
+    masks = {"resnet": jnp.ones((6, 3), jnp.float32)}
+    params = model.init(jax.random.key(0), feats, masks,
+                        jnp.zeros((6, 4), jnp.int32))
+    obs.gauge("rl.decode.sampler_draws").set(0.0)
+    jax.eval_shape(lambda p: sample_decode(
+        model, p, feats, masks, jax.random.key(1), num_rollouts=5), params)
+    assert obs.gauge("rl.decode.sampler_draws").value == 30.0
+
+
 def test_scst_records_compaction_counters(tmp_path):
     """With a recorder installed, an SCST step feeds the depth histogram
     AND the compaction counter pair from the decoded tokens (the default
@@ -1285,10 +1329,11 @@ def test_rl_epoch_spans_name_the_turnover_and_the_reward_parts(
     for name in parts:
         assert count[name] == steps
         assert {s["parent"] for s in sp if s["name"] == name} == {"rl.reward"}
-    # events land in end order: a reward's parts are the three before it
+    # events land in end order: a reward's parts are the three before it on
+    # its own thread (a stage of the prefetch worker may end among them)
     for i, s in enumerate(sp):
         if s["name"] == "rl.reward":
-            mine = sp[i - 3:i]
+            mine = [q for q in sp[:i] if q["thread"] == s["thread"]][-3:]
             assert tuple(p["name"] for p in mine) == parts
             assert sum(p["dur"] for p in mine) <= s["dur"] + 3e-6
             assert s["self_dur"] == pytest.approx(

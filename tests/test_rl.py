@@ -654,7 +654,12 @@ def test_parallel_rl_decode_greedy_matches_single(model_setup):
 
 def test_rl_decode_fused_matches_two_loop(model_setup):
     """make_rl_decode's fused one-loop default is bit-exact vs the two-loop
-    reference (the PR-4 acceptance pin): greedy AND samples, fixed rng."""
+    reference (the PR-4 acceptance pin) in its greedy lane, and in its
+    sampled lanes vs the Gumbel-max loop whose key stream the fused family
+    keeps (tests/_gumbel_sample.py); the two-loop side's samples are
+    ``sample_decode``'s own, one uniform a lane. Fixed rng."""
+    from _gumbel_sample import gumbel_sample_decode
+    from cst_captioning_tpu.decoding import sample_decode
     from cst_captioning_tpu.rl import make_rl_decode
 
     model, state, feats, masks = model_setup
@@ -667,12 +672,25 @@ def test_rl_decode_fused_matches_two_loop(model_setup):
         state.params, feats, masks, rng
     )
     np.testing.assert_array_equal(np.asarray(g_one), np.asarray(g_two))
-    np.testing.assert_array_equal(np.asarray(s_one), np.asarray(s_two))
+    s_gumbel, _ = gumbel_sample_decode(
+        model, state.params, feats, masks, rng, num_rollouts=K, max_len=T
+    )
+    np.testing.assert_array_equal(np.asarray(s_one), np.asarray(s_gumbel))
+    s_plain, _ = sample_decode(
+        model, state.params, feats, masks, rng, num_rollouts=K, max_len=T
+    )
+    np.testing.assert_array_equal(np.asarray(s_two), np.asarray(s_plain))
 
 
 def test_parallel_rl_decode_fused_matches_two_loop(model_setup):
     """The sharded (batch_axes) fused decode is bit-exact vs the sharded
-    two-loop reference — same mesh, same rng, same shard-folded streams."""
+    two-loop reference in its greedy lane. A decode has no cross-example
+    interaction, so shard ``i``'s samples are those of its own rows under
+    ``fold_in(rng, i)``: the fused side's by the Gumbel-max loop
+    (tests/_gumbel_sample.py), the two-loop side's by ``sample_decode``,
+    which pins the ``axis_index`` fold for both families."""
+    from _gumbel_sample import gumbel_sample_decode
+    from cst_captioning_tpu.decoding import sample_decode
     from cst_captioning_tpu.rl import make_parallel_rl_decode
 
     model, state, feats, masks = model_setup
@@ -690,7 +708,23 @@ def test_parallel_rl_decode_fused_matches_two_loop(model_setup):
         state_r.params, f_s, m_s, rng
     )
     np.testing.assert_array_equal(np.asarray(g_one), np.asarray(g_two))
-    np.testing.assert_array_equal(np.asarray(s_one), np.asarray(s_two))
+    shards = mesh.devices.size
+    rows = next(iter(feats.values())).shape[0] // shards
+    for i in range(shards):
+        own = slice(i * rows, (i + 1) * rows)
+        f_i = {n: v[own] for n, v in feats.items()}
+        m_i = {n: v[own] for n, v in masks.items()}
+        rng_i = jax.random.fold_in(rng, i)
+        s_gumbel, _ = gumbel_sample_decode(
+            model, state.params, f_i, m_i, rng_i, num_rollouts=K, max_len=T
+        )
+        np.testing.assert_array_equal(
+            np.asarray(s_one)[:, own], np.asarray(s_gumbel))
+        s_plain, _ = sample_decode(
+            model, state.params, f_i, m_i, rng_i, num_rollouts=K, max_len=T
+        )
+        np.testing.assert_array_equal(
+            np.asarray(s_two)[:, own], np.asarray(s_plain))
 
 
 def test_train_epoch_pipelined_matches_sequential_at_lr0(model_setup):
