@@ -19,7 +19,11 @@ max(decode, tokenize) + corpus instead of their sum (the Podracer
 actor/learner decoupling, arXiv 2104.06272, in miniature). The overlap
 ledger (eval.decode_seconds / eval.score_seconds histograms,
 eval.overlap_* gauges, fill/drain spans) feeds cli.obs_report's eval
-section. Decoding itself picks beam-on-lanes (``EvalConfig.beam_impl``) or
+section. Both paths decode through one loop (``_decoded``), timed from
+inside: a batch's ``data.collate``, ``eval.h2d``, ``eval.launch`` and
+``eval.collect`` spans share ``(eval_pass, eval_batch)``, and the counter
+``eval.starved_seconds`` holds the seconds the loop left the device with
+nothing queued. Decoding itself picks beam-on-lanes (``EvalConfig.beam_impl``) or
 the NPAD anytime mode (``EvalConfig.npad_lanes``, arXiv 1605.03835).
 """
 
@@ -131,6 +135,12 @@ class Evaluator:
         # the host parameters last handed over and their placed copy
         self._placed: tuple | None = None
         self._observed = False      # the decode-state gauges are set
+        # under obs only (``_launched`` / ``_collected``): passes begun,
+        # decodes launched and not yet collected, and when (perf_counter) a
+        # collect last left none
+        self._passes = 0
+        self._in_flight = 0
+        self._drained: float | None = None
 
         dec_model = model
         if self.sp and not model.cfg.seq_axis:
@@ -303,38 +313,130 @@ class Evaluator:
             rows.observe(float(n))
 
     def _dispatch(self, params, batch, bi: int):
-        """Collate-upload batch ``bi`` and launch its decode (async)."""
+        """Upload batch ``bi`` and launch its decode (async): the enqueue of
+        the placement under ``eval.h2d``, the launch under ``eval.launch``."""
         params = self._on_device(params)
-        if self._fm_shardings is not None:
-            # numpy straight into the target sharding (single transfer)
-            put = (
-                multihost.put_global if self.multiproc
-                else multihost.put_full_global
-            )
-            feats, masks = put(
-                self._fm_shardings, (batch.feats, batch.feat_masks)
-            )
-        else:
-            feats, masks, *_ = batch_arrays(batch)
+        watched = obs.enabled()
+        nbytes = sum(
+            a.nbytes for part in (batch.feats, batch.feat_masks)
+            for a in part.values()
+        ) if watched else 0
+        with obs.span("eval.h2d", bytes=nbytes):
+            if self._fm_shardings is not None:
+                # numpy straight into the target sharding (single transfer)
+                put = (
+                    multihost.put_global if self.multiproc
+                    else multihost.put_full_global
+                )
+                feats, masks = put(
+                    self._fm_shardings, (batch.feats, batch.feat_masks)
+                )
+            else:
+                feats, masks, *_ = batch_arrays(batch)
+        if watched:
+            obs.counter("eval.h2d.bytes").inc(nbytes)
         self._observe_decode(params, feats, masks)
-        tokens = self._decode(
-            params, feats, masks, jax.random.fold_in(self._decode_key, bi)
-        )
-        if tokens.is_fully_addressable:
-            # start the device->host transfer now so it overlaps the next
-            # decode; by readback time the tokens are already on host
-            tokens.copy_to_host_async()
+        with obs.span("eval.launch"):
+            if watched:
+                self._launched()
+            tokens = self._decode(
+                params, feats, masks, jax.random.fold_in(self._decode_key, bi)
+            )
+            if tokens.is_fully_addressable:
+                # start the device->host transfer now so it overlaps the next
+                # decode; by readback time the tokens are already on host
+                tokens.copy_to_host_async()
         return tokens
 
-    def generate(self, params) -> dict[str, str]:
-        """Decode every video of the split -> {video_id: caption string}.
+    def _launched(self) -> None:
+        """Under obs, at a launch's start: the seconds since a collect left
+        no decode launched and uncollected go to ``eval.starved_seconds``,
+        and the stamp goes. It lives on the ``Evaluator``, so a pass's whole
+        turnover (drain, scoring, the snapshot, whatever the caller does
+        between two ``evaluate()`` calls (under a ``Trainer``, the training
+        between two validations), the next pass's first collate and upload)
+        is counted: up to the pass's end before its snapshot (``evaluate``),
+        the rest here."""
+        starved = obs.counter("eval.starved_seconds")   # at 0 from launch one
+        if self._drained is not None:
+            starved.inc(time.perf_counter() - self._drained)
+            self._drained = None
+        self._in_flight += 1
+
+    def _collected(self) -> None:
+        """Under obs, at a collect's end: stamp the time if it left nothing
+        launched and uncollected."""
+        self._in_flight -= 1
+        if self._in_flight == 0:
+            self._drained = time.perf_counter()
+
+    def _stage(self, params, batches, n: int, bi: int):
+        """Pull batch ``bi`` of pass ``n`` and launch its decode ->
+        (tokens, batch, bi), or None at the split's end. The pass and the
+        batch ride on every span from here to the next pull (fields of their
+        own: a caller's ``epoch`` / ``step`` stay as they are)."""
+        if obs.enabled():
+            obs.set_context(eval_pass=n, eval_batch=bi)
+        batch = next(batches, None)
+        if batch is None:
+            return None
+        return self._dispatch(params, batch, bi), batch, bi
+
+    def _collect(self, tokens, batch, bi: int):
+        """Wait for batch ``bi``'s decode and read it back -> ([(video id,
+        its token row)] of the batch's valid rows, the seconds the wait and
+        the read-back took). It runs one batch behind the dispatch, under
+        its own batch's ``eval_batch``. The batch itself is not handed on:
+        its arrays go when the loop lets go of it, under the next decode."""
+        if obs.enabled():
+            obs.set_context(eval_batch=bi)
+        with obs.span("eval.collect"):
+            t0 = time.perf_counter()
+            if self.multiproc:
+                # this host's decoded rows only — batch.video_ids/valid are
+                # already the matching local slice
+                tok = multihost.to_host_local(tokens, self.mesh, P("data"))
+            else:
+                tok = jax.device_get(tokens)
+            self._count()
+            if obs.enabled():
+                self._collected()
+            dt = time.perf_counter() - t0
+        return [
+            (batch.video_ids[i], tok[i])
+            for i, ok in enumerate(batch.valid) if ok
+        ], dt
+
+    def _decoded(self, params):
+        """Every batch of the split, decoded: yields ``_collect``'s pairs in
+        batch order.
 
         One-deep software pipeline (the SCST epoch pattern, rl/scst.py):
         batch *i+1*'s collate + feature upload + decode dispatch all happen
-        BEFORE batch *i*'s tokens are read back and converted to words, so
-        the host half (h5 collate, device->host transfer, id->word decode)
-        overlaps the device decode instead of serializing after it. The
-        decoded captions are identical — only the dispatch order changes.
+        BEFORE batch *i*'s tokens are read back, so the host half (h5
+        collate, device->host transfer, whatever the consumer does with the
+        tokens) overlaps the device decode instead of serializing after it.
+        The decoded captions are identical — only the dispatch order
+        changes. All on the calling thread; the fill (batch 0's collate +
+        upload + launch, before any overlap can exist) has its span."""
+        n = self._passes
+        if obs.enabled():
+            self._passes += 1
+        batches = iter(self.batcher.epoch(shuffle=False))
+        try:
+            with obs.span("eval.pipeline.fill"):
+                pending = self._stage(params, batches, n, 0)
+            while pending is not None:
+                ahead = self._stage(params, batches, n, pending[2] + 1)
+                yield self._collect(*pending)
+                pending = ahead
+        finally:
+            if obs.enabled():
+                obs.set_context(eval_pass=None, eval_batch=None)
+
+    def generate(self, params) -> dict[str, str]:
+        """Decode every video of the split -> {video_id: caption string},
+        through the one-deep pipeline of ``_decoded``.
 
         Multi-host: each process collates only its contiguous slice of every
         global batch (the Batcher ``host_shard`` path the Trainer uses),
@@ -343,27 +445,9 @@ class Evaluator:
         and collates divide by process count while every process still
         returns the full dict (train/multihost.py)."""
         out: dict[str, str] = {}
-
-        def collect(tokens, batch):
-            if self.multiproc:
-                # this host's decoded rows only — batch.video_ids/valid are
-                # already the matching local slice
-                tok = multihost.to_host_local(tokens, self.mesh, P("data"))
-            else:
-                tok = jax.device_get(tokens)
-            self._count()
-            for i, ok in enumerate(batch.valid):
-                if ok:
-                    out[batch.video_ids[i]] = self.ds.vocab.decode(tok[i])
-
-        pending = None  # (device tokens, source batch) awaiting readback
-        for bi, batch in enumerate(self.batcher.epoch(shuffle=False)):
-            tokens = self._dispatch(params, batch, bi)
-            if pending is not None:
-                collect(*pending)
-            pending = (tokens, batch)
-        if pending is not None:
-            collect(*pending)
+        for items, _ in self._decoded(params):
+            for vid, row in items:
+                out[vid] = self.ds.vocab.decode(row)
         if self.multiproc:
             merged: dict[str, str] = {}
             for part in multihost.allgather_pyobj(out):
@@ -397,7 +481,7 @@ class Evaluator:
     def _evaluate_pipelined(self, params):
         """Two-stage decode/score pipeline -> (captions, metrics).
 
-        Stage 1 (device): the one-deep decode pipeline of ``generate``.
+        Stage 1 (device): the one-deep decode pipeline of ``_decoded``.
         Stage 2 (host pool): per-batch caption tokenization, plus the
         reference-pool tokenization fanned out BEFORE the first decode (the
         references don't depend on the model). The drain gathers the shards
@@ -413,48 +497,25 @@ class Evaluator:
         sc_hist = obs.histogram("eval.score_seconds")
         res_futs: list = []
         with ThreadPoolExecutor(max_workers=self.cfg.score_workers) as pool:
-            gts_items = [
-                (vid, list(caps)) for vid, caps in self.ds.gts_pool().items()
-            ]
-            shard = max(1, -(-len(gts_items) // self.cfg.score_workers))
-            gts_futs = [
-                pool.submit(self._tok_gts_shard, gts_items[i:i + shard])
-                for i in range(0, len(gts_items), shard)
-            ]
+            # the hand-over is main-thread time with nothing launched yet,
+            # and the workers it starts take the GIL from it: it has a name
+            with obs.span("eval.pipeline.refs"):
+                gts_items = [
+                    (vid, list(caps))
+                    for vid, caps in self.ds.gts_pool().items()
+                ]
+                shard = max(1, -(-len(gts_items) // self.cfg.score_workers))
+                gts_futs = [
+                    pool.submit(self._tok_gts_shard, gts_items[i:i + shard])
+                    for i in range(0, len(gts_items), shard)
+                ]
 
-            def collect(tokens, batch):
-                nonlocal decode_total
-                t0 = time.perf_counter()
-                tok = jax.device_get(tokens)
-                self._count()
-                dt = time.perf_counter() - t0
+            for items, dt in self._decoded(params):
                 decode_total += dt
                 dec_hist.observe(dt)
                 obs.counter("eval.batches").inc()
-                items = [
-                    (batch.video_ids[i], tok[i])
-                    for i, ok in enumerate(batch.valid) if ok
-                ]
                 obs.counter("eval.captions").inc(len(items))
                 res_futs.append(pool.submit(self._tok_res_shard, items))
-
-            # fill: batch 0's collate + upload + decode dispatch — the
-            # pipeline's lead-in, before any decode/score overlap can exist
-            batches = enumerate(self.batcher.epoch(shuffle=False))
-            with obs.span("eval.pipeline.fill"):
-                t_f0 = time.perf_counter()
-                bi, batch = next(batches, (None, None))
-                pending = (
-                    (self._dispatch(params, batch, bi), batch)
-                    if batch is not None else None
-                )
-                fill_s = time.perf_counter() - t_f0
-            for bi, batch in batches:
-                tokens = self._dispatch(params, batch, bi)
-                collect(*pending)
-                pending = (tokens, batch)
-            if pending is not None:
-                collect(*pending)
 
             # drain: decode is done — gather the tokenizer shards (mostly
             # already resolved if the overlap worked) and run the corpus
@@ -479,7 +540,6 @@ class Evaluator:
                 res_t = {vid: [toks] for vid, _, toks in res_items}
                 with obs.span("eval.score"):
                     metrics = self._scorer_pre.score(gts_t, res_t)
-                drain_s = time.perf_counter() - t_d0
 
         # the overlap ledger: scoring seconds that did NOT stall the drain
         # were hidden under device decode. efficiency normalizes by the
@@ -492,8 +552,6 @@ class Evaluator:
         obs.gauge("eval.overlap_efficiency").set(
             min(1.0, overlap_s / hideable) if hideable > 0 else 0.0
         )
-        obs.gauge("eval.pipeline.fill_s").set(fill_s)
-        obs.gauge("eval.pipeline.drain_s").set(drain_s)
         obs.gauge("eval.decode_total_s").set(decode_total)
         obs.gauge("eval.score_total_s").set(score_total)
         obs.gauge("eval.wall_s").set(time.perf_counter() - wall0)
@@ -512,6 +570,12 @@ class Evaluator:
         with obs.span("eval", split=self.ds.split):
             if self.cfg.pipelined and not self.multiproc:
                 captions, metrics = self._evaluate_pipelined(params)
+                if obs.enabled() and self._drained is not None:
+                    # the drain's share, so that the snapshot holds what
+                    # was starved before it
+                    now = time.perf_counter()
+                    obs.counter("eval.starved_seconds").inc(now - self._drained)
+                    self._drained = now
                 obs.snapshot_metrics(split=self.ds.split)
             else:
                 captions = self.generate(params)

@@ -64,7 +64,8 @@ _PHASE_ORDER = (
     "prefetch.wait", "rl.decode", "rl.reward", "rl.reward.readback",
     "rl.reward.observe", "rl.reward.score", "rl.update", "rl.epoch.drain",
     "rl.actor.decode", "rl.actor.broadcast", "rl.learner.step",
-    "eval", "eval.params.place", "eval.pipeline.fill", "eval.pipeline.drain",
+    "eval", "eval.params.place", "eval.pipeline.refs", "eval.pipeline.fill",
+    "eval.h2d", "eval.launch", "eval.collect", "eval.pipeline.drain",
     "eval.score", "serving.admit", "serving.encode",
     "serving.stride", "serving.detok", "obs.snapshot", "ckpt",
     "ckpt.readback", "ckpt.save", "ckpt.restore",
@@ -148,6 +149,7 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
     main_thread: str | None = None
     last_metrics: dict | None = None
     profiler_windows = 0
+    eval_t0 = eval_t1 = None    # the first ``eval`` span's start, the last's end
 
     for ev in events:
         ts = ev.get("ts")
@@ -179,6 +181,10 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             agg["total"] += dur
             agg["self_total"] += float(ev.get("self_dur", dur))
             agg["durs"].append(dur)
+            if name == "eval" and isinstance(ts, (int, float)):
+                # a span's line is written at its end
+                eval_t0 = ts - dur if eval_t0 is None else min(eval_t0, ts - dur)
+                eval_t1 = ts if eval_t1 is None else max(eval_t1, ts)
 
     wall = 0.0
     if t_start is not None and t_end is not None:
@@ -405,8 +411,20 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
 
     # eval overlap ledger (eval/evaluator.py _evaluate_pipelined): per-batch
     # decode-stage and per-shard score-stage histograms plus the stage-total
-    # gauges from the two-stage decode/score pipeline. None when the run
-    # never ran a pipelined eval (serial evaluator, multi-host, or no eval).
+    # gauges from the two-stage decode/score pipeline; fill and drain are the
+    # newest pass's two spans. The starved seconds (the decode loop with
+    # nothing launched on the device: every turnover between passes, and in
+    # it whatever the caller did between two ``evaluate()`` calls, a
+    # ``Trainer``'s training between two validations) stand against the
+    # stretch they lie in, from the first pass's start to the last's end:
+    # never against the passes' own wall, which holds no caller's time. The
+    # upload is the counter ``eval.h2d.bytes`` over the batches. None when
+    # the run never ran a pipelined eval (serial evaluator, multi-host, or
+    # no eval).
+    def durs_of(name: str) -> list[float]:
+        return [d for g in (spans, overlap) if name in g
+                for d in g[name]["durs"]]
+
     eval_sec = None
     edec = histograms.get("eval.decode_seconds")
     esc = histograms.get("eval.score_seconds")
@@ -423,9 +441,18 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             "score_p95_s": _hist_quantile(esc, 0.95) if esc else 0.0,
             "overlap_fraction": gauges.get("eval.overlap_fraction", 0.0),
             "overlap_efficiency": gauges.get("eval.overlap_efficiency", 0.0),
-            "fill_s": gauges.get("eval.pipeline.fill_s", 0.0),
-            "drain_s": gauges.get("eval.pipeline.drain_s", 0.0),
+            "fill_s": (durs_of("eval.pipeline.fill") or [0.0])[-1],
+            "drain_s": (durs_of("eval.pipeline.drain") or [0.0])[-1],
+            "starved_s": counters.get("eval.starved_seconds", 0.0),
+            "passes_span_s": (
+                eval_t1 - eval_t0 if eval_t0 is not None else 0.0
+            ),
+            "upload_bytes": counters.get("eval.h2d.bytes", 0.0),
         }
+        across = eval_sec["passes_span_s"]
+        eval_sec["starved_share"] = (
+            eval_sec["starved_s"] / across if across > 0 else 0.0
+        )
 
     # decoupled actor/learner RL (rl/async_scst.py): throughput counters,
     # host-observed occupancy gauges, and the staleness-in-updates
@@ -774,6 +801,18 @@ def render_report(report: dict[str, Any]) -> str:
             f"hidden under decode (efficiency "
             f"{100.0 * ev['overlap_efficiency']:.1f}% of the hideable "
             f"stage)   fill {ev['fill_s']:.3f}s   drain {ev['drain_s']:.3f}s"
+        )
+        lines.append(
+            f"  starved: {ev['starved_s']:.3f}s with no decode launched, the "
+            f"caller's time between passes included "
+            f"({100.0 * ev['starved_share']:.1f}% of the "
+            f"{ev['passes_span_s']:.3f}s from the first pass's start to the "
+            f"last's end)"
+        )
+        lines.append(
+            f"  upload: {ev['upload_bytes'] / 1e6:.1f} MB of features and "
+            f"masks, {ev['upload_bytes'] / 1e6 / max(ev['batches'], 1):.1f} "
+            f"MB a batch"
         )
     ra = report.get("rl_async")
     if ra:

@@ -1,8 +1,13 @@
 """Eval fast-path tests: the two-stage decode/score pipeline is bit-identical
 to the serial evaluator (metric table AND captions), the overlap ledger is
-recorded, and the NPAD eval mode runs end to end."""
+recorded, the NPAD eval mode runs end to end, and the decode loop is timed
+from inside (a batch's spans share its identity, they cover the pass, and
+``eval.starved_seconds`` is the gaps they show)."""
 
 import json
+import os
+import threading
+import time
 
 import jax
 import numpy as np
@@ -19,12 +24,17 @@ from cst_captioning_tpu.train.steps import batch_arrays
 
 
 @pytest.fixture(scope="module")
-def eval_setup(tmp_path_factory):
+def eval_files(tmp_path_factory):
     out = tmp_path_factory.mktemp("evalpipe")
-    paths = make_synthetic_dataset(
+    return make_synthetic_dataset(
         str(out), num_videos=12, modalities={"resnet": 16}, max_frames=4,
         seed=2,
     )
+
+
+@pytest.fixture(scope="module")
+def eval_setup(eval_files):
+    paths = eval_files
     ds = CaptionDataset(
         paths["info_json"], {"resnet": paths["resnet"]}, "test", 4
     )
@@ -111,8 +121,218 @@ def test_pipelined_records_overlap_ledger(eval_setup, tmp_path):
     names = {p["phase"] for p in rep["phases"]} | {
         p["phase"] for p in rep["overlap"]
     }
-    assert "eval.pipeline.fill" in names
-    assert "eval.pipeline.drain" in names
+    assert {"eval.pipeline.refs", "eval.pipeline.fill",
+            "eval.pipeline.drain"} <= names
+
+
+# ---- the decode loop timed from inside (PR 40) ------------------------------
+
+_BATCH_SPANS = ("data.collate", "eval.h2d", "eval.launch", "eval.collect")
+_CALLER = {"phase": "rl", "epoch": 3, "step": 7}
+_ROWS = 4       # a batch: 9 clips are two whole batches and a padded one
+
+
+@pytest.fixture(scope="module")
+def nine_clips(eval_files):
+    return CaptionDataset(
+        eval_files["info_json"], {"resnet": eval_files["resnet"]}, "train", 4)
+
+
+def _evaluator(eval_setup, ds, pipelined):
+    return Evaluator(
+        eval_setup[0], ds,
+        EvalConfig(beam_size=2, max_len=8, pipelined=pipelined,
+                   score_workers=2),
+        batch_size=_ROWS,
+    )
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["pipelined", "serial"])
+def two_passes(request, eval_setup, nine_clips, tmp_path_factory):
+    """Two ``evaluate()`` calls of one Evaluator under obs, on a thread that
+    carries a caller's context (a Trainer's validator), with a span of the
+    caller's after them -> the stream's events, the wall-clock window of
+    each call, ``eval.starved_seconds`` after each, the results, the
+    registry as ``metrics.prom`` has it, the spans' timeline."""
+    from cst_captioning_tpu.obs.report import load_events
+
+    _model, params, _ds = eval_setup
+    run_dir = str(tmp_path_factory.mktemp("evalspans"))
+    ev = _evaluator(eval_setup, nine_clips, request.param)
+    obs.REGISTRY.reset()
+    obs.configure(run_dir, run="evalspans")
+    obs.set_context(**_CALLER)
+    walls, starved, results = [], [], []
+    try:
+        for _ in range(2):
+            t0 = obs.wall_time()
+            results.append(ev.evaluate(params))
+            walls.append((t0, obs.wall_time()))
+            starved.append(obs.snapshot()["counters"]["eval.starved_seconds"])
+            time.sleep(0.02)    # the caller's bookkeeping between two passes
+        with obs.span("caller.after"):
+            pass
+        prom = obs.REGISTRY.to_prometheus()
+    finally:
+        obs.set_context(**dict.fromkeys(_CALLER))
+        obs.shutdown()
+        obs.REGISTRY.reset()
+    spans = [dict(e, t1=e["ts"], t0=e["ts"] - e["dur"])
+             for e in load_events(run_dir) if e.get("event") == "span"]
+    # the same spans on the clock the counter reads (perf_counter, seconds):
+    # an event's wall-clock stamp is taken when its line is written, later
+    with open(os.path.join(run_dir, "trace.json")) as f:
+        timeline = [(e["name"], e["ts"] / 1e6, (e["ts"] + e["dur"]) / 1e6)
+                    for e in json.load(f)["traceEvents"]]
+    return spans, walls, starved, results, prom, timeline
+
+
+def test_a_batch_s_spans_share_its_identity(two_passes, nine_clips):
+    """Every batch of every pass has exactly one collate, upload, launch and
+    collect, all four under its ``(eval_pass, eval_batch)``; the caller's
+    own context rides on them untouched and comes out as it went in."""
+    spans, *_ = two_passes
+    n_batches = -(-len(nine_clips.records) // _ROWS)
+    assert n_batches == 3 and len(nine_clips.records) % _ROWS
+    got = sorted((s["eval_pass"], s["eval_batch"], s["name"])
+                 for s in spans if s["name"] in _BATCH_SPANS)
+    assert got == sorted((p, b, name) for p in range(2)
+                         for b in range(n_batches) for name in _BATCH_SPANS)
+    ours = [s for s in spans if s["name"] in _BATCH_SPANS]
+    assert all(s[k] == v for s in ours for k, v in _CALLER.items())
+    assert {s["thread"] for s in ours} == {threading.current_thread().name}
+    assert all(s["bytes"] > 0 for s in ours if s["name"] == "eval.h2d")
+    (after,) = [s for s in spans if s["name"] == "caller.after"]
+    assert "eval_pass" not in after and "eval_batch" not in after
+    assert all(after[k] == v for k, v in _CALLER.items())
+    # the pass's end (drain, scoring, snapshot) belongs to no batch
+    assert all("eval_batch" not in s for s in spans
+               if s["name"] in ("eval", "eval.score", "eval.pipeline.drain"))
+
+
+def test_the_driving_thread_s_spans_cover_a_pass(two_passes):
+    """What the loop's thread spent inside ``evaluate()`` has a name: its
+    spans, the umbrella ``eval`` left out, cover 95 % of the calls' wall."""
+    spans, walls, *_ = two_passes
+    me = threading.current_thread().name
+    named = wall = 0.0
+    for w0, w1 in walls:
+        cut = sorted((max(s["t0"], w0), min(s["t1"], w1)) for s in spans
+                     if s["thread"] == me and s["name"] != "eval"
+                     and s["t1"] > w0 and s["t0"] < w1)
+        edge = w0
+        for a, b in cut:        # the union of the clipped intervals
+            named += max(b - max(a, edge), 0.0)
+            edge = max(edge, b)
+        wall += w1 - w0
+    assert named >= 0.95 * wall, (named, wall)
+
+
+def test_starved_seconds_is_the_gap_the_spans_show(two_passes):
+    """``eval.starved_seconds`` = the stretches between a collect that
+    leaves nothing launched and the next launch, read off the spans: none
+    inside a pass (the next batch is launched before a batch is collected),
+    one a turnover, the caller's time between two ``evaluate()`` in it. A
+    pass's end settles the running stretch before its snapshot, so that a
+    snapshot holds what was starved before it (the serial path takes none)."""
+    _spans, _walls, starved, _results, prom, timeline = two_passes
+    marks = sorted(
+        [(t0, +1) for name, t0, _ in timeline if name == "eval.launch"]
+        + [(t1, -1) for name, _, t1 in timeline if name == "eval.collect"])
+    gaps, emptied, in_flight = [], [], 0
+    for t, d in marks:
+        if d > 0 and in_flight == 0 and emptied:
+            gaps.append(t - emptied[-1])
+        in_flight += d
+        if in_flight == 0:
+            emptied.append(t)       # a pass's last collect
+    assert len(gaps) == 1 and gaps[0] > 0.02 and len(emptied) == 2
+    # from a pass's last collect to its snapshot: counted by that snapshot
+    snaps = sorted(t0 for name, t0, _ in timeline if name == "obs.snapshot")
+    tails = [b - a for a, b in zip(emptied, snaps)] or [0.0, 0.0]
+    # the first is a part of the one turnover; the second follows the last pass
+    assert len(tails) == 2 and 0.0 <= tails[0] < gaps[0] and tails[1] >= 0.0
+    assert starved[0] == pytest.approx(tails[0], abs=1e-3)
+    assert starved[1] == pytest.approx(gaps[0] + tails[1], abs=1e-3)
+    assert "eval_starved_seconds " in prom and "eval_h2d_bytes " in prom
+
+
+def test_obs_off_nothing_is_stamped_or_counted(two_passes, eval_setup,
+                                               nine_clips):
+    """Without a recorder the loop keeps no stamp and no count, the counter
+    does not come to be, and captions and table are the observed run's."""
+    _spans, _walls, _starved, results, *_ = two_passes
+    _model, params, _ds = eval_setup
+    pipelined = "eval.pipeline.drain" in {s["name"] for s in _spans}
+    ev = _evaluator(eval_setup, nine_clips, pipelined)
+    obs.REGISTRY.reset()
+    assert not obs.enabled()
+    try:
+        got = [ev.evaluate(params) for _ in range(2)]
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.REGISTRY.reset()
+    assert "eval.starved_seconds" not in counters
+    assert "eval.h2d.bytes" not in counters
+    assert (ev._passes, ev._in_flight, ev._drained) == (0, 0, None)
+    for a, b in zip(got, results):
+        assert list(a["captions"]) == list(b["captions"])
+        assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
+
+
+def test_report_reads_fill_and_drain_off_the_spans(two_passes):
+    """The report's eval section: fill and drain are the newest pass's two
+    spans (no gauge keeps a second copy; the serial evaluator has no drain),
+    the starved seconds stand against the stretch from the first pass's
+    start to the last's end, and the upload is the counter over the batches."""
+    from cst_captioning_tpu.obs.report import build_report, render_report
+
+    spans, _walls, starved, *_ = two_passes
+    events = [dict(s, event="span") for s in spans] + [{
+        "event": "metrics", "ts": spans[-1]["ts"],
+        "counters": {"eval.starved_seconds": starved[1], "eval.batches": 6.0,
+                     "eval.h2d.bytes": 6e6},
+        "gauges": {"eval.wall_s": 1.0},
+        "histograms": {"eval.decode_seconds": {
+            "buckets": [1.0], "counts": [6, 0], "sum": 0.6, "count": 6,
+            "max": 0.2}}}]
+    ev = build_report(events)["eval"]
+    last = lambda name: ([s["dur"] for s in spans  # noqa: E731
+                          if s["name"] == name] or [0.0])[-1]
+    assert ev["fill_s"] == last("eval.pipeline.fill")
+    assert ev["drain_s"] == last("eval.pipeline.drain")
+    passes = [s for s in spans if s["name"] == "eval"]
+    across = passes[-1]["t1"] - passes[0]["t0"]
+    assert across > sum(s["dur"] for s in passes)   # the caller's turn is in it
+    assert ev["starved_share"] == pytest.approx(starved[1] / across)
+    text = render_report(build_report(events))
+    assert "starved: " in text and "1.0 MB a batch" in text
+
+
+def test_report_s_starved_share_under_a_trainer_s_validator():
+    """Two validations with a long training between them: the starved
+    seconds hold the training (the stamp lives on the ``Evaluator``), so the
+    share stands against the stretch they lie in and says whose time it
+    holds; against the passes' own wall it would read thousands of percent."""
+    from cst_captioning_tpu.obs.report import build_report, render_report
+
+    def eval_span(t0, t1):
+        return {"event": "span", "name": "eval", "ts": t1, "dur": t1 - t0,
+                "thread": "MainThread"}
+
+    events = [eval_span(10.0, 12.0), eval_span(1010.0, 1012.0), {
+        "event": "metrics", "ts": 1012.0,
+        "counters": {"eval.starved_seconds": 998.9, "eval.batches": 4.0},
+        "gauges": {"eval.wall_s": 2.0},
+        "histograms": {"eval.decode_seconds": {
+            "buckets": [1.0], "counts": [4, 0], "sum": 3.0, "count": 4,
+            "max": 0.9}}}]
+    report = build_report(events)
+    assert report["eval"]["passes_span_s"] == pytest.approx(1002.0)
+    assert report["eval"]["starved_share"] == pytest.approx(998.9 / 1002.0)
+    assert "caller's time between passes included (99.7% of the 1002.000s" \
+        in render_report(report)
 
 
 def test_npad_eval_mode_end_to_end(eval_setup):
