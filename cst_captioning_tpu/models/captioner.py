@@ -93,71 +93,125 @@ def _bounded_logps(cell, params, carry, bank, inputs, targets, depth):
     PERF.md section 5). A step shares ``selected_logprob`` with the decode
     loops: the REINFORCE logprobs and the decode-time logprobs are the same
     association order by construction.
+
+    The word embedding is outside both loops. Every input token is known
+    before the first position runs, so all ``T x B`` rows are looked up once
+    (``DecoderCell.embed``, ``[T, B, d_embed]`` in the compute dtype; kept
+    for the backward pass) and a position's step (``DecoderCell.step``)
+    reads its ``[B, d_embed]`` slice. The loops see the parameter tree
+    without the ``word_embed`` leaf. The backward loop writes each
+    position's ``[B, d_embed]`` input cotangent into a ``[T, B, d_embed]``
+    buffer (zero from ``depth`` on), and after it the table's gradient is
+    their sum by input token, once, every addition in f32
+    (:func:`_rows_by_token`). No position makes, converts or adds a ``[V,
+    d_embed]`` array.
+
+    Under ``shard_map`` each shard runs its own rows to its own depth, so no
+    iteration of either loop may hold a collective over a mesh axis the
+    tokens vary over. A parameter that is the same on every shard of such
+    an axis (parallel/seq_parallel.py, which differentiates outside the
+    map) is therefore typed varying before the loops: its cotangent is
+    summed over the axis by that cast's transpose, once, after the backward
+    loop, where jax would otherwise sum it a position inside it.
     """
     from cst_captioning_tpu.decoding.common import selected_logprob
 
-    def step(p, carry, bank, tokens, t):
-        inputs, targets = tokens
-        carry, logits = cell.apply({"params": p}, carry, inputs[t], *bank)
+    def local(x):
+        over = tuple(jax.typeof(inputs).vma - jax.typeof(x).vma)
+        return jax.lax.pcast(x, over, to="varying") if over else x
+
+    params = jax.tree.map(local, params)
+    table = {"word_embed": params["word_embed"]}
+    rest = {k: v for k, v in params.items() if k != "word_embed"}
+
+    def step(p, carry, embedded, bank, targets, t):
+        carry, logits = cell.apply(
+            {"params": p}, carry, embedded, *bank, method=DecoderCell.step
+        )
         return carry, selected_logprob(logits.astype(jnp.float32), targets[t])
 
     def put(buffer, x, t):
         return jax.lax.dynamic_update_index_in_dim(buffer, x, t, 0)
 
-    def forward(p, carry, bank, tokens, depth):
+    def forward(table, p, carry, bank, tokens, depth):
+        inputs, targets = tokens
+        embedded = cell.apply(
+            {"params": table}, inputs, method=DecoderCell.embed
+        )
+
         def body(t, loop):
             carry, carries, logps = loop
             carries = jax.tree.map(lambda b, c: put(b, c, t), carries, carry)
-            carry, logp = step(p, carry, bank, tokens, t)
+            carry, logp = step(p, carry, embedded[t], bank, targets, t)
             return carry, carries, put(logps, logp, t)
 
         # zeros made from the operands: inside shard_map they vary over the
         # mesh axes the values written into them vary over
-        T = tokens[0].shape[:1]
+        T = inputs.shape[:1]
         carries = jax.tree.map(
             lambda c: jnp.zeros_like(c, shape=T + c.shape), carry
         )
-        logps = jnp.zeros_like(
-            carry[0][0], jnp.float32, shape=tokens[0].shape
-        )
+        logps = jnp.zeros_like(carry[0][0], jnp.float32, shape=inputs.shape)
         _, carries, logps = jax.lax.fori_loop(
             0, depth, body, (carry, carries, logps)
         )
-        return logps, carries
+        return logps, (carries, embedded)
 
     # the tokens and the depth are arguments and not closed over: where the
     # gradient is taken outside shard_map (parallel/seq_parallel.py) the
     # backward pass is traced apart from the forward pass
     @jax.custom_vjp
-    def run(p, carry, bank, tokens, depth):
-        return forward(p, carry, bank, tokens, depth)[0]
+    def run(table, p, carry, bank, tokens, depth):
+        return forward(table, p, carry, bank, tokens, depth)[0]
 
-    def run_fwd(p, carry, bank, tokens, depth):
-        logps, carries = forward(p, carry, bank, tokens, depth)
-        return logps, (p, carries, bank, tokens, depth)
+    def run_fwd(table, p, carry, bank, tokens, depth):
+        logps, (carries, embedded) = forward(
+            table, p, carry, bank, tokens, depth
+        )
+        return logps, (table, p, carries, embedded, bank, tokens, depth)
 
     def run_bwd(kept, d_logps):
-        p, carries, bank, tokens, depth = kept
+        table, p, carries, embedded, bank, tokens, depth = kept
+        inputs, targets = tokens
 
         def body(i, loop):
             t = depth - 1 - i
-            d_carry, d_rest = loop
+            d_carry, d_embedded, d_rest = loop
             _, step_vjp = jax.vjp(
-                lambda *args: step(*args, tokens, t),
-                p, jax.tree.map(lambda b: b[t], carries), bank,
+                lambda *args: step(*args, targets, t),
+                p, jax.tree.map(lambda b: b[t], carries), embedded[t], bank,
             )
-            g_p, d_carry, g_bank = step_vjp((d_carry, d_logps[t]))
-            return d_carry, jax.tree.map(jnp.add, d_rest, (g_p, g_bank))
+            g_p, d_carry, g_embedded, g_bank = step_vjp((d_carry, d_logps[t]))
+            return (
+                d_carry, put(d_embedded, g_embedded, t),
+                jax.tree.map(jnp.add, d_rest, (g_p, g_bank)),
+            )
 
         zeros = lambda tree: jax.tree.map(jnp.zeros_like, tree)  # noqa: E731
-        d_carry, (d_p, d_bank) = jax.lax.fori_loop(
+        d_carry, d_embedded, (d_p, d_bank) = jax.lax.fori_loop(
             0, depth, body,
-            (zeros(jax.tree.map(lambda b: b[0], carries)), zeros((p, bank))),
+            (zeros(jax.tree.map(lambda b: b[0], carries)), zeros(embedded),
+             zeros((p, bank))),
         )
-        return d_p, d_carry, d_bank, None, None   # integers: no cotangent
+        d_table = jax.tree.map(
+            lambda leaf: _rows_by_token(inputs, d_embedded, leaf), table
+        )
+        return d_table, d_p, d_carry, d_bank, None, None   # integers: none
 
     run.defvjp(run_fwd, run_bwd)
-    return run(params, carry, bank, (inputs, targets), depth)
+    return run(table, rest, carry, bank, (inputs, targets), depth)
+
+
+def _rows_by_token(tokens, rows, table):
+    """The cotangent of ``table`` ``[V, d]`` under ``table[tokens]``: the
+    sums of ``rows`` ``[..., d]`` by their token ``[...]``, every addition
+    in f32, in the table's dtype. One scatter-add of all the rows (of this
+    and the one-hot product ``scripts/update_row_sweep.py`` keeps to measure
+    against it, the faster on the chip: PERF.md section 6, PR 41)."""
+    summed = jnp.zeros_like(table, jnp.float32).at[tokens.reshape(-1)].add(
+        rows.reshape(-1, rows.shape[-1]).astype(jnp.float32)
+    )
+    return summed.astype(table.dtype)
 
 
 class CaptionModel(nn.Module):
@@ -297,7 +351,10 @@ class CaptionModel(nn.Module):
         captions that fill all T positions run all of them. A caller masks
         by the tokens (a PAD position carries no loss), so the 0.0 is what
         those positions came to after the mask; rl/scst.py's update is the
-        caller. Neither pass hands anyone a ``[B, T, V]`` logits stack."""
+        caller. Neither pass hands anyone a ``[B, T, V]`` logits stack, and
+        the word embedding is outside both: all ``B x T`` input rows are
+        looked up before the forward loop and their cotangents summed into
+        the table after the backward loop, once a call."""
         self._lstm_only("teacher_force_logps")
         from cst_captioning_tpu.decoding.common import caption_depth
 

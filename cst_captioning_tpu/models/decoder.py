@@ -7,6 +7,15 @@ the LSTM stack, project to vocab logits. Written as a single-step module so
 teacher forcing (``nn.scan``), greedy/multinomial sampling and beam search all
 share the exact same parameters and code path.
 
+The step is in two parts: :meth:`DecoderCell.embed` looks the token up and
+:meth:`DecoderCell.step` runs everything after the lookup on the embedded
+row. ``__call__`` is the one after the other, which is what every decode
+loop, the beam search and ``decode_logits`` run. The RL update's teacher
+forcing (``models/captioner.py::_bounded_logps``) knows all its input
+tokens before its loop starts, so it calls ``embed`` once for all positions
+and ``step`` in the loop: no position of it touches the ``[V, d_embed]``
+table, forward or backward.
+
 ``ops/decode_pallas.py`` reimplements exactly this step (minus dropout —
 decode is deterministic) as one fused TPU kernel over this module's
 parameter tree, selected by ``ModelConfig.decode_impl``; any change to the
@@ -62,19 +71,25 @@ class DecoderCell(nn.Module):
     def project_memory(self, memory: jnp.ndarray) -> jnp.ndarray:
         return self.attention.project_memory(memory)
 
-    def __call__(
+    def embed(self, token: jnp.ndarray) -> jnp.ndarray:
+        """int32 tokens ``[...]`` -> their rows of the word embedding
+        ``[..., d_embed]`` in the compute dtype."""
+        return self.word_embed(token)
+
+    def step(
         self,
         carry: Carry,
-        token: jnp.ndarray,        # [B] int32 previous token
+        embedded: jnp.ndarray,     # [B, d_embed] :meth:`embed` of the token
         memory: jnp.ndarray,       # [B, M, E]
         memory_proj: jnp.ndarray,  # [B, M, d_att]
         memory_mask: jnp.ndarray,  # [B, M]
         deterministic: bool = True,
     ) -> tuple[Carry, jnp.ndarray]:
-        """One decode step -> (new carry, logits [B, V] float32)."""
+        """One decode step from the embedded previous token -> (new carry,
+        logits [B, V] float32)."""
         h_top = carry[-1][1]
         ctx = self.attention(h_top, memory, memory_proj, memory_mask)
-        x = jnp.concatenate([self.word_embed(token), ctx], axis=-1)
+        x = jnp.concatenate([embedded, ctx], axis=-1)
         x = self.dropout(x, deterministic=deterministic)
         new_carry = []
         for i, cell in enumerate(self.lstm):
@@ -86,3 +101,18 @@ class DecoderCell(nn.Module):
         # logits in f32: softmax/loss stability is worth the cast
         logits = self.out_proj(x).astype(jnp.float32)
         return tuple(new_carry), logits
+
+    def __call__(
+        self,
+        carry: Carry,
+        token: jnp.ndarray,        # [B] int32 previous token
+        memory: jnp.ndarray,       # [B, M, E]
+        memory_proj: jnp.ndarray,  # [B, M, d_att]
+        memory_mask: jnp.ndarray,  # [B, M]
+        deterministic: bool = True,
+    ) -> tuple[Carry, jnp.ndarray]:
+        """One decode step -> (new carry, logits [B, V] float32)."""
+        return self.step(
+            carry, self.embed(token), memory, memory_proj, memory_mask,
+            deterministic,
+        )

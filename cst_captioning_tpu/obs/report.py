@@ -39,9 +39,12 @@ sections (PR 4):
   and device, which says which sampler the run had; beside it the
   ``rl.update.row_blocks`` / ``rl.update.block_rows``
   gauges: how the RL update was cut into row blocks when it was traced,
-  and the ``rl.update.positions.run`` / ``rl.update.positions`` counters:
+  the ``rl.update.positions.run`` / ``rl.update.positions`` counters:
   the share of its teacher-forcing scan's positions that held a token of
-  the rows they were run for, and so were run.
+  the rows they were run for, and so were run, and the
+  ``rl.update.embed_rows`` gauge: the input rows a block looks up before
+  its forward loop and sums into the word embedding's gradient after its
+  backward loop, once.
 """
 
 from __future__ import annotations
@@ -296,6 +299,11 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
     if gauges.get("rl.update.row_blocks"):
         update = {"row_blocks": float(gauges["rl.update.row_blocks"]),
                   "block_rows": float(gauges.get("rl.update.block_rows", 0.0))}
+        # rows (positions x rows of a block) whose cotangents one block sums
+        # into the word embedding after its backward loop
+        # (models/captioner.py::_bounded_logps; 0.0: a tree before PR 41)
+        if gauges.get("rl.update.embed_rows"):
+            update["embed_rows"] = float(gauges["rl.update.embed_rows"])
         positions = float(counters.get("rl.update.positions", 0))
         if positions:
             run = float(counters.get("rl.update.positions.run", 0))
@@ -680,6 +688,13 @@ def render_report(report: dict[str, Any]) -> str:
             f"{int(u['block_rows'])} row(s) a rollout chunk and device"
             + scan
         )
+        if "embed_rows" in u:
+            lines.append(
+                f"update word embedding: {int(u['embed_rows'])} input row(s) "
+                "a block looked up before its forward loop and summed into "
+                "the table after its backward loop, once (no position "
+                "touches the table)"
+            )
     ds = report.get("decode_state")
     if ds:
         if not (d or u):

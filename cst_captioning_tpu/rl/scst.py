@@ -224,8 +224,13 @@ def _decode_loss_sums(model, params, enc_tiled, tokens_flat, advantage_flat,
     no ``[T, rows, V]`` residual; and neither pass runs a position past the
     last at which one of THESE rows holds a token, so the bound is per
     rollout chunk, per row block and, inside ``shard_map``, per shard, with
-    no collective."""
+    no collective. The word embedding is outside both loops: these rows'
+    input tokens are looked up once before the forward loop and their
+    cotangents summed into the table once after the backward loop
+    (``models.captioner._bounded_logps``); the gauge
+    ``rl.update.embed_rows`` says how many, positions x rows."""
 
+    obs.gauge("rl.update.embed_rows").set(float(tokens_flat.size))
     logp = model.apply(
         params, enc_tiled, tokens_flat, method=CaptionModel.teacher_force_logps
     )
@@ -236,25 +241,29 @@ def _decode_loss_sums(model, params, enc_tiled, tokens_flat, advantage_flat,
 
 
 # Teacher-forced rows of one rollout chunk that a device runs as one block.
-# The update's backward scan keeps two accumulators for the cotangent of the
+# The update's backward loop keeps two accumulators for the cotangent of the
 # attention bank, bf16 [rows, slots, d] (`memory`) and [rows, slots, d_att]
-# (`memory_proj`), read, added to and written on every one of its T steps;
-# the rows decide whether the compiler holds them in the chip's fast memory
-# or in HBM, while the weight gradients' accumulators cost the same whatever
-# the rows and are paid once more with every block. Readings of
-# `scripts/update_row_sweep.py` on one TPU v5e, the update alone at B=1792,
-# K=5, update_chunks=5, preset 4's widths (my chip runs, PR 32; PERF.md
-# section 5), block rows -> ms an update, accumulators' memory:
-#   1792 -> 370.5 (both in HBM)      896 -> 324.4 (`memory` in fast memory)
-#    448 -> 313.4 (`memory` fast)    256 -> 320.9 (both fast)
-#    224 -> 291.5 (both fast)        128 -> 336.4 (both fast)
-# 448 it is: a data-parallel shard of 448 rows (B=1792 on four chips) keeps
-# the program it had, and one chip runs that program four times. 224 reads
-# 7 % better still; it would cut the four-chip program too (ROADMAP S1(b)).
-# Since PR 37 teacher forcing keeps no residual and stops at the block's
-# longest caption; the same sweep at depth 20 of 30 (my chip runs, PR 37):
-#   1792 -> 242.0    896 -> 211.6    448 -> 213.4    224 -> 187.9
-# with both accumulators in fast memory at 448: the order stands.
+# (`memory_proj`), read, added to and written at every position it runs; the
+# rows decide whether the compiler holds them in the chip's fast memory or
+# in HBM. The weight gradients' f32 accumulators cost the same whatever the
+# rows and are paid a position once more with every block, `out_proj`'s
+# [d, V] first among them; the word embedding's [V, d] is no longer one of
+# them (PR 41: its rows are summed into it once a block after the loop, so a
+# smaller block pays one more scatter of the same rows in all, not one more
+# dense table a position). Readings of `scripts/update_row_sweep.py` on one
+# TPU v5e, the update alone at B=1792, K=5, update_chunks=5, preset 4's
+# widths, every block at the depth (my chip runs, PR 41; PERF.md section
+# 6), block rows -> ms an update at depth 30 / 20 of 30, the two
+# accumulators' memory (`memory` / `memory_proj`):
+#   1792 -> 334.0 / 231.0 (hbm / hbm)     896 -> 286.1 / 199.7 (hbm / hbm)
+#    448 -> 251.9 / 176.9 (fast / fast)   224 -> 224.8 / 160.3 (fast / fast)
+# (before PR 41, the dense table a position: 448 -> 311.3 / 213.4, and at
+# depth 20 1792 -> 242.0, 896 -> 211.6, 224 -> 187.9: my chip runs, PRs 37
+# and 41.) 448 it is: a data-parallel shard of 448 rows (B=1792 on four
+# chips) keeps the program it had, and one chip runs that program four
+# times. 224 reads 9 % better still (it was 12 %: a block of 224 no longer
+# pays the embedding's accumulator a second time); it would cut the
+# four-chip program too (ROADMAP S1(c)).
 _ROW_BLOCK_CAP = 448
 
 

@@ -651,6 +651,60 @@ def test_report_update_row_blocks():
     lines = render_report(build_report(events)).splitlines()
     at = next(i for i, ln in enumerate(lines) if "decode early-exit" in ln)
     assert lines[at + 1].startswith("update row blocks: 4 block(s)")
+    assert "update word embedding" not in "\n".join(lines)
+
+
+def test_report_update_embed_rows():
+    """The ``rl.update.embed_rows`` gauge (rl/scst.py sets it when the
+    update's teacher forcing is traced: positions x rows of what one call
+    looks up before its forward loop and sums into the word embedding after
+    its backward loop) reaches the report and its text beside the row
+    blocks' line; tracing the update sets it."""
+    import jax
+    import jax.numpy as jnp
+
+    from cst_captioning_tpu.config.config import ModelConfig
+    from cst_captioning_tpu.models import CaptionModel
+    from cst_captioning_tpu.rl import scst
+
+    gauges = {"rl.update.row_blocks": 4.0, "rl.update.block_rows": 448.0,
+              "rl.update.embed_rows": 13440.0}
+    rep = build_report([
+        {"ts": 0.0, "event": "run_start", "run": "rows", "thread": "main"},
+        {"ts": 1.0, "event": "metrics", "counters": {}, "gauges": gauges,
+         "histograms": {}},
+        {"ts": 2.0, "event": "run_end", "run": "rows"},
+    ])
+    assert rep["update"]["embed_rows"] == 13440.0
+    lines = render_report(rep).splitlines()
+    at = next(i for i, ln in enumerate(lines)
+              if ln.startswith("update row blocks:"))
+    assert lines[at + 1].startswith(
+        "update word embedding: 13440 input row(s) a block")
+
+    # the program's side: 2 chunks of K = 4 over B = 6 rows under a cap of
+    # 8 make blocks of 3 rows x 2 rollouts, T = 4 positions each
+    cfg = ModelConfig(vocab_size=11, modalities=(("resnet", 8),), d_embed=8,
+                      d_hidden=8, d_att=4, max_len=4, max_frames=3,
+                      dtype="float32")
+    model = CaptionModel(cfg)
+    feats = {"resnet": jnp.zeros((6, 3, 8), jnp.float32)}
+    masks = {"resnet": jnp.ones((6, 3), jnp.float32)}
+    params = model.init(jax.random.key(0), feats, masks,
+                        jnp.zeros((6, 4), jnp.int32))
+    obs.gauge("rl.update.embed_rows").set(0.0)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(scst, "_ROW_BLOCK_CAP", 8)
+    try:
+        jax.eval_shape(
+            lambda p: scst._chunked_loss_grads(
+                model, p, feats, masks, jnp.ones((4, 6, 4), jnp.int32),
+                jnp.ones((4, 6)), jnp.ones((6,)), chunks=2),
+            params)
+    finally:
+        patch.undo()
+    assert obs.gauge("rl.update.block_rows").value == 3.0
+    assert obs.gauge("rl.update.embed_rows").value == 4 * 3 * 2
 
 
 def test_report_decode_sampler_draws():

@@ -230,6 +230,55 @@ def test_sp_rl_update_matches_single_device(setup):
             )
 
 
+def test_sp_rl_update_word_embedding_gradient(setup):
+    """The word embedding's gradient through ``make_sp_rl_update``, which
+    differentiates outside ``shard_map`` (teacher forcing's backward pass is
+    traced apart from its forward pass, the table is the same on every
+    shard and the rows summed into it are not): plain SGD at rate 1 makes
+    the leaf's change the gradient itself, and it equals the one-device
+    update's. Few tokens, so that a table row sums many input cotangents
+    across positions, rollouts and 'data' shards; and captions of every
+    length, so that the 'data' shards run their loops to different depths
+    (a sum over 'data' inside the loop, which is where jax puts the
+    cotangent of a parameter typed the same on every shard, then meets no
+    partner: the update ended the process)."""
+    import optax
+    from jax.sharding import NamedSharding
+    from cst_captioning_tpu.rl.scst import make_rl_update
+
+    cfg, model, params, feats, masks, labels = setup
+    K = 3
+    rng = np.random.default_rng(7)
+    lens = rng.integers(1, T + 1, size=(K, B, 1))
+    samples = jnp.asarray(
+        np.where(np.arange(T) < lens, rng.integers(4, 7, size=(K, B, T)), 0),
+        jnp.int32)
+    advantage = jnp.asarray(rng.normal(size=(K, B)), jnp.float32)
+    valid = jnp.ones((B,), jnp.float32)
+    state = create_train_state(model, optax.sgd(1.0), (feats, masks, labels),
+                               seed=3)
+    table = lambda st: np.asarray(  # noqa: E731
+        st.params["params"]["cell"]["word_embed"]["embedding"])
+    s_state, _ = make_rl_update(model)(
+        state, feats, masks, samples, advantage, valid
+    )
+    want = table(state) - table(s_state)
+    assert np.abs(want[4:7]).min() > 0 and not want[7:].any()
+
+    mesh = mesh_2d()
+    f, m = _place(mesh, cfg, feats, masks, "data")
+    kb_shard = NamedSharding(mesh, P(None, "data"))
+    for chunks in (1, 3):
+        p_state, _ = make_sp_rl_update(sp_model(cfg), mesh, chunks=chunks)(
+            state, f, m,
+            jax.device_put(samples, kb_shard),
+            jax.device_put(advantage, kb_shard),
+            jax.device_put(valid, NamedSharding(mesh, P("data"))),
+        )
+        np.testing.assert_allclose(table(state) - table(p_state), want,
+                                   rtol=1e-4, atol=1e-6)
+
+
 def test_sp_handles_very_long_frame_axis(setup):
     """The SP design point: a frame axis far beyond one batch's usual size
     still decodes (each device holds 1/8th of the frames)."""
