@@ -156,12 +156,26 @@ class ModelConfig:
     sparse_window_size: int = 2048
     sparse_init_blocks: int = 1
     sparse_dense_len: int = 8192
+    # decoder kind "eva" (models/eva.py: a pre-norm residual stack over a
+    # video prefix whose every layer mixes tokens by EVA attention: exact
+    # keys inside a window of ``window_size`` positions, one learned summary
+    # for every ``chunk_size`` positions of the windows before it, one
+    # softmax over both; float32 residual stream, norms with a unit offset,
+    # an output head of ``num_pred_heads`` blocks of ``vocab_size`` columns
+    # of which plain decoding reads the first). Sizes under the key names of
+    # the published config.json (benchmark/configs/evabyte_8l.json); it also
+    # reads hidden_size, num_hidden_layers, intermediate_size,
+    # num_attention_heads, rms_norm_eps and rope_theta above
+    window_size: int = 2048
+    chunk_size: int = 16
+    num_pred_heads: int = 1
+    init_std: float = 0.02
 
     def __post_init__(self):
-        if self.decoder not in ("lstm", "latent_moe", "sparse_linear"):
+        if self.decoder not in ("lstm", "latent_moe", "sparse_linear", "eva"):
             raise ValueError(
                 f"unknown decoder: {self.decoder!r} "
-                "(expected 'lstm', 'latent_moe' or 'sparse_linear')"
+                "(expected 'lstm', 'latent_moe', 'sparse_linear' or 'eva')"
             )
         object.__setattr__(self, "mixer_types",
                            tuple(str(m) for m in self.mixer_types))

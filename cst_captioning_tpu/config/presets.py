@@ -35,6 +35,19 @@ its 32 layers (``_MINICPM_SALA_8L``):
                                ``Evaluator``, beams on lanes: a clip's beams
                                share one copy of its prefix.
 
+Two more run the fourth (``model.decoder = "eva"``, models/eva.py) at the
+published widths of EvaByte, eight of its 32 layers (``_EVABYTE_8L``):
+
+10. ``evabyte_8l_xe``        — the stack behind the same 16384-slot prefix,
+                               a byte vocabulary of 320 and captions of 128
+                               steps; bfloat16 parameters and plain SGD. The
+                               benchmark makes its seeded policy from it
+                               (0 steps).
+11. ``evabyte_8l_eval_beam5`` — the same model, beam-5 eval through the
+                               ``Evaluator``, beams on lanes: a clip's beams
+                               share one copy of its prefix's summaries and
+                               of its last window's exact keys.
+
 Paper CST variant names map onto presets as: XE -> 1/2; CST_GT_None/SCST -> 3;
 CST_MS_SCB -> 4 (with ``rl.baseline="scb"``); WXE is preset 2 with
 ``train.loss="wxe"``.
@@ -263,6 +276,53 @@ def _minicpm_sala_8l_eval_beam5() -> ExperimentConfig:
     )
 
 
+# EvaByte (huggingface.co/EvaByte/EvaByte, config.json): every width as
+# published; eight of the 32 layers (every layer is the same kind: one of four
+# pipeline stages of eight). The prefix is the sparse/linear preset's: one
+# modality of 16384 patch tokens. 320 ids: the corpus' 4 specials and 316
+# symbols; the head holds num_pred_heads 8 blocks of them.
+_EVABYTE_8L = ModelConfig(
+    decoder="eva",
+    vocab_size=320,
+    modalities=(("patch", 1024),),
+    max_len=128,
+    max_frames=16384,
+    dropout=0.0,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+    hidden_size=4096,
+    num_hidden_layers=8,
+    intermediate_size=11008,
+    num_attention_heads=32,
+    rms_norm_eps=1e-5,
+    rope_theta=100000.0,
+    window_size=2048,
+    chunk_size=16,
+    num_pred_heads=8,
+    init_std=0.01275,
+)
+
+
+def _evabyte_8l_xe() -> ExperimentConfig:
+    return ExperimentConfig(
+        name="evabyte_8l_xe",
+        model=_EVABYTE_8L,
+        data=DataConfig(dataset="msrvtt", batch_size=2),
+        train=TrainConfig(loss="xe", optimizer="sgd", lr=1e-4, epochs=1),
+    )
+
+
+def _evabyte_8l_eval_beam5() -> ExperimentConfig:
+    return dataclasses.replace(
+        _evabyte_8l_xe(),
+        name="evabyte_8l_eval_beam5",
+        # beams on lanes, as the sparse/linear preset: what a clip's lanes
+        # share is read from one copy
+        eval=EvalConfig(beam_size=5, max_len=128, split="test",
+                        beam_impl="lanes", prefill_program=True),
+    )
+
+
 PRESETS = {
     "msvd_xe_meanpool": _msvd_xe_meanpool,
     "msrvtt_xe_attention": _msrvtt_xe_attention,
@@ -273,6 +333,8 @@ PRESETS = {
     "kimi_k2_ep32_eval_beam5": _kimi_k2_ep32_eval_beam5,
     "minicpm_sala_8l_xe": _minicpm_sala_8l_xe,
     "minicpm_sala_8l_eval_beam5": _minicpm_sala_8l_eval_beam5,
+    "evabyte_8l_xe": _evabyte_8l_xe,
+    "evabyte_8l_eval_beam5": _evabyte_8l_eval_beam5,
 }
 
 

@@ -263,7 +263,9 @@ def carry_tally(carry):
     leaf of a routed-expert decoder's state (models/latent_moe.py: token-
     expert assignments, rows a held expert) or the ``counted`` leaf of the
     sparse/linear decoder's (models/sparse_linear.py: keys its sparse layers'
-    queries saw and attended to), summed over every axis but its last two;
+    queries saw and attended to) and of the EVA decoder's (models/eva.py:
+    exact keys and summaries a query attended to, windows a caption
+    entered), summed over every axis but its last two;
     ``()`` for a carry that counts nothing (the LSTM's), which adds no leaf
     to the loop's state."""
     for leaf in ("routed", "counted"):

@@ -162,7 +162,7 @@ class Evaluator:
         elif W > 1:
             # a language-model decoder's search also returns what it counted
             # (decoding.common.carry_tally: routed assignments, or keys the
-            # sparse layers attended to); on a mesh the count would be a
+            # sparse or EVA layers attended to); on a mesh the count would be a
             # shard's, so there the decode returns tokens alone
             self._counts = model.cfg.decoder != "lstm" and mesh is None
             pick = slice(0, None, 2) if self._counts else 0
@@ -255,7 +255,11 @@ class Evaluator:
         ``decode.kv_bytes`` (keys and values: the prefix's once a clip, a
         caption's a lane), ``decode.index_bytes`` (the compressed keys the
         selection scores, once a clip), ``decode.state_bytes`` (the linear
-        layers' recurrent states, a lane)."""
+        layers' recurrent states, a lane); for the EVA decoder its two:
+        ``decode.window_bytes`` (exact keys and values: the prefix's last
+        window's once a clip, a caption's a lane) and
+        ``decode.summary_bytes`` (chunk summaries: the prefix's once a clip,
+        those a caption makes a lane)."""
         if not obs.enabled() or self._observed:
             return
         self._observed = True
@@ -282,6 +286,16 @@ class Evaluator:
             obs.gauge("decode.state_bytes").set(state)
             obs.gauge("decode.cache_bytes").set(kv + index + state)
             return
+        if kind == "eva":
+            shared = 1 if self.cfg.beam_impl == "lanes" else lanes
+            near = shared * size(enc.memory_proj[:2]) + lanes * size(
+                (enc.carry.k, enc.carry.v))
+            pooled = shared * size(enc.memory) + lanes * size(
+                (enc.carry.ks, enc.carry.vs))
+            obs.gauge("decode.window_bytes").set(near)
+            obs.gauge("decode.summary_bytes").set(pooled)
+            obs.gauge("decode.cache_bytes").set(near + pooled)
+            return
         obs.gauge("decode.cache_bytes").set(lanes * size(enc.carry))
         if kind == "latent_moe":
             obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
@@ -292,7 +306,8 @@ class Evaluator:
         ``moe.assignments`` / ``moe.assignments.local`` and the rows each
         held expert of each layer took (histogram ``moe.expert_rows``), or
         the sparse layers' ``sparse.keys_visible`` / ``sparse.keys_selected``
-        / ``sparse.dense_fallback_queries``."""
+        / ``sparse.dense_fallback_queries``, or the EVA layers'
+        ``eva.keys_exact`` / ``eva.keys_summary`` / ``eva.window_crossings``."""
         if not self._tallies:
             return
         tally = np.asarray(jax.device_get(self._tallies.pop(0)))
@@ -305,6 +320,14 @@ class Evaluator:
             obs.counter("sparse.keys_visible").inc(float(seen))
             obs.counter("sparse.keys_selected").inc(float(took))
             obs.counter("sparse.dense_fallback_queries").inc(float(dense))
+            return
+        if self.model.cfg.decoder == "eva":
+            # [1, 3]: a query (every layer's sees the same sets), exact keys
+            # and summaries attended; lanes whose caption entered a window
+            exact, summary, crossed = tally.sum(axis=0, dtype=np.float64)
+            obs.counter("eva.keys_exact").inc(float(exact))
+            obs.counter("eva.keys_summary").inc(float(summary))
+            obs.counter("eva.window_crossings").inc(float(crossed))
             return
         obs.counter("moe.assignments").inc(float(tally[:, -1].sum()))
         obs.counter("moe.assignments.local").inc(float(tally[:, :-1].sum()))

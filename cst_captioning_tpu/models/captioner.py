@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from cst_captioning_tpu.config.config import BOS_ID, ModelConfig
 from cst_captioning_tpu.models.decoder import Carry, DecoderCell
+from cst_captioning_tpu.models.eva import EvaDecoder
 from cst_captioning_tpu.models.latent_moe import LatentMoEDecoder
 from cst_captioning_tpu.models.sparse_linear import SparseLinearDecoder
 from cst_captioning_tpu.models.encoders import (
@@ -230,6 +231,11 @@ class CaptionModel(nn.Module):
             # attention layers over a long video prefix
             self.decoder = SparseLinearDecoder(cfg, name="decoder")
             return
+        if cfg.decoder == "eva":
+            # the fourth (models/eva.py): EVA attention over a long video
+            # prefix, exact keys in a window and a summary a chunk before it
+            self.decoder = EvaDecoder(cfg, name="decoder")
+            return
         if cfg.encoder == "meanpool":
             self.encoder = MeanPoolEncoder(cfg, name="encoder")
         else:
@@ -266,9 +272,13 @@ class CaptionModel(nn.Module):
             # compressed keys (``memory_proj``), the mask saying how many of
             # the compacted slots exist; what a lane owns is the carry
             (keys, values, pooled), n, carry = self.decoder.prefill(feats, masks)
-            slots = len(self.cfg.modalities) * self.cfg.max_frames
-            live = (jnp.arange(slots)[None, :] < n[:, None]).astype(jnp.float32)
-            return EncoderOutput((keys, values), pooled, live, carry)
+            return EncoderOutput((keys, values), pooled, self._live(n), carry)
+        if self.cfg.decoder == "eva":
+            # likewise: every layer's chunk summaries (``memory``) and the
+            # exact keys of the window the prefix ends in (``memory_proj``)
+            # are the clip's, held once; the carry is a lane's
+            (summaries, near), n, carry = self.decoder.prefill(feats, masks)
+            return EncoderOutput(summaries, near, self._live(n), carry)
         memory, mmask = self.encoder(feats, masks)
         memory_proj = self.cell.project_memory(memory)
         ctx0 = masked_mean(memory, mmask, axis=1, axis_name=self.cfg.seq_axis)
@@ -277,6 +287,12 @@ class CaptionModel(nn.Module):
             for i in range(self.cfg.num_layers)
         )
         return EncoderOutput(memory, memory_proj, mmask, carry)
+
+    def _live(self, n: jnp.ndarray) -> jnp.ndarray:
+        """The memory mask of a prefix whose valid slots are compacted to
+        the front: [B, slots] float32, 1 on each row's first ``n``."""
+        slots = len(self.cfg.modalities) * self.cfg.max_frames
+        return (jnp.arange(slots)[None, :] < n[:, None]).astype(jnp.float32)
 
     # ---- single step (greedy / sampling / beam all call this) ---------------
 
@@ -292,6 +308,10 @@ class CaptionModel(nn.Module):
         if self.cfg.decoder == "sparse_linear":
             return self.decoder.step(
                 carry, token, (*enc.memory, enc.memory_proj),
+                enc.memory_mask.sum(axis=-1).astype(jnp.int32))
+        if self.cfg.decoder == "eva":
+            return self.decoder.step(
+                carry, token, (enc.memory, enc.memory_proj),
                 enc.memory_mask.sum(axis=-1).astype(jnp.int32))
         return self.cell(
             carry, token, enc.memory, enc.memory_proj, enc.memory_mask, deterministic
@@ -373,6 +393,6 @@ class CaptionModel(nn.Module):
         train: bool = False,
     ) -> jnp.ndarray:
         """-> logits [B, T, V] (f32); logits[:, t] predicts labels[:, t]."""
-        if self.cfg.decoder in ("latent_moe", "sparse_linear"):
+        if self.cfg.decoder != "lstm":
             return self.decoder(feats, masks, labels)
         return self.decode_logits(self.encode(feats, masks), labels, train)

@@ -96,6 +96,22 @@ def rope_inv_freq(cfg: ModelConfig) -> jnp.ndarray:
     return float(cfg.rope_theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
 
 
+def compact_prefix(cfg: ModelConfig, embed, feats, masks):
+    """Each modality's features through its projection ``embed[name]`` -> (x
+    [B, P, h] in ``cfg.dtype`` with each row's valid slots first and zeros
+    behind them, n [B]: how many there are)."""
+    dt = jnp.dtype(cfg.dtype)
+    names = cfg.modality_names
+    valid = jnp.concatenate([masks[n] > 0 for n in names], axis=1)
+    x = jnp.concatenate(
+        [feats[n].astype(dt) @ embed[n].astype(dt) for n in names], 1)
+    order = jnp.argsort(jnp.logical_not(valid), axis=1, stable=True)
+    x = jnp.take_along_axis(x, order[:, :, None], axis=1)
+    n = valid.sum(axis=1).astype(jnp.int32)
+    live = jnp.arange(x.shape[1])[None, :] < n[:, None]
+    return x * live[..., None].astype(dt), n
+
+
 def _heads(x, n: int):
     return x.reshape(x.shape[:-1] + (n, x.shape[-1] // n))
 
@@ -213,19 +229,6 @@ class SparseLinearDecoder(nn.Module):
         self.norm = self.param("norm", nn.initializers.ones, (c.hidden_size,), pd)
         self.lm_head = self.param("lm_head", w, (c.hidden_size, c.vocab_size), pd)
 
-    def _prefix(self, feats, masks):
-        """-> (x [B, P, h] with each row's valid slots first, n [B])."""
-        dt = jnp.dtype(self.cfg.dtype)
-        names = self.cfg.modality_names
-        valid = jnp.concatenate([masks[n] > 0 for n in names], axis=1)
-        x = jnp.concatenate(
-            [feats[n].astype(dt) @ self.embed[n].astype(dt) for n in names], 1)
-        order = jnp.argsort(jnp.logical_not(valid), axis=1, stable=True)
-        x = jnp.take_along_axis(x, order[:, :, None], axis=1)
-        n = valid.sum(axis=1).astype(jnp.int32)
-        live = jnp.arange(x.shape[1])[None, :] < n[:, None]
-        return x * live[..., None].astype(dt), n
-
     def _logits(self, x):
         c = self.cfg
         x = rms_norm(x, self.norm, c.rms_norm_eps)
@@ -240,7 +243,7 @@ class SparseLinearDecoder(nn.Module):
         the linear layers' states after the prefix."""
         c = self.cfg
         spec = sparse_spec(c)
-        x, n = self._prefix(feats, masks)
+        x, n = compact_prefix(c, self.embed, feats, masks)
         B, P, h = x.shape
         positions = jnp.broadcast_to(jnp.arange(P), (B, P))
         keys, values, pooled, states, counted = [], [], [], [], []

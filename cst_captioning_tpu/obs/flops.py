@@ -208,13 +208,30 @@ def sparse_linear_per_tok_flops(mc, context: int) -> float:
     return float(total)
 
 
+# ---- the EVA-attention decoder (models/eva.py) ---------------------------------
+
+
+def eva_per_tok_flops(mc, context: int) -> float:
+    """Matmul FLOPs one token costs in the ``decoder="eva"`` stack at
+    ``context`` positions seen (its own the last), head excluded: a layer's
+    four projections and gated FFN, and its scores and values over the exact
+    keys of its window and one summary a chunk of the windows before."""
+    h, m = mc.hidden_size, mc.intermediate_size
+    last = context - 1
+    keys = last % mc.window_size + 1 \
+        + (mc.window_size // mc.chunk_size) * (last // mc.window_size)
+    return float(mc.num_hidden_layers * (
+        2 * 4 * h * h + 2 * 3 * h * m + 2 * 2 * h * keys))
+
+
 def model_xe_flops_per_row(mc) -> float:
     """Matmul FLOPs of one teacher-forced XE row (forward + backward as 3x
     forward) of the model ``mc`` (a ``ModelConfig``) describes, by its
     decoder kind: what ``Trainer`` feeds the ``flops.xe.step`` counter."""
     feat_dims = tuple(d for _, d in mc.modalities)
     per_tok = {"latent_moe": latent_moe_per_tok_flops,
-               "sparse_linear": sparse_linear_per_tok_flops}.get(mc.decoder)
+               "sparse_linear": sparse_linear_per_tok_flops,
+               "eva": eva_per_tok_flops}.get(mc.decoder)
     if per_tok is not None:
         n_prefix = len(feat_dims) * mc.max_frames
         fwd = 2.0 * mc.max_frames * sum(feat_dims) * mc.hidden_size

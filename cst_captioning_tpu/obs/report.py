@@ -334,6 +334,13 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             "keys_selected": counters.get("sparse.keys_selected", 0.0),
             "dense_fallback_queries": counters.get(
                 "sparse.dense_fallback_queries", 0.0),
+            # the EVA decoder (models/eva.py): its two kinds of state, and
+            # what its queries attended to at each granularity
+            "window_bytes": gauges.get("decode.window_bytes"),
+            "summary_bytes": gauges.get("decode.summary_bytes"),
+            "keys_exact": counters.get("eva.keys_exact", 0.0),
+            "keys_summary": counters.get("eva.keys_summary", 0.0),
+            "window_crossings": counters.get("eva.window_crossings", 0.0),
         }
 
     # serving section (serving/engine.py): request funnel counters + the
@@ -726,6 +733,21 @@ def render_report(report: dict[str, Any]) -> str:
                 f"keys they saw "
                 f"({100.0 * ds['keys_selected'] / ds['keys_visible']:.2f}%); "
                 f"{int(ds['dense_fallback_queries'])} under the dense length"
+            )
+        if ds.get("window_bytes") is not None:
+            lines.append(
+                f"of it exact keys and values of a window "
+                f"{ds['window_bytes'] / 2**20:.1f} MiB, chunk summaries "
+                f"{(ds['summary_bytes'] or 0) / 2**20:.1f} MiB"
+            )
+        if ds.get("keys_exact"):
+            keys = ds["keys_exact"] + ds["keys_summary"]
+            lines.append(
+                f"EVA layers: a query attended to {int(ds['keys_exact'])} "
+                f"exact keys and {int(ds['keys_summary'])} summaries "
+                f"({100.0 * ds['keys_summary'] / keys:.2f}% summaries); "
+                f"{int(ds['window_crossings'])} lane(s) entered a new window "
+                f"inside a caption"
             )
     sv = report.get("serving")
     if sv:

@@ -303,3 +303,28 @@ def test_prefix_mixer_kernel_compiles_for_v5e_at_the_published_widths(mixer, chi
             chip, x, x, x, _sds((H,), jnp.float32), n)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and f"%{mixer}" in text
+
+
+# ---- the EVA decoder's prefix kernel (PR 42) ----------------------------------
+
+
+def test_eva_prefix_kernel_compiles_for_v5e_at_the_published_widths(chip):
+    """EvaByte's attention over a 16384-position prefix of two clips,
+    bfloat16, 32 heads of 128, windows of 2048 and chunks of 16, at the
+    program's tiles: Mosaic accepts it, and the custom call carries the
+    kernel's name, which is how the benchmark's readers find its operations
+    in a device trace (``layer_metrics/_kernels.py``)."""
+    from cst_captioning_tpu.models.eva import eva_spec, head_dim
+    from cst_captioning_tpu.ops import eva_attention
+
+    mc = get_preset("evabyte_8l_eval_beam5").model
+    spec = eva_spec(mc)
+    rows, P, H, d = 2, mc.max_frames, mc.num_attention_heads, head_dim(mc)
+    x = _sds((rows, P, H, d), jnp.bfloat16)
+    pooled = _sds((rows, P // spec.chunk, H, d), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, ks, vs, n: eva_attention.eva_prefill(
+            q, k, v, ks, vs, n, spec, impl="pallas"),
+        chip, x, x, x, pooled, pooled, _sds((rows,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%eva_attn_prefill" in text
