@@ -22,19 +22,35 @@ def device_key(seed: int) -> jax.Array:
     Eager ``jax.random.key(int)`` stages the seed through an implicit
     host->device transfer (vetoed by the sanitizer gate's
     ``jax.transfer_guard("disallow")``); jitted with a static seed, the key
-    materializes on device with no runtime transfer at all — and the jit
-    cache makes per-epoch re-derivation free."""
+    materializes on device with no runtime transfer at all. The seed is one
+    value a process, so static costs one program and every later call is a
+    hit in the jit cache: what changes from epoch to epoch (the epoch, the
+    rollback salt) goes through :func:`device_fold_in` instead."""
     return jax.jit(jax.random.key, static_argnums=0)(seed)
 
 
-def device_fold_in(key: jax.Array, n) -> jax.Array:
-    """``jax.random.fold_in`` with the folded integer compiled in static.
+# one program for every folded integer (device_fold_in says why)
+_fold_in = jax.jit(jax.random.fold_in)
 
-    Eager ``fold_in(key, python_int)`` stages the int through an implicit
-    host->device transfer on every call — once per epoch inside the
-    sanitized RL loop; static-jitted, the constant lives in the (cached)
-    executable. Bit-identical to the eager spelling."""
-    return jax.jit(jax.random.fold_in, static_argnums=1)(key, int(n))
+
+def device_fold_in(key: jax.Array, n: int) -> jax.Array:
+    """``jax.random.fold_in`` of ``n`` in ``[0, 2**32)``, ``n`` traced.
+
+    ``n`` (an epoch, a rollback salt) takes a new value every call, so it is
+    an argument of one compiled program and never a static one: compiled in,
+    every epoch was a program of its own to trace, lower and load on the main
+    thread. It reaches the device as a ``uint32`` scalar by an EXPLICIT
+    ``device_put`` onto the key's own placement: handed to the program as a
+    host value it would be an implicit host->device transfer on every call,
+    once per epoch inside the sanitized RL loop, which
+    ``jax.transfer_guard("disallow")`` vetoes. The key goes through the same
+    ``device_put`` (no copy: it is there already) so that both arguments are
+    committed to that placement whether the caller's key was (a folded key)
+    or not (``device_key``'s), and one cached program serves both.
+    ``fold_in`` converts its data to ``uint32`` itself, so the key is
+    bit-identical to the eager spelling and to the one with ``n`` compiled
+    in."""
+    return _fold_in(*jax.device_put((key, np.uint32(n)), key.sharding))
 
 
 @flax.struct.dataclass

@@ -1510,8 +1510,12 @@ class Trainer:
         # (epoch k uses fold_in(base, k) whether or not the process
         # restarted); a rollback salt re-randomizes it together with the
         # batch order
-        # device_key: eager jax.random.key would stage the seed through
-        # an implicit transfer once per epoch, inside the sanitized loop
+        # the seed is static (device_key: one value a process, a jit-cache
+        # hit every epoch); the salt and the epoch are TRACED arguments of
+        # one fold-in program for all epochs (device_fold_in), uploaded by
+        # an explicit device_put: static, each epoch would compile a program
+        # of its own right here; eager, each would stage its integer
+        # through an implicit transfer inside the sanitized loop
         with obs.span("rl.epoch.keys"):
             base_rng = device_key(cfg.train.seed + 1)
             if self.batcher.salt:
