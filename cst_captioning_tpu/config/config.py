@@ -32,6 +32,12 @@ def _freeze_modalities(m: Mapping[str, int]) -> tuple[tuple[str, int], ...]:
     return tuple((str(k), int(v)) for k, v in m.items())
 
 
+# the decoder kinds ``ModelConfig.decoder`` names (models/captioner.py builds
+# each); every kind but the first is a language-model stack behind a video
+# prefix that runs the evaluation path only
+DECODERS = ("lstm", "latent_moe", "sparse_linear", "eva", "window_moe")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Caption model shape (reference ``model.py::CaptionModel`` capability)."""
@@ -170,12 +176,34 @@ class ModelConfig:
     chunk_size: int = 16
     num_pred_heads: int = 1
     init_std: float = 0.02
+    # decoder kind "window_moe" (models/window_moe.py: a pre-norm residual
+    # stack over a video prefix whose layers attend one of two ways,
+    # ``mixer_types`` says which: "full" is causal grouped-query softmax
+    # attention with ``num_key_value_heads`` key/value heads and rope at
+    # ``rope_theta``; "window" sees the last ``sliding_window`` positions,
+    # its own included, with ``swa_num_key_value_heads`` key/value heads,
+    # rope at ``swa_rope_theta`` and one learned sink a query head in the
+    # softmax's denominator. Keys of ``head_dim``, values of ``v_head_dim``
+    # scaled by ``attention_value_scale``, rope on the first ``int(head_dim *
+    # partial_rotary_factor)`` dimensions. The FFN is latent_moe's: dense in
+    # the layers whose published index (``first_layer_index`` + the held
+    # index) is under ``first_k_dense_replace``, this chip's share of the
+    # routed experts in the others (``n_shared_experts`` 0: no shared branch).
+    # Sizes under the key names of the published config.json
+    # (benchmark/configs/mimo_v2_5_ep16.json); it also reads hidden_size,
+    # num_hidden_layers, intermediate_size, num_attention_heads, rms_norm_eps,
+    # initializer_range and the expert fields above
+    swa_num_key_value_heads: int = 0
+    sliding_window: int = 128
+    partial_rotary_factor: float = 1.0
+    swa_rope_theta: float = 10000.0
+    attention_value_scale: float = 1.0
 
     def __post_init__(self):
-        if self.decoder not in ("lstm", "latent_moe", "sparse_linear", "eva"):
+        if self.decoder not in DECODERS:
             raise ValueError(
-                f"unknown decoder: {self.decoder!r} "
-                "(expected 'lstm', 'latent_moe', 'sparse_linear' or 'eva')"
+                f"unknown decoder: {self.decoder!r} (expected one of "
+                f"{', '.join(repr(d) for d in DECODERS)})"
             )
         object.__setattr__(self, "mixer_types",
                            tuple(str(m) for m in self.mixer_types))

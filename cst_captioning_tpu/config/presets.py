@@ -48,6 +48,21 @@ published widths of EvaByte, eight of its 32 layers (``_EVABYTE_8L``):
                                share one copy of its prefix's summaries and
                                of its last window's exact keys.
 
+Two more run the fifth (``model.decoder = "window_moe"``,
+models/window_moe.py) at the published widths of MiMo-V2.5, its first eleven
+layers as one chip's share of a 16-way expert-parallel deployment
+(``_MIMO_V2_5_EP16``):
+
+12. ``mimo_v2_5_ep16_xe``    — the stack behind the same 16384-slot prefix;
+                               bfloat16 parameters and plain SGD. The
+                               benchmark makes its seeded policy from it
+                               (0 steps).
+13. ``mimo_v2_5_ep16_eval_beam5`` — the same model, beam-5 eval through the
+                               ``Evaluator``, beams on lanes: a clip's beams
+                               share one copy of its prefix's keys, and the
+                               step takes all lanes at once, so the routed
+                               experts walk one list of rows.
+
 Paper CST variant names map onto presets as: XE -> 1/2; CST_GT_None/SCST -> 3;
 CST_MS_SCB -> 4 (with ``rl.baseline="scb"``); WXE is preset 2 with
 ``train.loss="wxe"``.
@@ -323,6 +338,72 @@ def _evabyte_8l_eval_beam5() -> ExperimentConfig:
     )
 
 
+# MiMo-V2.5 (huggingface.co/XiaomiMiMo/MiMo-V2.5, config.json): every width
+# as published; the depth is the published layers 0-10 (hybrid_layer_pattern:
+# full, window x 4, full, window x 5; moe_layer_freq: layer 0 dense, the
+# others routed experts), the first pipeline stage. 16 chips share each layer
+# by expert parallelism: this one holds experts 0-15 of the 256 and 19072 of
+# the 152576 ids. One modality of 16384 patch tokens, the sparse/linear
+# preset's prefix.
+_MIMO_V2_5_EP16 = ModelConfig(
+    decoder="window_moe",
+    vocab_size=19072,
+    modalities=(("patch", 1024),),
+    max_len=30,
+    max_frames=16384,
+    dropout=0.0,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+    hidden_size=4096,
+    num_hidden_layers=11,
+    first_k_dense_replace=1,
+    intermediate_size=16384,
+    moe_intermediate_size=2048,
+    n_routed_experts=256,
+    n_shared_experts=0,
+    num_experts_per_tok=8,
+    routed_scaling_factor=1.0,
+    num_attention_heads=64,
+    num_key_value_heads=4,
+    swa_num_key_value_heads=8,
+    head_dim=192,
+    v_head_dim=128,
+    sliding_window=128,
+    partial_rotary_factor=0.334,
+    rope_theta=10000000.0,
+    swa_rope_theta=10000.0,
+    attention_value_scale=0.707,
+    rms_norm_eps=1e-5,
+    initializer_range=0.02,
+    experts_held=16,
+    expert_share_index=0,
+    mixer_types=("full",) + ("window",) * 4 + ("full",) + ("window",) * 5,
+    published_layers=48,
+    first_layer_index=0,
+)
+
+
+def _mimo_v2_5_ep16_xe() -> ExperimentConfig:
+    return ExperimentConfig(
+        name="mimo_v2_5_ep16_xe",
+        model=_MIMO_V2_5_EP16,
+        data=DataConfig(dataset="msrvtt", batch_size=2),
+        train=TrainConfig(loss="xe", optimizer="sgd", lr=1e-4, epochs=1),
+    )
+
+
+def _mimo_v2_5_ep16_eval_beam5() -> ExperimentConfig:
+    return dataclasses.replace(
+        _mimo_v2_5_ep16_xe(),
+        name="mimo_v2_5_ep16_eval_beam5",
+        # beams on lanes: a clip's prefix keys are read from one copy, and
+        # this kind's step takes all the lanes at once (models/captioner.py
+        # ALL_LANES), so its experts walk one list of lanes x clips rows
+        eval=EvalConfig(beam_size=5, max_len=30, split="test",
+                        beam_impl="lanes", prefill_program=True),
+    )
+
+
 PRESETS = {
     "msvd_xe_meanpool": _msvd_xe_meanpool,
     "msrvtt_xe_attention": _msrvtt_xe_attention,
@@ -335,6 +416,8 @@ PRESETS = {
     "minicpm_sala_8l_eval_beam5": _minicpm_sala_8l_eval_beam5,
     "evabyte_8l_xe": _evabyte_8l_xe,
     "evabyte_8l_eval_beam5": _evabyte_8l_eval_beam5,
+    "mimo_v2_5_ep16_xe": _mimo_v2_5_ep16_xe,
+    "mimo_v2_5_ep16_eval_beam5": _mimo_v2_5_ep16_eval_beam5,
 }
 
 

@@ -236,7 +236,9 @@ def lane_decode_step(model, params, carry, token, enc):
     ``CaptionModel.decode_step``; "pallas" calls the fused decode-step
     kernel (ops/decode_pallas.py — attention + LSTM stack + out_proj in one
     launch, weights resident in VMEM across the row grid). Decode is
-    inference-only, so the kernel needs no VJP.
+    inference-only, so the kernel needs no VJP. A decoder kind that takes
+    its step for all lanes at once (``models.captioner.ALL_LANES``) is
+    called so and not vmapped.
     """
     if getattr(model.cfg, "decode_impl", "xla") == "pallas":
         from cst_captioning_tpu.ops.decode_pallas import fused_decode_step
@@ -247,7 +249,14 @@ def lane_decode_step(model, params, carry, token, enc):
             num_layers=model.cfg.num_layers,
         )
 
-    from cst_captioning_tpu.models.captioner import CaptionModel
+    from cst_captioning_tpu.models.captioner import ALL_LANES, CaptionModel
+
+    if model.cfg.decoder in ALL_LANES:
+        # the kind's own step over all lanes at once: G x B rows as one list
+        # through its routed experts, attention grouped by clip
+        return model.apply(
+            params, carry, token, enc, method=CaptionModel.decode_lanes
+        )
 
     def one_lane(carry_k, token_k):
         return model.apply(
@@ -265,14 +274,17 @@ def carry_tally(carry):
     sparse/linear decoder's (models/sparse_linear.py: keys its sparse layers'
     queries saw and attended to) and of the EVA decoder's (models/eva.py:
     exact keys and summaries a query attended to, windows a caption
-    entered), summed over every axis but its last two;
-    ``()`` for a carry that counts nothing (the LSTM's), which adds no leaf
+    entered) and of the window/full decoder's (models/window_moe.py: pairs
+    a window and a full layer attended), summed over every axis but its last
+    two; ``()`` for a carry that counts nothing (the LSTM's), which adds no leaf
     to the loop's state."""
-    for leaf in ("routed", "counted"):
-        counts = getattr(carry, leaf, None)
-        if counts is not None:
-            return counts.sum(axis=tuple(range(counts.ndim - 2)))
-    return ()
+    found = [counts.sum(axis=tuple(range(counts.ndim - 2)))
+             for counts in (getattr(carry, leaf, None)
+                            for leaf in ("routed", "counted"))
+             if counts is not None]
+    # one leaf as it is; both (models/window_moe.py: routed experts and
+    # attended pairs) as the pair (routed, counted)
+    return found[0] if len(found) == 1 else tuple(found)
 
 
 def pcast_varying(tree, axes: tuple[str, ...]):

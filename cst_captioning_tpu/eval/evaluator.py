@@ -161,8 +161,9 @@ class Evaluator:
             )[0]
         elif W > 1:
             # a language-model decoder's search also returns what it counted
-            # (decoding.common.carry_tally: routed assignments, or keys the
-            # sparse or EVA layers attended to); on a mesh the count would be a
+            # (decoding.common.carry_tally: routed assignments, keys the
+            # sparse or EVA layers attended to, or pairs beside assignments
+            # for the window/full decoder); on a mesh the count would be a
             # shard's, so there the decode returns tokens alone
             self._counts = model.cfg.decoder != "lstm" and mesh is None
             pick = slice(0, None, 2) if self._counts else 0
@@ -259,7 +260,11 @@ class Evaluator:
         ``decode.window_bytes`` (exact keys and values: the prefix's last
         window's once a clip, a caption's a lane) and
         ``decode.summary_bytes`` (chunk summaries: the prefix's once a clip,
-        those a caption makes a lane)."""
+        those a caption makes a lane); for the window/full decoder
+        ``decode.prefix_key_bytes`` (its full layers' prefix keys and values,
+        once a clip) and ``decode.window_bytes`` (its window layers' tail
+        slices once a clip and their caption keys a lane), the full layers'
+        caption keys a lane being the rest of ``decode.cache_bytes``."""
         if not obs.enabled() or self._observed:
             return
         self._observed = True
@@ -296,6 +301,20 @@ class Evaluator:
             obs.gauge("decode.summary_bytes").set(pooled)
             obs.gauge("decode.cache_bytes").set(near + pooled)
             return
+        if kind == "window_moe":
+            shared = 1 if self.cfg.beam_impl == "lanes" else lanes
+            windowed = [m == "window" for m in self.model.cfg.mixer_types]
+            of = lambda leaves, want: size(  # noqa: E731
+                [a for a, w in zip(leaves, windowed) if w == want])
+            clip = lambda want: sum(of(x, want) for x in enc.memory)  # noqa: E731
+            lane = lambda want: of(enc.carry.k, want) + of(enc.carry.v, want)  # noqa: E731
+            prefix = shared * clip(False)
+            near = shared * clip(True) + lanes * lane(True)
+            obs.gauge("decode.prefix_key_bytes").set(prefix)
+            obs.gauge("decode.window_bytes").set(near)
+            obs.gauge("decode.cache_bytes").set(prefix + near + lanes * lane(False))
+            obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
+            return
         obs.gauge("decode.cache_bytes").set(lanes * size(enc.carry))
         if kind == "latent_moe":
             obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
@@ -307,12 +326,27 @@ class Evaluator:
         held expert of each layer took (histogram ``moe.expert_rows``), or
         the sparse layers' ``sparse.keys_visible`` / ``sparse.keys_selected``
         / ``sparse.dense_fallback_queries``, or the EVA layers'
-        ``eva.keys_exact`` / ``eva.keys_summary`` / ``eva.window_crossings``."""
+        ``eva.keys_exact`` / ``eva.keys_summary`` / ``eva.window_crossings``;
+        the window/full decoder's expert counts and, beside them, the
+        query-key pairs its layers attended, ``attn.pairs_window`` /
+        ``attn.pairs_full``, and what plain causal attention in every layer
+        would have, ``attn.pairs_causal``."""
         if not self._tallies:
             return
-        tally = np.asarray(jax.device_get(self._tallies.pop(0)))
+        tally = jax.device_get(self._tallies.pop(0))
         if not obs.enabled():
             return
+        if self.model.cfg.decoder == "window_moe":
+            # (routed, [1, 2]): a query's pairs in one window layer and in
+            # one full layer, which are plain causal attention's a layer
+            tally, pairs = tally
+            near, whole = np.asarray(pairs).sum(axis=0, dtype=np.float64)
+            kinds = self.model.cfg.mixer_types
+            windows = sum(m == "window" for m in kinds)
+            obs.counter("attn.pairs_window").inc(float(near) * windows)
+            obs.counter("attn.pairs_full").inc(float(whole) * (len(kinds) - windows))
+            obs.counter("attn.pairs_causal").inc(float(whole) * len(kinds))
+        tally = np.asarray(tally)
         if self.model.cfg.decoder == "sparse_linear":
             # [sparse layers, 3]: a key/value group a query, keys seen, keys
             # attended to, queries under the dense length

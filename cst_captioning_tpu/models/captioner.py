@@ -27,6 +27,7 @@ from cst_captioning_tpu.models.decoder import Carry, DecoderCell
 from cst_captioning_tpu.models.eva import EvaDecoder
 from cst_captioning_tpu.models.latent_moe import LatentMoEDecoder
 from cst_captioning_tpu.models.sparse_linear import SparseLinearDecoder
+from cst_captioning_tpu.models.window_moe import WindowMoEDecoder
 from cst_captioning_tpu.models.encoders import (
     MeanPoolEncoder,
     TemporalAttentionEncoder,
@@ -215,6 +216,11 @@ def _rows_by_token(tokens, rows, table):
     return summed.astype(table.dtype)
 
 
+# the decoder kinds whose step takes all lanes at once (``decode_lanes``);
+# decoding/common.py ``lane_decode_step`` vmaps ``decode_step`` for the others
+ALL_LANES = ("window_moe",)
+
+
 class CaptionModel(nn.Module):
     cfg: ModelConfig
 
@@ -235,6 +241,11 @@ class CaptionModel(nn.Module):
             # the fourth (models/eva.py): EVA attention over a long video
             # prefix, exact keys in a window and a summary a chunk before it
             self.decoder = EvaDecoder(cfg, name="decoder")
+            return
+        if cfg.decoder == "window_moe":
+            # the fifth (models/window_moe.py): window and full grouped-query
+            # attention over a long video prefix, routed experts behind it
+            self.decoder = WindowMoEDecoder(cfg, name="decoder")
             return
         if cfg.encoder == "meanpool":
             self.encoder = MeanPoolEncoder(cfg, name="encoder")
@@ -279,6 +290,12 @@ class CaptionModel(nn.Module):
             # are the clip's, held once; the carry is a lane's
             (summaries, near), n, carry = self.decoder.prefill(feats, masks)
             return EncoderOutput(summaries, near, self._live(n), carry)
+        if self.cfg.decoder == "window_moe":
+            # likewise: every layer's prefix keys and values (``memory``: a
+            # full layer's whole, a window layer's last window) from where
+            # each clip's window slice starts (``memory_proj``)
+            (keys, values, start), n, carry = self.decoder.prefill(feats, masks)
+            return EncoderOutput((keys, values), start, self._live(n), carry)
         memory, mmask = self.encoder(feats, masks)
         memory_proj = self.cell.project_memory(memory)
         ctx0 = masked_mean(memory, mmask, axis=1, axis_name=self.cfg.seq_axis)
@@ -313,9 +330,24 @@ class CaptionModel(nn.Module):
             return self.decoder.step(
                 carry, token, (enc.memory, enc.memory_proj),
                 enc.memory_mask.sum(axis=-1).astype(jnp.int32))
+        if self.cfg.decoder == "window_moe":
+            return self.decoder.step(
+                carry, token, (*enc.memory, enc.memory_proj),
+                enc.memory_mask.sum(axis=-1).astype(jnp.int32))
         return self.cell(
             carry, token, enc.memory, enc.memory_proj, enc.memory_mask, deterministic
         )
+
+    def decode_lanes(self, carry, token: jnp.ndarray, enc: EncoderOutput):
+        """One step for every lane at once, of a decoder kind that takes it
+        so (``ALL_LANES``): carry leaves ``[G, B, ...]``, token [G, B], the
+        encoder output once a clip -> (carry, logits [G, B, V]). Where
+        :meth:`decode_step` vmapped a lane would run the rows of each lane
+        apart, this runs ``G x B`` rows as one list (the routed experts'
+        walk) and attends grouped by clip over the keys the lanes share."""
+        return self.decoder.step_lanes(
+            carry, token, (*enc.memory, enc.memory_proj),
+            enc.memory_mask.sum(axis=-1).astype(jnp.int32))
 
     # ---- teacher forcing -----------------------------------------------------
 

@@ -36,7 +36,7 @@ read from (Kimi-K2 / DeepSeek-V3 family; benchmark/configs/kimi_k2_ep32.json).
   (``expert_share_index`` says which), routes over all of them, normalises
   over all chosen, and computes the chosen experts it holds for every token
   routed to them: tokens are sorted by held expert and each expert walks its
-  own rows in blocks, as many as it has (``_held_experts``), so there is no
+  own rows in blocks, as many as it has (models/experts.py), so there is no
   capacity and no dropped token. What the absent experts would add is left
   out and the partial result goes on; nothing stands in for the other chips.
 
@@ -55,6 +55,14 @@ import jax
 import jax.numpy as jnp
 
 from cst_captioning_tpu.config.config import BOS_ID, ModelConfig
+from cst_captioning_tpu.models.experts import (  # noqa: F401  (re-exported)
+    check_share,
+    expert_block_rows,
+    expert_ffn,
+    expert_shapes,
+    gated,
+    route,
+)
 
 
 @flax.struct.dataclass
@@ -121,79 +129,6 @@ def rms_norm(x, weight, eps: float):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def _gated(x, gate, up, down):
-    dt = x.dtype
-    return (jax.nn.silu(x @ gate.astype(dt)) * (x @ up.astype(dt))) @ down.astype(dt)
-
-
-def route(x, gate, bias, k: int, scale: float):
-    """Float32 sigmoid router over every expert: -> (chosen [N, k] expert
-    ids, weights [N, k] float32). ``bias`` moves the choice, never the
-    weights; the weights are normalised over all ``k`` chosen."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x, gate.astype(x.dtype), preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), k)
-    picked = jnp.take_along_axis(s, chosen, axis=-1)
-    return chosen, picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
-
-
-def expert_block_rows(n_tokens: int, k: int, n_experts: int) -> int:
-    """Rows an expert walks at a time: the smallest multiple of 128 that
-    holds twice the rows an expert expects under uniform routing, at most
-    1024 (and never more than the tokens there are, rounded up to 8)."""
-    expected = n_tokens * k / max(n_experts, 1)
-    rows = min(-(-int(2 * expected) // 128) * 128 or 128, 1024)
-    return min(rows, -(-n_tokens // 8) * 8)
-
-
-def _held_experts(x, chosen, weights, live, gate_w, up_w, down_w, lo: int,
-                  n_experts: int, differentiable: bool):
-    """The held experts' part of ``sum_e w_e expert_e(x)`` for ``x [N, h]``.
-
-    Tokens are sorted by held expert (a stable argsort a column) and every
-    held expert walks the rows routed to it in blocks of
-    :func:`expert_block_rows`, as many blocks as it has rows: a
-    ``fori_loop`` with a traced trip count, so an expert nobody chose costs
-    nothing and one everybody chose takes all of them — no capacity, no
-    dropped token. ``differentiable`` (teacher forcing, which a loss may
-    differentiate) spells the same walk as a static number of blocks under
-    ``lax.cond``, since a loop with a traced trip count has no transpose.
-    -> (out [N, h] float32, tally [N, held + 1] int32: a token's rows on
-    each held expert, and its assignments over all experts)."""
-    N, k = chosen.shape
-    held = gate_w.shape[0]
-    local = chosen - lo
-    onehot = (local[:, :, None] == jnp.arange(held)) & live[:, None, None]
-    hit = onehot.any(axis=1)                                       # [N, held]
-    wt = (onehot * weights[:, :, None]).sum(axis=1)                # [N, held]
-    counts = hit.sum(axis=0).astype(jnp.int32)
-    rows_a_block = expert_block_rows(N, k, n_experts)
-    order = jnp.argsort(jnp.logical_not(hit), axis=0, stable=True)  # hits first
-    blocks = -(-N // rows_a_block)
-    order = jnp.pad(order, ((0, blocks * rows_a_block - N), (0, 0)))
-    # the experts' weighted outputs are summed in float32 on purpose
-    out = jnp.zeros((N, x.shape[-1]), jnp.float32)  # graftlint: disable=GL005
-    for e in range(held):
-        def block(b, acc, e=e):
-            start = b * rows_a_block
-            rows = jax.lax.dynamic_slice_in_dim(order[:, e], start, rows_a_block)
-            ok = start + jnp.arange(rows_a_block) < counts[e]
-            y = _gated(x[rows], gate_w[e], up_w[e], down_w[e])
-            y = y.astype(jnp.float32) * jnp.where(ok, wt[rows, e], 0.0)[:, None]
-            return acc.at[jnp.where(ok, rows, N)].add(y, mode="drop")
-
-        n_blocks = -(-counts[e] // rows_a_block)
-        if differentiable:
-            for b in range(blocks):
-                out = jax.lax.cond(b < n_blocks, lambda a, b=b: block(b, a),
-                                   lambda a: a, out)
-        else:
-            out = jax.lax.fori_loop(0, n_blocks, block, out)
-    assigned = jnp.where(live, k, 0).astype(jnp.int32)
-    return out, jnp.concatenate([hit.astype(jnp.int32), assigned[:, None]], 1)
-
-
 class LatentMoELayer(nn.Module):
     """One block: latent attention, then a dense or a routed-expert FFN."""
 
@@ -224,15 +159,7 @@ class LatentMoELayer(nn.Module):
             shapes.update(gate_proj=(w, (h, m)), up_proj=(w, (h, m)),
                           down_proj=(w, (m, h)))
         else:
-            m, held = c.moe_intermediate_size, c.experts_held
-            ms = m * c.n_shared_experts
-            shapes.update(
-                gate=(w, (h, c.n_routed_experts)),
-                experts_gate_proj=(w, (held, h, m)),
-                experts_up_proj=(w, (held, h, m)),
-                experts_down_proj=(w, (held, m, h)),
-                shared_gate_proj=(w, (h, ms)), shared_up_proj=(w, (h, ms)),
-                shared_down_proj=(w, (ms, h)))
+            shapes.update(expert_shapes(c, w))
         self.p = {name: self.param(name, init, shape, pd)
                   for name, (init, shape) in shapes.items()}
         if not self.dense:
@@ -244,19 +171,10 @@ class LatentMoELayer(nn.Module):
 
     def ffn(self, x, live, differentiable: bool):
         """x [N, h], live [N] -> (out [N, h], tally [N, held + 1] or None)."""
-        c, p = self.cfg, self.p
+        p = self.p
         if self.dense:
-            return _gated(x, p["gate_proj"], p["up_proj"], p["down_proj"]), None
-        chosen, weights = route(x, p["gate"], self.bias, c.num_experts_per_tok,
-                                c.routed_scaling_factor)
-        routed, tally = _held_experts(
-            x, chosen, weights, live, p["experts_gate_proj"],
-            p["experts_up_proj"], p["experts_down_proj"],
-            c.expert_share_index * c.experts_held, c.n_routed_experts,
-            differentiable)
-        shared = _gated(x, p["shared_gate_proj"], p["shared_up_proj"],
-                        p["shared_down_proj"])
-        return (shared.astype(jnp.float32) + routed).astype(x.dtype), tally
+            return gated(x, p["gate_proj"], p["up_proj"], p["down_proj"]), None
+        return expert_ffn(self.cfg, p, self.bias, x, live, differentiable)
 
     # ---- attention -------------------------------------------------------------
 
@@ -368,16 +286,7 @@ class LatentMoEDecoder(nn.Module):
 
     def setup(self):
         c = self.cfg
-        held, share = c.experts_held, c.expert_share_index
-        if not 0 < held <= c.n_routed_experts or not (
-                0 <= share * held <= c.n_routed_experts - held):
-            raise ValueError(
-                f"experts_held {held} at expert_share_index {share} is no "
-                f"share of n_routed_experts {c.n_routed_experts}")
-        if not 0 < c.num_experts_per_tok <= c.n_routed_experts:
-            raise ValueError(
-                f"num_experts_per_tok {c.num_experts_per_tok} must be in "
-                f"1..n_routed_experts {c.n_routed_experts}")
+        check_share(c)
         if c.qk_rope_head_dim % 2 or c.num_hidden_layers < 1:
             raise ValueError("qk_rope_head_dim must be even and "
                              "num_hidden_layers >= 1")

@@ -224,6 +224,34 @@ def eva_per_tok_flops(mc, context: int) -> float:
         2 * 4 * h * h + 2 * 3 * h * m + 2 * 2 * h * keys))
 
 
+# ---- the window/full-attention, routed-expert decoder (models/window_moe.py) --
+
+
+def window_moe_per_tok_flops(mc, context: int) -> float:
+    """Matmul FLOPs one token costs in the ``decoder="window_moe"`` stack at
+    ``context`` positions seen (its own the last), head excluded: a layer's
+    four projections (keys of ``head_dim``, values of ``v_head_dim``, the
+    key/value head count of its kind), its scores and values over the keys it
+    attends (all of them in a full layer, the last ``sliding_window`` in a
+    window layer), and its FFN: dense, or the router and the held experts by
+    expectation (``latent_moe_per_tok_flops``'s convention)."""
+    h, H, dk, dv = mc.hidden_size, mc.num_attention_heads, mc.head_dim, mc.v_head_dim
+    held = mc.num_experts_per_tok * mc.experts_held / max(mc.n_routed_experts, 1)
+    total = 0.0
+    for i, kind in enumerate(mc.mixer_types):
+        window = kind == "window"
+        G = mc.swa_num_key_value_heads if window else mc.num_key_value_heads
+        keys = min(context, mc.sliding_window) if window else context
+        total += 2 * h * (H * dk + G * (dk + dv)) + 2 * H * dv * h \
+            + 2 * H * (dk + dv) * keys
+        if mc.first_layer_index + i < mc.first_k_dense_replace:
+            total += 2 * 3 * h * mc.intermediate_size
+        else:
+            total += 2 * h * mc.n_routed_experts \
+                + 2 * 3 * h * mc.moe_intermediate_size * held
+    return float(total)
+
+
 def model_xe_flops_per_row(mc) -> float:
     """Matmul FLOPs of one teacher-forced XE row (forward + backward as 3x
     forward) of the model ``mc`` (a ``ModelConfig``) describes, by its
@@ -231,7 +259,8 @@ def model_xe_flops_per_row(mc) -> float:
     feat_dims = tuple(d for _, d in mc.modalities)
     per_tok = {"latent_moe": latent_moe_per_tok_flops,
                "sparse_linear": sparse_linear_per_tok_flops,
-               "eva": eva_per_tok_flops}.get(mc.decoder)
+               "eva": eva_per_tok_flops,
+               "window_moe": window_moe_per_tok_flops}.get(mc.decoder)
     if per_tok is not None:
         n_prefix = len(feat_dims) * mc.max_frames
         fwd = 2.0 * mc.max_frames * sum(feat_dims) * mc.hidden_size

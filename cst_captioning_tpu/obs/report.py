@@ -341,6 +341,13 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             "keys_exact": counters.get("eva.keys_exact", 0.0),
             "keys_summary": counters.get("eva.keys_summary", 0.0),
             "window_crossings": counters.get("eva.window_crossings", 0.0),
+            # the window/full decoder (models/window_moe.py): its full layers'
+            # prefix keys beside ``window_bytes``, and the query-key pairs
+            # its layers attended against plain causal attention's
+            "prefix_key_bytes": gauges.get("decode.prefix_key_bytes"),
+            "pairs_window": counters.get("attn.pairs_window", 0.0),
+            "pairs_full": counters.get("attn.pairs_full", 0.0),
+            "pairs_causal": counters.get("attn.pairs_causal", 0.0),
         }
 
     # serving section (serving/engine.py): request funnel counters + the
@@ -734,11 +741,27 @@ def render_report(report: dict[str, Any]) -> str:
                 f"({100.0 * ds['keys_selected'] / ds['keys_visible']:.2f}%); "
                 f"{int(ds['dense_fallback_queries'])} under the dense length"
             )
-        if ds.get("window_bytes") is not None:
+        if ds.get("prefix_key_bytes") is not None:
+            lines.append(
+                f"of it the full layers' prefix keys and values "
+                f"{ds['prefix_key_bytes'] / 2**20:.1f} MiB, the window "
+                f"layers' last window and caption keys "
+                f"{(ds['window_bytes'] or 0) / 2**20:.1f} MiB"
+            )
+        elif ds.get("window_bytes") is not None:
             lines.append(
                 f"of it exact keys and values of a window "
                 f"{ds['window_bytes'] / 2**20:.1f} MiB, chunk summaries "
                 f"{(ds['summary_bytes'] or 0) / 2**20:.1f} MiB"
+            )
+        if ds.get("pairs_causal"):
+            pairs = ds["pairs_window"] + ds["pairs_full"]
+            lines.append(
+                f"window and full layers: queries attended "
+                f"{int(ds['pairs_window'])} pairs inside a window and "
+                f"{int(ds['pairs_full'])} under the diagonal, "
+                f"{100.0 * pairs / ds['pairs_causal']:.2f}% of plain causal "
+                f"attention's {int(ds['pairs_causal'])} in every layer"
             )
         if ds.get("keys_exact"):
             keys = ds["keys_exact"] + ds["keys_summary"]

@@ -328,3 +328,41 @@ def test_eva_prefix_kernel_compiles_for_v5e_at_the_published_widths(chip):
         chip, x, x, x, pooled, pooled, _sds((rows,), jnp.int32))
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%eva_attn_prefill" in text
+
+
+# ---- the window/full decoder's two prefix kernels (PR 45) ---------------------
+
+
+@pytest.mark.parametrize("kernel", ["window_attn_prefill", "full_attn_prefill"])
+def test_window_and_full_prefix_kernels_compile_for_v5e_at_the_published_widths(
+        kernel, chip):
+    """MiMo-V2.5's two attention kinds over a 16384-position prefix of two
+    clips, bfloat16, 64 query heads over 8 (window) or 4 (full) key/value
+    heads, keys of 192 (not a multiple of the 128 lanes: head-major blocks
+    whose last axis is the whole 192) and values of 128, at the program's
+    tiles: Mosaic accepts each, and the custom call carries the kernel's
+    name, which is how the benchmark's readers tell the two apart in a device
+    trace (``layer_metrics/_kernels.py``)."""
+    from cst_captioning_tpu.ops import window_attention
+
+    mc = get_preset("mimo_v2_5_ep16_eval_beam5").model
+    rows, P, H = 2, mc.max_frames, mc.num_attention_heads
+    dk, dv, bf16 = mc.head_dim, mc.v_head_dim, jnp.bfloat16
+    assert (dk, dv) == (192, 128)
+    n = _sds((rows,), jnp.int32)
+    if kernel == "window_attn_prefill":
+        G = mc.swa_num_key_value_heads
+        compiled = _compile(
+            lambda q, k, v, s, n: window_attention.window_prefill(
+                q, k, v, s, n, mc.sliding_window, impl="pallas"),
+            chip, _sds((rows, P, H, dk), bf16), _sds((rows, P, G, dk), bf16),
+            _sds((rows, P, G, dv), bf16), _sds((H,), jnp.float32), n)
+    else:
+        G = mc.num_key_value_heads
+        compiled = _compile(
+            lambda q, k, v, n: window_attention.full_prefill(
+                q, k, v, n, impl="pallas"),
+            chip, _sds((rows, P, H, dk), bf16), _sds((rows, P, G, dk), bf16),
+            _sds((rows, P, G, dv), bf16), n)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{kernel}" in text
