@@ -26,8 +26,19 @@ attribute the main thread pins.
 
 Where a batch's bytes come from and go to: with ``data.cache_features`` the
 features come out of the dataset's contiguous table in one gather a stream,
-else out of h5 a row at a time; they go into arrays the ``Batch`` owns, or,
-when ``epoch`` was handed a staging ring, into the ring's next slot. Who may
+else out of h5 a row at a time; labels, mask and weights come out of the
+dataset's label table (``CaptionDataset.label_table``, built once for the
+batcher's ``max_len`` by the thread that collates the first batch) in one
+gather each, on either path. A feature gather of at least two blocks of
+``_BLOCK_BYTES`` is cut into row blocks that run on the process's one gather
+pool (``_gather_pool``: made on first use, owned by this module, as wide as
+a share of the cores the process may use); the collating thread waits for
+every block inside its one ``data.collate`` span, so the pool's threads open
+no span of their own. A smaller one (a batch under 2 x 16 MiB a stream,
+every frame mask, any gather on a machine too small for a pool) is one call
+on the collating thread, as every label gather is. The bytes go into arrays
+the ``Batch`` owns, or, when ``epoch`` was handed a staging ring, into the
+ring's next slot. Who may
 keep a ``Batch``'s arrays: whoever drew it by plain iteration (``iter``,
 ``epoch()``), for good; a ``Batch`` drawn with ``epoch(staging=ring)`` only
 until the next one is drawn: the ring then owns them (data/prefetch.py).
@@ -37,13 +48,15 @@ Which bytes a batch holds never depends on any of this.
 from __future__ import annotations
 
 import itertools
+import os
+import threading
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 from dataclasses import dataclass
 from typing import NamedTuple
 
 import numpy as np
 
 from cst_captioning_tpu import obs
-from cst_captioning_tpu.config.config import EOS_ID, PAD_ID
 from cst_captioning_tpu.data.dataset import CaptionDataset
 
 
@@ -62,15 +75,63 @@ class Batch:
         return int(self.valid.sum())
 
 
-def encode_label_row(caption_ids: list[int], max_len: int) -> tuple[np.ndarray, np.ndarray]:
-    """ids (no specials) -> (labels [T], mask [T]) with EOS and PAD=0 padding."""
-    row = np.full((max_len,), PAD_ID, dtype=np.int32)
-    m = np.zeros((max_len,), dtype=np.float32)
-    toks = caption_ids[: max_len - 1]          # reserve one slot for EOS
-    row[: len(toks)] = toks
-    row[len(toks)] = EOS_ID
-    m[: len(toks) + 1] = 1.0
-    return row, m
+# No row block of a pooled gather is smaller than this, and a gather under
+# two of them is one call on the collating thread: under it a thread's
+# hand-over costs what its share of the copy saves
+_BLOCK_BYTES = 16 << 20
+# past eight threads the copy is bound by the host's memory, not by threads
+_MAX_POOL_WIDTH = 8
+
+_pool: ThreadPoolExecutor | None = None
+_pool_width = 0     # 0: not looked yet; 1: this machine gets no pool
+_pool_lock = threading.Lock()
+
+
+def _gather_pool() -> tuple[ThreadPoolExecutor | None, int]:
+    """(the process's gather pool, its width), made on the first call: half
+    the cores the process may use, at most ``_MAX_POOL_WIDTH``; (None, 1)
+    where that leaves one thread."""
+    global _pool, _pool_width
+    with _pool_lock:
+        if not _pool_width:
+            _pool_width = min(_MAX_POOL_WIDTH,
+                              max(1, len(os.sched_getaffinity(0)) // 2))
+            if _pool_width > 1:
+                _pool = ThreadPoolExecutor(_pool_width,
+                                           thread_name_prefix="collate.gather")
+        return _pool, _pool_width
+
+
+def _gather(table: np.ndarray, rows: np.ndarray, out: np.ndarray) -> list[Future]:
+    """``out[b] = table[rows[b]]``: begun over row blocks on the gather pool
+    (-> the blocks' futures, for :func:`_finish`), or, under two blocks' bytes
+    or without a pool, done in one call here (-> [])."""
+    # mode="clip", not the default "raise": with out= the default gathers
+    # into a temporary first and copies it over (PERF.md section 6, PR 24);
+    # rows index the table's own rows, never out of range
+    pool = None
+    if out.nbytes >= 2 * _BLOCK_BYTES:
+        pool, width = _gather_pool()
+    if pool is None:
+        np.take(table, rows, axis=0, out=out, mode="clip")
+        return []
+    obs.gauge("data.collate.pool_width").set(width)
+    row_bytes = out.nbytes // len(rows)
+    step = -(-_BLOCK_BYTES // row_bytes)     # rounded up: no block is under
+    # a block is rows lo:hi of out: contiguous, so each call writes in place
+    return [pool.submit(np.take, table, rows[lo : lo + step], 0,
+                        out[lo : lo + step], "clip")
+            for lo in range(0, len(rows), step)]
+
+
+def _finish(blocks: list[Future]) -> None:
+    """Wait for every block (none may still be writing when the caller goes
+    on, or raises), then raise the first one's error, if any."""
+    if blocks:
+        wait(blocks)
+        obs.counter("data.collate.blocks").inc(len(blocks))
+        for block in blocks:
+            block.result()
 
 
 class Batcher:
@@ -218,38 +279,34 @@ class Batcher:
                 obs.counter("data.collate.fresh").inc()
             feats, fmasks = dict(slot["feats"]), dict(slot["fmasks"])
             labels, mask, weights = slot["labels"], slot["mask"], slot["weights"]
-            labels.fill(PAD_ID)
-            mask.fill(0.0)
-            weights.fill(1.0)
 
-            rows = np.fromiter((ri for ri, _ in items), np.intp, len(items))
+            rows, cis = np.ascontiguousarray(
+                np.array(items, np.intp).reshape(-1, 2).T)
+            video_ids = [self.ds.video_ids[ri] for ri in rows.tolist()]
             tables = self.ds.feature_tables(rows)
             if tables is not None:
-                # one gather a stream, in one call that releases the GIL.
-                # mode="clip", not the default "raise": with out= the default
-                # gathers into a temporary first and copies it over (PERF.md
-                # section 6, PR 24); rows are record indices, never out of range
+                # one gather a stream, a large one over row blocks on the pool
+                blocks = []
                 for n, (f, fm) in tables.items():
-                    np.take(f, rows, axis=0, out=feats[n], mode="clip")
-                    np.take(fm, rows, axis=0, out=fmasks[n], mode="clip")
-            video_ids = []
-            # memoize per-video h5 reads within the batch: seq_per_vid>1 and
-            # wrap-padding repeat videos
-            read: dict[str, dict] = {}
-            for b, (ri, ci) in enumerate(items):
-                rec = self.ds.records[ri]
-                video_ids.append(rec.video_id)
-                if tables is None:
-                    if rec.video_id not in read:
-                        read[rec.video_id] = self.ds.features_for(rec.video_id)
-                    for n, (f, fm) in read[rec.video_id].items():
+                    blocks += _gather(f, rows, feats[n])
+                    blocks += _gather(fm, rows, fmasks[n])
+                _finish(blocks)
+            else:
+                # memoize per-video h5 reads within the batch: seq_per_vid>1
+                # and wrap-padding repeat videos
+                read: dict[str, dict] = {}
+                for b, vid in enumerate(video_ids):
+                    if vid not in read:
+                        read[vid] = self.ds.features_for(vid)
+                    for n, (f, fm) in read[vid].items():
                         feats[n][b] = f
                         fmasks[n][b] = fm
-                if rec.caption_ids:
-                    ci = min(ci, len(rec.caption_ids) - 1)
-                    labels[b], mask[b] = encode_label_row(rec.caption_ids[ci], T)
-                    if rec.weights:
-                        weights[b] = rec.weights[ci]
+            # a caption index past a record's last caption reads the last
+            lt = self.ds.label_table(T)
+            at = lt.first[rows] + np.minimum(cis, lt.ncap[rows] - 1)
+            for table, out in ((lt.labels, labels), (lt.mask, mask),
+                               (lt.weights, weights)):
+                np.take(table, at, axis=0, out=out, mode="clip")
             return Batch(
                 feats=feats,
                 feat_masks=fmasks,

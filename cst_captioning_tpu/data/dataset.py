@@ -12,6 +12,14 @@ schema we own (the reference's exact h5 key names were unverifiable, §0):
 
 All feature arrays are padded/truncated to ``max_frames`` on read and carry a
 frame-validity mask, so every batch has static shapes for XLA.
+
+What a dataset holds in host memory beside its records: with
+``cache_features`` one contiguous feature table a stream (n_videos *
+max_frames * sum(dims) * 4 bytes, filled a row at a time as rows are first
+asked for), and, for every ``max_len`` a batcher has asked for, the label
+table (:meth:`CaptionDataset.label_table`: n_captions * (max_len * 8 + 4)
+bytes, 31 MB for MSR-VTT's 130 k captions at 30, built whole on first use by
+the thread that asks). The dataset owns both; what it hands out is read-only.
 """
 
 from __future__ import annotations
@@ -19,9 +27,11 @@ from __future__ import annotations
 import json
 import os
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
+from cst_captioning_tpu.config.config import EOS_ID, PAD_ID
 from cst_captioning_tpu.data.vocab import Vocab
 
 try:
@@ -86,6 +96,28 @@ def _read_only(a: np.ndarray) -> np.ndarray:
     return v
 
 
+def encode_label_row(caption_ids: list[int], max_len: int) -> tuple[np.ndarray, np.ndarray]:
+    """ids (no specials) -> (labels [T], mask [T]) with EOS and PAD=0 padding."""
+    row = np.full((max_len,), PAD_ID, dtype=np.int32)
+    m = np.zeros((max_len,), dtype=np.float32)
+    toks = caption_ids[: max_len - 1]          # reserve one slot for EOS
+    row[: len(toks)] = toks
+    row[len(toks)] = EOS_ID
+    m[: len(toks) + 1] = 1.0
+    return row, m
+
+
+class LabelTable(NamedTuple):
+    """Every caption of every record as a batch row, read-only: the row of
+    caption ``ci`` of record ``ri`` is ``first[ri] + min(ci, ncap[ri] - 1)``."""
+
+    labels: np.ndarray      # [C, T] int32: word ids + EOS, then PAD
+    mask: np.ndarray        # [C, T] float32: 1 on real tokens incl. EOS
+    weights: np.ndarray     # [C]    float32: WXE consensus weights
+    first: np.ndarray       # [n_videos] intp: a record's first row
+    ncap: np.ndarray        # [n_videos] intp: its rows (at least one)
+
+
 class CaptionDataset:
     """Videos of one split with their features, captions, and reward pools."""
 
@@ -126,13 +158,14 @@ class CaptionDataset:
             for name, path in feature_files.items()
         }
         self.max_frames = max_frames
+        self.video_ids = [r.video_id for r in self.records]  # by record index
         self._gts_pool: dict[str, list[str]] | None = None
+        self._label_tables: dict[int, LabelTable] = {}  # by max_len
         # opt-in host-RAM feature cache (DataConfig.cache_features): h5 reads
         # are the host hot path on repeat epochs — with the cache, each
         # video's padded features are read once, into its row of one
         # contiguous table a stream, and every later batch is one gather a
-        # stream (Batcher._collate). Memory = n_videos * max_frames *
-        # sum(dims) * 4 bytes, touched a row at a time as rows are filled
+        # stream (Batcher._collate); the module docstring has its memory
         self._tables: dict[str, tuple[np.ndarray, np.ndarray]] | None = None
         if cache_features:
             n = len(self.records)
@@ -201,6 +234,29 @@ class CaptionDataset:
             tables = self.feature_tables(np.array([ri]))
             return {name: (f[ri], m[ri]) for name, (f, m) in tables.items()}
         return {name: store.get(video_id) for name, store in self.stores.items()}
+
+    def label_table(self, max_len: int) -> LabelTable:
+        """What :func:`encode_label_row` gives for every caption of every
+        record, with the captions' consensus weights, built on the first call
+        for a ``max_len`` and kept (records are immutable post-init). A
+        record without captions holds one all-PAD row of weight 1. Two
+        threads that ask at once both build it, to the same bytes."""
+        table = self._label_tables.get(max_len)
+        if table is None:
+            ncap = np.array([max(len(r.caption_ids), 1) for r in self.records],
+                            np.intp)
+            first = np.cumsum(ncap) - ncap
+            labels = np.full((int(ncap.sum()), max_len), PAD_ID, np.int32)
+            mask = np.zeros(labels.shape, np.float32)
+            weights = np.ones(labels.shape[:1], np.float32)
+            for rec, k in zip(self.records, first.tolist()):
+                for ci, ids in enumerate(rec.caption_ids):
+                    labels[k + ci], mask[k + ci] = encode_label_row(ids, max_len)
+                if rec.weights:
+                    weights[k : k + len(rec.weights)] = rec.weights
+            table = self._label_tables[max_len] = LabelTable(
+                *map(_read_only, (labels, mask, weights, first, ncap)))
+        return table
 
     def gts_pool(self) -> dict[str, list[str]]:
         """video_id -> list of tokenized GT caption strings (reward/eval refs).

@@ -45,6 +45,12 @@ sections (PR 4):
   ``rl.update.embed_rows`` gauge: the input rows a block looks up before
   its forward loop and sums into the word embedding's gradient after its
   backward loop, once.
+
+The **collate** line reads ``data.collate.staged`` / ``.fresh`` (batches
+collated into a reused staging slot / into fresh arrays) and
+``data.collate.blocks`` with the ``data.collate.pool_width`` gauge: row
+blocks of feature gathers that ran on the batcher's gather pool, and how
+wide the pool is; 0 blocks says every gather ran on the collating thread.
 """
 
 from __future__ import annotations
@@ -244,13 +250,18 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
     covered = sum(p["self_s"] for p in phases)
 
     # where batches were collated (data/batcher.py): into a staging slot that
-    # already had its arrays, or into arrays allocated for the batch
+    # already had its arrays, or into arrays allocated for the batch; and how
+    # many row blocks of their feature gathers ran on the gather pool (0: every
+    # gather was one call on the collating thread), with the pool's width
     staged = float(counters.get("data.collate.staged", 0))
     fresh = float(counters.get("data.collate.fresh", 0))
     collate = None
     if staged + fresh > 0:
         collate = {"staged": staged, "fresh": fresh,
-                   "staged_share": staged / (staged + fresh)}
+                   "staged_share": staged / (staged + fresh),
+                   "blocks": float(counters.get("data.collate.blocks", 0)),
+                   "pool_width": float(
+                       gauges.get("data.collate.pool_width", 0.0))}
 
     # how the prefetch feed took the epochs' ends (data/prefetch.py): epochs
     # that found the worker already on them, epochs that started it, staged
@@ -651,7 +662,10 @@ def render_report(report: dict[str, Any]) -> str:
         lines.append(
             f"collate: {int(c['staged'])} batch(es) into reused staging "
             f"slots, {int(c['fresh'])} into fresh arrays "
-            f"({100.0 * c['staged_share']:.1f}% staged)"
+            f"({100.0 * c['staged_share']:.1f}% staged); "
+            + (f"{int(c['blocks'])} gather block(s) on a pool of "
+               f"{int(c['pool_width'])} thread(s)" if c["blocks"]
+               else "every gather on the collating thread")
         )
     f = report.get("prefetch")
     if f:

@@ -70,3 +70,23 @@ def _sanitizer_gate(request):
         return
     with jax.transfer_guard("disallow"), jax.debug_nans(True):
         yield
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Row blocks of 512 bytes in ``data/batcher.py``, so that a tiny cached
+    dataset's feature gathers run on the gather pool (tests/test_data.py: a
+    resnet row is 768 bytes, a c3d row 384, so blocks of one row and of two);
+    a pool of two where the machine gets none."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from cst_captioning_tpu.data import batcher
+
+    monkeypatch.setattr(batcher, "_BLOCK_BYTES", 512)
+    if batcher._gather_pool()[0] is not None:
+        yield
+        return
+    with ThreadPoolExecutor(2, thread_name_prefix="collate.gather") as pool:
+        monkeypatch.setattr(batcher, "_pool", pool)
+        monkeypatch.setattr(batcher, "_pool_width", 2)
+        yield

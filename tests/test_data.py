@@ -339,6 +339,13 @@ def _assert_same(got: dict, ref: dict):
         np.testing.assert_array_equal(have, want, err_msg=k)
 
 
+def _blocks():
+    from cst_captioning_tpu import obs
+
+    return obs.counter("data.collate.blocks").snapshot()
+
+
+@pytest.mark.parametrize("pooled", [False, True], ids=["inline", "pooled"])
 @pytest.mark.parametrize("staged", [False, True], ids=["fresh", "staged"])
 @pytest.mark.parametrize("cache", [False, True], ids=["h5", "table"])
 @pytest.mark.parametrize(
@@ -348,12 +355,16 @@ def _assert_same(got: dict, ref: dict):
     ids=["video", "caption_spv2", "caption_spv3_shard1of2", "video_shard0of2"],
 )
 def test_batches_equal_row_loop_reference(synth, mode, seq_per_vid, host_shard,
-                                          cache, staged):
+                                          cache, staged, pooled, request):
     """Two epochs (so the table is read cold and warm, and every slot is
     rewritten), a wrap-padded last batch, a salt: each array of each batch
-    is the reference's, whether collated into fresh arrays or a ring."""
+    is the reference's, whether collated into fresh arrays or a ring, by one
+    gather a stream on this thread or by row blocks on the pool."""
     from cst_captioning_tpu.data.prefetch import StagingRing
 
+    if pooled:
+        request.getfixturevalue("small_blocks")
+    blocks0 = _blocks()
     ds, plain = _open(synth, cache), _open(synth, False)
     kw = dict(batch_size=10, max_len=7, mode=mode, seq_per_vid=seq_per_vid,
               seed=5)
@@ -371,8 +382,65 @@ def test_batches_equal_row_loop_reference(synth, mode, seq_per_vid, host_shard,
             _assert_same(_flat(batch), ref)
             n += 1
         assert n == len(refs)
+    # a batch of 10 rows: a block a resnet row, one for two c3d rows; 5 rows
+    # a host: 5 and 3. The h5 path and a default-sized block run none
+    per_batch = {10: 10 + 5, 5: 5 + 3}[batcher.local_batch_size]
+    want = 2 * len(refs) * per_batch if pooled and cache else 0
+    assert _blocks() - blocks0 == want
     ds.close()
     plain.close()
+
+
+def test_label_table_equals_encode_label_row(synth, tmp_path):
+    """Every caption of every record, at a max_len some captions outgrow,
+    under consensus weights other than 1; a caption index past a record's
+    last reads the last one; the table is read-only and built once."""
+    from cst_captioning_tpu.data.dataset import encode_label_row
+
+    base = _open(synth, False)
+    rng = np.random.default_rng(3)
+    w = {r.video_id: rng.uniform(0.25, 2.0, len(r.caption_ids)).astype(np.float32)
+         for r in base.records}
+    np.savez(tmp_path / "w.npz", **w)
+    base.close()
+    ds = CaptionDataset(
+        synth["info_json"], {n: synth[n] for n in _BOTH}, "train", 6,
+        consensus_weights=str(tmp_path / "w.npz"),
+    )
+    T = 7          # captions hold 4 to 8 words: under, at and over T - 1
+    assert any(len(c) > T - 1 for r in ds.records for c in r.caption_ids)
+    assert any(len(c) < T - 1 for r in ds.records for c in r.caption_ids)
+    lt = ds.label_table(T)
+    assert lt is ds.label_table(T) and lt is not ds.label_table(T + 1)
+    assert lt.labels.dtype == np.int32 and lt.mask.dtype == np.float32
+    assert lt.weights.dtype == np.float32
+    assert len(lt.labels) == sum(len(r.caption_ids) for r in ds.records)
+    with pytest.raises(ValueError):
+        lt.labels[0, 0] = 1
+    items = []
+    for ri, rec in enumerate(ds.records):
+        assert lt.ncap[ri] == len(rec.caption_ids)
+        for ci, ids in enumerate(rec.caption_ids):
+            row, m = encode_label_row(ids, T)
+            k = lt.first[ri] + ci
+            np.testing.assert_array_equal(lt.labels[k], row)
+            np.testing.assert_array_equal(lt.mask[k], m)
+            assert lt.weights[k] == np.float32(rec.weights[ci]) != 1.0
+            items.append((ri, ci))
+        items.append((ri, len(rec.caption_ids) + 2))    # past the last
+    # and through a batch, on the h5 path
+    batcher = Batcher(ds, batch_size=len(items), max_len=T, mode="caption")
+    batcher._slot = None
+    got = batcher._collate(items, np.ones((len(items),), bool))
+    for b, (ri, ci) in enumerate(items):
+        rec = ds.records[ri]
+        ci = min(ci, len(rec.caption_ids) - 1)
+        row, m = encode_label_row(rec.caption_ids[ci], T)
+        np.testing.assert_array_equal(got.labels[b], row)
+        np.testing.assert_array_equal(got.mask[b], m)
+        assert got.weights[b] == np.float32(rec.weights[ci])
+        assert got.video_ids[b] == rec.video_id
+    ds.close()
 
 
 def test_feature_table_is_read_only_and_lazy(synth):
@@ -445,9 +513,14 @@ def _wait_for(cond, seconds=5.0):
         time.sleep(0.005)
 
 
-def test_slot_is_not_rewritten_before_its_upload_completes(synth):
+@pytest.mark.parametrize("pooled", [False, True], ids=["inline", "pooled"])
+def test_slot_is_not_rewritten_before_its_upload_completes(synth, pooled,
+                                                           request):
     from cst_captioning_tpu.data.prefetch import StagingRing, prefetch_to_device
 
+    if pooled:      # two rows a batch: a block a resnet row, c3d in one call
+        request.getfixturevalue("small_blocks")
+    blocks0 = _blocks()
     ds = _open(synth, True)
     batcher = Batcher(ds, batch_size=2, max_len=7, mode="video", seed=1)
     assert batcher.num_batches() == 6
@@ -479,6 +552,7 @@ def test_slot_is_not_rewritten_before_its_upload_completes(synth):
     for u in it:                                # and does proceed after
         u.done.set()
     assert len(uploads) == 6
+    assert _blocks() - blocks0 == (6 * 2 if pooled else 0)
     ds.close()
 
 
@@ -581,15 +655,17 @@ def test_prefetch_retires_worker_with_slots_outstanding(synth):
     ds.close()
 
 
-def test_collate_counters_and_take_mode(synth, monkeypatch):
+def test_collate_counters_and_take_mode(synth, monkeypatch, request):
     from cst_captioning_tpu import obs
+    from cst_captioning_tpu.data import batcher as batcher_module
     from cst_captioning_tpu.data.prefetch import StagingRing
 
     calls = []
     real_take = np.take
 
     def take(a, indices, axis=None, out=None, mode="raise"):
-        calls.append((out is not None, mode))
+        calls.append((out is not None, mode,
+                      threading.current_thread().name))
         return real_take(a, indices, axis=axis, out=out, mode=mode)
 
     monkeypatch.setattr(np, "take", take)
@@ -602,6 +678,7 @@ def test_collate_counters_and_take_mode(synth, monkeypatch):
     batcher = Batcher(ds, batch_size=4, max_len=7, mode="video", seed=1)
     n = batcher.num_batches()
     s0, f0 = counts()
+    blocks0 = _blocks()
     list(batcher.epoch())                       # plain: all fresh
     assert counts() == (s0, f0 + n)
     ring = StagingRing(0)                       # two slots: two first fills
@@ -610,9 +687,27 @@ def test_collate_counters_and_take_mode(synth, monkeypatch):
     list(batcher.epoch(staging=ring))           # warm ring: all staged
     assert counts() == (s0 + 2 * n - 2, f0 + n + 2)
     # a gather with out= never runs under mode="raise" (it would buffer
-    # the whole batch through a temporary)
-    assert len(calls) == 3 * n * 2 * len(_BOTH)
-    assert all(has_out and mode != "raise" for has_out, mode in calls)
+    # the whole batch through a temporary): features and frame mask of each
+    # stream, then labels, mask and weights, every one a call on this thread
+    assert len(calls) == 3 * n * (2 * len(_BOTH) + 3)
+    assert all(has_out and mode != "raise" for has_out, mode, _ in calls)
+    me = threading.current_thread().name
+    assert {name for _, _, name in calls} == {me}
+    assert _blocks() == blocks0                 # no block ran on the pool
+    # with the pool engaged: four rows a batch are four blocks of resnet and
+    # two of c3d, on the pool's threads and on no other; the rest stays here
+    request.getfixturevalue("small_blocks")
+    del calls[:]
+    list(batcher.epoch(staging=ring))
+    assert _blocks() - blocks0 == n * (4 + 2)
+    assert len(calls) == n * (4 + 2 + len(_BOTH) + 3)
+    assert all(has_out and mode != "raise" for has_out, mode, _ in calls)
+    pool_calls = [name for _, _, name in calls if name != me]
+    assert len(pool_calls) == n * (4 + 2)
+    assert all(name.startswith("collate.gather") for name in pool_calls)
+    width = obs.snapshot()["gauges"]["data.collate.pool_width"]
+    assert width == batcher_module._gather_pool()[1] >= 2
+    assert counts() == (s0 + 3 * n - 2, f0 + n + 2)
     ds.close()
 
 
@@ -833,6 +928,128 @@ def test_feed_raises_an_error_on_the_batch_it_happened_on(synth):
     _no_prefetch_thread(n_before)
     ds.close()
     plain.close()
+
+
+def test_feed_raises_a_gather_blocks_error_on_its_batch(synth, monkeypatch,
+                                                        small_blocks):
+    """One row block of epoch 1's first batch fails on the pool while epoch
+    0 is being consumed: epoch 0 ends cleanly; the error comes when epoch 1
+    asks for that batch, and not before every other block of the batch has
+    ended; the feed then serves the epoch, its slots rewritten whole."""
+    from cst_captioning_tpu.data.batcher import EpochKey
+    from cst_captioning_tpu.data.prefetch import PrefetchFeed, StagingRing
+
+    ds, plain = _open(synth, True), _open(synth, False)
+    batcher = Batcher(ds, **_FEED_KW)
+    ring = StagingRing(2)
+    broken = {1}
+    armed, failed = threading.Event(), threading.Event()
+    lock = threading.Lock()
+    running = {"begun": 0, "ended": 0}
+    real_take = np.take
+
+    def take(a, indices, axis=None, out=None, mode="raise"):
+        if not threading.current_thread().name.startswith("collate.gather"):
+            return real_take(a, indices, axis=axis, out=out, mode=mode)
+        with lock:
+            running["begun"] += 1
+            boom = armed.is_set()
+            armed.clear()
+        try:
+            if boom:
+                failed.set()
+                raise RuntimeError("boom")
+            if failed.is_set():
+                time.sleep(0.02)        # the failed block's slower siblings
+            return real_take(a, indices, axis=axis, out=out, mode=mode)
+        finally:
+            with lock:
+                running["ended"] += 1
+
+    monkeypatch.setattr(np, "take", take)
+
+    def draw(key):
+        it = key.batches(ring)
+        if key.index in broken:
+            armed.set()                 # the next pooled block raises
+        yield from it
+
+    feed = PrefetchFeed(draw, EpochKey.following, size=2, transform=_placed,
+                        place=False, staging=ring)
+    key = EpochKey(batcher, 0, 0, 0, 3)
+    it = feed.epoch(key)
+    head = [next(it) for _ in range(batcher.num_batches() - 1)]
+    assert failed.wait(5.0)     # on the pool, epoch 0's last batch not taken
+    _assert_epoch(head + list(it), _cold_epoch(plain, salt=0, epoch_index=0))
+    c0 = _feed_counts()
+    with pytest.raises(RuntimeError, match="boom"):
+        next(feed.epoch(key.following()))
+    assert running["begun"] == running["ended"] > 0
+    assert (_feed_counts() - c0).tolist() == [0, 1, 0]
+    _wait_for(lambda: not [t for t in threading.enumerate()
+                           if t.name == "prefetch"])
+    assert ring._pending == [None] * 4
+    broken.clear()
+    _assert_epoch(list(feed.epoch(key.following())),
+                  _cold_epoch(plain, salt=0, epoch_index=1))
+    feed.close()
+    assert running["begun"] == running["ended"]
+    ds.close()
+    plain.close()
+
+
+def test_one_gather_pool_serves_threads_that_collate_at_once(synth, monkeypatch,
+                                                            small_blocks):
+    """More collating threads than cores, the interpreter switching every
+    few bytecodes: all of them get the one pool, however many ask for it
+    first at the same moment, and each one's batches are the reference's."""
+    import os
+    import sys
+
+    from cst_captioning_tpu.data import batcher as batcher_module
+
+    # a pool not yet made, on a machine taken to have eight cores
+    monkeypatch.setattr(batcher_module, "_pool", None)
+    monkeypatch.setattr(batcher_module, "_pool_width", 0)
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(8)))
+    n = 2 * os.cpu_count()
+    plain = _open(synth, False)
+    kw = dict(batch_size=10, max_len=7, mode="caption", seq_per_vid=2, seed=5)
+    refs = _reference_epoch(plain, salt=0, epoch_index=0, host_shard=(0, 1),
+                            **kw)
+    sets = [_open(synth, True) for _ in range(n)]
+    start = threading.Barrier(n)
+    pools, errors = [], []
+
+    def work(ds):
+        try:
+            start.wait(10.0)
+            pools.append(batcher_module._gather_pool())
+            for _ in range(3):
+                got = [_flat(b) for b in Batcher(ds, **kw).epoch(epoch_index=0)]
+                for have, ref in zip(got, refs, strict=True):
+                    _assert_same(have, ref)
+        except BaseException as e:      # surfaced below, on the test's thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=work, args=(ds,)) for ds in sets]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+    finally:
+        sys.setswitchinterval(interval)
+    pool, width = batcher_module._gather_pool()
+    pool.shutdown()
+    assert not [t for t in threads if t.is_alive()]
+    assert not errors, errors
+    assert width == 4 and len(pools) == n
+    assert all(p is pool and w == 4 for p, w in pools)
+    for ds in sets + [plain]:
+        ds.close()
 
 
 @pytest.mark.parametrize("n", [1, 4])

@@ -1313,10 +1313,65 @@ def test_staged_prefetch_records_the_fence_and_the_report_the_share(
         collate = next(c for c in by["data.collate"] if _inside(c, stage))
         assert f["ts"] + f["dur"] <= collate["ts"] + 1.0
     rep = report_run(str(tmp_path / "run"))
+    # an h5 dataset: no gather ran on the pool
     assert rep["collate"] == {"staged": 2 * n - 2, "fresh": 2,
-                              "staged_share": (2 * n - 2) / (2 * n)}
+                              "staged_share": (2 * n - 2) / (2 * n),
+                              "blocks": 0, "pool_width": 0}
+    text = render_report(rep)
     assert f"{2 * n - 2} batch(es) into reused staging slots, 2 into fresh" \
-        in render_report(rep)
+        in text
+    assert "every gather on the collating thread" in text
+
+
+def test_pooled_collate_is_one_span_a_batch_on_the_staging_thread(
+    tmp_path, small_blocks,
+):
+    """A cached dataset whose feature gathers run over row blocks on the
+    gather pool: still one data.collate a batch, on the staging thread, inside
+    its prefetch.stage; the pool's threads record nothing at all; the report
+    says how many blocks ran and on how wide a pool."""
+    from cst_captioning_tpu.data import (
+        Batcher,
+        CaptionDataset,
+        batcher as batcher_module,
+        make_synthetic_dataset,
+    )
+    from cst_captioning_tpu.data.prefetch import prefetch_to_device
+
+    synth = make_synthetic_dataset(
+        str(tmp_path / "synth"), num_videos=12, modalities={"resnet": 16},
+        max_frames=4, seed=5,
+    )
+    ds = CaptionDataset(synth["info_json"], {"resnet": synth["resnet"]},
+                        "train", 4, cache_features=True)
+    width = batcher_module._gather_pool()[1]
+    blocks0 = obs.counter("data.collate.blocks").snapshot()
+
+    obs.configure(str(tmp_path / "run"), run="t")
+    batcher = Batcher(ds, batch_size=4, max_len=8, mode="video")
+    n = len(list(prefetch_to_device(
+        batcher.epoch(), size=2, transform=lambda b: (b.feats, b.feat_masks),
+    )))
+    assert n == batcher.num_batches() > 1
+    obs.shutdown()
+    ran = obs.counter("data.collate.blocks").snapshot() - blocks0
+    assert ran == 2 * n             # a row is 256 bytes: two rows a block
+    evs = json.load(open(tmp_path / "run" / "trace.json"))["traceEvents"]
+    collates = [e for e in evs if e["name"] == "data.collate"]
+    stages = [e for e in evs if e["name"] == "prefetch.stage"]
+    assert len(collates) == len(stages) == n
+    assert {e["tid"] for e in collates} == {"prefetch"}
+    for stage in stages:
+        assert sum(_inside(c, stage) for c in collates) == 1
+    assert not [e for e in evs
+                if str(e.get("tid", "")).startswith("collate.gather")]
+    rep = report_run(str(tmp_path / "run"))
+    assert rep["collate"]["blocks"] == obs.counter(
+        "data.collate.blocks").snapshot()
+    assert rep["collate"]["pool_width"] == width
+    assert (f"{int(rep['collate']['blocks'])} gather block(s) on a pool of "
+            f"{width} thread(s)") in render_report(rep)
+    ds.close()
 
 
 @pytest.mark.parametrize("enabled", [True, False], ids=["obs_on", "obs_off"])
