@@ -66,6 +66,20 @@ def host_copy(state):
     return jax.device_get(_keys_to_data(state))
 
 
+def host_copy_begin(state) -> None:
+    """Start :func:`host_copy`'s transfers and return at once: each leaf's
+    device->host copy is queued behind the program that writes it, so a
+    caller with more device work to dispatch (the primed RL pipeline, after
+    an epoch's last update) lets the read-back run under that work, and the
+    ``host_copy`` that follows finds the copies done or in flight. Typed
+    keys are left to ``host_copy`` (it reads their raw data, a new array),
+    and so is an array that spans processes."""
+    for x in jax.tree.leaves(state):
+        if (isinstance(x, jax.Array) and x.is_fully_addressable
+                and not _is_prng_key(x)):
+            x.copy_to_host_async()
+
+
 def _data_to_keys(loaded, template):
     """Re-wrap raw key data as typed keys wherever the template has them."""
     return jax.tree.map(
@@ -77,13 +91,15 @@ def _data_to_keys(loaded, template):
 
 def save_state(ckpt_dir: str, name: str, state: TrainState,
                infos: dict[str, Any] | None = None,
-               extra_files: Mapping[str, bytes] | None = None) -> str:
+               extra_files: Mapping[str, bytes | Callable[[], bytes]]
+               | None = None) -> str:
     """Durably write state+infos under ``ckpt_dir/name``; returns the path.
 
-    ``extra_files`` (name -> bytes) ride along in the same atomic swap and
-    are covered by the manifest — the drain-aware RL seam (``seam.npz``)
-    uses this so the seam tokens can never outlive or predate the state
-    they belong to.
+    ``extra_files`` (name -> bytes, or a callable that gives them and is
+    called here, once the state is written) ride along in the same atomic
+    swap and are covered by the manifest — the drain-aware RL seam
+    (``seam.npz``) uses this so the seam tokens can never outlive or predate
+    the state they belong to.
 
     CONTRACT: one writer per ``ckpt_dir`` at a time — crash-atomic (a kill
     mid-save leaves the previous generation intact: only the stale ``.tmp``
@@ -107,6 +123,8 @@ def save_state(ckpt_dir: str, name: str, state: TrainState,
     for extra_name, blob in (extra_files or {}).items():
         if extra_name in blobs or os.sep in extra_name:
             raise ValueError(f"bad extra checkpoint file name {extra_name!r}")
+        if callable(blob):
+            blob = blob()
         write_bytes_durable(os.path.join(tmp, extra_name), blob)
         blobs[extra_name] = blob
     write_manifest(tmp, blobs)
@@ -248,18 +266,28 @@ class CheckpointManager:
              infos: dict | None = None) -> bool:
         """Save 'latest' always; promote to 'best' when the metric improves.
 
+        ``infos["extra_files"]``, where given, is taken out of the infos and
+        written beside the state in the same atomic swap, as
+        :meth:`save_step`'s ``extra_files`` are (:func:`save_state` has
+        their form): the tokens of the batch the primed RL pipeline decoded
+        across the epoch's end (``seam.npz``). TEMPORARY route: it rides in
+        ``infos`` because the call's signature is a seam to the benchmark,
+        whose stand-in for this class takes these three; once that stand-in
+        takes ``extra_files=`` this method does too (ROADMAP S4).
+
         Returns True when a new best was recorded.
         """
         infos = dict(infos or {})
+        extra_files = infos.pop("extra_files", None)
         improved = value is not None and self._improved(value)
         if improved:
             self.best_value = float(value)
         # both checkpoints carry the post-update best so 'latest' metadata
         # never lags 'best' (ADVICE r1)
         infos["best_value"] = self.best_value
-        self._save("latest", state, infos)
+        self._save("latest", state, infos, extra_files=extra_files)
         if improved:
-            self._save("best", state, infos)
+            self._save("best", state, infos, extra_files=extra_files)
         return improved
 
     def save_step(self, state: TrainState, step: int,

@@ -271,6 +271,13 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         ("dropped", "prefetch.dropped"))}
     if not any(feed.values()):
         feed = None
+    # how the RL loop took them (rl/scst.py): epochs that began with the pair
+    # the epoch before had decoded inside its drain, epochs that began with
+    # an empty pipeline
+    rl_epochs = {k: float(counters.get(f"rl.epoch.{k}", 0))
+                 for k in ("primed", "cold")}
+    if not any(rl_epochs.values()):
+        rl_epochs = None
 
     depth = histograms.get("rl.decode.depth")
     decode = None
@@ -569,6 +576,7 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
         "overlap": overlap_rows,
         "collate": collate,
         "prefetch": feed,
+        "rl_epochs": rl_epochs,
         "decode": decode,
         "update": update,
         "decode_state": decode_state,
@@ -676,6 +684,15 @@ def render_report(report: dict[str, Any]) -> str:
             f"batches staged by the worker of the epoch before, "
             f"{int(f['cold'])} started it cold; {int(f['dropped'])} staged "
             "batch(es) dropped"
+        )
+    e = report.get("rl_epochs")
+    if e:
+        if not c and not f:
+            lines.append("")
+        lines.append(
+            f"rl epochs: {int(e['primed'])} began with the pipeline primed "
+            f"inside the drain of the epoch before, {int(e['cold'])} with it "
+            "empty"
         )
     d = report.get("decode")
     if d:

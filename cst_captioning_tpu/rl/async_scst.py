@@ -27,7 +27,12 @@ sync schedule exactly — depth 2 IS the sync loop's default 1-deep pipeline
 1 the ``pipelined=False`` sequential loop — and the per-batch
 ``rng, srng = jax.random.split(rng)`` chain is the sync loop's — tokens,
 logprobs, params, and opt_state reproduce ``SCSTTrainer.train_epoch``
-bit-for-bit (tests/test_async_scst.py). Genuinely decoupled runs are NOT
+bit-for-bit (tests/test_async_scst.py), PER EPOCH-SIZED CALL: the ring
+fills and drains inside every epoch, where the sync trainer given a phase
+of several epochs runs its pipeline on across their ends
+(``next_epoch``: an epoch's first batch is then decoded one update stale
+too), so a multi-epoch sync phase is one loop over the phase and not this
+schedule epoch by epoch. Genuinely decoupled runs are NOT
 token-identical to sync: the per-shard RNG fold runs over a different
 submesh size — documented, and why strict exists.
 

@@ -23,7 +23,8 @@ reward stays debuggable on host, the device work stays fused.
 
 from __future__ import annotations
 
-from typing import Callable
+import itertools
+from typing import Any, Callable, Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -659,6 +660,29 @@ def make_parallel_rl_update(model, mesh: Mesh, axis: str = "data",
     ))
 
 
+def _close(batches) -> None:
+    """Close an epoch's batches where they can be closed (a generator over
+    a feed, whose worker retires then; a plain iterator has nothing to)."""
+    close = getattr(batches, "close", None)
+    if close is not None:
+        close()
+
+
+class PrimedEpoch(NamedTuple):
+    """What a pipelined :meth:`SCSTTrainer.train_epoch` that ran on over its
+    epoch's end leaves for the next call (:attr:`SCSTTrainer.primed`): the
+    next epoch, opened, with its first two batches decoded. The caller
+    passes ``batches`` and ``rng`` as that call's; the call itself takes the
+    pair over."""
+
+    batches: Iterator   # the next epoch's batches after its first two
+    rng: Any            # its key after those two splits
+    scored: tuple | None    # batch 0': ``_apply``'s arguments (None: an
+    #                         epoch of one batch, which is ``decoded``)
+    decoded: tuple      # batch 1' (or 0'): ``_score``'s arguments
+    first: tuple        # batch 0''s decoded (greedy, samples), its video ids
+
+
 class SCSTTrainer:
     """Per-batch CST step: decode -> consensus reward -> REINFORCE update.
 
@@ -672,6 +696,9 @@ class SCSTTrainer:
     is the pipelined loop (SURVEY.md §7 "hard parts"): the host scores batch
     *i* while the device decodes batch *i+1*.
     """
+
+    # what the last train_epoch call left for the next (PrimedEpoch), or None
+    primed: PrimedEpoch | None = None
 
     def __init__(
         self,
@@ -1094,10 +1121,47 @@ class SCSTTrainer:
 
     # ---- pipelined epoch ----------------------------------------------------
 
+    def _replicated_key(self, rng):
+        """An epoch's key, replicated onto the mesh ONCE: the sharded decode
+        takes its rng replicated (in_specs P()), and a single-device key
+        would otherwise be implicitly re-replicated device-to-device on
+        EVERY batch's dispatch (the sanitizer gate's transfer_guard vetoes
+        that); every split of it inherits the replicated placement.
+        Bit-identical — placement only. A mesh that spans processes takes
+        the key through multihost's global placement (device_put refuses
+        devices of another process); one process gets the same device_put
+        as ever."""
+        if self.mesh is None:
+            return rng
+        from cst_captioning_tpu.train.mesh import replicate
+
+        return replicate(self.mesh, rng)
+
+    def drop_primed(self) -> None:
+        """Forget what :meth:`train_epoch` left for the next call: the
+        opened batches are closed (a feed behind them retires its worker and
+        starts over) and the pair's features are let go. For a caller whose
+        next epoch is no longer the one that was opened: a rollback, a lost
+        or rejoined peer, the phase's end."""
+        primed, self.primed = self.primed, None
+        if primed is not None:
+            _close(primed.batches)
+
+    def primed_seam(self) -> dict | None:
+        """Host copies of the tokens of the next epoch's first batch, where
+        the last call primed it (else None): what a checkpoint of the state
+        that call returned needs beside it, since that batch was decoded one
+        update before that state. ``next_epoch=True`` names their position
+        (the next epoch's batch 0), as a stop while priming does."""
+        if self.primed is None:
+            return None
+        return dict(self._seam_capture(*self.primed.first), next_epoch=True)
+
     def train_epoch(self, state: TrainState, batches, rng, on_step=None,
                     pipelined: bool = True, should_stop=None,
                     seam: dict | None = None,
-                    seam_sink: dict | None = None):
+                    seam_sink: dict | None = None,
+                    next_epoch: Callable[[], tuple | None] | None = None):
         """SCST over an epoch of batches.
 
         ``should_stop()`` (optional) is polled once per batch; when it turns
@@ -1118,10 +1182,10 @@ class SCSTTrainer:
         BIT-IDENTICAL to the uninterrupted run (previously the seam batch
         was re-decoded against params one update fresher).
 
-        ``seam`` (pipelined only): tokens for the first batch, from a prior
-        ``seam_sink``. Ignored (with a live decode fallback) when the batch
-        identity check fails — a changed data order must never silently
-        marry old tokens to new features.
+        ``seam`` (pipelined only): tokens for the first batch this call
+        decodes, from a prior ``seam_sink``. Ignored (with a live decode
+        fallback) when the batch identity check fails — a changed data order
+        must never silently marry old tokens to new features.
 
         ``batches`` yields ``(feats, masks, video_ids, valid)`` with arrays
         already on device.
@@ -1145,25 +1209,57 @@ class SCSTTrainer:
         batches' features are live at once (scored, decoded-awaiting-score,
         current) vs two in the strict loop.
 
+        ``next_epoch`` (pipelined, with a ``seam_sink``) PRIMES the pipeline
+        across the epoch's end. Called once, when ``batches`` has ended and
+        no stop was asked for, it opens the epoch after this one and returns
+        ``(batches', rng')``, or None where there is none. The schedule then
+        runs on over the cut, with n this epoch's batches and i' the next
+        epoch's::
+
+            update(n-2) -> decode(0') -> host-score(n-1)
+            update(n-1) -> decode(1') -> host-score(0')
+
+        and the call returns with the device still holding update(n-1) and
+        decode(1'). The returned state has this epoch's n updates and none of
+        the next epoch's (a decode reads parameters and donates nothing), and
+        ``metrics`` has n entries. What the next call needs is kept in
+        :attr:`primed` (a :class:`PrimedEpoch`): the rest of ``batches'``
+        and ``rng'`` after its two splits, which the caller passes as that
+        call's ``batches`` and ``rng``, and the scored batch 0' and the
+        decoded batch 1', which that call takes over by itself. It begins by
+        dispatching update(0'), before it takes a batch, and goes on at
+        decode(2') -> host-score(1'): no epoch begins with an empty
+        pipeline, and EVERY batch is decoded one update stale, an epoch's
+        first included (it used to read fresh parameters only because the
+        loop happened to start there). Every rollout is still scored once
+        and applied once, in the same order, from the same per-epoch keys;
+        the trajectory is that of ONE pipelined loop over the phase's
+        batches end to end, so it differs from a phase cut into calls of one
+        epoch each (cold every time) by that first batch's staleness. Batch
+        0' was decoded one update before the returned state: a checkpoint of
+        that state carries its tokens (:meth:`primed_seam`), and a resume
+        replays them as ``seam`` (0' from the file, 1' decoded from the
+        saved state with the saved key), bit-identical to the primed run. A
+        caller whose next call is not that epoch calls :meth:`drop_primed`.
+        A stop that arrives while priming ends it there: this epoch's
+        updates are applied, batch 0''s tokens go to ``seam_sink`` with
+        ``next_epoch=True`` (their position is the next epoch's batch 0),
+        ``batches'`` is closed and nothing is kept. Counters
+        ``rl.epoch.primed`` / ``rl.epoch.cold``: calls that began with a
+        primed pair / with an empty pipeline.
+
         ``pipelined=False``: strict on-policy SCST — :meth:`train_step` per
         batch with the same rng stream (the reference's loop, SURVEY.md
-        §3.2).
+        §3.2). It never primes.
 
         Returns ``(state, metrics_list)``; ``on_step(metrics)`` fires per batch.
         """
-        if self.mesh is not None:
-            # replicate the epoch key onto the mesh ONCE: the sharded decode
-            # takes its rng replicated (in_specs P()), and a single-device
-            # key would otherwise be implicitly re-replicated device-to-
-            # device on EVERY batch's dispatch (the sanitizer gate's
-            # transfer_guard vetoes that); every split below inherits the
-            # replicated placement. Bit-identical — placement only. A mesh
-            # that spans processes takes the key through multihost's global
-            # placement (device_put refuses devices of another process);
-            # one process gets the same device_put as ever.
-            from cst_captioning_tpu.train.mesh import replicate
-
-            rng = replicate(self.mesh, rng)
+        primed, self.primed = self.primed, None
+        obs.counter(
+            "rl.epoch.cold" if primed is None else "rl.epoch.primed"
+        ).inc()
+        if primed is None:
+            rng = self._replicated_key(rng)
         out = []
 
         def emit(m):
@@ -1171,9 +1267,12 @@ class SCSTTrainer:
             if on_step is not None:
                 on_step(m)
 
+        if should_stop is None:
+            should_stop = lambda: False     # noqa: E731
+
         if not pipelined:
             for feats, masks, video_ids, valid in batches:
-                if should_stop is not None and should_stop():
+                if should_stop():
                     break
                 rng, srng = jax.random.split(rng)
                 state, m = self.train_step(
@@ -1184,66 +1283,106 @@ class SCSTTrainer:
 
         scored = None     # _apply args: advantage ready, update not dispatched
         decoded = None    # _score args: decode dispatched, not yet scored
-        first = True
-        for feats, masks, video_ids, valid in batches:
-            if should_stop is not None and should_stop():
-                if seam_sink is not None:
-                    # drain-aware stop: run THIS iteration's schedule prefix
-                    # (update(i-2) then decode(i)) so the seam batch is
-                    # decoded against the params the uninterrupted pipeline
-                    # would have used, and capture its tokens for the
-                    # checkpoint instead of scoring it
-                    if scored is not None:
-                        state, m = self._apply(state, *scored)
-                        scored = None
-                        emit(m)
-                    rng, srng = jax.random.split(rng)
-                    with obs.span("rl.decode"):
-                        d = self.decode(state.params, feats, masks, srng)
-                    seam_sink.update(self._seam_capture(d, video_ids))
-                    if decoded is not None:
-                        state, m = self._apply(state, *self._score(*decoded))
-                        emit(m)
-                    decoded = None
-                break
+
+        def update():
+            """Dispatch the oldest pending batch's update."""
+            nonlocal state, scored, decoded
+            if scored is None:
+                scored, decoded = self._score(*decoded), None
+            state, m = self._apply(state, *scored)
+            scored = None
+            emit(m)
+
+        def slot(batch, score=True):
+            """One slot of the schedule: update(i-2) -> decode(i) ->
+            host-score(i-1), the host scoring while the device runs the two
+            just queued. Returns decode(i)'s tokens. ``score=False`` is the
+            drain-aware stop: the slot's device work and no more, so that
+            the seam batch is decoded against the params the uninterrupted
+            pipeline would have used; the caller captures its tokens for the
+            checkpoint instead of scoring it."""
+            nonlocal rng, seam, scored, decoded
             if scored is not None:
-                state, m = self._apply(state, *scored)
-                scored = None
-                emit(m)
+                update()
+            # split even for a replayed batch, so that later batches'
+            # streams stay aligned with the uninterrupted run
             rng, srng = jax.random.split(rng)
-            if first and seam is not None and self._seam_matches(
-                seam, video_ids
-            ):
-                # resumed seam batch: replay the persisted tokens (decoded
-                # pre-preemption at this exact schedule position); the rng
-                # split above is still consumed so later batches' streams
-                # stay aligned with the uninterrupted run
-                d = self._seam_tokens_to_device(seam)
+            feats, masks, video_ids, valid = batch
+            replay, seam = seam, None
+            if replay is not None and self._seam_matches(replay, video_ids):
+                # the call's first decode, resumed: the tokens persisted
+                # before the stop at this exact schedule position
+                d = self._seam_tokens_to_device(replay)
             else:
                 with obs.span("rl.decode"):
                     d = self.decode(state.params, feats, masks, srng)
                     for arr in d:
                         # start the device->host token transfer NOW, so it
-                        # overlaps this decode — by the time _score reads the
-                        # tokens they are already on host. greedy is None for
-                        # the scb/none baselines (no greedy rollout);
-                        # multi-host global arrays are not fully addressable
-                        # here and their reads go through to_host_local.
+                        # overlaps this decode — by the time _score reads
+                        # the tokens they are already on host. greedy is
+                        # None for the scb/none baselines (no greedy
+                        # rollout); multi-host global arrays are not fully
+                        # addressable here and their reads go through
+                        # to_host_local.
                         if arr is not None and arr.is_fully_addressable:
                             arr.copy_to_host_async()
-            first = False
-            if decoded is not None:
-                # host scores batch i-1 while the device runs update(i-2) +
-                # decode(i) queued above
-                scored = self._score(*decoded)
-            greedy, samples = d
-            valid_np = self._valid_np(valid, len(video_ids))
-            decoded = (greedy, samples, feats, masks, video_ids, valid_np)
-        # drain in order: update(n-2), then score+update(n-1)
-        if scored is not None:
-            state, m = self._apply(state, *scored)
-            emit(m)
-        if decoded is not None:
-            state, m = self._apply(state, *self._score(*decoded))
-            emit(m)
+            if score:
+                if decoded is not None:
+                    scored = self._score(*decoded)
+                valid_np = self._valid_np(valid, len(video_ids))
+                decoded = (*d, feats, masks, video_ids, valid_np)
+            return d
+
+        if primed is not None:
+            # the pair the epoch before this one decoded inside its drain.
+            # Batch 0''s update goes first of all, before a batch is taken
+            # from the feed (whose worker wakes on that and takes the
+            # interpreter lock for its next collate): the device has only
+            # decode(1') left of what that call queued
+            scored, decoded = primed.scored, primed.decoded
+            del primed      # the pair's features die with their updates
+            if scored is not None:
+                update()
+        stopped = False
+        for batch in batches:
+            if should_stop():
+                stopped = True
+                if seam_sink is not None:
+                    seam_sink.update(self._seam_capture(
+                        slot(batch, score=False), batch[2]
+                    ))
+                break
+            slot(batch)
+
+        # this epoch's updates, once the pending ones are applied
+        n = len(out) + (scored is not None) + (decoded is not None)
+        ahead = None
+        if not stopped and next_epoch is not None and seam_sink is not None:
+            ahead = next_epoch()
+        first = None      # batch 0' of the next epoch: (its tokens, its ids)
+        opened = None     # the next epoch's batches, while they are ours
+        try:
+            if ahead is not None:
+                # the next epoch's fill inside this epoch's drain
+                opened, rng = ahead[0], self._replicated_key(ahead[1])
+                for batch in itertools.islice(opened, 2):
+                    stopped = should_stop()
+                    if stopped and first is not None:
+                        break       # 0' is the seam: 1' is never decoded
+                    d = slot(batch, score=not stopped)
+                    first = first or (d, batch[2])
+                    if stopped:
+                        break
+            # drain in order: update(n-2), then score+update(n-1)
+            while len(out) < n:
+                update()
+            if first is not None and not stopped:
+                self.primed = PrimedEpoch(opened, rng, scored, decoded, first)
+                opened = None
+        finally:
+            if opened is not None:
+                _close(opened)
+        if first is not None and stopped:
+            # 0' was decoded one update before the state this call returns
+            seam_sink.update(self._seam_capture(*first), next_epoch=True)
         return state, out

@@ -43,7 +43,7 @@ from cst_captioning_tpu.obs import anomaly as _anomaly
 from cst_captioning_tpu.obs import flops as _flops
 from cst_captioning_tpu.obs import recorder as flight
 from cst_captioning_tpu.ckpt import CheckpointManager, load_params
-from cst_captioning_tpu.ckpt.checkpoint import host_copy
+from cst_captioning_tpu.ckpt.checkpoint import host_copy, host_copy_begin
 from cst_captioning_tpu.config.config import EvalConfig, ExperimentConfig
 from cst_captioning_tpu.data.batcher import Batcher, EpochKey
 from cst_captioning_tpu.data.dataset import CaptionDataset
@@ -512,10 +512,10 @@ class Trainer:
 
     def _load_seam(self, src_dir: str, infos: dict) -> dict | None:
         """Load the seam sidecar of the checkpoint that just restored (if
-        its save drained a pipelined RL epoch)."""
+        its save drained a pipelined RL epoch, or closed an epoch whose
+        drain had primed the next: the file names its own position)."""
         name = infos.get("ckpt_name", "")
-        if not name or infos.get("phase") != "rl" \
-                or not infos.get("batch_index"):
+        if not name:
             return None
         path = os.path.join(src_dir, name, "seam.npz")
         if not os.path.exists(path):
@@ -723,11 +723,12 @@ class Trainer:
         if jax.process_index() == 0:
             extra = None
             if seam:
-                extra = {
-                    "seam.npz": self._seam_bytes(
-                        seam, self.epoch, batch_index
-                    ),
-                }
+                # tokens decoded while priming are the next epoch's batch 0
+                at = (
+                    (self.epoch + 1, 0) if seam.get("next_epoch")
+                    else (self.epoch, batch_index)
+                )
+                extra = {"seam.npz": self._seam_bytes(seam, *at)}
             with obs.span("ckpt", kind="step"):
                 with obs.span("ckpt.readback"):
                     host_state = host_copy(self.state)
@@ -1341,6 +1342,26 @@ class Trainer:
         tokens, so BOTH ``rl.pipelined`` modes resume bit-identically to the
         uninterrupted run (previously the pipelined resume re-decoded the
         seam batch against params one update fresher).
+
+        The pipelined loop runs on ACROSS an epoch's end
+        (``SCSTTrainer.train_epoch``, ``next_epoch``): where the phase has a
+        next epoch, this epoch's drain decodes that epoch's first two
+        batches (from its own staged batches and its own folded key) under
+        its last two updates, so that no epoch begins with an empty
+        pipeline. Every batch is then decoded one update stale, an epoch's
+        first included; each is scored once and applied once, in order, and
+        a step of epoch e+1 is counted in epoch e+1. At an epoch's end
+        ``self.state`` holds exactly that epoch's updates: validation, the
+        ``rl_epoch`` line and the checkpoint see what a loop cut at the
+        epoch would show them, the state's read-back runs under the decode
+        still queued, and the checkpoint carries the next epoch's first
+        batch's tokens (``seam.npz`` at position (epoch + 1, 0): that batch
+        was decoded one update before the saved state), so a resume from an
+        epoch-end checkpoint is bit-identical too. The phase's last epoch,
+        ``rl.pipelined=False`` and the decoupled topology drain as ever; a
+        stop while priming saves the state with that seam; a rollback, a
+        degraded or regrown mesh and a rebuilt batcher drop the primed pair
+        with the staged batches (``SCSTTrainer.drop_primed``).
         """
         cfg = self.cfg
         if epochs is None:
@@ -1452,10 +1473,12 @@ class Trainer:
                             run, epochs_left=target - self.rl_epochs,
                         )
                     except RollbackRequested as e:
+                        scst.drop_primed()
                         self._apply_rollback("rl", e, sentinel)
                     except PeerLost as e:
                         if self.cfg.train.elastic != "degraded":
                             raise
+                        scst.drop_primed()
                         self._continue_degraded("rl", e)
                         # the decode/update closures and the batcher's host
                         # share are mesh-shaped: rebuild on the shrunk mesh
@@ -1463,6 +1486,7 @@ class Trainer:
                         self._rl_batcher = rl_batcher
                         run["first_step"] = True
                     except health_mod.HostRejoin as e:
+                        scst.drop_primed()
                         if self._continue_regrown("rl", e):
                             # rebuild mesh-shaped closures on the FULL mesh
                             scst, rl_batcher = build_scst()
@@ -1470,6 +1494,7 @@ class Trainer:
                             run["first_step"] = True
         finally:
             self._rl_batcher = None
+            scst.drop_primed()
             self._close_feed()      # as in train_xe
         return last_val
 
@@ -1488,16 +1513,20 @@ class Trainer:
         # drain-aware seam replay: the tokens the drained pipeline decoded
         # for exactly this (epoch, batch) position — replayed so the seam
         # batch is not re-decoded against params one update fresher than
-        # the uninterrupted schedule. Anything else (position mismatch,
-        # strict pipeline off) falls back to the old re-decode.
+        # the uninterrupted schedule. They are the call's first decode: the
+        # batch the epoch (re)starts at or, where every batch of this epoch
+        # was applied before the save, the next epoch's first (decoded while
+        # priming). Anything else (position mismatch, strict pipeline off)
+        # falls back to the old re-decode.
         seam = None
-        seam_capable = (
-            cfg.rl.pipelined or cfg.train.rl_topology == "decoupled"
-        )
-        if skip and self._pending_seam is not None:
+        decoupled = cfg.train.rl_topology == "decoupled"
+        seam_capable = cfg.rl.pipelined or decoupled
+        if self._pending_seam is not None:
             cand, self._pending_seam = self._pending_seam, None
-            if seam_capable and cand["epoch"] == self.epoch \
-                    and cand["batch_index"] == skip:
+            at = (cand["epoch"], cand["batch_index"])
+            if seam_capable and at in (
+                (self.epoch, skip), (self.epoch + 1, 0)
+            ):
                 seam = cand
             else:
                 self.log.log(
@@ -1505,26 +1534,30 @@ class Trainer:
                     seam_epoch=cand["epoch"],
                     seam_batch_index=cand["batch_index"],
                 )
-        # per-epoch sampling rng is FOLDED from the global epoch, not drawn
-        # from a running split chain, so a resumed phase continues the stream
-        # (epoch k uses fold_in(base, k) whether or not the process
-        # restarted); a rollback salt re-randomizes it together with the
-        # batch order
-        # the seed is static (device_key: one value a process, a jit-cache
-        # hit every epoch); the salt and the epoch are TRACED arguments of
-        # one fold-in program for all epochs (device_fold_in), uploaded by
-        # an explicit device_put: static, each epoch would compile a program
-        # of its own right here; eager, each would stage its integer
-        # through an implicit transfer inside the sanitized loop
-        with obs.span("rl.epoch.keys"):
-            base_rng = device_key(cfg.train.seed + 1)
-            if self.batcher.salt:
-                base_rng = device_fold_in(base_rng, self.batcher.salt)
-            ep_rng = device_fold_in(base_rng, self.epoch)
-            # mid-epoch resume: advance the per-batch split chain past the
-            # ``skip`` batches the checkpoint already trained on
-            for _ in range(skip):
-                ep_rng = jax.random.split(ep_rng)[0]
+        if scst.primed is not None:
+            # the epoch before this one opened it inside its drain: its
+            # first two batches are decoded, its key is folded and split
+            batches, ep_rng = scst.primed.batches, scst.primed.rng
+        else:
+            with obs.span("rl.epoch.keys"):
+                ep_rng = self._rl_epoch_key(self.epoch, skip)
+            batches = self._rl_device_batches(rl_batcher, skip=skip,
+                                              epochs=epochs_left)
+        kw = {}
+        if cfg.rl.pipelined and not decoupled and epochs_left > 1:
+
+            def next_epoch():
+                """The epoch after this one, for the pipeline to run on
+                into: the batches the feed's worker staged ahead, and its
+                key."""
+                rl_batcher.epoch_index = self.epoch + 1
+                with obs.span("rl.epoch.keys"):
+                    key = self._rl_epoch_key(self.epoch + 1)
+                return self._rl_device_batches(
+                    rl_batcher, epochs=epochs_left - 1
+                ), key
+
+            kw["next_epoch"] = next_epoch
         step_counter = {"step": int(self.state.step)}
         batch_counter = {"n": skip}
         if obs.enabled():
@@ -1573,8 +1606,6 @@ class Trainer:
         # to device by a host thread. pipelined=False: strict on-policy.
         # should_stop: a SIGTERM stops consuming at the next batch boundary
         # and the pipeline drains, so state == batch_counter steps exactly
-        batches = self._rl_device_batches(rl_batcher, skip=skip,
-                                          epochs=epochs_left)
         # the rl.epoch span's self time is what no span inside it claims
         # (rl.decode/reward/update, prefetch.wait, rl.epoch.drain): rng
         # splits, step bookkeeping, the epoch's unattributed remainder
@@ -1595,24 +1626,41 @@ class Trainer:
                     ) or self._regrow_host is not None,
                     seam=seam,
                     seam_sink=seam_sink if seam_capable else None,
+                    **kw,
                 )
             finally:
                 batches.close()     # as in _xe_epoch
+            if scst.primed is not None:
+                # the epoch's last update is queued and a decode behind it:
+                # the state's read-back runs under that decode. Begun here
+                # and not inside the call: self.state has just let go of the
+                # state before this one, whose cached host copy (174 MB in
+                # the benchmark's cell) is freed before this one's is made;
+                # with both alive in turn every other epoch was 20 ms longer
+                # on four chips (PERF.md section 6, PR 47)
+                host_copy_begin(self.state)
             profiler.stop()
+            peer_lost = self.health is not None and self.health.peer_lost
+            stop_seam = None
+            if pre.requested or peer_lost or self._regrow_host is not None:
+                # the tokens a stop's checkpoint carries: the batch the
+                # pipeline was stopped at or, where it had already primed
+                # the next epoch, that epoch's first
+                stop_seam = seam_sink or scst.primed_seam()
             if pre.requested:
                 self._preempt_save(
                     "rl", step_counter["step"], batch_counter["n"], sentinel,
-                    seam=seam_sink or None,
+                    seam=stop_seam,
                 )
-            if self.health is not None and self.health.peer_lost:
+            if peer_lost:
                 self._peer_loss_save(
                     "rl", step_counter["step"], batch_counter["n"], sentinel,
-                    seam=seam_sink or None,
+                    seam=stop_seam,
                 )
             if self._regrow_host is not None:
                 self._regrow_save(
                     "rl", step_counter["step"], batch_counter["n"], sentinel,
-                    seam=seam_sink or None,
+                    seam=stop_seam,
                 )
             # the wait for the queued updates: both read device scalars of
             # the epoch's last steps back (the device is busy meanwhile)
@@ -1637,11 +1685,44 @@ class Trainer:
             **meter.epoch_summary(),
         )
         obs.snapshot_metrics(epoch=self.epoch)
-        return self._validate_and_checkpoint(step_counter["step"])
+        # after a primed epoch the checkpoint carries the tokens of the next
+        # epoch's first batch: it was decoded one update before this state
+        return self._validate_and_checkpoint(
+            step_counter["step"],
+            seam=scst.primed_seam if scst.primed is not None else None,
+        )
+
+    def _rl_epoch_key(self, epoch: int, skip: int = 0):
+        """The sampling key of RL epoch ``epoch``, advanced past ``skip``
+        batches. FOLDED from the global epoch, not drawn from a running
+        split chain, so a resumed phase continues the stream (epoch k uses
+        fold_in(base, k) whether or not the process restarted); a rollback
+        salt re-randomizes it together with the batch order. The seed is
+        static (device_key: one value a process, a jit-cache hit every
+        epoch); the salt and the epoch are TRACED arguments of one fold-in
+        program for all epochs (device_fold_in), uploaded by an explicit
+        device_put: static, each epoch would compile a program of its own
+        right here; eager, each would stage its integer through an implicit
+        transfer inside the sanitized loop."""
+        rng = device_key(self.cfg.train.seed + 1)
+        if self.batcher.salt:
+            rng = device_fold_in(rng, self.batcher.salt)
+        rng = device_fold_in(rng, epoch)
+        # mid-epoch resume: advance the per-batch split chain past the
+        # ``skip`` batches the checkpoint already trained on
+        for _ in range(skip):
+            rng = jax.random.split(rng)[0]
+        return rng
 
     # ---- validation --------------------------------------------------------
 
-    def _validate_and_checkpoint(self, step_no: int | None = None) -> float | None:
+    def _validate_and_checkpoint(self, step_no: int | None = None,
+                                 seam=None) -> float | None:
+        """Validation where it is due, then the epoch-end checkpoint.
+        ``seam`` (a primed RL epoch's end: a callable that gives the next
+        epoch's first batch's tokens) rides along as ``seam.npz``, as a
+        seam does beside a step save; it is asked for and serialised only
+        where a checkpoint is really written, after the read-back."""
         value = None
         if self.validator is not None and (
             self.epoch % self.cfg.train.eval_every_epochs == 0
@@ -1652,22 +1733,34 @@ class Trainer:
             result = self.validator.evaluate(self.state.params)
             value = result["metrics"].get("CIDEr-D")
             self.log.log("validate", epoch=self.epoch, cider_d=value)
+        if seam is not None and multihost.is_multiprocess():
+            # the tokens are gathered across processes: every process takes
+            # part, before process 0 goes on alone
+            tokens = seam()
+            seam = lambda: tokens   # noqa: E731
         if jax.process_index() != 0:
             return value
         with obs.span("ckpt", kind="epoch"):
+            # full config snapshot: the reference's `infos` pickle carried
+            # the whole opt namespace (SURVEY.md §5 checkpoint row);
+            # global_step/phase/batch_index/data_salt feed mid-epoch
+            # resume ordering. Made before the read-back: after a primed RL
+            # epoch the state's copy is in flight, and this runs under it
+            infos = self._ckpt_infos(step_no=step_no)
+            if seam is not None:
+                infos["extra_files"] = {
+                    "seam.npz": lambda: self._seam_bytes(
+                        seam(), self.epoch, 0
+                    ),
+                }
             # the read-back apart from the write: it waits for whatever the
             # device still has queued, then the device idles for the copy
+            # (after a primed RL epoch the copy was begun when the call
+            # returned, behind the epoch's last update, and runs under the
+            # next epoch's second decode)
             with obs.span("ckpt.readback"):
                 host_state = host_copy(self.state)
-            is_best = self.ckpt.save(
-                host_state,
-                value,
-                # full config snapshot: the reference's `infos` pickle carried
-                # the whole opt namespace (SURVEY.md §5 checkpoint row);
-                # global_step/phase/batch_index/data_salt feed mid-epoch
-                # resume ordering
-                infos=self._ckpt_infos(step_no=step_no),
-            )
+            is_best = self.ckpt.save(host_state, value, infos=infos)
         if is_best:
             self.log.log("new_best", epoch=self.epoch, cider_d=value)
         return value

@@ -379,6 +379,217 @@ def test_rl_pipelined_preempt_seam_resume_is_bit_identical(datasets,
     assert chaosrun == straight
 
 
+# ---- the RL pipeline primed across the epoch's end ---------------------------
+
+# 10 train videos / batch 2 = 5 RL batches an epoch; rl.step visit v is the
+# (v % 5)-th update of RL epoch v // 5
+RL_BATCHES = 5
+
+
+def _run_primed(train_ds, ckpt_dir, resume="", **kw):
+    cfg = make_cfg(ckpt_dir, len(train_ds.vocab), pipelined=True,
+                   batch_size=2, seq_per_vid=1, epochs=1, rl_epochs=3,
+                   resume=resume, **kw)
+    tr = Trainer(cfg, train_ds, None, log_path=ckpt_dir + "/ev.jsonl",
+                 use_mesh=False)
+    tr.train_xe()
+    tr.train_rl()
+    return tr
+
+
+def _rl_steps(path):
+    return {e["step"]: (e["reward"], e["rl_loss"])
+            for e in events_of(path, "rl_step")}
+
+
+@pytest.fixture(scope="module")
+def primed_straight(datasets, tmp_path_factory):
+    """Three pipelined RL epochs, uninterrupted: the second and the third
+    begin with the pair the epoch before decoded inside its drain."""
+    d = str(tmp_path_factory.mktemp("primedstraight"))
+    return _run_primed(datasets[0], d), d
+
+
+# what stops the run in the second RL epoch, and where the loop notices it.
+# (The second: the RL phase counts its steps from 0, so a step save of the
+# first RL epoch would tie with the XE phase's last save. A slot polls the
+# stop, then dispatches the pending update: a stop raised in update(i)'s
+# step is noticed at the poll after it.)
+_BOUNDARY_STOPS = {
+    # update(n-3), dispatched in batch n-1's slot: noticed at the first
+    # priming poll -> update(n-2), decode(0') as the seam, drain
+    "stop_before_priming": Fault("rl.step", "preempt", at=2 * RL_BATCHES - 3),
+    # update(n-2), dispatched in batch 0''s slot: noticed at the second
+    # poll, decode(1') never dispatched
+    "stop_while_priming": Fault("rl.step", "preempt", at=2 * RL_BATCHES - 2),
+    # the scoring of batch 0' (two reward calls a batch, greedy baseline),
+    # the call's last act: noticed by the Trainer when it has returned with
+    # the primed pair
+    "stop_after_priming": Fault("reward.call", "preempt",
+                                at=4 * RL_BATCHES),
+    # the process dies on the next epoch's first step: what is left is the
+    # epoch-end checkpoint
+    "killed_after_epoch_end_checkpoint": Fault("rl.step", "kill",
+                                               at=2 * RL_BATCHES),
+}
+
+
+@pytest.mark.parametrize("case", list(_BOUNDARY_STOPS))
+def test_rl_primed_epoch_end_resume_is_bit_identical(datasets, primed_straight,
+                                                     tmp_path_factory, case):
+    """A pipelined RL run stopped at an epoch's end (before, while or after
+    the next epoch's first two batches are decoded inside its drain), or
+    killed and resumed from the epoch-end checkpoint, continues bit for bit
+    as the uninterrupted run: the state holds exactly the epoch's updates,
+    and the next epoch's first batch's tokens ride beside it as the seam at
+    (epoch + 1, batch 0), because that batch was decoded one update before
+    the saved state."""
+    from cst_captioning_tpu.resilience.chaos import SimulatedKill
+
+    train_ds, _ = datasets
+    tr_straight, d1 = primed_straight
+    d2 = str(tmp_path_factory.mktemp("primed_" + case))
+    fault = _BOUNDARY_STOPS[case]
+    with FaultPlan([fault]).activate():
+        with pytest.raises(SimulatedKill if fault.kind == "kill"
+                           else Preempted):
+            _run_primed(train_ds, d2)
+    if fault.kind == "kill":
+        name = "latest"
+        assert not events_of(d2 + "/ev.jsonl", "ckpt_step")
+    else:
+        save = events_of(d2 + "/ev.jsonl", "ckpt_step")[-1]
+        # every batch of the epoch is applied; the seam is the next epoch's
+        assert save["phase"] == "rl" and save["seam"] is True
+        assert save["batch_index"] == RL_BATCHES
+        name = f"step_{save['step']:08d}"
+    with np.load(os.path.join(d2, name, "seam.npz")) as z:
+        # global epoch 2 is the second RL epoch (one XE epoch before them)
+        assert (int(z["epoch"]), int(z["batch_index"])) == (3, 0)
+    infos = json.load(open(os.path.join(d2, name, "infos.json")))
+    assert infos["global_step"] == 2 * RL_BATCHES
+    assert "extra_files" not in infos
+
+    tr_res = _run_primed(train_ds, d2, resume="auto")
+    loaded = events_of(d2 + "/ev.jsonl", "seam_loaded")
+    assert loaded and (loaded[-1]["epoch"], loaded[-1]["batch_index"]) == (3, 0)
+    assert not events_of(d2 + "/ev.jsonl", "seam_discarded")
+    assert tr_res.rl_epochs == tr_straight.rl_epochs == 3
+    assert int(tr_res.state.step) == int(tr_straight.state.step)
+    params_equal(tr_straight.state.params, tr_res.state.params)
+    assert _rl_steps(d2 + "/ev.jsonl") == _rl_steps(d1 + "/ev.jsonl")
+
+
+def _one_deep_train_epoch():
+    """A plain reference for ``SCSTTrainer.train_epoch`` in a primed phase:
+    decode(i) -> score(i-1) -> update(i-1), one batch deep and nothing else,
+    run on over every epoch's end. The batch decoded and not yet applied
+    (the next epoch's first) and that epoch's key after its split are
+    carried to the next call, which skips the batch in its own iterator."""
+    carried: dict = {}
+
+    def train_epoch(self, state, batches, rng, on_step=None, next_epoch=None,
+                    **kw):
+        out = []
+        pending = carried.pop("pending", None)
+        if pending is not None:
+            rng = carried.pop("rng")
+            assert next(batches)[2] == pending[4]
+
+        def decode(batch, srng):
+            f, m, v, valid = batch
+            d = self.decode(state.params, f, m, srng)
+            return (*d, f, m, v, self._valid_np(valid, len(v)))
+
+        def finish():
+            nonlocal state
+            state, m = self._finish(state, *pending)
+            out.append(m)
+            on_step(m)
+
+        for batch in batches:
+            rng, srng = jax.random.split(rng)
+            decoded = decode(batch, srng)
+            if pending is not None:
+                finish()
+            pending = decoded
+        ahead = next_epoch() if next_epoch is not None else None
+        if ahead is not None:
+            rng, srng = jax.random.split(ahead[1])
+            decoded = decode(next(ahead[0]), srng)
+            ahead[0].close()
+            finish()
+            carried.update(pending=decoded, rng=rng)
+        else:
+            finish()
+        return state, out
+
+    return train_epoch
+
+
+def test_rl_primed_phase_is_one_loop_over_the_phase(datasets, primed_straight,
+                                                    tmp_path_factory,
+                                                    monkeypatch):
+    """``train_rl`` over three pipelined epochs IS one 1-deep pipelined loop
+    over the phase's batches end to end (every batch decoded one update
+    stale, an epoch's first included): parameters and per-step rewards and
+    losses are those of a plain 1-deep reference driven through the same
+    Trainer. A phase cut into calls of one epoch each begins every epoch
+    with fresh parameters instead, and lands elsewhere."""
+    from cst_captioning_tpu.rl.scst import SCSTTrainer
+
+    train_ds, _ = datasets
+    tr_straight, d1 = primed_straight
+    d2 = str(tmp_path_factory.mktemp("primedref"))
+    with monkeypatch.context() as mp:
+        mp.setattr(SCSTTrainer, "train_epoch", _one_deep_train_epoch())
+        tr_ref = _run_primed(train_ds, d2)
+    assert int(tr_ref.state.step) == int(tr_straight.state.step)
+    params_equal(tr_straight.state.params, tr_ref.state.params)
+    assert _rl_steps(d2 + "/ev.jsonl") == _rl_steps(d1 + "/ev.jsonl")
+
+    d3 = str(tmp_path_factory.mktemp("primedcut"))
+    cfg = make_cfg(d3, len(train_ds.vocab), pipelined=True, batch_size=2,
+                   seq_per_vid=1, epochs=1, rl_epochs=3)
+    tr_cut = Trainer(cfg, train_ds, None, log_path=d3 + "/ev.jsonl",
+                     use_mesh=False)
+    tr_cut.train_xe()
+    for _ in range(3):
+        tr_cut.train_rl(epochs=1)
+    assert _rl_steps(d3 + "/ev.jsonl") != _rl_steps(d1 + "/ev.jsonl")
+
+
+def test_rl_rollback_drops_the_primed_pair(datasets, tmp_path_factory):
+    """A divergence found in an epoch's drain, when the next epoch's first
+    two batches are already decoded: the rollback drops the pair with the
+    staged batches (the replayed epoch is re-salted, so neither is its), the
+    replay begins with an empty pipeline, and the phase still runs out."""
+    import threading
+
+    from cst_captioning_tpu import obs
+
+    train_ds, _ = datasets
+    d = str(tmp_path_factory.mktemp("primedroll"))
+
+    def counts():
+        return np.array([obs.counter(f"rl.epoch.{k}").snapshot()
+                         for k in ("primed", "cold")])
+
+    c0 = counts()
+    # rl.batch visit 7: the third batch of the second RL epoch
+    with FaultPlan([Fault("rl.batch", "nan", at=RL_BATCHES + 2)]).activate():
+        tr = _run_primed(train_ds, d, on_divergence="rollback")
+    (rb,) = events_of(d + "/ev.jsonl", "rollback")
+    assert rb["restored_epoch"] == 2 and rb["salt"] == 1
+    assert tr.rl_epochs == 3 and tr.batcher.salt == 1
+    assert tr._pending_seam is None
+    assert not [t for t in threading.enumerate() if t.name == "prefetch"]
+    # RL epochs 1, 2, 2 again, 3: the replay of 2 is the one more cold start
+    assert (counts() - c0).tolist() == [2, 2]
+    for leaf in jax.tree_util.tree_leaves(tr.state.params):
+        assert np.isfinite(np.asarray(leaf)).all()
+
+
 def test_partial_preempt_xe_strict_drains_and_raises(datasets,
                                                      tmp_path_factory):
     """partial_preempt during XE under elastic='strict': drain -> durable
@@ -659,33 +870,49 @@ def test_enospc_during_training_rotation_recovers(datasets, tmp_path_factory):
 
 @pytest.mark.slow
 def test_decoupled_preempt_ring_seam_resume_is_bit_identical(
-        datasets, tmp_path_factory):
+        datasets, tmp_path_factory, monkeypatch):
     """Decoupled-topology twin of the pipelined seam test: preempting the
     actor/learner loop mid-epoch persists the in-flight rollout RING next
     to the checkpoint; the resume replays those exact tokens. With shared
     roles (use_mesh=False) the default depth-2/bound-1 ring IS the sync
     1-deep pipeline, so the whole chain — straight decoupled, preempted +
     resumed decoupled, straight pipelined sync — lands on bit-identical
-    params."""
+    params, PER EPOCH-SIZED CALL: the ring fills and drains inside every
+    epoch, as the sync loop does when it is asked for one epoch a call. The
+    sync loop given the phase of two primes the second epoch across the
+    boundary (its first batch decoded one update stale): that run is pinned
+    to the plain 1-deep loop over the whole phase instead."""
+    from cst_captioning_tpu.rl.scst import SCSTTrainer
+
     train_ds, _ = datasets
     d0 = str(tmp_path_factory.mktemp("decsync"))
     d1 = str(tmp_path_factory.mktemp("decstraight"))
     d2 = str(tmp_path_factory.mktemp("decpreempt"))
 
-    def run(ckpt_dir, resume="", topology="decoupled"):
+    def run(ckpt_dir, resume="", topology="decoupled", epoch_calls=False):
         cfg = make_cfg(ckpt_dir, len(train_ds.vocab), pipelined=True,
                        batch_size=2, seq_per_vid=1, epochs=1, resume=resume,
                        rl_topology=topology)
         tr = Trainer(cfg, train_ds, None, log_path=ckpt_dir + "/ev.jsonl",
                      use_mesh=False)
         tr.train_xe()
-        tr.train_rl()
+        if epoch_calls:
+            for _ in range(cfg.rl.epochs):
+                tr.train_rl(epochs=1)
+        else:
+            tr.train_rl()
         return tr
 
-    tr_sync = run(d0, topology="sync")
+    tr_sync = run(d0, topology="sync", epoch_calls=True)
     tr_straight = run(d1)
     # shared roles + depth 2 + bound 1 replays the sync pipelined schedule
     params_equal(tr_sync.state.params, tr_straight.state.params)
+    # the two-epoch sync phase in one call: one loop over both epochs
+    tr_phase = run(str(tmp_path_factory.mktemp("decphase")), topology="sync")
+    with monkeypatch.context() as mp:
+        mp.setattr(SCSTTrainer, "train_epoch", _one_deep_train_epoch())
+        tr_ref = run(str(tmp_path_factory.mktemp("decref")), topology="sync")
+    params_equal(tr_phase.state.params, tr_ref.state.params)
 
     # 5 rl.step visits per epoch; visit 6 = the second update of epoch 2
     # -> the stop lands with a decoded-but-unscored ring entry in flight

@@ -1509,6 +1509,10 @@ def test_one_prefetch_worker_serves_a_phase_and_the_report_says_so(
     assert ("prefetch: 3 epoch(s) found their first batches staged by the "
             "worker of the epoch before, 2 started it cold; 0 staged "
             "batch(es) dropped") in render_report(rep)
+    # the pipelined RL loop runs on across every epoch's end but the phase's
+    assert rep["rl_epochs"] == {"primed": rl - 1, "cold": 1}
+    assert ("rl epochs: 2 began with the pipeline primed inside the drain of "
+            "the epoch before, 1 with it empty") in render_report(rep)
 
 
 def test_spans_land_in_a_profiler_trace(tmp_path):

@@ -1,5 +1,7 @@
 """RL tests: consensus rewards, SCB baseline, SCST learning on a rigged reward."""
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -947,3 +949,277 @@ def test_train_epoch_pipelined_matches_one_deep_schedule_at_lr(model_setup):
         lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
         s_new.params, s_old.params,
     )
+
+
+# ---- the pipeline primed across the epoch's end ------------------------------
+
+
+class _Recording:
+    """An ``SCSTTrainer`` whose decode, reward and update write down, in
+    dispatch order, which batch each was called for: ``(what, epoch, i)``.
+    Batches are told apart by their first video id (``e<epoch>b<i>.v0``) and
+    by the feature array they carry."""
+
+    def __init__(self, model, epochs=3, n=4, B=8, **cfg):
+        self.log: list[tuple] = []
+        self.tag_of: dict[int, tuple] = {}
+        rl_cfg = RLConfig(enabled=True, num_rollouts=2, baseline="greedy",
+                          **cfg)
+        self.trainer = t = SCSTTrainer(model, self._reward, rl_cfg)
+        self._decode, self._update = t.decode, t.update
+        t.decode, t.update = self.decode, self.update
+        self._target = TokenReward(target=7)
+        self.B = B
+        self.keys = [jax.random.key(100 + e) for e in range(epochs)]
+        self.n = n
+
+    def batches(self, model_setup, e, n=None):
+        _, _, feats, masks = model_setup
+        out = []
+        for i in range(self.n if n is None else n):
+            f = {"resnet": feats["resnet"] * (1.0 + 0.05 * (self.n * e + i))}
+            self.tag_of[id(f["resnet"])] = (e, i)
+            vids = [f"e{e}b{i}.v{k}" for k in range(self.B)]
+            out.append((f, masks, vids, None))
+        return out
+
+    def decode(self, params, feats, masks, rng):
+        self.log.append(("decode", *self.tag_of[id(feats["resnet"])]))
+        return self._decode(params, feats, masks, rng)
+
+    def update(self, state, feats, *rest):
+        self.log.append(("update", *self.tag_of[id(feats["resnet"])]))
+        return self._update(state, feats, *rest)
+
+    def _reward(self, video_ids, rows):
+        e, i = video_ids[0][1:].split(".")[0].split("b")
+        if not self.log or self.log[-1] != ("score", int(e), int(i)):
+            self.log.append(("score", int(e), int(i)))  # greedy + samples
+        return self._target(video_ids, rows)
+
+    def one_deep(self, state, epochs):
+        """The reference: the 1-deep decode(i) -> score(i-1) -> update(i-1)
+        loop over the epochs' batches end to end, each batch decoded with the
+        key its own epoch's split chain gives it. Returns the state after
+        every update."""
+        t, states, pending = self.trainer, [], None
+        t.decode, t.update = self._decode, self._update
+        try:
+            for e, batches in enumerate(epochs):
+                rng = self.keys[e]
+                for f, m, v, _ in batches:
+                    rng, srng = jax.random.split(rng)
+                    d = t.decode(state.params, f, m, srng)
+                    if pending is not None:
+                        state, _ = t._finish(state, *pending)
+                        states.append(state)
+                    pending = (*d, f, m, v, np.ones((self.B,), np.float32))
+            state, _ = t._finish(state, *pending)
+            states.append(state)
+        finally:
+            t.decode, t.update = self.decode, self.update
+            del self.log[:]
+        return states
+
+
+def _same_params(a, b):
+    jax.tree.map(
+        lambda x, y: np.testing.assert_array_equal(np.asarray(x), np.asarray(y)),
+        a.params, b.params,
+    )
+
+
+def _epoch_counts():
+    return np.array([obs.counter(f"rl.epoch.{k}").snapshot()
+                     for k in ("primed", "cold")])
+
+
+def _slots(e, first, last):
+    """update(i-2) -> decode(i) -> score(i-1) for batches first..last of
+    epoch ``e``, the two before them already in flight."""
+    out = []
+    for i in range(first, last + 1):
+        out += [("update", e, i - 2), ("decode", e, i), ("score", e, i - 1)]
+    return out
+
+
+def test_train_epoch_primed_across_the_epochs_end(model_setup):
+    """Two epochs of four batches, the second opened by ``next_epoch`` inside
+    the first one's drain: the dispatch order runs on over the cut, the state
+    the first call returns holds exactly its four updates and four metrics,
+    the second call begins with the primed pair, and the parameters after
+    both are bit-identical to the 1-deep loop over all eight batches with
+    each batch's own key."""
+    model, state, _, _ = model_setup
+    rec = _Recording(model)
+    e0, e1 = rec.batches(model_setup, 0), rec.batches(model_setup, 1)
+    ref = rec.one_deep(state, [e0, e1])
+    counts0 = _epoch_counts()
+    steps: list[int] = []
+    opened = []
+
+    def next_epoch():
+        opened.append(iter(e1))
+        return opened[0], rec.keys[1]
+
+    sink: dict = {}
+    s0, m0 = rec.trainer.train_epoch(
+        state, iter(e0), rec.keys[0], on_step=lambda m: steps.append(0),
+        seam_sink=sink, next_epoch=next_epoch,
+    )
+    assert rec.log == (
+        [("decode", 0, 0), ("decode", 0, 1), ("score", 0, 0)]
+        + _slots(0, 2, 3)
+        # the fill of epoch 1 inside the drain of epoch 0
+        + [("update", 0, 2), ("decode", 1, 0), ("score", 0, 3),
+           ("update", 0, 3), ("decode", 1, 1), ("score", 1, 0)]
+    )
+    # (b) exactly this epoch's updates, none of the next epoch's
+    assert len(m0) == 4 and steps == [0] * 4 and int(s0.step) == 4
+    _same_params(s0, ref[3])
+    # what the next call needs is the trainer's to keep; the sink is a stop's
+    primed = rec.trainer.primed
+    assert sink == {} and primed.batches is opened[0]
+    tokens = rec.trainer.primed_seam()
+    assert tokens["next_epoch"] is True and tokens["video_ids"] == e1[0][2]
+    np.testing.assert_array_equal(tokens["samples"],
+                                  np.asarray(primed.first[0][1]))
+
+    del rec.log[:]
+    sink1: dict = {}
+    s1, m1 = rec.trainer.train_epoch(
+        s0, primed.batches, primed.rng, on_step=lambda m: steps.append(1),
+        seam_sink=sink1,
+    )
+    del primed
+    assert rec.log == _slots(1, 2, 3) + [
+        ("update", 1, 2), ("score", 1, 3), ("update", 1, 3)]
+    assert len(m1) == 4 and steps == [0] * 4 + [1] * 4 and int(s1.step) == 8
+    assert sink1 == {}      # the last epoch drains as ever
+    # taken over, not copied: nothing keeps the pair's batches alive
+    assert rec.trainer.primed is None and rec.trainer.primed_seam() is None
+    _same_params(s1, ref[7])
+    assert (_epoch_counts() - counts0).tolist() == [1, 1]
+
+
+def _run_phase(rec, state, epochs, **kw):
+    """What ``Trainer.train_rl`` does with ``train_epoch`` over a phase: the
+    next epoch's iterator and key through ``next_epoch`` on every epoch but
+    the last, and what the trainer kept of them (``primed``) as the next
+    call's batches and key. Returns the state and the number of metrics
+    after every call."""
+    its = [iter(b) for b in epochs]
+    ends = []
+    for e in range(len(epochs)):
+        nxt = None
+        if e + 1 < len(epochs):
+            nxt = lambda e=e: (its[e + 1], rec.keys[e + 1])  # noqa: E731
+        primed = rec.trainer.primed
+        batches, key = (its[e], rec.keys[e]) if primed is None else (
+            primed.batches, primed.rng)
+        del primed
+        state, m = rec.trainer.train_epoch(
+            state, batches, key, seam_sink={}, next_epoch=nxt, **kw,
+        )
+        ends.append((state, len(m)))
+    return ends
+
+
+@pytest.mark.parametrize("sizes", [(4, 4), (1, 3), (3, 1, 2), (2, 2, 2),
+                                   (1, 1, 1)],
+                         ids=lambda s: "-".join(map(str, s)))
+def test_primed_phase_matches_one_deep_for_any_epoch_sizes(model_setup, sizes):
+    """Whatever the epochs' lengths (an epoch of one batch hands over a
+    decoded batch and nothing scored; one of two enters the next call with
+    nothing left to fetch), every call returns its own epoch's updates and
+    no more, and the phase is the 1-deep loop over all batches end to end."""
+    model, state, _, _ = model_setup
+    rec = _Recording(model)
+    epochs = [rec.batches(model_setup, e, n) for e, n in enumerate(sizes)]
+    ref = rec.one_deep(state, epochs)
+    done = 0
+    for (s, n_metrics), n in zip(_run_phase(rec, state, epochs), sizes,
+                                 strict=True):
+        done += n
+        assert n_metrics == n and int(s.step) == done
+        _same_params(s, ref[done - 1])
+    # every batch decoded once, scored once and applied once, in order
+    for what in ("decode", "score", "update"):
+        assert [x[1:] for x in rec.log if x[0] == what] == [
+            (e, i) for e, n in enumerate(sizes) for i in range(n)]
+
+
+_DRAINS_AS_EVER = {
+    # (train_epoch's further arguments, polls of should_stop before it says
+    # stop (None: never), what the first call's sink holds)
+    "last_epoch": (dict(next_epoch=None), None, set()),
+    "no_next_epoch": (dict(next_epoch=lambda: None), None, set()),
+    "strict": (dict(pipelined=False), None, set()),
+    "no_sink": (dict(seam_sink=None), None, None),
+    "stop_before_priming": ({}, 4, {"samples", "greedy", "video_ids",
+                                    "next_epoch"}),
+    "stop_while_priming": ({}, 5, {"samples", "greedy", "video_ids",
+                                   "next_epoch"}),
+}
+
+
+@pytest.mark.parametrize("case", list(_DRAINS_AS_EVER))
+def test_train_epoch_drains_as_ever_where_it_does_not_prime(model_setup, case):
+    """The phase's last epoch, a phase with no epoch to follow, the strict
+    loop, a caller with no sink, and a stop that arrives before or while
+    priming: the call ends with its own four updates applied and nothing
+    handed over, and counts as a cold start (``rl.epoch.cold``). A stop
+    while priming leaves the next epoch's first batch's tokens in the sink
+    (position: the next epoch's batch 0); a run resumed from the returned
+    state with them is bit-identical to the primed one."""
+    model, state, _, _ = model_setup
+    kw, stop_after, holds = _DRAINS_AS_EVER[case]
+    rec = _Recording(model, pipelined=kw.get("pipelined", True))
+    e0, e1 = rec.batches(model_setup, 0), rec.batches(model_setup, 1)
+    strict = kw.get("pipelined") is False
+    if strict:
+        ref, s = [], state
+        for e, batches in enumerate((e0, e1)):
+            rng = rec.keys[e]
+            for f, m, v, _ in batches:
+                rng, srng = jax.random.split(rng)
+                s, _ = rec.trainer.train_step(s, f, m, v, srng)
+                ref.append(s)
+        del rec.log[:]
+    else:
+        ref = rec.one_deep(state, [e0, e1])
+    polls = itertools.count()
+    sink: dict = {}
+    it1 = iter(e1)
+    args = dict(seam_sink=sink, next_epoch=lambda: (it1, rec.keys[1]),
+                should_stop=lambda: (stop_after is not None
+                                     and next(polls) >= stop_after))
+    args.update(kw)
+    counts0 = _epoch_counts()
+    s0, m0 = rec.trainer.train_epoch(state, iter(e0), rec.keys[0], **args)
+    assert len(m0) == 4 and int(s0.step) == 4
+    _same_params(s0, ref[3])
+    assert (_epoch_counts() - counts0).tolist() == [0, 1]
+    assert rec.trainer.primed is None       # nothing is kept
+    if holds is not None:
+        assert set(sink) == holds
+    ahead = [x for x in rec.log if x[1] == 1]
+    if stop_after is None:
+        assert not ahead        # nothing of the next epoch was touched
+        assert rec.log[-3:] == (
+            [("decode", 0, 3), ("score", 0, 3), ("update", 0, 3)] if strict
+            else [("update", 0, 2), ("score", 0, 3), ("update", 0, 3)])
+        return
+    # the stop: update(n-2) -> decode(0') as the seam, then this epoch's rest
+    assert ahead == [("decode", 1, 0)]
+    assert rec.log[-4:] == [("update", 0, 2), ("decode", 1, 0),
+                            ("score", 0, 3), ("update", 0, 3)]
+    assert sink["next_epoch"] is True and sink["video_ids"] == e1[0][2]
+    del rec.log[:]
+    s1, m1 = rec.trainer.train_epoch(
+        s0, iter(e1), rec.keys[1], seam=dict(sink, epoch=1, batch_index=0),
+        seam_sink={},
+    )
+    assert ("decode", 1, 0) not in rec.log and ("decode", 1, 1) in rec.log
+    assert len(m1) == 4
+    _same_params(s1, ref[7])

@@ -152,3 +152,35 @@ def test_mesh_hot_loops_run_clean_under_transfer_guard(
     rewards = [e["reward"] for e in events if e["event"] == "rl_epoch"]
     assert len(losses) == cfg.train.epochs and len(rewards) == cfg.rl.epochs
     assert all(x == x for x in losses + rewards)
+
+
+@pytest.mark.parametrize("use_mesh", [False, True], ids=["one_device", "mesh"])
+def test_primed_rl_epochs_run_clean_under_transfer_guard(
+    sanitize_datasets, tmp_path_factory, hot_guard, use_mesh
+):
+    """Three pipelined RL epochs, the second and third primed inside the
+    drain of the epoch before: the next epoch's key is folded and replicated
+    there, its first two batches are decoded there, and the state's copy to
+    the host is begun before the read-back asks for it: all of it explicit."""
+    import dataclasses
+
+    from cst_captioning_tpu import obs
+
+    train_ds, _ = sanitize_datasets
+    ckpt_dir = str(tmp_path_factory.mktemp("sanitize_primed"))
+    log_path = ckpt_dir + "/events.jsonl"
+    cfg = _cfg(ckpt_dir, len(train_ds.vocab))
+    cfg = dataclasses.replace(
+        cfg,
+        data=dataclasses.replace(cfg.data, batch_size=8 if use_mesh else 4),
+        rl=dataclasses.replace(cfg.rl, epochs=3, pipelined=True),
+    )
+    tr = Trainer(cfg, train_ds, None, log_path=log_path, use_mesh=use_mesh)
+    tr.train_xe()
+    primed0 = obs.counter("rl.epoch.primed").snapshot()
+    with hot_guard():
+        tr.train_rl()
+    assert obs.counter("rl.epoch.primed").snapshot() - primed0 == 2
+    events = [json.loads(l) for l in open(log_path)]
+    rewards = [e["reward"] for e in events if e["event"] == "rl_epoch"]
+    assert len(rewards) == 3 and all(r == r for r in rewards)
