@@ -62,13 +62,6 @@ class ModelConfig:
     # (pmax/psum over ICI), so videos longer than one chip's HBM still train
     # and decode. "" = single-device frame axis (the default).
     seq_axis: str = ""
-    # temporal-attention context implementation: "xla" (the fused composite
-    # XLA compiles, default) or "pallas" (ops/attention_pallas.py — blockwise
-    # online softmax over the frame axis; parity-tested. A round-4 builder's
-    # run on a v5e had XLA tying or beating it at every M up to 8192
-    # (BASELINE.md), no cell reaches it, so "xla" is recommended everywhere
-    # and ROADMAP.md D2 lists the kernel for deletion)
-    attention_impl: str = "xla"
     # decode-step implementation for the greedy/sampling/fused RL decode
     # loops (README "Decode fast path"): "xla" (the composite the loops'
     # lane-batched step compiles to, default) or "pallas"
@@ -222,11 +215,6 @@ class ModelConfig:
             )
         if self.encoder not in ("meanpool", "temporal_attention"):
             raise ValueError(f"unknown encoder: {self.encoder!r}")
-        if self.attention_impl not in ("xla", "pallas"):
-            raise ValueError(
-                f"unknown attention_impl: {self.attention_impl!r} "
-                "(expected 'xla' or 'pallas')"
-            )
         if self.decode_impl not in ("xla", "pallas"):
             raise ValueError(
                 f"unknown decode_impl: {self.decode_impl!r} "
@@ -629,14 +617,6 @@ class ExperimentConfig:
     mesh: MeshConfig = field(default_factory=MeshConfig)
 
     def __post_init__(self):
-        if self.model.attention_impl == "pallas" and self.mesh.seq_devices > 1:
-            # the sequence-parallel path uses the collective softmax and
-            # would silently override the kernel — fail loudly instead
-            raise ValueError(
-                "attention_impl='pallas' is not implemented for the "
-                "sequence-parallel ('seq_devices > 1') path; use one or the "
-                "other"
-            )
         if self.model.decode_impl == "pallas" and self.mesh.seq_devices > 1:
             # the decode kernel fuses its own (single-device) attention
             # softmax — it cannot express the collective 'seq' softmax

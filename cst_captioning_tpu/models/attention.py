@@ -27,9 +27,6 @@ class AdditiveAttention(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     # mesh axis the frame dimension is sharded over ("" = not sharded)
     seq_axis: str = ""
-    # "xla" composite (default) or the "pallas" blockwise kernel
-    # (ops/attention_pallas.py); the collective seq_axis path overrides
-    impl: str = "xla"
 
     def setup(self):
         self.mem_proj = nn.Dense(
@@ -58,16 +55,6 @@ class AdditiveAttention(nn.Module):
     ) -> jnp.ndarray:
         """-> context [B, E]: mask-weighted sum of memory slots."""
         q = self.query_proj(query)
-        if self.impl == "pallas" and not self.seq_axis:
-            from cst_captioning_tpu.ops import fused_additive_attention
-
-            # the score kernel vector, read by pushing the identity through
-            # the Dense (also creates the param during init, keeping the
-            # parameter tree identical to the XLA path's)
-            v = self.score(jnp.eye(self.d_att, dtype=self.dtype))[:, 0]
-            return fused_additive_attention(
-                q, v, memory, memory_proj, memory_mask
-            )
         scores = self.score(jnp.tanh(memory_proj + q[:, None, :]))[..., 0]  # [B, M]
         # -1e9, not -inf: a row with zero valid slots must yield a finite
         # (uniform) softmax over zeroed memory, not NaNs that poison the step

@@ -55,7 +55,7 @@ class DecoderCell(nn.Module):
         )
         self.attention = AdditiveAttention(
             d_att=cfg.d_att, dtype=dtype, param_dtype=pdtype, name="attention",
-            seq_axis=cfg.seq_axis, impl=cfg.attention_impl,
+            seq_axis=cfg.seq_axis,
         )
         self.lstm = [
             nn.OptimizedLSTMCell(

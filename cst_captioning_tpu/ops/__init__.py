@@ -4,8 +4,6 @@ XLA's fusions cover this model well (SURVEY.md §2: "the TPU build's native
 layer is XLA itself plus optional Pallas kernels"); this package holds the
 optional kernels where explicit VMEM blocking beats the default:
 
-- the long-context additive-attention context (flash-style online softmax
-  over the frame axis, ``model.attention_impl="pallas"``);
 - the weight-stationary fused decode step (attention + LSTM stack + output
   projection in one launch, ``model.decode_impl="pallas"`` — README
   "Decode fast path");
@@ -13,12 +11,10 @@ optional kernels where explicit VMEM blocking beats the default:
   (ops/decode_mp.py — README "Model parallelism").
 """
 
-from cst_captioning_tpu.ops.attention_pallas import fused_additive_attention
 from cst_captioning_tpu.ops.decode_mp import mp_beam_step, mp_decode_stride
 from cst_captioning_tpu.ops.decode_pallas import fused_decode_step
 
 __all__ = [
-    "fused_additive_attention",
     "fused_decode_step",
     "mp_beam_step",
     "mp_decode_stride",

@@ -52,7 +52,7 @@ tests/test_ops_decode_pallas.py.
 
 Off-TPU (CPU tests) the kernel runs in Pallas interpret mode automatically;
 inside a varying-axis-checked shard_map in interpret mode it falls back to
-the jnp composite (same caveat as ops/attention_pallas.py).
+the jnp composite.
 """
 
 from __future__ import annotations
@@ -349,7 +349,7 @@ def _fused_call(cell_params, carry, emb, memory, memory_proj, memory_mask,
     args += [wop, bop]
 
     # inside a varying-axis-checked shard_map the outputs' vma must be
-    # declared (same recipe as ops/attention_pallas.py)
+    # declared
     sds = functools.partial(
         jax.ShapeDtypeStruct,
         vma=_vma(emb, memory, memory_proj, memory_mask, carry),

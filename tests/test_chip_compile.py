@@ -29,7 +29,6 @@ from jax.sharding import SingleDeviceSharding
 from cst_captioning_tpu.config import get_preset
 from cst_captioning_tpu.models import CaptionModel
 from cst_captioning_tpu.models.captioner import CaptionModel as CM
-from cst_captioning_tpu.ops.attention_pallas import fused_additive_attention
 from cst_captioning_tpu.ops.decode_pallas import (
     fused_beam_step,
     fused_decode_step,
@@ -124,10 +123,6 @@ def _kernel_case(name, block_b, sh):
     tok, fin = _sds((G, B), jnp.int32), _sds((G, B), jnp.bool_)
     noise = _sds((S, K, B, V), jnp.float32)
     i32 = _sds((), jnp.int32)
-    if name == "attention":
-        q = _sds((B, mc.d_att), enc.memory.dtype)
-        v = _sds((mc.d_att,), jnp.float32)
-        return fused_additive_attention, (q, v) + bank
     if name == "step":
         return (
             lambda c, ca, t, m, p, k: fused_decode_step(
@@ -170,7 +165,6 @@ def _kernel_case(name, block_b, sh):
 
 
 @pytest.mark.parametrize("name,block_b", [
-    ("attention", None),   # its own (8, 128) blocks; no batch-block knob used
     ("step", PRESET_BLOCK), ("step", SERVING_BLOCK),
     ("stride", PRESET_BLOCK), ("stride", SERVING_BLOCK),
     ("paged_stride", PRESET_BLOCK), ("paged_stride", SERVING_BLOCK),
