@@ -35,7 +35,8 @@ def _freeze_modalities(m: Mapping[str, int]) -> tuple[tuple[str, int], ...]:
 # the decoder kinds ``ModelConfig.decoder`` names (models/captioner.py builds
 # each); every kind but the first is a language-model stack behind a video
 # prefix that runs the evaluation path only
-DECODERS = ("lstm", "latent_moe", "sparse_linear", "eva", "window_moe")
+DECODERS = ("lstm", "latent_moe", "sparse_linear", "eva", "window_moe",
+            "cca_moe")
 
 
 @dataclass(frozen=True)
@@ -191,6 +192,24 @@ class ModelConfig:
     partial_rotary_factor: float = 1.0
     swa_rope_theta: float = 10000.0
     attention_value_scale: float = 1.0
+    # decoder kind "cca_moe" (models/cca_moe.py: a pre-norm residual stack
+    # over a video prefix whose every layer attends in a compressed latent,
+    # ``num_attention_heads`` query heads over ``num_key_value_heads``
+    # key/value heads of ``head_dim``, q and k mixed along the sequence by two
+    # causal convolutions of widths ``cca_time0`` (depthwise) and
+    # ``cca_time1`` (a head a group) before the scores, half of a value taken
+    # from the token before; its FFN is one of ``n_routed_experts`` experts of
+    # ``moe_intermediate_size``, or none, chosen top-1 by an MLP router of
+    # width ``router_hidden_size`` whose input is carried from layer to
+    # layer; learned scales on the residual merge; the head is the token
+    # embedding, ``tie_word_embeddings``). Sizes under the key names of the
+    # published config.json (benchmark/configs/zaya1_8b_20l.json); it also
+    # reads hidden_size, num_hidden_layers, partial_rotary_factor, rope_theta,
+    # rms_norm_eps, initializer_range and the expert-share fields above
+    cca_time0: int = 2
+    cca_time1: int = 2
+    router_hidden_size: int = 0
+    tie_word_embeddings: bool = False
 
     def __post_init__(self):
         if self.decoder not in DECODERS:

@@ -63,6 +63,19 @@ layers as one chip's share of a 16-way expert-parallel deployment
                                step takes all lanes at once, so the routed
                                experts walk one list of rows.
 
+Two more run the sixth (``model.decoder = "cca_moe"``, models/cca_moe.py) at
+the published widths of ZAYA1-8B, 20 of its 40 layers with every expert and
+the whole tied vocabulary (``_ZAYA1_8B_20L``):
+
+14. ``zaya1_8b_20l_xe``      — the stack behind the same 16384-slot prefix;
+                               bfloat16 parameters and plain SGD. The
+                               benchmark makes its seeded policy from it
+                               (0 steps).
+15. ``zaya1_8b_20l_eval_beam5`` — the same model, beam-5 eval through the
+                               ``Evaluator``, beams on lanes with the step
+                               taken for all lanes at once: a clip's beams
+                               share one copy of its prefix's latent keys.
+
 Paper CST variant names map onto presets as: XE -> 1/2; CST_GT_None/SCST -> 3;
 CST_MS_SCB -> 4 (with ``rl.baseline="scb"``); WXE is preset 2 with
 ``train.loss="wxe"``.
@@ -404,6 +417,65 @@ def _mimo_v2_5_ep16_eval_beam5() -> ExperimentConfig:
     )
 
 
+# ZAYA1-8B (huggingface.co/Zyphra/ZAYA1-8B, config.json): every width, all
+# 16 experts and the whole tied vocabulary as published; the depth is the
+# published layers 0-19 of 40 (every layer is of one kind), the first of two
+# pipeline stages. One modality of 16384 patch tokens, the sparse/linear
+# preset's prefix.
+_ZAYA1_8B_20L = ModelConfig(
+    decoder="cca_moe",
+    vocab_size=262272,
+    modalities=(("patch", 1024),),
+    max_len=30,
+    max_frames=16384,
+    dropout=0.0,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+    hidden_size=2048,
+    num_hidden_layers=20,
+    moe_intermediate_size=2048,
+    n_routed_experts=16,
+    n_shared_experts=0,
+    num_experts_per_tok=1,
+    num_attention_heads=8,
+    num_key_value_heads=2,
+    head_dim=128,
+    cca_time0=2,
+    cca_time1=2,
+    partial_rotary_factor=0.5,
+    rope_theta=5000000.0,
+    router_hidden_size=256,
+    tie_word_embeddings=True,
+    rms_norm_eps=1e-5,
+    initializer_range=0.02,
+    experts_held=16,
+    expert_share_index=0,
+    published_layers=40,
+    first_layer_index=0,
+)
+
+
+def _zaya1_8b_20l_xe() -> ExperimentConfig:
+    return ExperimentConfig(
+        name="zaya1_8b_20l_xe",
+        model=_ZAYA1_8B_20L,
+        data=DataConfig(dataset="msrvtt", batch_size=2),
+        train=TrainConfig(loss="xe", optimizer="sgd", lr=1e-4, epochs=1),
+    )
+
+
+def _zaya1_8b_20l_eval_beam5() -> ExperimentConfig:
+    return dataclasses.replace(
+        _zaya1_8b_20l_xe(),
+        name="zaya1_8b_20l_eval_beam5",
+        # beams on lanes, the step taken for all lanes at once (models/
+        # captioner.py ALL_LANES): a clip's latent prefix keys are read from
+        # one copy and the experts walk one list of lanes x clips rows
+        eval=EvalConfig(beam_size=5, max_len=30, split="test",
+                        beam_impl="lanes", prefill_program=True),
+    )
+
+
 PRESETS = {
     "msvd_xe_meanpool": _msvd_xe_meanpool,
     "msrvtt_xe_attention": _msrvtt_xe_attention,
@@ -418,6 +490,8 @@ PRESETS = {
     "evabyte_8l_eval_beam5": _evabyte_8l_eval_beam5,
     "mimo_v2_5_ep16_xe": _mimo_v2_5_ep16_xe,
     "mimo_v2_5_ep16_eval_beam5": _mimo_v2_5_ep16_eval_beam5,
+    "zaya1_8b_20l_xe": _zaya1_8b_20l_xe,
+    "zaya1_8b_20l_eval_beam5": _zaya1_8b_20l_eval_beam5,
 }
 
 

@@ -274,16 +274,18 @@ def carry_tally(carry):
     sparse/linear decoder's (models/sparse_linear.py: keys its sparse layers'
     queries saw and attended to) and of the EVA decoder's (models/eva.py:
     exact keys and summaries a query attended to, windows a caption
-    entered) and of the window/full decoder's (models/window_moe.py: pairs
-    a window and a full layer attended), summed over every axis but its last
+    entered), of the window/full decoder's (models/window_moe.py: pairs
+    a window and a full layer attended) and of the compressed-latent
+    decoder's (models/cca_moe.py: pairs attended, rows that chose no
+    expert), summed over every axis but its last
     two; ``()`` for a carry that counts nothing (the LSTM's), which adds no leaf
     to the loop's state."""
     found = [counts.sum(axis=tuple(range(counts.ndim - 2)))
              for counts in (getattr(carry, leaf, None)
                             for leaf in ("routed", "counted"))
              if counts is not None]
-    # one leaf as it is; both (models/window_moe.py: routed experts and
-    # attended pairs) as the pair (routed, counted)
+    # one leaf as it is; both (models/window_moe.py, models/cca_moe.py:
+    # routed experts and attended pairs) as the pair (routed, counted)
     return found[0] if len(found) == 1 else tuple(found)
 
 

@@ -264,7 +264,12 @@ class Evaluator:
         ``decode.prefix_key_bytes`` (its full layers' prefix keys and values,
         once a clip) and ``decode.window_bytes`` (its window layers' tail
         slices once a clip and their caption keys a lane), the full layers'
-        caption keys a lane being the rest of ``decode.cache_bytes``."""
+        caption keys a lane being the rest of ``decode.cache_bytes``; for the
+        compressed-latent decoder ``decode.prefix_key_bytes`` (every layer's
+        prefix keys and values in the latent, once a clip) and
+        ``decode.conv_tail_bytes`` (the convolution tail a lane keeps: one
+        position's latents and late value half a layer), its caption keys a
+        lane being the rest."""
         if not obs.enabled() or self._observed:
             return
         self._observed = True
@@ -315,6 +320,17 @@ class Evaluator:
             obs.gauge("decode.cache_bytes").set(prefix + near + lanes * lane(False))
             obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
             return
+        if kind == "cca_moe":
+            shared = 1 if self.cfg.beam_impl == "lanes" else lanes
+            prefix = shared * size(enc.memory)
+            tail = lanes * size(
+                (enc.carry.tail_c, enc.carry.tail_a, enc.carry.tail_v))
+            obs.gauge("decode.prefix_key_bytes").set(prefix)
+            obs.gauge("decode.conv_tail_bytes").set(tail)
+            obs.gauge("decode.cache_bytes").set(
+                prefix + tail + lanes * size((enc.carry.k, enc.carry.v)))
+            obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
+            return
         obs.gauge("decode.cache_bytes").set(lanes * size(enc.carry))
         if kind == "latent_moe":
             obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
@@ -330,7 +346,10 @@ class Evaluator:
         the window/full decoder's expert counts and, beside them, the
         query-key pairs its layers attended, ``attn.pairs_window`` /
         ``attn.pairs_full``, and what plain causal attention in every layer
-        would have, ``attn.pairs_causal``."""
+        would have, ``attn.pairs_causal``; the compressed-latent decoder's
+        expert counts, the rows whose router chose no expert
+        (``moe.assignments.skipped``) and the pairs its causal attention
+        attended (``attn.pairs_causal``)."""
         if not self._tallies:
             return
         tally = jax.device_get(self._tallies.pop(0))
@@ -346,6 +365,14 @@ class Evaluator:
             obs.counter("attn.pairs_window").inc(float(near) * windows)
             obs.counter("attn.pairs_full").inc(float(whole) * (len(kinds) - windows))
             obs.counter("attn.pairs_causal").inc(float(whole) * len(kinds))
+        if self.model.cfg.decoder == "cca_moe":
+            # (routed, [1, 2]): a query's pairs in one layer (every layer
+            # attends the same), and the rows of all layers that chose none
+            tally, counted = tally
+            pairs, skipped = np.asarray(counted).sum(axis=0, dtype=np.float64)
+            obs.counter("attn.pairs_causal").inc(
+                float(pairs) * self.model.cfg.num_hidden_layers)
+            obs.counter("moe.assignments.skipped").inc(float(skipped))
         tally = np.asarray(tally)
         if self.model.cfg.decoder == "sparse_linear":
             # [sparse layers, 3]: a key/value group a query, keys seen, keys

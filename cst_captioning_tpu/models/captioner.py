@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from cst_captioning_tpu.config.config import BOS_ID, ModelConfig
+from cst_captioning_tpu.models.cca_moe import CCAMoEDecoder
 from cst_captioning_tpu.models.decoder import Carry, DecoderCell
 from cst_captioning_tpu.models.eva import EvaDecoder
 from cst_captioning_tpu.models.latent_moe import LatentMoEDecoder
@@ -218,7 +219,7 @@ def _rows_by_token(tokens, rows, table):
 
 # the decoder kinds whose step takes all lanes at once (``decode_lanes``);
 # decoding/common.py ``lane_decode_step`` vmaps ``decode_step`` for the others
-ALL_LANES = ("window_moe",)
+ALL_LANES = ("window_moe", "cca_moe")
 
 
 class CaptionModel(nn.Module):
@@ -246,6 +247,12 @@ class CaptionModel(nn.Module):
             # the fifth (models/window_moe.py): window and full grouped-query
             # attention over a long video prefix, routed experts behind it
             self.decoder = WindowMoEDecoder(cfg, name="decoder")
+            return
+        if cfg.decoder == "cca_moe":
+            # the sixth (models/cca_moe.py): attention in a compressed,
+            # convolution-mixed latent over a long video prefix, one expert
+            # or none behind an MLP router, the head tied to the embedding
+            self.decoder = CCAMoEDecoder(cfg, name="decoder")
             return
         if cfg.encoder == "meanpool":
             self.encoder = MeanPoolEncoder(cfg, name="encoder")
@@ -296,6 +303,13 @@ class CaptionModel(nn.Module):
             # each clip's window slice starts (``memory_proj``)
             (keys, values, start), n, carry = self.decoder.prefill(feats, masks)
             return EncoderOutput((keys, values), start, self._live(n), carry)
+        if self.cfg.decoder == "cca_moe":
+            # likewise: every layer's prefix keys and values in the latent
+            # (``memory``); the convolution tail the prefix's last position
+            # leaves rides in the carry a clip hands its lanes
+            bank, n, carry = self.decoder.prefill(feats, masks)
+            none = jnp.zeros((n.shape[0], 0), jnp.dtype(self.cfg.dtype))
+            return EncoderOutput(bank, none, self._live(n), carry)
         memory, mmask = self.encoder(feats, masks)
         memory_proj = self.cell.project_memory(memory)
         ctx0 = masked_mean(memory, mmask, axis=1, axis_name=self.cfg.seq_axis)
@@ -334,6 +348,10 @@ class CaptionModel(nn.Module):
             return self.decoder.step(
                 carry, token, (*enc.memory, enc.memory_proj),
                 enc.memory_mask.sum(axis=-1).astype(jnp.int32))
+        if self.cfg.decoder == "cca_moe":
+            return self.decoder.step(
+                carry, token, enc.memory,
+                enc.memory_mask.sum(axis=-1).astype(jnp.int32))
         return self.cell(
             carry, token, enc.memory, enc.memory_proj, enc.memory_mask, deterministic
         )
@@ -345,9 +363,10 @@ class CaptionModel(nn.Module):
         :meth:`decode_step` vmapped a lane would run the rows of each lane
         apart, this runs ``G x B`` rows as one list (the routed experts'
         walk) and attends grouped by clip over the keys the lanes share."""
+        bank = enc.memory if self.cfg.decoder == "cca_moe" \
+            else (*enc.memory, enc.memory_proj)
         return self.decoder.step_lanes(
-            carry, token, (*enc.memory, enc.memory_proj),
-            enc.memory_mask.sum(axis=-1).astype(jnp.int32))
+            carry, token, bank, enc.memory_mask.sum(axis=-1).astype(jnp.int32))
 
     # ---- teacher forcing -----------------------------------------------------
 

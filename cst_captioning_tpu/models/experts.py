@@ -1,7 +1,8 @@
 """The routed-expert FFN of one chip's share (expert parallelism without its
 exchange), shared by the decoder kinds that have one: ``latent_moe``
-(models/latent_moe.py: a shared expert beside the routed ones) and
-``window_moe`` (models/window_moe.py: none).
+(models/latent_moe.py: a shared expert beside the routed ones),
+``window_moe`` (models/window_moe.py: none) and ``cca_moe`` (models/
+cca_moe.py: none, and a router of its own, :func:`route_mlp`).
 
 A float32 sigmoid router over all ``n_routed_experts``; the chosen are the
 ``num_experts_per_tok`` largest of ``score + bias`` (the bias steers the
@@ -13,6 +14,11 @@ held expert and each expert walks its own rows in blocks, as many as it has
 (:func:`held_experts`), so there is no capacity and no dropped token. What the
 absent experts would add is left out and the partial result goes on; nothing
 stands in for the other chips.
+
+:func:`route_mlp` is the other router (ZAYA1's): a stream of its own across
+depth (this layer's down-projection plus a learned share of the layer
+before's), an MLP over it, a softmax over the experts and one output more,
+and the one largest: a token may choose no expert at all.
 """
 
 from __future__ import annotations
@@ -39,6 +45,28 @@ def route(x, gate, bias, k: int, scale: float):
     return chosen, picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
 
 
+def route_mlp(p, x, r_prev, eps: float):
+    """The MLP router, float32 from its down-projection on: x [N, h], r_prev
+    [N, R] (the layer before's stream; zeros before the first) -> (r [N, R]:
+    ``x W_down + gamma r_prev``, chosen [N], weight [N] float32).
+    ``softmax(W3 gelu(W2 gelu(W1 norm(r) + b1) + b2) + b3)`` has one output
+    more than there are experts; the last is "no expert". The
+    choice is the largest of ``p + bias`` (the bias steers the choice only),
+    the weight the chosen output's own probability."""
+    f32, best = jnp.float32, jax.lax.Precision.HIGHEST
+    r = jnp.dot(x, p["router_down"].astype(x.dtype), preferred_element_type=f32,
+                precision=best) + p["router_eda"].astype(f32) * r_prev
+    y = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + eps) \
+        * p["router_norm"].astype(f32)
+    for i in (1, 2):
+        y = jax.nn.gelu(jnp.dot(y, p[f"router_w{i}"].astype(f32), precision=best)
+                        + p[f"router_b{i}"].astype(f32), approximate=False)
+    prob = jax.nn.softmax(jnp.dot(y, p["router_w3"].astype(f32), precision=best)
+                          + p["router_b3"].astype(f32), axis=-1)
+    chosen = jnp.argmax(prob + p["router_bias"].astype(f32), axis=-1)
+    return r, chosen, jnp.take_along_axis(prob, chosen[:, None], axis=-1)[:, 0]
+
+
 def expert_block_rows(n_tokens: int, k: int, n_experts: int) -> int:
     """Rows an expert walks at a time: the smallest multiple of 128 that
     holds twice the rows an expert expects under uniform routing, at most
@@ -49,7 +77,7 @@ def expert_block_rows(n_tokens: int, k: int, n_experts: int) -> int:
 
 
 def held_experts(x, chosen, weights, live, gate_w, up_w, down_w, lo: int,
-                 n_experts: int, differentiable: bool):
+                 n_experts: int, differentiable: bool, layer=None):
     """The held experts' part of ``sum_e w_e expert_e(x)`` for ``x [N, h]``.
 
     Tokens are sorted by held expert (a stable argsort a column) and every
@@ -60,10 +88,15 @@ def held_experts(x, chosen, weights, live, gate_w, up_w, down_w, lo: int,
     dropped token. ``differentiable`` (teacher forcing, which a loss may
     differentiate) spells the same walk as a static number of blocks under
     ``lax.cond``, since a loop with a traced trip count has no transpose.
+    With ``layer`` (a traced index) the weights are every layer's, stacked
+    ``[layers, held, ...]``, and an expert's are taken where a block uses
+    them: a stack scanned over its layers hands the walk no copy of a
+    layer's experts.
     -> (out [N, h] float32, tally [N, held + 1] int32: a token's rows on
     each held expert, and its assignments over all experts)."""
     N, k = chosen.shape
-    held = gate_w.shape[0]
+    held = gate_w.shape[0 if layer is None else 1]
+    of = (lambda w, e: w[e]) if layer is None else (lambda w, e: w[layer, e])
     local = chosen - lo
     onehot = (local[:, :, None] == jnp.arange(held)) & live[:, None, None]
     hit = onehot.any(axis=1)                                       # [N, held]
@@ -80,7 +113,7 @@ def held_experts(x, chosen, weights, live, gate_w, up_w, down_w, lo: int,
             start = b * rows_a_block
             rows = jax.lax.dynamic_slice_in_dim(order[:, e], start, rows_a_block)
             ok = start + jnp.arange(rows_a_block) < counts[e]
-            y = gated(x[rows], gate_w[e], up_w[e], down_w[e])
+            y = gated(x[rows], of(gate_w, e), of(up_w, e), of(down_w, e))
             y = y.astype(jnp.float32) * jnp.where(ok, wt[rows, e], 0.0)[:, None]
             return acc.at[jnp.where(ok, rows, N)].add(y, mode="drop")
 

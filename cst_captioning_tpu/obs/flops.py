@@ -252,6 +252,27 @@ def window_moe_per_tok_flops(mc, context: int) -> float:
     return float(total)
 
 
+# ---- the compressed-latent, top-1-expert decoder (models/cca_moe.py) ----------
+
+
+def cca_moe_per_tok_flops(mc, context: int) -> float:
+    """Matmul FLOPs one token costs in the ``decoder="cca_moe"`` stack at
+    ``context`` positions seen (its own the last), head excluded: a layer's
+    projections into the latent and out of it, the grouped convolution's two
+    taps a head, the scores and values over every key, the router (its
+    down-projection and three MLP products) and one expert at the share of
+    tokens that choose one of this chip's by expectation: ``experts_held`` of
+    the router's ``n_routed_experts + 1`` outputs (the last is no expert)."""
+    h, H, G, d = (mc.hidden_size, mc.num_attention_heads,
+                  mc.num_key_value_heads, mc.head_dim)
+    R, E = mc.router_hidden_size, mc.n_routed_experts
+    attn = 2 * h * (H + 2 * G) * d + 2 * H * d * h \
+        + 2 * (H + G) * 2 * d * d + 2 * H * 2 * d * context
+    router = 2 * h * R + 2 * 2 * R * R + 2 * R * (E + 1)
+    expert = 2 * 3 * h * mc.moe_intermediate_size * mc.experts_held / (E + 1)
+    return float(mc.num_hidden_layers * (attn + router + expert))
+
+
 def model_xe_flops_per_row(mc) -> float:
     """Matmul FLOPs of one teacher-forced XE row (forward + backward as 3x
     forward) of the model ``mc`` (a ``ModelConfig``) describes, by its
@@ -260,7 +281,8 @@ def model_xe_flops_per_row(mc) -> float:
     per_tok = {"latent_moe": latent_moe_per_tok_flops,
                "sparse_linear": sparse_linear_per_tok_flops,
                "eva": eva_per_tok_flops,
-               "window_moe": window_moe_per_tok_flops}.get(mc.decoder)
+               "window_moe": window_moe_per_tok_flops,
+               "cca_moe": cca_moe_per_tok_flops}.get(mc.decoder)
     if per_tok is not None:
         n_prefix = len(feat_dims) * mc.max_frames
         fwd = 2.0 * mc.max_frames * sum(feat_dims) * mc.hidden_size

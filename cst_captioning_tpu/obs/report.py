@@ -366,6 +366,11 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             "pairs_window": counters.get("attn.pairs_window", 0.0),
             "pairs_full": counters.get("attn.pairs_full", 0.0),
             "pairs_causal": counters.get("attn.pairs_causal", 0.0),
+            # the compressed-latent decoder (models/cca_moe.py): the
+            # convolution tails its lanes keep beside ``prefix_key_bytes``,
+            # and the rows whose router chose no expert
+            "conv_tail_bytes": gauges.get("decode.conv_tail_bytes"),
+            "assignments_skipped": counters.get("moe.assignments.skipped", 0.0),
         }
 
     # serving section (serving/engine.py): request funnel counters + the
@@ -758,6 +763,11 @@ def render_report(report: dict[str, Any]) -> str:
                 f"{ds['expert_rows_max']:.0f}, mean "
                 f"{ds['expert_rows_mean']:.1f}"
             )
+            if ds.get("conv_tail_bytes") is not None:
+                lines[-1] += (
+                    f"; {int(ds['assignments_skipped'])} chose no expert "
+                    f"({100.0 * ds['assignments_skipped'] / ds['assignments']:.2f}%)"
+                )
         if ds.get("kv_bytes") is not None:
             lines.append(
                 f"of it keys and values {ds['kv_bytes'] / 2**20:.1f} MiB, "
@@ -772,7 +782,15 @@ def render_report(report: dict[str, Any]) -> str:
                 f"({100.0 * ds['keys_selected'] / ds['keys_visible']:.2f}%); "
                 f"{int(ds['dense_fallback_queries'])} under the dense length"
             )
-        if ds.get("prefix_key_bytes") is not None:
+        if ds.get("conv_tail_bytes") is not None:
+            lines.append(
+                f"of it the prefix keys and values in the latent "
+                f"{ds['prefix_key_bytes'] / 2**20:.1f} MiB, the lanes' "
+                f"convolution tails {ds['conv_tail_bytes'] / 2**20:.2f} MiB; "
+                f"queries attended {int(ds['pairs_causal'])} pairs under "
+                f"the diagonal"
+            )
+        elif ds.get("prefix_key_bytes") is not None:
             lines.append(
                 f"of it the full layers' prefix keys and values "
                 f"{ds['prefix_key_bytes'] / 2**20:.1f} MiB, the window "
@@ -785,7 +803,7 @@ def render_report(report: dict[str, Any]) -> str:
                 f"{ds['window_bytes'] / 2**20:.1f} MiB, chunk summaries "
                 f"{(ds['summary_bytes'] or 0) / 2**20:.1f} MiB"
             )
-        if ds.get("pairs_causal"):
+        if ds.get("pairs_causal") and ds.get("conv_tail_bytes") is None:
             pairs = ds["pairs_window"] + ds["pairs_full"]
             lines.append(
                 f"window and full layers: queries attended "

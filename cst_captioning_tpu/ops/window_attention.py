@@ -17,9 +17,10 @@ The prefix (:func:`window_prefill`, :func:`full_prefill`) never forms a
 window layer's against the slice of keys its band can reach, a full layer's
 against all keys under a mask (the parity oracle, what runs off the TPU and
 what a gradient can pass through). ``impl="pallas"`` is one flash kernel body
-under two names (``window_attn_prefill`` / ``full_attn_prefill`` in a device
-trace): grid rows x key/value heads x query tiles x key tiles, online softmax,
-a key/value head's ``H / G`` query heads in one block so that they share each
+under three names (``window_attn_prefill`` / ``full_attn_prefill`` in a device
+trace, and ``cca_attn_prefill`` for models/cca_moe.py's latent heads): grid
+rows x key/value heads x query tiles x key tiles, online softmax, a key/value
+head's ``H / G`` query heads in one block so that they share each
 key tile's copy. A query tile walks only the key tiles its band (or the
 diagonal) reaches: the grid's last axis is as long as the longest such walk,
 a step past a tile's walk does nothing and asks for the tile before it again
@@ -53,6 +54,9 @@ _NEG = -1.0e30
 # (query tile, key tile) of the two kernels: a block holds a key/value head's
 # query heads (8 window, 16 full at the published widths), 2048 rows each way
 WINDOW_TILES, FULL_TILES = (256, 128), (128, 512)
+# the compressed-latent layers' (models/cca_moe.py): 4 query heads a block,
+# 2048 rows as well
+CCA_TILES = (512, 512)
 
 
 def _with_sink(s, sink):
@@ -195,7 +199,7 @@ def _flash_kernel(*refs, tq: int, tk: int, window: int | None, sink: bool):
 
 
 def _prefill_pallas(q, k, v, sink, n, window: int | None, tq: int, tk: int,
-                    interpret: bool):
+                    interpret: bool, name: str):
     """q [B, P, H, dk], k [B, P, G, dk], v [B, P, G, dv] with ``tq | P`` and
     ``tk | P`` -> [B, P, H, dv]."""
     B, P, H, dk = q.shape
@@ -238,13 +242,13 @@ def _prefill_pallas(q, k, v, sink, n, window: int | None, tq: int, tk: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
-        name="full_attn_prefill" if window is None else "window_attn_prefill",
+        name=name,
         interpret=interpret,
     )(n.astype(jnp.int32), *operands)
     return out.reshape(B, P, H, dv)
 
 
-def _prefill(q, k, v, sink, n, window, impl, tiles):
+def _prefill(q, k, v, sink, n, window, impl, tiles, name):
     if impl != "pallas":
         return _prefill_xla(q, k, v, sink, window)
     P = q.shape[1]
@@ -254,7 +258,7 @@ def _prefill(q, k, v, sink, n, window, impl, tiles):
         raise ValueError(f"one of the tiles {tiles} must divide the other")
     grow = lambda x: jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))  # noqa: E731
     out = _prefill_pallas(grow(q), grow(k), grow(v), sink, n, window, tq, tk,
-                          interpret=jax.default_backend() != "tpu")
+                          interpret=jax.default_backend() != "tpu", name=name)
     return out[:, :P]
 
 
@@ -264,14 +268,24 @@ def window_prefill(q, k, v, sink, n, window: int, impl: str = "xla",
     the denominator: q [B, P, H, dk]; k [B, P, G, dk]; v [B, P, G, dv]; sink
     [H]; n [B]: the positions that exist -> out [B, P, H, dv]. A row's
     outputs from position ``n`` on are not defined (nothing reads them)."""
-    return _prefill(q, k, v, sink, n, int(window), impl, tiles)
+    return _prefill(q, k, v, sink, n, int(window), impl, tiles,
+                    "window_attn_prefill")
 
 
 def full_prefill(q, k, v, n, impl: str = "xla",
                  tiles: tuple[int, int] = FULL_TILES):
     """The prefix's queries over every key up to their own: shapes as
     :func:`window_prefill`, no sink."""
-    return _prefill(q, k, v, None, n, None, impl, tiles)
+    return _prefill(q, k, v, None, n, None, impl, tiles, "full_attn_prefill")
+
+
+def cca_prefill(q, k, v, n, impl: str = "xla",
+                tiles: tuple[int, int] = CCA_TILES):
+    """:func:`full_prefill` under a name of its own (``cca_attn_prefill`` in
+    a device trace) and with tiles of its own: attention in a compressed
+    latent (models/cca_moe.py), keys and values of one width, 4 query heads a
+    key/value head."""
+    return _prefill(q, k, v, None, n, None, impl, tiles, "cca_attn_prefill")
 
 
 # ---- a decode step ------------------------------------------------------------

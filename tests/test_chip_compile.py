@@ -360,3 +360,27 @@ def test_window_and_full_prefix_kernels_compile_for_v5e_at_the_published_widths(
             _sds((rows, P, G, dv), bf16), n)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and f"%{kernel}" in text
+
+
+# ---- the compressed-latent decoder's prefix kernel (PR 50) --------------------
+
+
+def test_cca_prefix_kernel_compiles_for_v5e_at_the_published_widths(chip):
+    """ZAYA1-8B's latent attention over a 16384-position prefix of two clips,
+    bfloat16, 8 query heads over 2 key/value heads of 128 (4 query heads a
+    block), at the program's tiles: the full-attention flash kernel body under
+    its third name, by which the benchmark's readers find it in a device
+    trace (``layer_metrics/cca_attn_ms_per_step.py``)."""
+    from cst_captioning_tpu.ops import window_attention
+
+    mc = get_preset("zaya1_8b_20l_eval_beam5").model
+    rows, P = 2, mc.max_frames
+    H, G, d = mc.num_attention_heads, mc.num_key_value_heads, mc.head_dim
+    assert (H, G, d) == (8, 2, 128)
+    compiled = _compile(
+        lambda q, k, v, n: window_attention.cca_prefill(q, k, v, n, impl="pallas"),
+        chip, _sds((rows, P, H, d), jnp.bfloat16),
+        _sds((rows, P, G, d), jnp.bfloat16), _sds((rows, P, G, d), jnp.bfloat16),
+        _sds((rows,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%cca_attn_prefill" in text

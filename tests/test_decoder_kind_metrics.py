@@ -58,6 +58,15 @@ KINDS = {
         experts_held=4, expert_share_index=1,
         mixer_types=("full", "window", "window", "full"), published_layers=48,
         first_layer_index=0)),
+    "cca_moe": ("zaya1_8b_20l_eval_beam5", dict(
+        _COMMON, modalities=(("patch", 16),), max_frames=48,
+        num_hidden_layers=3, moe_intermediate_size=16, n_routed_experts=8,
+        n_shared_experts=0, num_experts_per_tok=1, num_attention_heads=8,
+        num_key_value_heads=2, head_dim=8, cca_time0=2, cca_time1=2,
+        partial_rotary_factor=0.5, rope_theta=5e6, router_hidden_size=8,
+        tie_word_embeddings=True, rms_norm_eps=1e-5, initializer_range=0.3,
+        experts_held=4, expert_share_index=1, published_layers=40,
+        first_layer_index=0)),
 }
 
 
@@ -128,6 +137,18 @@ PARENT = {
                      "attn.pairs_causal": 74140.0, "moe.assignments": 6152.0,
                      "moe.assignments.local": 1717.0,
                      "moe.expert_rows.count": 24, "moe.expert_rows.sum": 1717.0}},
+    # the sixth kind came with PR 50: the values its own commit's run gave
+    # (4 clips x 3 layers x 2 heads x 48 positions x 8 numbers of keys and of
+    # values once a clip; 20 lanes' tails of 168 numbers a layer; 20 lanes'
+    # 8 caption positions)
+    "cca_moe": {
+        "gauges": {"decode.cache_bytes": 175488.0,
+                   "decode.prefix_key_bytes": 73728.0,
+                   "decode.conv_tail_bytes": 40320.0, "moe.experts_held": 4.0},
+        "counters": {"attn.pairs_causal": 55605.0, "moe.assignments": 1538.0,
+                     "moe.assignments.local": 929.0,
+                     "moe.assignments.skipped": 377.0,
+                     "moe.expert_rows.count": 24, "moe.expert_rows.sum": 929.0}},
 }
 
 
