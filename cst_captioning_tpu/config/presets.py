@@ -233,8 +233,8 @@ def _kimi_k2_ep32_eval_beam5() -> ExperimentConfig:
     return dataclasses.replace(
         _kimi_k2_ep32_xe(),
         name="kimi_k2_ep32_eval_beam5",
-        # beams flattened into the batch: the routed experts walk one list
-        # of rows a step, where "lanes" would vmap the walk over the beams
+        # beams flattened into the batch: the routed experts take one list of
+        # rows a step, where "lanes" would vmap them over the beams
         eval=EvalConfig(beam_size=5, max_len=30, split="test",
                         beam_impl="reference"),
     )

@@ -47,6 +47,7 @@ from cst_captioning_tpu.data.dataset import CaptionDataset
 from cst_captioning_tpu.decoding import beam_search, greedy_decode, npad_decode
 from cst_captioning_tpu.metrics.scorer import CaptionScorer
 from cst_captioning_tpu.metrics.tokenizer import ptb_tokenize
+from cst_captioning_tpu.models.experts import expert_tile_rows
 from cst_captioning_tpu.parallel import (
     CompilePlan,
     compile_fn,
@@ -135,6 +136,7 @@ class Evaluator:
         # the host parameters last handed over and their placed copy
         self._placed: tuple | None = None
         self._observed = False      # the decode-state gauges are set
+        self._rows_a_tile = 1       # (``_observe_experts``)
         # under obs only (``_launched`` / ``_collected``): passes begun,
         # decodes launched and not yet collected, and when (perf_counter) a
         # collect last left none
@@ -318,7 +320,7 @@ class Evaluator:
             obs.gauge("decode.prefix_key_bytes").set(prefix)
             obs.gauge("decode.window_bytes").set(near)
             obs.gauge("decode.cache_bytes").set(prefix + near + lanes * lane(False))
-            obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
+            self._observe_experts(lanes, feats)
             return
         if kind == "cca_moe":
             shared = 1 if self.cfg.beam_impl == "lanes" else lanes
@@ -329,17 +331,29 @@ class Evaluator:
             obs.gauge("decode.conv_tail_bytes").set(tail)
             obs.gauge("decode.cache_bytes").set(
                 prefix + tail + lanes * size((enc.carry.k, enc.carry.v)))
-            obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
+            self._observe_experts(lanes, feats)
             return
         obs.gauge("decode.cache_bytes").set(lanes * size(enc.carry))
         if kind == "latent_moe":
-            obs.gauge("moe.experts_held").set(self.model.cfg.experts_held)
+            self._observe_experts(lanes, feats)
+
+    def _observe_experts(self, lanes: int, feats) -> None:
+        """A routed-expert decoder's gauges: the experts this chip holds and
+        the rows of a tile of their grouped product (models/experts.py) at a
+        search step's rows, every lane of every clip of a batch."""
+        c = self.model.cfg
+        self._rows_a_tile = expert_tile_rows(
+            lanes * jax.tree.leaves(feats)[0].shape[0], c.num_experts_per_tok,
+            c.n_routed_experts)
+        obs.gauge("moe.experts_held").set(c.experts_held)
+        obs.gauge("moe.rows_a_tile").set(self._rows_a_tile)
 
     def _count(self) -> None:
         """The oldest uncollected batch's counts, read where its tokens are
         (the decode that produced both has finished): counters
-        ``moe.assignments`` / ``moe.assignments.local`` and the rows each
-        held expert of each layer took (histogram ``moe.expert_rows``), or
+        ``moe.assignments`` / ``moe.assignments.local``, the rows each
+        held expert of each layer took (histogram ``moe.expert_rows``) and
+        the row tiles that hold them (``moe.row_tiles``), or
         the sparse layers' ``sparse.keys_visible`` / ``sparse.keys_selected``
         / ``sparse.dense_fallback_queries``, or the EVA layers'
         ``eva.keys_exact`` / ``eva.keys_summary`` / ``eva.window_crossings``;
@@ -392,6 +406,9 @@ class Evaluator:
             return
         obs.counter("moe.assignments").inc(float(tally[:, -1].sum()))
         obs.counter("moe.assignments.local").inc(float(tally[:, :-1].sum()))
+        # the fewest tiles of ``moe.rows_a_tile`` that hold each expert's rows
+        obs.counter("moe.row_tiles").inc(
+            float(np.ceil(tally[:, :-1] / self._rows_a_tile).sum()))
         rows = obs.histogram("moe.expert_rows", _EXPERT_ROW_BUCKETS)
         for n in tally[:, :-1].reshape(-1):
             rows.observe(float(n))

@@ -50,7 +50,7 @@ down-projection on, the experts' weighted sum and the logits.
   one leaf stacked over the layers (``layers/<name> [L, ...]``) and the stack
   is a ``lax.scan`` of one block whose carry is the pair ``(x, r)``: the
   stream and the router's. A program traces and compiles one layer. A layer's
-  leaves are taken by index inside the block, an expert's where a block of
+  leaves are taken by index inside the block, an expert's where a tile of
   its rows uses them (``experts.held_experts(layer=...)``).
 - **Two streams.** ``r_l`` feeds the next layer's router, so a block returns
   two values; the stream's residual merge has learned scales and offsets.
@@ -73,8 +73,8 @@ batch-major.
 **A step for all lanes at once** (:meth:`CCAMoEDecoder.step_lanes`): the
 beam's ``[lanes, clips]`` tokens go through projections, mixing, router and
 experts as one list of ``lanes x clips`` rows (sequences of one position whose
-"position before" is the tail; the held experts' walk keeps its traced trip
-counts), and attend grouped by clip over the shared keys. :meth:`step` is the
+"position before" is the tail; the held experts are one grouped product a
+layer over all of them), and attend grouped by clip over the shared keys. :meth:`step` is the
 same code with one lane.
 
 The last layer's attention output and FFN over the prefix feed nothing and

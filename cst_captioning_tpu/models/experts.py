@@ -9,8 +9,10 @@ A float32 sigmoid router over all ``n_routed_experts``; the chosen are the
 choice only), ``w = score / sum(chosen scores) * routed_scaling_factor``. The
 layer holds ``experts_held`` consecutive experts (``expert_share_index`` says
 which), routes over all of them, normalises over all chosen, and computes the
-chosen experts it holds for every token routed to them: tokens are sorted by
-held expert and each expert walks its own rows in blocks, as many as it has
+chosen experts it holds for every token routed to them: the (token, expert)
+pairs are sorted once by held expert and one grouped product walks the row
+tiles that hold a pair, as many as an expert has, or (several experts a token
+over many rows) every held expert walks its own rows in blocks
 (:func:`held_experts`), so there is no capacity and no dropped token. What the
 absent experts would add is left out and the partial result goes on; nothing
 stands in for the other chips.
@@ -26,11 +28,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from cst_captioning_tpu.ops import grouped_ffn
+from cst_captioning_tpu.ops.grouped_ffn import gated  # noqa: F401  (the dense FFNs')
 
-def gated(x, gate, up, down):
-    """The gated FFN ``(silu(x W_g) * x W_u) W_d`` in ``x``'s dtype."""
-    dt = x.dtype
-    return (jax.nn.silu(x @ gate.astype(dt)) * (x @ up.astype(dt))) @ down.astype(dt)
+# the most the grouped product's sorted copy of ``x`` may hold where a token
+# has several experts (:func:`held_experts`)
+CHUNK_BYTES = 32 * 1024 * 1024
 
 
 def route(x, gate, bias, k: int, scale: float):
@@ -67,10 +70,22 @@ def route_mlp(p, x, r_prev, eps: float):
     return r, chosen, jnp.take_along_axis(prob, chosen[:, None], axis=-1)[:, 0]
 
 
+def expert_tile_rows(n_tokens: int, k: int, n_experts: int) -> int:
+    """Rows of a tile of the grouped product: the power of two that holds
+    four times the rows an expert expects under uniform routing, from 16 (a
+    beam step's few rows: a tile reads an expert's bytes once) to 512 (a
+    prefix: the matrix unit outruns the matrices' reads)."""
+    rows = 16
+    while rows < min(4 * n_tokens * k / max(n_experts, 1), 512):
+        rows *= 2
+    return rows
+
+
 def expert_block_rows(n_tokens: int, k: int, n_experts: int) -> int:
-    """Rows an expert walks at a time: the smallest multiple of 128 that
-    holds twice the rows an expert expects under uniform routing, at most
-    1024 (and never more than the tokens there are, rounded up to 8)."""
+    """Rows an expert walks at a time where the experts are walked one by one
+    (:func:`held_experts`): the smallest multiple of 128 that holds twice the
+    rows an expert expects under uniform routing, at most 1024 (and never
+    more than the tokens there are, rounded up to 8)."""
     expected = n_tokens * k / max(n_experts, 1)
     rows = min(-(-int(2 * expected) // 128) * 128 or 128, 1024)
     return min(rows, -(-n_tokens // 8) * 8)
@@ -80,52 +95,104 @@ def held_experts(x, chosen, weights, live, gate_w, up_w, down_w, lo: int,
                  n_experts: int, differentiable: bool, layer=None):
     """The held experts' part of ``sum_e w_e expert_e(x)`` for ``x [N, h]``.
 
-    Tokens are sorted by held expert (a stable argsort a column) and every
-    held expert walks the rows routed to it in blocks of
-    :func:`expert_block_rows`, as many blocks as it has rows: a
-    ``fori_loop`` with a traced trip count, so an expert nobody chose costs
-    nothing and one everybody chose takes all of them — no capacity, no
-    dropped token. ``differentiable`` (teacher forcing, which a loss may
-    differentiate) spells the same walk as a static number of blocks under
-    ``lax.cond``, since a loop with a traced trip count has no transpose.
-    With ``layer`` (a traced index) the weights are every layer's, stacked
-    ``[layers, held, ...]``, and an expert's are taken where a block uses
-    them: a stack scanned over its layers hands the walk no copy of a
-    layer's experts.
+    **One grouped product** (ops/grouped_ffn.py) wherever a token has one
+    expert (``k`` = 1) or the sorted copy of ``x`` with every pair held fits
+    ``CHUNK_BYTES`` (a beam step's few rows): the ``N x k`` (token, expert)
+    pairs are sorted once by held expert (not held, not live and "no expert"
+    behind them), each expert's pairs laid out from a tile boundary on in
+    tiles of :func:`expert_tile_rows`, and the product walks the tiles that
+    hold a pair; a token's (at most ``k``) outputs are gathered back from
+    the slots its pairs took and summed in float32 in the order it chose
+    them: no scatter, no loop a held expert. ``differentiable`` (teacher
+    forcing, which a loss may differentiate) takes this path with the
+    product's compiled map, since a kernel has no transpose.
+    **An expert at a time** elsewhere (``k`` > 1 over many rows: the sorted
+    copy would be ``k`` times ``x``, and summing ``k`` gathered copies or
+    scatter-adding a copy's rows costs more than the product wins: measured,
+    PERF.md section 6, PR 51): tokens sorted by held expert (a stable argsort
+    a column) and every held expert walks its rows in blocks of
+    :func:`expert_block_rows`, a ``fori_loop`` with a traced trip count,
+    each block's outputs scatter-added to their tokens' float32 sums.
+    Either way an expert nobody chose costs nothing and one everybody chose
+    takes all its rows: no capacity, no dropped token.
+    The weights are ``[held, ...]`` or, with ``layer`` (a traced index),
+    every layer's stacked ``[layers, held, ...]``: an expert's matrices are
+    read where they lie, so a stack scanned over its layers hands neither
+    path a copy of a layer's experts.
     -> (out [N, h] float32, tally [N, held + 1] int32: a token's rows on
     each held expert, and its assignments over all experts)."""
     N, k = chosen.shape
-    held = gate_w.shape[0 if layer is None else 1]
-    of = (lambda w, e: w[e]) if layer is None else (lambda w, e: w[layer, e])
+    h = x.shape[-1]
+    if layer is None:
+        layer, gate_w, up_w, down_w = 0, gate_w[None], up_w[None], down_w[None]
+    held = gate_w.shape[1]
+    gate_w, up_w, down_w = (w.reshape((-1,) + w.shape[2:])
+                            for w in (gate_w, up_w, down_w))
     local = chosen - lo
-    onehot = (local[:, :, None] == jnp.arange(held)) & live[:, None, None]
-    hit = onehot.any(axis=1)                                       # [N, held]
-    wt = (onehot * weights[:, :, None]).sum(axis=1)                # [N, held]
-    counts = hit.sum(axis=0).astype(jnp.int32)
-    rows_a_block = expert_block_rows(N, k, n_experts)
-    order = jnp.argsort(jnp.logical_not(hit), axis=0, stable=True)  # hits first
-    blocks = -(-N // rows_a_block)
-    order = jnp.pad(order, ((0, blocks * rows_a_block - N), (0, 0)))
+    key = jnp.where((local >= 0) & (local < held) & live[:, None], local, held)
+    onehot = key[:, :, None] == jnp.arange(held)                # [N, k, held]
+    hit = onehot.any(axis=1)
+    counts = hit.sum(axis=0, dtype=jnp.int32)
+    tile = expert_tile_rows(N, k, n_experts)
+    most = min(N * k, N * k // tile + held)     # tiles, if every pair is held
     # the experts' weighted outputs are summed in float32 on purpose
-    out = jnp.zeros((N, x.shape[-1]), jnp.float32)  # graftlint: disable=GL005
-    for e in range(held):
-        def block(b, acc, e=e):
-            start = b * rows_a_block
-            rows = jax.lax.dynamic_slice_in_dim(order[:, e], start, rows_a_block)
-            ok = start + jnp.arange(rows_a_block) < counts[e]
-            y = gated(x[rows], of(gate_w, e), of(up_w, e), of(down_w, e))
-            y = y.astype(jnp.float32) * jnp.where(ok, wt[rows, e], 0.0)[:, None]
-            return acc.at[jnp.where(ok, rows, N)].add(y, mode="drop")
+    out = jnp.zeros((N, h), jnp.float32)  # graftlint: disable=GL005
+    if k == 1 or differentiable or \
+            most * tile * h * x.dtype.itemsize <= CHUNK_BYTES:
+        out = _grouped(x, key, jnp.where(key < held, weights, 0.0), onehot,
+                       counts, layer * held, (gate_w, up_w, down_w), tile, most,
+                       "xla" if differentiable else grouped_ffn.impl(), out)
+    else:
+        wt = (onehot * weights[:, :, None]).sum(axis=1)         # [N, held]
+        rows_a_block = expert_block_rows(N, k, n_experts)
+        order = jnp.argsort(jnp.logical_not(hit), axis=0, stable=True)
+        order = jnp.pad(order, ((0, -N % rows_a_block), (0, 0)))
+        for e in range(held):
+            def block(b, acc, e=e):
+                start = b * rows_a_block
+                rows = jax.lax.dynamic_slice_in_dim(order[:, e], start, rows_a_block)
+                ok = start + jnp.arange(rows_a_block) < counts[e]
+                y = gated(x[rows], *(w[layer * held + e]
+                                     for w in (gate_w, up_w, down_w)))
+                y = y.astype(jnp.float32) * jnp.where(ok, wt[rows, e], 0.0)[:, None]
+                return acc.at[jnp.where(ok, rows, N)].add(y, mode="drop")
 
-        n_blocks = -(-counts[e] // rows_a_block)
-        if differentiable:
-            for b in range(blocks):
-                out = jax.lax.cond(b < n_blocks, lambda a, b=b: block(b, a),
-                                   lambda a: a, out)
-        else:
-            out = jax.lax.fori_loop(0, n_blocks, block, out)
+            out = jax.lax.fori_loop(0, -(-counts[e] // rows_a_block), block, out)
     assigned = jnp.where(live, k, 0).astype(jnp.int32)
     return out, jnp.concatenate([hit.astype(jnp.int32), assigned[:, None]], 1)
+
+
+def _grouped(x, key, weights, onehot, counts, base, stack, tile: int, most: int,
+             impl: str, out):
+    """:func:`held_experts` as one grouped product: key [N, k] (a pair's
+    held expert, ``held`` for none), weights [N, k] (0 where none), onehot
+    [N, k, held], counts [held]; ``stack`` the experts' three matrices
+    ``[groups, ...]`` of which this layer's begin at ``base``; ``most`` row
+    tiles of ``tile`` at most."""
+    N, k = key.shape
+    held = counts.shape[0]
+    onehot = onehot.reshape(N * k, held)
+    order = jnp.argsort(key.reshape(-1), stable=True)           # the one sort
+    # a pair's place among its expert's, in the pairs' order (the sort's)
+    rank = ((jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1) * onehot).sum(axis=1)
+    tiles = -(-counts // tile)
+    tile_end = jnp.cumsum(tiles)
+    n_tiles, first_tile = tile_end[-1], tile_end - tiles
+    t = jnp.arange(most)
+    expert = (jnp.minimum(t, n_tiles - 1)[:, None] >= tile_end).sum(axis=1)
+    # the pair a slot of the layout holds: its expert's first + its place (a
+    # slot past its expert's pairs holds some pair, whose output nothing reads)
+    place = ((t - first_tile[expert]) * tile)[:, None] + jnp.arange(tile)
+    first = jnp.cumsum(counts) - counts
+    pair = order[jnp.minimum(first[expert][:, None] + place, N * k - 1)]
+    ys = grouped_ffn.grouped_gated(
+        x[pair.reshape(-1) // k], base + expert, n_tiles, *stack, tile=tile,
+        impl=impl)
+    # a token's outputs, from the slots its pairs took, in the order it chose
+    slot = first_tile[jnp.minimum(key, held - 1)] * tile + rank.reshape(N, k)
+    for j in range(k):
+        out = out + weights[:, j, None] * ys[slot[:, j]]
+    return out
 
 
 def expert_shapes(cfg, init) -> dict:

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from cst_captioning_tpu.config.config import BOS_ID, ModelConfig
 from cst_captioning_tpu.models.experts import (  # noqa: F401  (re-exported)
     check_share,
-    expert_block_rows,
+    expert_tile_rows,
     expert_ffn,
     expert_shapes,
     gated,

@@ -42,9 +42,10 @@ an LSTM carry.
 
 **A step for all lanes at once** (:meth:`WindowMoEDecoder.step_lanes`): the
 beam's ``[lanes, clips]`` tokens go through projections, router and experts
-as one list of ``lanes x clips`` rows (the held experts' walk keeps its
-traced trip counts, which a ``vmap`` over lanes would turn into masked loops
-to the longest expert), and attend grouped by clip over the shared keys.
+as one list of ``lanes x clips`` rows (the held experts are then one
+grouped product a layer over all of them, where a ``vmap`` over lanes would
+make it a product a lane, each reading its experts' matrices again), and
+attend grouped by clip over the shared keys.
 :meth:`step` is the same code with one lane.
 
 The last layer's attention output and FFN over the prefix feed nothing and

@@ -343,6 +343,9 @@ def build_report(events: Iterable[dict]) -> dict[str, Any]:
             "expert_rows_max": float(rows.get("max", 0.0)),
             "expert_rows_mean": (
                 rows["sum"] / rows["count"] if rows.get("count") else 0.0),
+            # the grouped product's row tiles (models/experts.py)
+            "rows_a_tile": gauges.get("moe.rows_a_tile"),
+            "row_tiles": counters.get("moe.row_tiles", 0.0),
             # the sparse/linear decoder (models/sparse_linear.py): the three
             # kinds of state apart, and what its sparse layers' queries saw
             "kv_bytes": gauges.get("decode.kv_bytes"),
@@ -763,6 +766,13 @@ def render_report(report: dict[str, Any]) -> str:
                 f"{ds['expert_rows_max']:.0f}, mean "
                 f"{ds['expert_rows_mean']:.1f}"
             )
+            if ds.get("row_tiles"):
+                tile = int(ds["rows_a_tile"] or 1)
+                lines[-1] += (
+                    f"; in {int(ds['row_tiles'])} row tiles of {tile} ("
+                    f"{100.0 * ds['assignments_local'] / (ds['row_tiles'] * tile):.1f}"
+                    "% filled)"
+                )
             if ds.get("conv_tail_bytes") is not None:
                 lines[-1] += (
                     f"; {int(ds['assignments_skipped'])} chose no expert "

@@ -384,3 +384,53 @@ def test_cca_prefix_kernel_compiles_for_v5e_at_the_published_widths(chip):
         _sds((rows,), jnp.int32))
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%cca_attn_prefill" in text
+
+
+# ---- the held experts' grouped product (PR 51) -------------------------------
+
+
+@pytest.mark.parametrize("preset,rows,grouped", [
+    ("zaya1_8b_20l_eval_beam5", 32768, True),
+    ("mimo_v2_5_ep16_eval_beam5", 10, True),
+    ("kimi_k2_ep32_eval_beam5", 1280, False)])
+def test_the_held_experts_compile_for_v5e_at_the_published_widths(
+        preset, rows, grouped, chip):
+    """``held_experts`` of the three routed-expert cells (ZAYA's prefix of
+    two clips of 16384 positions, MiMo's beam step of 10 lanes, Kimi's step of
+    256 clips' five lanes), bfloat16, the experts' matrices as they are stored (ZAYA's
+    every layer's in one stack, a traced layer). Where a token has one expert
+    or the rows are few the held experts are one grouped product: the kernel
+    is in the program under the name a device trace shows
+    (``held_experts_gmm``), with no loop around it, and reads the stack
+    through a view and no copy. Several experts a token over many rows (Kimi's
+    step) walk an expert at a time: a loop a held expert and
+    no kernel."""
+    from cst_captioning_tpu.models import experts
+
+    mc = get_preset(preset).model
+    h, m, held, k = (mc.hidden_size, mc.moe_intermediate_size, mc.experts_held,
+                     mc.num_experts_per_tok)
+    stacked = mc.decoder == "cca_moe"
+    lead = (mc.num_hidden_layers, held) if stacked else (held,)
+
+    def walk(x, chosen, weights, gate, up, down, layer):
+        out, tally = experts.held_experts(
+            x, chosen, weights, jnp.ones((rows,), bool), gate, up, down, 0,
+            mc.n_routed_experts, False, **({"layer": layer} if stacked else {}))
+        return out.astype(x.dtype), tally
+
+    compiled = _compile(
+        walk, chip, _sds((rows, h), jnp.bfloat16), _sds((rows, k), jnp.int32),
+        _sds((rows, k), jnp.float32), _sds(lead + (h, m), jnp.bfloat16),
+        _sds(lead + (h, m), jnp.bfloat16), _sds(lead + (m, h), jnp.bfloat16),
+        _sds((), jnp.int32))
+    text = compiled.as_text()
+    assert ("%held_experts_gmm" in text) == grouped
+    assert text.count(" while(") == (0 if grouped else held)
+    stack = "bf16[" + ",".join(map(str, lead + (h, m))) + "]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and stack in line.split(" copy(")[0]]
+    # the sorted copy of x and the products' output: no more than the parent's
+    # float32 accumulator and its blocks took over a ZAYA prefix (613 MiB)
+    if stacked and rows == 32768:
+        assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20

@@ -181,3 +181,38 @@ def test_a_decoder_kind_sets_its_gauges_and_counters(snapshots, kind, what):
         got.update({f"moe.expert_rows.{k}": rows.get(k) for k in ("count", "sum")
                     if f"moe.expert_rows.{k}" in want})
     assert got == want
+
+
+@pytest.mark.parametrize("kind", ["cca_moe", "latent_moe", "window_moe"])
+def test_a_routed_expert_kind_counts_the_row_tiles_of_the_tally_it_read(
+        tmp_path, kind, monkeypatch):
+    """``moe.rows_a_tile`` is the grouped product's tile at a search step's
+    rows (a beam of 5 over a batch of 4), and ``moe.row_tiles`` the sum over
+    layers and held experts of ``ceil(rows / tile)`` of every batch's tally
+    ``Evaluator._count`` read, so that ``moe.assignments.local / (moe.row_tiles
+    x moe.rows_a_tile)`` is the tiles' fill."""
+    import numpy as np
+
+    from cst_captioning_tpu.eval.evaluator import Evaluator
+    from cst_captioning_tpu.models.experts import expert_tile_rows
+
+    read, count = [], Evaluator._count
+
+    def tapped(self):
+        if self._tallies:
+            first = self._tallies[0]
+            read.append(np.asarray(first[0] if isinstance(first, tuple) else first))
+        count(self)
+
+    monkeypatch.setattr(Evaluator, "_count", tapped)
+    preset, tiny = KINDS[kind]
+    cfg = ModelConfig(decoder=kind, **tiny)
+    _result, snap = _observed_pass(tmp_path, cfg, get_preset(preset).eval)
+    tile = expert_tile_rows(5 * 4, cfg.num_experts_per_tok, cfg.n_routed_experts)
+    assert snap["gauges"]["moe.rows_a_tile"] == tile == (16 if kind == "cca_moe" else 32)
+    assert len(read) == 2 and all(t.shape[1] == cfg.experts_held + 1 for t in read)
+    tiles = sum(int(np.ceil(t[:, :-1] / tile).sum()) for t in read)
+    assert snap["counters"]["moe.row_tiles"] == tiles > 0
+    local = snap["counters"]["moe.assignments.local"]
+    assert local == sum(int(t[:, :-1].sum()) for t in read)
+    assert 0 < local / (tiles * tile) <= 1

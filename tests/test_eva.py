@@ -356,12 +356,14 @@ def test_the_other_decoders_beam_outputs_are_the_parent_commit_s(kind, impl):
                     ).astype(np.float32) for name, dim in (("resnet", 32), ("c3d", 16))}
     masks = {name: mask.copy() for name in ("resnet", "c3d")}
     labels = rng.integers(4, 64, size=(6, 12)).astype(np.int32)
+    from test_sparse_linear import assert_the_golden_scores
+
     params = model.init(jax.random.key(0), feats, masks, labels)
     tokens, score = jax.jit(lambda p: beam_search(
         model, p, feats, masks, beam_size=5, beam_impl=impl)[:2])(params)
     want = _golden()[f"{kind}.{impl}"]
     assert np.asarray(tokens).tolist() == want["tokens"]
-    assert np.asarray(score, np.float32).view(np.uint32).tolist() == want["score_bits"]
+    assert_the_golden_scores(kind, score, want["score_bits"])
 
 
 @pytest.mark.parametrize("setup", ["rolls"], indirect=True)

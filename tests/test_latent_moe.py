@@ -268,11 +268,11 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(setup, ref):
 
 def test_every_token_on_one_expert_drops_none(setup):
     """No capacity: a router that sends every token to the same held expert
-    still computes every row (more rows than one block holds)."""
+    still computes every row (more rows than one tile holds)."""
     cfg, *_ = setup
     layer = latent_moe.LatentMoELayer(cfg, dense=False)
     rng = np.random.default_rng(11)
-    n = 300
+    n = 600
     x = jnp.asarray(np.abs(rng.normal(size=(n, cfg.hidden_size))), jnp.float32)
     live = jnp.ones((n,), bool)
     p = layer.init(jax.random.key(2), x, live, False, method=layer.ffn)["params"]
@@ -280,7 +280,7 @@ def test_every_token_on_one_expert_drops_none(setup):
     gate[:, 2] = 1.0                     # positive inputs: expert 2 always first
     p = dict(p, gate=jnp.asarray(gate),
              e_score_correction_bias=jnp.zeros((16,), jnp.float32))
-    assert latent_moe.expert_block_rows(n, 4, 16) < n
+    assert latent_moe.expert_tile_rows(n, 4, 16) < n
     for differentiable in (False, True):
         _out, tally = jax.jit(lambda q, d=differentiable: layer.apply(
             {"params": q}, x, live, d, method=layer.ffn))(p)

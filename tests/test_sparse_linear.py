@@ -325,6 +325,22 @@ _OTHER_KINDS = {
 }
 
 
+def assert_the_golden_scores(kind: str, score, bits: list) -> None:
+    """A kind's beam scores against a golden's bits: to the bit, but for the
+    routed-expert kinds, whose held experts are one grouped product a layer
+    since PR 51 (models/experts.py): a token's experts are summed in the
+    order it chose them, no longer in the experts' own, so their scores sit
+    within float32's last bits of the goldens written before it
+    (tests/test_window_moe.py holds them to the bit as that commit gave
+    them)."""
+    got = np.asarray(score, np.float32)
+    if kind in ("latent_moe", "window_moe"):
+        np.testing.assert_allclose(
+            got, np.asarray(bits, np.uint32).view(np.float32), rtol=2e-6)
+    else:
+        assert got.view(np.uint32).tolist() == bits
+
+
 @pytest.mark.parametrize("impl", ["lanes", "reference"])
 @pytest.mark.parametrize("kind", sorted(_OTHER_KINDS))
 def test_the_other_decoders_beam_outputs_are_the_parent_commit_s(kind, impl):
@@ -344,7 +360,7 @@ def test_the_other_decoders_beam_outputs_are_the_parent_commit_s(kind, impl):
         model, p, feats, masks, beam_size=5, beam_impl=impl)[:2])(params)
     want = _golden()[f"{kind}.{impl}"]
     assert np.asarray(tokens).tolist() == want["tokens"]
-    assert np.asarray(score, np.float32).view(np.uint32).tolist() == want["score_bits"]
+    assert_the_golden_scores(kind, score, want["score_bits"])
 
 
 @pytest.mark.parametrize("setup", ["prunes"], indirect=True)

@@ -386,16 +386,19 @@ def _search(model, params, feats, masks, beam, impl="lanes"):
 def test_the_step_for_all_lanes_is_the_vmapped_step_bit_for_bit(
         setup, beam, monkeypatch):
     """``lane_decode_step`` calls this kind's step with all lanes at once;
-    vmapped a lane like the other kinds' (the held experts' loops masked to
-    the longest) it emits the same tokens, scores and counts to the bit."""
+    vmapped a lane like the other kinds' it emits the same tokens and counts
+    to the bit, and the same scores to float32's last bits: a lane's grouped
+    product (models/experts.py) is then one of a batch of products, which the
+    CPU's kernels sum in another order than the one product of all lanes."""
     _cfg, model, params, feats, masks, _labels = setup
     lanes = _search(model, params, feats, masks, beam)
     monkeypatch.setattr(captioner, "ALL_LANES", ())
     vmapped = _search(model, params, feats, masks, beam)
-    for a, b in zip(jax.tree.leaves(lanes), jax.tree.leaves(vmapped)):
+    np.testing.assert_array_equal(np.asarray(lanes[0]), np.asarray(vmapped[0]))
+    np.testing.assert_allclose(np.asarray(lanes[1]), np.asarray(vmapped[1]),
+                               rtol=2e-6)
+    for a, b in zip(jax.tree.leaves(lanes[2]), jax.tree.leaves(vmapped[2])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert np.asarray(lanes[1]).view(np.uint32).tolist() == \
-        np.asarray(vmapped[1]).view(np.uint32).tolist()
 
 
 @pytest.mark.parametrize("beam", [1, 3, 5])
@@ -480,23 +483,38 @@ def test_beam_outputs_are_the_golden_s(kind, impl):
     out of ``latent_moe`` and changed no bit), this kind's as this commit
     does (tests/golden_beam_pr45.json, written by running these lines on the
     parent commit and, for ``window_moe``, on this one; test_eva.py holds the
-    LSTM and the sparse/linear kind to tests/golden_beam_pr41.json)."""
+    LSTM and the sparse/linear kind to tests/golden_beam_pr41.json). Since
+    PR 51 the two routed-expert kinds' tokens are those and their scores
+    float32's last bits from those (``assert_the_golden_scores``)."""
+    from test_sparse_linear import assert_the_golden_scores
+
     model, params, feats, masks, _labels = _six_clips(_golden_kinds()[kind])
     tokens, score = jax.jit(lambda p: beam_search(
         model, p, feats, masks, beam_size=5, beam_impl=impl)[:2])(params)
     want = _golden("golden_beam_pr45.json")[f"{kind}.{impl}"]
     assert np.asarray(tokens).tolist() == want["tokens"]
-    assert np.asarray(score, np.float32).view(np.uint32).tolist() == want["score_bits"]
+    assert_the_golden_scores(kind, score, want["score_bits"])
+    # the routed-expert kinds' bits as PR 51's grouped product gives them
+    # (tests/golden_beam_pr51.json, written by running these lines on it)
+    now = _golden("golden_beam_pr51.json").get(f"{kind}.{impl}", want)
+    assert np.asarray(score, np.float32).view(np.uint32).tolist() == now["score_bits"]
 
 
 def test_latent_moe_is_bit_identical_after_its_expert_layer_moved():
-    """Teacher forcing (the experts' walk in its differentiable spelling) and
-    prefill then twelve steps (the traced trip counts), as the parent commit
-    computed them: every bit of the logits (a hash) and the steps' tallies."""
+    """Teacher forcing (the grouped product in its differentiable spelling)
+    and prefill then twelve steps, as PR 51's commit computed them: every bit
+    of the logits (a hash) and the steps' tallies
+    (tests/golden_beam_pr51.json); the first logits within float32's last
+    bits of what the commit before the expert layer moved gave, and the
+    tallies the same (tests/golden_beam_pr45.json)."""
     model, params, feats, masks, labels = _six_clips(_golden_kinds()["latent_moe"])
-    want = _golden("golden_beam_pr45.json")
+    was, want = _golden("golden_beam_pr45.json"), _golden("golden_beam_pr51.json")
     logits = np.asarray(jax.jit(model.apply)(params, feats, masks, labels), np.float32)
-    assert list(logits.shape) == want["latent_moe.call"]["shape"]
+    assert list(logits.shape) == want["latent_moe.call"]["shape"] == \
+        was["latent_moe.call"]["shape"]
+    np.testing.assert_allclose(
+        logits[0, 0, :8], np.asarray(was["latent_moe.call"]["first_row_bits"],
+                                     np.uint32).view(np.float32), rtol=1e-5)
     assert logits[0, 0, :8].view(np.uint32).tolist() == \
         want["latent_moe.call"]["first_row_bits"]
     assert hashlib.sha256(logits.tobytes()).hexdigest() == \
@@ -515,7 +533,8 @@ def test_latent_moe_is_bit_identical_after_its_expert_layer_moved():
     logits, routed = jax.jit(through_the_cache)(params)
     assert hashlib.sha256(np.asarray(logits, np.float32).tobytes()).hexdigest() \
         == want["latent_moe.steps"]["sha256"]
-    assert np.asarray(routed).tolist() == want["latent_moe.steps"]["routed"]
+    assert np.asarray(routed).tolist() == want["latent_moe.steps"]["routed"] == \
+        was["latent_moe.steps"]["routed"]
 
 
 # ---- the chip's share ------------------------------------------------------------
